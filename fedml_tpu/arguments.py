@@ -71,10 +71,6 @@ _SCHEMA: Dict[str, Any] = {
     "clients_per_device": None,  # schedule width; derived if None
     "precision": "float32",      # or "bfloat16" for the compute path
     "rounds_per_dispatch": 8,    # fused-block length (rounds per dispatch)
-    # opt-in persistent XLA compilation cache: repeat runs skip the fused-
-    # program compile that dominates short-run wall time (time-to-accuracy
-    # benches). Off (None) by default — identical behavior to before.
-    "compile_cache_dir": None,
     # auto: defended rounds fuse train->attack->defense->CDP->server into
     # ONE dispatch whenever the sharded defense path applies; host forces
     # the 3-dispatch host-orchestrated pipeline; fused refuses configs

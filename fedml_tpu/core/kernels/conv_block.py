@@ -13,16 +13,22 @@ intermediate activations never leave VMEM. Design notes:
 * Convolutions are 9 shifted matmuls on the spatially pre-padded input
   (``acc += x_pad[:, dy:dy+H, dx:dx+W, :] @ w[dy, dx]``) — MXU dots with
   ``preferred_element_type=f32``, no conv primitive inside the kernel.
-* Stride-2 blocks compute the stride-1 output and subsample: a SAME-padded
-  3x3 stride-2 conv equals the stride-1 SAME conv sampled at odd positions
-  for even extents (pad_lo 0 vs 1 cancels) and even positions for odd
-  extents; the 1x1 projection samples even positions for both parities.
-  Only 2 of ResNet-56's 27 blocks are strided, so the extra full-res conv
-  work is noise next to the saved elementwise HBM traffic.
+* Stride-2 blocks compute the stride-1 output and keep every second
+  position. The halo is padded the way SAME pads a stride-2 window —
+  (0, 2) on an even extent, (1, 1) on an odd one — so the kept positions
+  are always the EVEN ones, for the 3x3 conv and the 1x1 projection
+  alike: Mosaic refuses a reduction over a value whose layout carries the
+  sublane offset an odd-position pick leaves behind. Only 2 of
+  ResNet-56's 27 blocks are strided, so the extra full-res conv work is
+  noise next to the saved elementwise HBM traffic.
 * GroupNorm statistics are computed in f32 with the same one-pass
-  ``max(0, E[x^2] - E[x]^2)`` formula as flax, per sample per group.
-* ``interpret=True`` off-TPU (the repo-wide ``_interp`` idiom from
-  ``llm/attention.py``) keeps tier-1 parity tests runnable on CPU.
+  ``max(0, E[x^2] - E[x]^2)`` formula as flax, per sample per group:
+  per-channel sums, then a ``[c, groups]`` membership matmul, so no
+  reshape splits the lane (channel) dimension — Mosaic has no layout for
+  that shape cast.
+* Interpreted off-TPU (``core.kernels.interpret``) so the tier-1 parity
+  tests run on CPU; ``tests/test_chip_compile.py`` AOT-compiles every
+  ResNet-56 stage geometry for the v5e.
 * The backward pass is a ``custom_vjp`` that RECOMPUTES the block via
   ``jax.vjp`` of :func:`reference_block` — residual-recompute semantics:
   no intermediate activations are saved, and gradients are exactly the
@@ -42,10 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # CPU wheels may lack the TPU extension; interpret mode needs none
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from . import interpret, tpu_compiler_params
 
 #: flax GroupNorm default epsilon — the unfused path's value
 GN_EPS = 1e-6
@@ -59,16 +62,6 @@ MAX_FUSED_CHANNELS = 64
 DEFAULT_BLOCK_N = 8
 
 Params = Dict[str, Any]
-
-
-def _interp() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _compiler_params():
-    if _interp() or pltpu is None:
-        return None
-    return pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
 
 
 # ---------------------------------------------------------------------------
@@ -124,10 +117,16 @@ def reference_block(x, params: Params, *, strides: int = 1, groups: int = 8,
 # Pallas kernel.
 
 
-def _subsample2(y, off_h: int, off_w: int):
-    """Static stride-2 subsample along H and W starting at the given
-    offsets, via pad+reshape (Mosaic-friendly: no strided slicing)."""
-    for axis, off in ((1, off_h), (2, off_w)):
+def _stride2_pad(extent: int, strides: int):
+    """SAME halo of a 3-wide window along one axis: (0, 2) for a stride-2
+    window over an even extent, (1, 1) otherwise."""
+    return (0, 2) if strides == 2 and extent % 2 == 0 else (1, 1)
+
+
+def _subsample2(y):
+    """Keep the even positions along H and W, via pad+reshape
+    (Mosaic-friendly: no strided slicing)."""
+    for axis in (1, 2):
         shape = list(y.shape)
         if shape[axis] % 2:
             pads = [(0, 0)] * y.ndim
@@ -136,7 +135,7 @@ def _subsample2(y, off_h: int, off_w: int):
             shape[axis] += 1
         new_shape = shape[:axis] + [shape[axis] // 2, 2] + shape[axis + 1:]
         idx = [slice(None)] * (y.ndim + 1)
-        idx[axis + 1] = off
+        idx[axis + 1] = 0
         y = y.reshape(new_shape)[tuple(idx)]
     return y
 
@@ -154,9 +153,8 @@ def _block_kernel(*refs, strides: int, groups: int, eps: float, h: int,
     bn = xp.shape[0]
     ho = -(-h // strides)
     wo = -(-w // strides)
-    # stride-2 = stride-1 sampled at parity-dependent offsets (see module
-    # docstring): odd positions for even extents, even for odd extents
-    off_h, off_w = (h % 2 == 0), (w % 2 == 0)
+    lo_h = _stride2_pad(h, strides)[0]
+    lo_w = _stride2_pad(w, strides)[0]
 
     def conv3(xpad, w_ref, hh, ww):
         cin = xpad.shape[-1]
@@ -173,24 +171,35 @@ def _block_kernel(*refs, strides: int, groups: int, eps: float, h: int,
 
     def gn(y, s_ref, b_ref):
         _, hh, ww, c = y.shape
-        yg = y.reshape(bn, hh * ww, groups, c // groups)
-        mean = jnp.mean(yg, axis=(1, 3), keepdims=True)
-        mean2 = jnp.mean(yg * yg, axis=(1, 3), keepdims=True)
+        cg = c // groups
+        y3 = y.reshape(bn, hh * ww, c)
+        ch = jax.lax.broadcasted_iota(jnp.int32, (c, groups), 0)
+        gr = jax.lax.broadcasted_iota(jnp.int32, (c, groups), 1)
+        member = (ch // cg == gr).astype(f32)         # [c, groups]
+        cnt = float(hh * ww * cg)
+
+        def per_channel(stat):  # [bn, c] sums -> group mean, per channel
+            g = jnp.dot(stat, member, preferred_element_type=f32) / cnt
+            return jnp.dot(g, member.T, preferred_element_type=f32)
+
+        mean = per_channel(jnp.sum(y3, axis=1))
+        mean2 = per_channel(jnp.sum(y3 * y3, axis=1))
         var = jnp.maximum(mean2 - mean * mean, 0.0)
-        yn = ((yg - mean) * jax.lax.rsqrt(var + eps)).reshape(bn, hh, ww, c)
-        return yn * s_ref[...].astype(f32) + b_ref[...].astype(f32)
+        yn = (y3 - mean[:, None, :]) * jax.lax.rsqrt(var + eps)[:, None, :]
+        yn = yn * s_ref[...].astype(f32) + b_ref[...].astype(f32)
+        return yn.reshape(bn, hh, ww, c)
 
     y = conv3(xp, w1_ref, h, w)
     if strides == 2:
-        y = _subsample2(y, int(off_h), int(off_w))
+        y = _subsample2(y)
     y = jnp.maximum(gn(y, g1s_ref, g1b_ref), 0.0)
     yp = jnp.pad(y, ((0, 0), (1, 1), (1, 1), (0, 0)))
     y2 = gn(conv3(yp, w2_ref, ho, wo), g2s_ref, g2b_ref)
 
-    x_core = xp[:, 1:1 + h, 1:1 + w, :]
+    x_core = xp[:, lo_h:lo_h + h, lo_w:lo_w + w, :]
     if has_proj:
-        if strides == 2:  # 1x1 stride-2 samples EVEN positions always
-            x_core = _subsample2(x_core, 0, 0)
+        if strides == 2:
+            x_core = _subsample2(x_core)
         cin = x_core.shape[-1]
         cout = wp_ref.shape[-1]
         r = jnp.dot(x_core.reshape(bn * ho * wo, cin),
@@ -211,7 +220,8 @@ def _pallas_block(x, params: Params, strides: int, groups: int, eps: float,
     bn = max(1, min(int(block_n), n))
     n_pad = -(-n // bn) * bn
     # host-side spatial pre-pad (SAME halo) + batch pad to the grid
-    xp = jnp.pad(x, ((0, n_pad - n), (1, 1), (1, 1), (0, 0)))
+    xp = jnp.pad(x, ((0, n_pad - n), _stride2_pad(h, strides),
+                     _stride2_pad(w, strides), (0, 0)))
     has_proj = "wp" in params
 
     def row2(a):  # [c] GN params as [1, c]: TPU refs want >= 2D
@@ -239,9 +249,13 @@ def _pallas_block(x, params: Params, strides: int, groups: int, eps: float,
         grid=(n_pad // bn,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bn, ho, wo, cout), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, ho, wo, cout), x.dtype),
-        interpret=_interp(),
-        compiler_params=_compiler_params(),
+        # the reference's dtype: bf16 activations against f32 params
+        # promote to f32, and the backward recompute expects that cotangent
+        out_shape=jax.ShapeDtypeStruct(
+            (n_pad, ho, wo, cout),
+            jnp.result_type(x.dtype, *(v.dtype for v in params.values()))),
+        interpret=interpret(),
+        compiler_params=tpu_compiler_params(),
     )(*inputs)
     return out[:n] if n_pad != n else out
 
@@ -271,7 +285,7 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 
 def fused_block(x, params: Params, *, strides: int = 1, groups: int = 8,
                 eps: float = GN_EPS):
-    """The fused BasicBlock: Pallas forward (interpret mode off-TPU),
+    """The fused BasicBlock: Pallas forward (interpreted off-TPU),
     reference-recompute backward. Same signature/params as
     :func:`reference_block`; parity within f32 round-off."""
     return _fused(x, params, int(strides), int(groups), float(eps))

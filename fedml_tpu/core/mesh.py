@@ -13,6 +13,7 @@ them to ICI/DCN transfers; there is no NCCL/MPI plumbing to manage.
 
 from __future__ import annotations
 
+import logging
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -21,6 +22,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..constants import AXIS_CLIENT, AXIS_DATA, AXIS_FSDP, AXIS_TENSOR
+
+logger = logging.getLogger(__name__)
 
 
 def build_mesh(
@@ -47,6 +50,11 @@ def build_mesh(
     if math.prod(sizes) != n:
         raise ValueError(f"mesh shape {dict(zip(names, sizes))} != {n} devices")
     dev_array = np.asarray(devices).reshape(sizes)
+    # every engine run names the devices it landed on: the mesh takes
+    # whatever jax.devices() returns, and JAX itself falls back to the
+    # CPU when no accelerator initialises
+    logger.info("mesh %s over %d x %s (%s)", dict(zip(names, sizes)), n,
+                devices[0].device_kind, devices[0].platform)
     return Mesh(dev_array, axis_names=tuple(names))
 
 

@@ -26,7 +26,6 @@ default path measures nothing it didn't before.
 
 from __future__ import annotations
 
-import contextlib
 import logging
 from typing import Any, Optional
 
@@ -34,13 +33,24 @@ from . import metrics as obs_metrics
 
 logger = logging.getLogger(__name__)
 
-# bf16 peak TFLOP/s per chip, by device-kind substring (public specs).
-# Single source of truth — bench.py imports this table, so the bench's
-# MFU and the profiling plane's gauge can never disagree on peaks.
-PEAK_TFLOPS_BF16 = (
-    ("v6", 918.0), ("v5p", 459.0), ("v5e", 197.0), ("v5", 197.0),
-    ("v4", 275.0), ("v3", 123.0), ("v2", 45.0), ("cpu", 0.5),
-)
+# Per-chip peaks by the EXACT ``device_kind`` JAX reports: (bf16 TFLOP/s,
+# HBM GB/s), from Google Cloud's published TPU specifications. The one
+# table: bench.py and the roofline balance read it through
+# ``peak_tflops`` / ``hbm_gbps``, so the bench's MFU, the profiling gauge
+# and the roofline cannot disagree. A v5e reports "TPU v5 lite" and a v5p "TPU v5"; matching by
+# substring would hand one the other's peak. A TPU kind that is not here
+# has no peak (None), never a neighbour's. The "cpu" row is a nominal host
+# figure that lets the off-chip tests exercise the MFU/roofline plumbing;
+# the roofline record flags it ``static_only``.
+DEVICE_PEAKS = {
+    "TPU v2": (45.0, 700.0),
+    "TPU v3": (123.0, 900.0),
+    "TPU v4": (275.0, 1228.0),
+    "TPU v5 lite": (197.0, 819.0),
+    "TPU v5": (459.0, 2765.0),
+    "TPU v6 lite": (918.0, 1640.0),
+    "cpu": (0.5, 25.0),
+}
 
 _cfg = {"device": False}
 
@@ -56,11 +66,13 @@ def device_profiling_enabled() -> bool:
 def peak_tflops(device) -> Optional[float]:
     """Per-chip bf16 peak for a jax device, or None for unknown kinds
     (report MFU as null, never a guess)."""
-    kind = str(getattr(device, "device_kind", "cpu")).lower()
-    for key, peak in PEAK_TFLOPS_BF16:
-        if key in kind:
-            return peak
-    return None
+    return DEVICE_PEAKS.get(str(device.device_kind), (None, None))[0]
+
+
+def hbm_gbps(device) -> Optional[float]:
+    """Per-chip HBM bandwidth for a jax device, or None for unknown
+    kinds."""
+    return DEVICE_PEAKS.get(str(device.device_kind), (None, None))[1]
 
 
 def mfu_value(flops: float, wall_s: float, n_devices: int,
@@ -84,13 +96,9 @@ def mfu_value(flops: float, wall_s: float, n_devices: int,
 
 
 def trace_annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` when available (names dispatch
-    regions in a TensorBoard/XPlane trace), else a null context."""
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # older jax or no profiler backend
-        return contextlib.nullcontext()
+    """Names a dispatch region in a profiler (XPlane) trace."""
+    import jax
+    return jax.profiler.TraceAnnotation(name)
 
 
 def sample_hbm_peak_gb() -> Optional[float]:

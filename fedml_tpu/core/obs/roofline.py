@@ -9,8 +9,8 @@ compiles, it walks the optimized HLO (plus ``compiled.cost_analysis()``
 
 * operand/output shapes and analytical FLOPs + bytes accessed,
 * arithmetic intensity and a compute- vs memory-bound classification
-  against a per-device-kind machine-balance table (:data:`HBM_GBPS`
-  extends :data:`profiler.PEAK_TFLOPS_BF16` with memory bandwidth),
+  against the per-device-kind peaks table
+  (:data:`profiler.DEVICE_PEAKS`: bf16 FLOP/s and HBM bandwidth),
 * a roofline-predicted execution time (``max(flops/peak, bytes/bw)``)
   and its share of the program's predicted device time, plus a
   predicted whole-program MFU,
@@ -62,17 +62,10 @@ from . import profiler as obs_profiler
 
 logger = logging.getLogger(__name__)
 
-# ---------------------------------------------------------------------------
-# machine balance: HBM GB/s per device kind, keyed like PEAK_TFLOPS_BF16
-# (public specs). Together the two tables give the machine balance
-# (flops/byte) every op's arithmetic intensity classifies against. The
-# "cpu" entry is a NOMINAL host-memory figure so a laptop/CI run still
-# produces a ranked table — flagged static-only, never trusted as a
-# measurement.
-HBM_GBPS = (
-    ("v6", 1640.0), ("v5p", 2765.0), ("v5e", 819.0), ("v5", 819.0),
-    ("v4", 1228.0), ("v3", 900.0), ("v2", 700.0), ("cpu", 25.0),
-)
+# machine balance: peak FLOP/s and HBM bandwidth both come from the one
+# table, ``profiler.DEVICE_PEAKS``, keyed by exact ``device_kind``;
+# together they give the balance (flops/byte) every op's arithmetic
+# intensity classifies against.
 
 _BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1,
           "f8e5m2": 1, "s64": 8, "u64": 8, "s32": 4, "u32": 4,
@@ -90,14 +83,6 @@ def set_default_enabled(on: bool) -> None:
 
 def default_enabled() -> bool:
     return _cfg["default_enabled"]
-
-
-def hbm_gbps(device) -> Optional[float]:
-    kind = str(getattr(device, "device_kind", "cpu")).lower()
-    for key, bw in HBM_GBPS:
-        if key in kind:
-            return bw
-    return None
 
 
 @dataclass
@@ -125,10 +110,10 @@ def machine_balance(device=None) -> MachineBalance:
     if device is None:
         import jax
         device = jax.devices()[0]
-    kind = str(getattr(device, "device_kind", "cpu")).lower()
+    kind = str(device.device_kind)
     peak = obs_profiler.peak_tflops(device)
-    bw = hbm_gbps(device)
-    static = ("cpu" in kind) or peak is None or bw is None
+    bw = obs_profiler.hbm_gbps(device)
+    static = kind == "cpu" or peak is None or bw is None
     if static and not _static_warned[0]:
         _static_warned[0] = True
         logger.warning(
@@ -155,6 +140,7 @@ _GROUPS_RE = re.compile(r"replica_groups=\{\{([0-9,]+)\}")
 _METADATA_RE = re.compile(r'metadata=\{[^}]*op_name="([^"]*)"')
 _CALL_RE = re.compile(r"(?:calls|to_apply|body)=%?([\w.\-]+)")
 _COND_RE = re.compile(r"condition=%?([\w.\-]+)")
+_OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
 
 
 def _parse_shapes(text: str) -> List[Tuple[str, Tuple[int, ...]]]:
@@ -207,6 +193,20 @@ def _split_operands(line: str, start: int) -> Tuple[str, str]:
     return line[start + 1:], ""
 
 
+def _resolve_operand_shapes(ops: List[HloOp]) -> None:
+    """XLA prints an operand as its shape and name (``f32[8,16]{1,0} %a``)
+    or, since the jax 0.9 line, as the name alone (``%a``). Where no
+    inline shape was printed, look the names up in the computation's own
+    instruction table — every operand is an instruction (or parameter)
+    of the same computation."""
+    table = {op.name: op.out_shapes for op in ops}
+    for op in ops:
+        if op.operand_shapes:
+            continue
+        for name in _OPERAND_NAME_RE.findall(op.operand_text):
+            op.operand_shapes.extend(table.get(name, ()))
+
+
 def parse_hlo(text: str) -> Tuple[Dict[str, List[HloOp]], Optional[str]]:
     """Parse optimized HLO text into ``{computation: [HloOp]}`` plus the
     entry computation's name. Tolerant: unmatched lines are skipped."""
@@ -222,6 +222,8 @@ def parse_hlo(text: str) -> Tuple[Dict[str, List[HloOp]], Optional[str]]:
                 entry = name
             continue
         if line.startswith("}"):
+            if cur is not None:
+                _resolve_operand_shapes(cur)
             cur = None
             continue
         if cur is None:

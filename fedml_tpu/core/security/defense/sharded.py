@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ...jax_compat import shard_map
 from . import robust_agg
 
 PyTree = Any
@@ -781,7 +780,7 @@ def _build_sharded_fn(mesh: Mesh, axis: str, defense_type: str,
     out_specs = (P(axis), state_spec, P())
     if return_matrix:
         out_specs = out_specs + (P(None, axis),)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis), P(), P(), P(), P(), state_spec, P(), P()),
         out_specs=out_specs,

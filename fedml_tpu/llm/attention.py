@@ -32,6 +32,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..core import kernels
+
 NEG_INF = -1e30
 
 # (axis_name, axis_size) for ring attention; set by the sequence-parallel
@@ -256,23 +258,6 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, mask_ref, do_ref, lse_ref,
     dv_ref[:] = dv.astype(dv_ref.dtype)
 
 
-def _interp():
-    return jax.default_backend() != "tpu"
-
-
-def _compiler_params():
-    """Raise the Mosaic scoped-VMEM cap above the 16 MiB default: the
-    kernels keep the full-length K/V refs resident, and at seq 8192 with
-    d=128 that sits a few hundred KiB over the default cap. v5e/v4 chips
-    have 128 MiB of VMEM; 64 MiB keeps headroom for double-buffering and
-    admits sequences to ~64k on one chip (ring attention shards beyond
-    that). None in interpret mode (TPU-only knob)."""
-    if _interp():
-        return None
-    import jax.experimental.pallas.tpu as pltpu
-    return pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
-
-
 def _flash_fwd(q, k, v, mask, block_q: int, block_k: int):
     import jax.experimental.pallas as pl
 
@@ -300,8 +285,8 @@ def _flash_fwd(q, k, v, mask, block_q: int, block_k: int):
             jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32),
         ],
-        interpret=_interp(),
-        compiler_params=_compiler_params(),
+        interpret=kernels.interpret(),
+        compiler_params=kernels.tpu_compiler_params(),
     )(qf, kf, vf, mask)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3), lse
 
@@ -335,8 +320,8 @@ def _flash_bwd(q, k, v, mask, o, lse, g, block_q: int, block_k: int):
         ],
         out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
-        interpret=_interp(),
-        compiler_params=_compiler_params(),
+        interpret=kernels.interpret(),
+        compiler_params=kernels.tpu_compiler_params(),
     )(qf, kf, vf, mask, gf, lse, dd)
 
     dk, dv = pl.pallas_call(
@@ -360,8 +345,8 @@ def _flash_bwd(q, k, v, mask, o, lse, g, block_q: int, block_k: int):
             jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         ],
-        interpret=_interp(),
-        compiler_params=_compiler_params(),
+        interpret=kernels.interpret(),
+        compiler_params=kernels.tpu_compiler_params(),
     )(kf, vf, qf, mask, gf, lse, dd)
 
     unflat = lambda a: a.reshape(b, h, s, d).transpose(0, 2, 1, 3)

@@ -36,6 +36,15 @@ def llm_config_from_args(args) -> LLMConfig:
     ``ModelArguments``, ``train/llm/configurations.py:156``)."""
     precision = str(getattr(args, "precision", "float32")).lower()
     dtype = "bfloat16" if precision in ("bf16", "bfloat16") else "float32"
+    # default: the fused Pallas flash kernels on TPU (O(s·block) memory in
+    # both directions), dense elsewhere (interpret-mode flash is for
+    # tests, not training)
+    impl = getattr(args, "llm_attention_impl", None)
+    chosen = str(impl or ("flash" if jax.default_backend() == "tpu"
+                          else "dense"))
+    logger.info("attention impl %r (%s, backend %s)", chosen,
+                "configured" if impl else "platform default",
+                jax.default_backend())
     return LLMConfig(
         vocab_size=int(getattr(args, "llm_vocab_size", ByteTokenizer.vocab_size)),
         hidden_size=int(getattr(args, "llm_hidden_size", 128)),
@@ -45,12 +54,7 @@ def llm_config_from_args(args) -> LLMConfig:
         num_kv_heads=getattr(args, "llm_num_kv_heads", None),
         max_seq_len=int(getattr(args, "llm_max_seq_len", 128)),
         dtype=dtype,
-        # default: the fused Pallas flash kernels on TPU (O(s·block) memory
-        # in both directions), dense elsewhere (interpret-mode flash is for
-        # tests, not training)
-        attention_impl=str(getattr(args, "llm_attention_impl", None)
-                           or ("flash" if jax.default_backend() == "tpu"
-                               else "dense")),
+        attention_impl=chosen,
     )
 
 

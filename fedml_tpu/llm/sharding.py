@@ -123,7 +123,6 @@ def make_ring_forward(model_apply: Callable, mesh: Mesh,
     logits`` (sharded on the sequence axis); ``attn_mask`` is a [b, S]
     key-padding mask (1 = real token) sharded over ``sp`` alongside the
     tokens — it rotates with K/V inside ring attention."""
-    from ..core.jax_compat import shard_map
 
     size = mesh.shape[axis_name]
 
@@ -131,7 +130,7 @@ def make_ring_forward(model_apply: Callable, mesh: Mesh,
         with ring_axis(axis_name, size):
             return model_apply(params, tokens, attn_mask)
 
-    fwd = shard_map(
+    fwd = jax.shard_map(
         local_fwd, mesh=mesh,
         in_specs=(P(), P(None, axis_name), P(None, axis_name)),
         out_specs=P(None, axis_name, None),

@@ -88,7 +88,6 @@ from ...core.async_rounds import (adaptive_staleness_cap, buffer_k_from_args,
 from ...core.algframe.types import TrainHyper
 from ...core.chaos import ChaosCrash
 from ...core.collectives import psum_tree, vector_to_tree_like
-from ...core.jax_compat import shard_map
 from ...core.security.defense import sharded as sharded_defense
 from ...core.selection import slot_placement
 from ..sampling import build_schedule
@@ -319,7 +318,7 @@ class AsyncBufferedSimulator(TPUSimulator):
             return (new_params, new_sstate, states, rows_mat, metrics,
                     slot_mets)
 
-        shard_fn = shard_map(
+        shard_fn = jax.shard_map(
             pour_body,
             mesh=self.mesh,
             in_specs=(P(), P(), P(AXIS_CLIENT), P(AXIS_CLIENT),
@@ -420,7 +419,7 @@ class AsyncBufferedSimulator(TPUSimulator):
             return (new_params, new_sstate, states, rows_mat, metrics,
                     slot_mets, new_dstate, verdict, new_ring)
 
-        shard_fn = shard_map(
+        shard_fn = jax.shard_map(
             pour_body,
             mesh=self.mesh,
             in_specs=(P(), P(), P(AXIS_CLIENT), P(AXIS_CLIENT),

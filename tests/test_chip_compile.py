@@ -1,0 +1,101 @@
+"""AOT pre-flight: every Pallas kernel in the tree must compile for the
+chip it was written for, checked here on the CPU.
+
+The installed libtpu can compile for a TPU v5e without one:
+``jax.experimental.topologies`` describes a ``v5e:2x2`` host, and
+``core.kernels.compile_for_tpu`` makes the kernels lower for Mosaic
+although the default backend is the CPU. A Mosaic refusal therefore fails
+tier-1 instead of costing chip time. A missing topology is a failure, not
+a skip: without it nothing here says anything about the chip.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+from fedml_tpu.core import kernels
+from fedml_tpu.core.kernels.conv_block import fused_block
+from fedml_tpu.core.obs import roofline
+from fedml_tpu.llm.attention import flash_causal_attention
+
+pytestmark = pytest.mark.pallas
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    assert len(topo.devices) == 4
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices[0]
+
+
+def _compile(fn, device, *avals):
+    s = SingleDeviceSharding(device)
+    with kernels.compile_for_tpu():
+        return jax.jit(fn, in_shardings=s, out_shardings=s).lower(
+            *avals).compile()
+
+
+def _flash_train(q, k, v):
+    return jax.value_and_grad(
+        lambda q, k, v: flash_causal_attention(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("shape", [(8, 1024, 8, 128), (1, 8192, 8, 128)])
+def test_flash_fwd_bwd_compiles_for_v5e(v5e, shape):
+    """The two chip_smoke shapes: the bench_llm_mfu step and the long
+    context one, where K/V residency needs the raised VMEM limit."""
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    compiled = _compile(_flash_train, v5e, q, q, q)
+    # forward, dQ and dK/dV kernels, compiled by Mosaic — not interpreted
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_flash_bwd_never_materializes_scores(v5e):
+    """Training-memory contract: at s=4096 the compiled fwd+bwd must not
+    allocate an [s, s] f32 buffer (64 MiB); flash peak temp stays under a
+    quarter of that."""
+    s, d = 4096, 64
+    q = jax.ShapeDtypeStruct((1, s, 1, d), jnp.bfloat16)
+    mem = _compile(_flash_train, v5e, q, q, q).memory_analysis()
+    scores_bytes = s * s * 4
+    assert mem.temp_size_in_bytes < scores_bytes // 4, (
+        f"temp {mem.temp_size_in_bytes} vs scores {scores_bytes}")
+
+
+# ResNet-56's three stages at the flagship batch, and the strided
+# transitions between them (the larger one costs 5 s: full gate only)
+@pytest.mark.parametrize("hw,cin,c,strides", [
+    (32, 16, 16, 1), (16, 32, 32, 1), (8, 64, 64, 1), (16, 32, 64, 2),
+    pytest.param(32, 16, 32, 2, marks=pytest.mark.slow)])
+def test_conv_block_compiles_for_v5e(v5e, hw, cin, c, strides):
+    shapes = {"w1": (3, 3, cin, c), "w2": (3, 3, c, c)}
+    for g in ("g1", "g2") + (("gp",) if strides == 2 else ()):
+        shapes[g + "_scale"] = shapes[g + "_bias"] = (c,)
+    if strides == 2:
+        shapes["wp"] = (1, 1, cin, c)
+    p = {k: jax.ShapeDtypeStruct(v, jnp.bfloat16) for k, v in shapes.items()}
+    x = jax.ShapeDtypeStruct((32, hw, hw, cin), jnp.bfloat16)
+    compiled = _compile(
+        lambda x, p: fused_block(x, p, strides=strides), v5e, x, p)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_roofline_walker_reads_tpu_hlo_text(v5e):
+    """The static HLO walker on the text of a program compiled for the
+    v5e (tiled layouts, operands printed by name): exact matmul FLOPs,
+    the chip's own row of the peaks table, not static-only."""
+    a = jax.ShapeDtypeStruct((512, 1024), jnp.bfloat16)
+    b = jax.ShapeDtypeStruct((1024, 256), jnp.bfloat16)
+    compiled = _compile(lambda a, b: jnp.tanh(a @ b), v5e, a, b)
+    rec = roofline.analyze_compiled("mm", compiled, device=v5e)
+    assert rec["device_kind"] == "TPU v5 lite" and not rec["static_only"]
+    assert (rec["peak_tflops"], rec["hbm_gbps"]) == (197.0, 819.0)
+    assert rec["total_flops"] == 2 * 512 * 1024 * 256 + 512 * 256
+    assert rec["attributed_share"] == 1.0

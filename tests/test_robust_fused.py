@@ -334,26 +334,6 @@ class TestContributionFusion:
         assert len(sim.contribution.history[0]["contributions"]) == 8
 
 
-class TestCompileCache:
-    def test_compile_cache_dir_knob_wires_jax_config(self, tmp_path):
-        """Opt-in persistent compilation cache: the knob must land in
-        jax.config and create the directory; absent knob changes nothing."""
-        cache = tmp_path / "xla-cache"
-        prev = jax.config.jax_compilation_cache_dir
-        try:
-            args = sim_args(compile_cache_dir=str(cache))
-            sim = build_sim(args)
-            assert jax.config.jax_compilation_cache_dir == str(cache)
-            assert cache.is_dir()
-            sim.run_round(0, hyper_for(args))  # compiles go through cache
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
-
-    def test_compile_cache_off_by_default(self):
-        args = sim_args()
-        assert getattr(args, "compile_cache_dir", None) is None
-
-
 class TestDonation:
     """params/server_state/client_states are donated to the round
     programs; outputs replace them 1:1, and the engine must never touch a

@@ -75,7 +75,6 @@ class TestCostModel:
         import jax
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from fedml_tpu.core.jax_compat import shard_map
         from fedml_tpu.core.obs import roofline
         devs = jax.devices()
         if len(devs) < 2:
@@ -85,7 +84,7 @@ class TestCostModel:
         def body(x, w):
             return jax.lax.psum(x @ w, "d")
 
-        f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("d"), P()),
+        f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("d"), P()),
                               out_specs=P()))
         co = f.lower(jnp.ones((4 * len(devs), 16)),
                      jnp.ones((16, 8))).compile()
@@ -137,17 +136,25 @@ class TestCostModel:
         # full stack = 64 KiB; the window is one 1 KiB row (+ output)
         assert rec["total_bytes"] < 64 * 256 * 4 / 2
 
-    def test_machine_balance_table_is_total(self):
-        """Every peak-TFLOPs device kind has an HBM entry, and a CPU
-        balance is static-only while a TPU one is not."""
+    def test_peaks_table_is_keyed_by_exact_device_kind(self):
+        """One table, looked up by the exact ``device_kind`` the chip
+        reports: the v5e ("TPU v5 lite") must not lend its peak to the
+        v5p ("TPU v5"), an unknown TPU kind has no peak at all, and a
+        CPU balance is static-only while a TPU one is not."""
         from fedml_tpu.core.obs import profiler, roofline
 
         class Dev:
             def __init__(self, kind):
                 self.device_kind = kind
 
-        for key, _peak in profiler.PEAK_TFLOPS_BF16:
-            assert roofline.hbm_gbps(Dev(key)) is not None, key
+        v5e = Dev("TPU v5 lite")
+        assert profiler.peak_tflops(v5e) == 197.0
+        assert profiler.hbm_gbps(v5e) == 819.0
+        assert profiler.peak_tflops(Dev("TPU v5")) != 197.0
+        for kind in ("TPU v5e", "TPU v7", "tpu v5 lite"):
+            assert profiler.peak_tflops(Dev(kind)) is None, kind
+            assert profiler.hbm_gbps(Dev(kind)) is None, kind
+        assert roofline.machine_balance(Dev("TPU v7")).static_only
         cpu = roofline.machine_balance(Dev("cpu"))
         assert cpu.static_only and cpu.flops_per_byte is not None
         v4 = roofline.machine_balance(Dev("TPU v4"))
@@ -161,9 +168,10 @@ class TestEngineCapture:
             self, tmp_path, xla_compile_counter):
         """Real engine run with obs_roofline: every JSONL line validates
         (the replay gate for the new kinds), the round program's record
-        attributes >=90% of predicted time, and the dispatch records
-        still report exactly one compile (the AOT capture is not charged
-        to the dispatch)."""
+        attributes >=90% of predicted time, and the round program is
+        compiled once between the AOT capture and the two dispatches
+        (jax 0.9 hands the captured executable to the dispatch, so the
+        dispatch records report no compile of their own)."""
         from fedml_tpu.core import mlops
         from fedml_tpu.core.obs import roofline, schema
         args = _mk(obs_roofline=True, log_file_dir=str(tmp_path))
@@ -171,8 +179,10 @@ class TestEngineCapture:
         sim = _build_sim(args)
         hyper = _hyper(args)
         sim.run_round(0, hyper)
+        xla_compile_counter.reset()
         sim.run_round(1, hyper)
-        assert sim.dispatch_stats["compiles"] == 1
+        assert xla_compile_counter.delta() == 0
+        assert sim.dispatch_stats["compiles"] == 0
 
         rep = roofline.report("round")
         assert rep is not None
