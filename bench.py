@@ -26,12 +26,9 @@ import json
 import time
 
 
-# MFU comes from the observability layer's profiling plane
-# (core/obs/profiler): the peak table and the MFU formula live there —
-# single source of truth, so the bench's MFU columns and the engine's
-# fed_round_mfu gauge can never disagree. The FLOPs model is unchanged
-# (engine.round_cost_flops), so the BENCH trajectory stays comparable.
-from fedml_tpu.core.obs import metrics as _obs_metrics
+# The peak table and the MFU formula live in core/obs/profiler. The FLOPs
+# model is unchanged (engine.round_cost_flops), so the BENCH trajectory
+# stays comparable.
 from fedml_tpu.core.obs import profiler as _obs_profiler
 
 
@@ -146,17 +143,12 @@ def bench_flagship():
         tpu_sim._donate = True
         tpu_sim._fused_fn = tpu_sim._build_fused_fn()
 
-    # FLOPs of the real (non-padded) work per round, for MFU — computed
-    # by the profiling plane (same formula as the engine's per-round
-    # fed_round_mfu gauge) and recorded there so a bench run's metrics
-    # snapshot carries the flagship MFU too
+    # FLOPs of the real (non-padded) work per round, for MFU
     flops = tpu_sim.round_cost_flops(hyper)
     n_dev = tpu_sim.n_devices
     achieved_tflops = (flops / tpu_round_s) / 1e12 if flops else 0.0
     mfu = _obs_profiler.mfu_value(flops, tpu_round_s, n_dev,
                                   device=jax.devices()[0])
-    if mfu is not None:
-        _obs_metrics.record_round_mfu(mfu, tflops=achieved_tflops)
 
     # --- baseline: golden per-client loop (reference SP architecture),
     # scaled down (8 of 64 clients) then per-sample normalized

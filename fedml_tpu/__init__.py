@@ -31,7 +31,7 @@ import numpy as np
 from .arguments import Arguments, add_args, load_arguments
 from .runner import FedMLRunner
 from . import constants
-from .core import mlops
+from .core import mlops, obs
 
 __version__ = "0.1.0"
 
@@ -89,10 +89,13 @@ def init(args: Optional[Arguments] = None, **overrides: Any) -> Arguments:
     else:
         for k, v in overrides.items():
             setattr(args, k, v)
-    seed = int(getattr(args, "random_seed", 0))
-    random.seed(seed)
-    np.random.seed(seed)
-    mlops.init(args)
+    # the knobs first, so that the span below already obeys obs_tracing
+    obs.configure(args)
+    with obs.span("setup.init", root=True):
+        seed = int(getattr(args, "random_seed", 0))
+        random.seed(seed)
+        np.random.seed(seed)
+        mlops.init(args)
     return args
 
 

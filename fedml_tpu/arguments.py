@@ -343,7 +343,6 @@ _SCHEMA: Dict[str, Any] = {
     # workloads that never cross a round boundary — serving, the
     # cross-device handshake, agents; skips when nothing changed
     "obs_metrics_flush_s": 60.0,
-    "obs_profile_device": False,  # host/device split + per-round MFU
     # compute-plane roofline capture (core/obs/roofline): AOT-compiles
     # each dispatched program once per abstract-shape signature and
     # emits the per-op roofline + collective-traffic record — OPT-IN

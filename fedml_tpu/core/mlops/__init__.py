@@ -202,51 +202,130 @@ def log_selection(round_idx: int, strategy: str,
 
 
 def log_dispatch(name: str, wall_s: float, rounds: int = 1,
-                 compiles: int = 0) -> None:
+                 compiles: int = 0,
+                 phases: Optional[Dict[str, float]] = None) -> None:
     """One device dispatch at the engine seam: host-side wall time of the
     dispatch call, how many FL rounds it carried (fused blocks > 1), and
     how many XLA compiles it triggered (the recompile counter — a steady
-    state of 0 is the invariant; anything else is shape instability)."""
-    obs_metrics.record_dispatch(name, wall_s, rounds, compiles)
+    state of 0 is the invariant; anything else is shape instability).
+    ``phases`` (:func:`compile_phases_since`) feeds the metrics registry
+    the seconds each compile phase took inside the dispatch."""
+    obs_metrics.record_dispatch(name, wall_s, rounds, compiles, phases)
     _emit("dispatch", {"dispatch": name, "wall_s": round(float(wall_s), 6),
                        "rounds": int(rounds), "compiles": int(compiles)})
 
 
 # --- XLA compile counter ---------------------------------------------------
-# Process-wide count of backend compiles, fed by jax.monitoring duration
-# events ('/jax/core/compile/backend_compile_duration' fires once per
-# non-cache-hit compile). Engines snapshot it around dispatches to expose
-# a per-dispatch recompile delta; tests pin it to catch shape-instability
-# regressions that would otherwise recompile silently every round.
+# Process-wide totals of what JAX reports through jax.monitoring about
+# making a program: seconds tracing it to a jaxpr, lowering that to MLIR,
+# compiling it in the backend ('/jax/core/compile/backend_compile_duration'
+# fires once per compile request that misses the in-memory cache, and
+# covers a load from the persistent cache too) and, inside that, loading it
+# from the persistent cache. Engines snapshot the totals around dispatches
+# to expose a per-dispatch recompile delta; tests pin the count to catch
+# shape-instability regressions that would otherwise recompile silently
+# every round. The same seconds land on the innermost span open on the
+# thread that compiled, so a recompile names its dispatch and round.
 
-_compile_counter: Dict[str, Any] = {"count": 0, "installed": False}
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_PHASE_OF_EVENT = {
+    _TRACE_EVENT: "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    _COMPILE_EVENT: "compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+_compile_counter: Dict[str, Any] = {
+    "installed": False, "lock": threading.Lock(),
+    "totals": {"trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+               "cache_load_s": 0.0, "compiles": 0, "cache_hits": 0}}
+# per thread: the outermost traces seen so far as (start, seconds). JAX
+# times a jitted function traced inside another one twice, once on its own
+# and once inside the outer trace's duration, and reports the inner one
+# first; an event therefore swallows the entries that started inside it.
+_traces = threading.local()
+_TRACES_KEPT = 4096
+
+
+def _outermost_trace_s(secs: float) -> float:
+    """The part of a trace event's duration not reported before."""
+    now = time.perf_counter()
+    start = now - secs
+    stack = getattr(_traces, "stack", None)
+    if stack is None:
+        stack = _traces.stack = []
+    inner = 0.0
+    while stack and stack[-1][0] >= start - 5e-5:  # listener latency
+        inner += stack.pop()[1]
+    stack.append((start, secs))
+    if len(stack) > 2 * _TRACES_KEPT:
+        del stack[:-_TRACES_KEPT]
+    return max(secs - inner, 0.0)
+
+
+def _count(key: str, amount) -> None:
+    with _compile_counter["lock"]:
+        _compile_counter["totals"][key] += amount
+    sp = obs_trace.current_span()
+    if sp is not None:
+        sp.add_to_attr(key, amount)
+
+
+def _on_event_duration(event: str, secs: float, **kw) -> None:
+    phase = _PHASE_OF_EVENT.get(event)
+    if phase is None:
+        return
+    if event == _TRACE_EVENT:
+        secs = _outermost_trace_s(secs)
+    elif event == _COMPILE_EVENT:
+        _count("compiles", 1)
+    _count(phase, float(secs))
+
+
+def _on_event(event: str, **kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _count("cache_hits", 1)
 
 
 def install_compile_counter() -> None:
-    """Idempotent: registers the jax.monitoring listener once per
+    """Idempotent: registers the two jax.monitoring listeners once per
     process. Safe to call before any jit runs."""
     if _compile_counter["installed"]:
         return
+    _compile_counter["installed"] = True  # on failure too: don't retry
     try:
         import jax.monitoring as _jm
-
-        def _on_event_duration(event: str, duration: float, **kw) -> None:
-            if event == _COMPILE_EVENT:
-                _compile_counter["count"] += 1
-
         _jm.register_event_duration_secs_listener(_on_event_duration)
-        _compile_counter["installed"] = True
+        _jm.register_event_listener(_on_event)
     except Exception as e:  # pragma: no cover - jax without monitoring
         logger.warning("compile counter unavailable (%s); dispatch "
                        "records will report compiles=0", e)
-        _compile_counter["installed"] = True  # don't retry every round
+
+
+def compile_phases() -> Dict[str, Any]:
+    """What making programs has cost this process since
+    :func:`install_compile_counter`: seconds in ``trace_s`` (nested traces
+    counted once), ``lower_s``, ``compile_s`` (backend compile requests,
+    loads from the persistent cache among them) and ``cache_load_s`` (those
+    loads alone), with the ``compiles`` and ``cache_hits`` counts."""
+    with _compile_counter["lock"]:
+        return dict(_compile_counter["totals"])
+
+
+def compile_phases_since(before: Dict[str, Any]) -> Dict[str, Any]:
+    """The keys of :func:`compile_phases` that moved since the snapshot
+    ``before``, with by how much; empty when nothing was traced, lowered
+    or compiled."""
+    now = compile_phases()
+    return {k: v - before[k] for k, v in now.items() if v != before[k]}
 
 
 def compile_count() -> int:
     """Backend compiles observed so far in this process (0 until
     :func:`install_compile_counter` has run)."""
-    return int(_compile_counter["count"])
+    return int(_compile_counter["totals"]["compiles"])
 
 
 def log_training_status(status: str, run_id: Optional[str] = None) -> None:

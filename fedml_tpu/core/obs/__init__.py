@@ -7,12 +7,14 @@
 2. **Metrics** (:mod:`.metrics`): a typed counter/gauge/histogram
    registry absorbing the scattered one-shot records — wire bytes by
    message type, pour staleness and buffer occupancy, arrival rates,
-   selection decisions, compile count, dispatch wall time, checkpoint
-   flush time, HBM peak, per-round MFU — with Prometheus text exposition
-   and a periodic JSONL snapshot.
-3. **Profiling** (:mod:`.profiler`): per-dispatch host/device wall-time
-   attribution at the engine seam + the FLOPs model as a first-class
-   per-round MFU gauge (opt-in: blocking defeats dispatch overlap).
+   selection decisions, compile count and seconds by phase, dispatch
+   wall time, checkpoint flush time, HBM peak — with Prometheus text
+   exposition and a periodic JSONL snapshot.
+3. **Device facts** (:mod:`.profiler`): the per-chip peaks table with the
+   MFU arithmetic over it, and the device-memory sample the engine takes
+   at the close of every round. Host time lands on the device's timeline
+   through the tracer: a context-manager span is also a
+   ``jax.profiler`` annotation.
 
 4. **Compute plane** (:mod:`.roofline`): per-op roofline attribution of
    compiled programs (opt-in ``obs_roofline`` — one AOT compile per
@@ -26,9 +28,9 @@ plane's records. :mod:`.schema` is the one table every record kind
 validates against.
 
 Knobs (``arguments.py``): tracing + metrics default ON (cheap — spans
-are dicts, metric hooks are dict lookups); ``obs_profile_device``
-defaults OFF. ``configure(args)`` is called by ``mlops.init``; without
-it the defaults apply, so library use without init still traces.
+are dicts, metric hooks are dict lookups). ``configure(args)`` is called
+by ``mlops.init``; without it the defaults apply, so library use without
+init still traces.
 """
 
 from __future__ import annotations
@@ -52,8 +54,6 @@ def configure(args=None) -> None:
     # never fires there, so a crash would lose everything since init
     metrics.set_flush_interval(
         float(getattr(args, "obs_metrics_flush_s", 60.0) or 0.0))
-    profiler.set_device_profiling(
-        bool(getattr(args, "obs_profile_device", False)))
     # compute-plane roofline capture (opt-in: costs one AOT backend
     # compile per program); engines read their own args knob first —
     # this default covers seams without an args object (serving)

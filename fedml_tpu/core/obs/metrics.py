@@ -3,8 +3,8 @@
 Absorbs the scattered one-shot ``mlops.log_*`` numbers into ONE queryable
 surface: wire bytes by message type (fed at the ``Message.encode`` seam),
 pour staleness and buffer occupancy histograms, arrival-rate gauges,
-selection decisions, XLA compile count, dispatch wall time, checkpoint
-flush time, HBM peak, per-round MFU. Two readouts:
+selection decisions, XLA compile count and seconds by phase, dispatch wall
+time, checkpoint flush time, HBM peak. Two readouts:
 
 * :func:`exposition` — Prometheus text format (the de-facto wire format
   for pull-based scrapers; also what a human pastes into an issue);
@@ -327,8 +327,12 @@ def record_wire_stage(msg_type: Any, stage: str, nbytes: int) -> None:
 
 
 def record_dispatch(name: str, wall_s: float, rounds: int,
-                    compiles: int) -> None:
-    """Engine ``_traced`` seam: dispatch wall time + compile counter."""
+                    compiles: int,
+                    phases: Optional[Dict[str, float]] = None) -> None:
+    """Engine ``_traced`` seam: dispatch wall time + compile counter, and
+    the seconds JAX spent in each compile phase inside the dispatch
+    (``mlops.compile_phases``: ``trace_s``, ``lower_s``, ``compile_s``,
+    ``cache_load_s``)."""
     if not _cfg["enabled"]:
         return
     REGISTRY.histogram("fed_dispatch_wall_seconds",
@@ -344,6 +348,12 @@ def record_dispatch(name: str, wall_s: float, rounds: int,
         REGISTRY.counter("fed_xla_compiles_total",
                          "XLA backend compiles observed at dispatch "
                          "seams").inc(int(compiles))
+    for phase, secs in (phases or {}).items():
+        if phase.endswith("_s") and secs > 0:
+            REGISTRY.counter("fed_compile_seconds_total",
+                             "seconds in JAX compile phases at dispatch "
+                             "seams", labels=("phase",)).inc(
+                                 float(secs), phase=phase[:-2])
 
 
 def record_pour(staleness: Sequence[float], buffered: int,
@@ -450,24 +460,20 @@ def record_checkpoint_flush(wall_s: float) -> None:
                        buckets=WALL_BUCKETS).observe(float(wall_s))
 
 
-def record_hbm_peak(gb: float) -> None:
+def record_hbm_peak(in_use_gb: float, reserved_gb: float) -> None:
+    """Fullest device's memory peaks (GiB, process-monotonic): bytes in
+    use, the runtime's reservation for program temporaries, their sum."""
     if not _cfg["enabled"]:
         return
     REGISTRY.gauge("fed_hbm_peak_gb",
-                   "per-device peak HBM (GiB, process-monotonic "
-                   "counter)").set(float(gb))
-
-
-def record_round_mfu(mfu: float, tflops: Optional[float] = None) -> None:
-    """Profiling plane: per-round model FLOPs utilization (same FLOPs
-    model as the bench — ``engine.round_cost_flops``)."""
-    if not _cfg["enabled"]:
-        return
-    REGISTRY.gauge("fed_round_mfu",
-                   "per-round model FLOPs utilization").set(float(mfu))
-    if tflops is not None:
-        REGISTRY.gauge("fed_round_tflops",
-                       "achieved TFLOP/s over the round").set(float(tflops))
+                   "per-device peak HBM in use (GiB, process-monotonic "
+                   "counter)").set(float(in_use_gb))
+    REGISTRY.gauge("fed_hbm_reserved_peak_gb",
+                   "per-device peak HBM reserved for program temporaries "
+                   "(GiB)").set(float(reserved_gb))
+    REGISTRY.gauge("fed_hbm_total_peak_gb",
+                   "per-device peak HBM in use plus reserved "
+                   "(GiB)").set(float(in_use_gb) + float(reserved_gb))
 
 
 def record_roofline(program: str, predicted_mfu: Optional[float],

@@ -1,27 +1,12 @@
-"""Critical-path profiling at the engine dispatch seam.
+"""Device facts: the per-chip peaks table, MFU arithmetic, memory sampling.
 
-The MFU-gap investigation's missing tool: the flagship ``fedavg_resnet56``
-has sat at 6.9% MFU for four bench rounds while ResNet-18 hits 40% on the
-same engine — i.e. the gap is host/input-side, and a single opaque
-``wall_s`` per dispatch cannot localize it. This module splits a
-dispatch's wall time into
-
-* ``host_s`` — the host-side dispatch call (arg staging, trace/lowering,
-  enqueue; jax returns before the device finishes), and
-* ``device_wait_s`` — the tail the host then waits for the device
-  (``block_until_ready``), i.e. device compute not overlapped by host
-  work,
-
-wraps the dispatch in a ``jax.profiler`` annotation (so a TensorBoard
-trace captured around a run carries the same names), and converts the
-engine's existing FLOPs model (``round_cost_flops`` — unchanged, so the
-BENCH trajectory stays comparable) into a per-round MFU gauge + ``kind:
-profile`` JSONL record.
-
-Device profiling is OPT-IN (``obs_profile_device: true``): blocking on
-every dispatch defeats the async-dispatch overlap the engines are built
-around (most of all the async pour's train/aggregate overlap), so the
-default path measures nothing it didn't before.
+What is left of the profiling plane. Host time reaches the device's
+timeline through the tracer (``core/obs/trace.py``: a context-manager span
+is also a ``jax.profiler`` annotation), and the share of the chip's peak a
+round reaches is the benchmark's ``round_mfu`` (``benchmarks/flops/``), so
+nothing here blocks on a dispatch or keeps a FLOPs model. ``bench.py``,
+``chip_smoke.py`` and ``roofline.py`` still read the peaks table and
+``mfu_value``.
 """
 
 from __future__ import annotations
@@ -51,16 +36,6 @@ DEVICE_PEAKS = {
     "TPU v6 lite": (918.0, 1640.0),
     "cpu": (0.5, 25.0),
 }
-
-_cfg = {"device": False}
-
-
-def set_device_profiling(on: bool) -> None:
-    _cfg["device"] = bool(on)
-
-
-def device_profiling_enabled() -> bool:
-    return _cfg["device"]
 
 
 def peak_tflops(device) -> Optional[float]:
@@ -95,61 +70,31 @@ def mfu_value(flops: float, wall_s: float, n_devices: int,
     return achieved_tflops / (peak_tflops_per_chip * max(int(n_devices), 1))
 
 
-def trace_annotation(name: str):
-    """Names a dispatch region in a profiler (XPlane) trace."""
-    import jax
-    return jax.profiler.TraceAnnotation(name)
-
-
 def sample_hbm_peak_gb() -> Optional[float]:
-    """Per-device peak HBM (GiB) from memory_stats, or None off-TPU; the
-    counter is process-monotonic, so deltas attribute intervals."""
-    try:
-        import jax
-        stats = jax.local_devices()[0].memory_stats() or {}
-        peak = stats.get("peak_bytes_in_use")
-        if not peak:
-            return None
-        gb = peak / 2 ** 30
-        obs_metrics.record_hbm_peak(gb)
-        return round(gb, 4)
-    except Exception:
+    """Sample the fullest local device's ``memory_stats()`` into three
+    gauges (GiB): the peak bytes in use, the peak reservation (where the
+    TPU runtime keeps a program's temporaries, outside ``bytes_in_use``),
+    and their sum. Returns the peak in use, or None where the backend
+    keeps no statistics (the CPU's does not; the gauges then stay
+    absent). Both counters are process-monotonic. One call took 1.5
+    microseconds on the v5e's host (PERF.md, PR 26), so the engine takes
+    one at the close of every ``round`` / ``block`` span. A backend that
+    raises from ``memory_stats()`` counts as one that keeps none: a gauge
+    never fails a round."""
+    import jax
+    best = None
+    for dev in jax.local_devices():
+        try:
+            stats = dev.memory_stats()
+        except Exception:
+            stats = None
+        if not stats or "peak_bytes_in_use" not in stats:
+            continue
+        pair = (int(stats["peak_bytes_in_use"]),
+                int(stats.get("peak_bytes_reserved", 0)))
+        if best is None or sum(pair) > sum(best):
+            best = pair
+    if best is None:
         return None
-
-
-def record_dispatch_profile(name: str, rounds: int, host_s: float,
-                            device_wait_s: Optional[float],
-                            flops_per_round: Optional[float],
-                            n_devices: int,
-                            compiles: int = 0) -> Optional[float]:
-    """Emit one ``profile`` record (+ MFU/TFLOPs gauges when the FLOPs
-    model is available). Returns the per-round MFU or None.
-
-    ``total_s = host_s + device_wait_s`` is the honest wall cost of the
-    dispatch when the host blocked (device profiling on); with only
-    ``host_s`` known the MFU is not computed — an enqueue time is not a
-    round time."""
-    total_s = host_s + (device_wait_s or 0.0)
-    mfu = None
-    tflops = None
-    if (flops_per_round and rounds and device_wait_s is not None
-            and total_s > 0):
-        flops = float(flops_per_round) * int(rounds)
-        tflops = (flops / total_s) / 1e12
-        mfu = mfu_value(flops, total_s, n_devices)
-        if mfu is not None:
-            obs_metrics.record_round_mfu(mfu, tflops=tflops)
-    rec = {"dispatch": str(name), "rounds": int(rounds),
-           "host_s": round(float(host_s), 6),
-           "total_s": round(total_s, 6)}
-    if device_wait_s is not None:
-        rec["device_wait_s"] = round(float(device_wait_s), 6)
-    if compiles:
-        rec["compiles"] = int(compiles)
-    if tflops is not None:
-        rec["tflops"] = round(tflops, 4)
-    if mfu is not None:
-        rec["mfu"] = round(mfu, 5)
-    from .. import mlops
-    mlops._emit("profile", rec)
-    return mfu
+    obs_metrics.record_hbm_peak(best[0] / 2 ** 30, best[1] / 2 ** 30)
+    return round(best[0] / 2 ** 30, 4)

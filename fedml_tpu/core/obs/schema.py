@@ -106,6 +106,10 @@ RECORD_SCHEMAS: Dict[str, Dict[str, FieldSpec]] = {
              "parent_id": _f(HEX16, required=True, nullable=True),
              "start_ts": _f(NUM, required=True),
              "end_ts": _f(NUM, required=True),
+             # time.time_ns() at both ends: what relates a span to a
+             # profiler trace (core/obs/trace.py)
+             "start_ns": _f(INT, required=True),
+             "end_ns": _f(INT, required=True),
              "duration_s": _f(NUM, required=True),
              "pid": _f(INT, required=True),
              "attrs": _f(DICT),
@@ -114,15 +118,6 @@ RECORD_SCHEMAS: Dict[str, Dict[str, FieldSpec]] = {
     # core/obs/metrics.py registry flush
     "metrics_snapshot": {"metrics": _f(DICT, required=True),
                          "step": _f(INT, nullable=True)},
-    # core/obs/profiler.py dispatch profile
-    "profile": {"dispatch": _f(STR, required=True),
-                "rounds": _f(INT, required=True),
-                "host_s": _f(NUM, required=True),
-                "total_s": _f(NUM, required=True),
-                "device_wait_s": _f(NUM),
-                "compiles": _f(INT),
-                "tflops": _f(NUM),
-                "mfu": _f(NUM)},
     # mlops.log_health — component health transitions: watchdog trips
     # (status: stalled | nan_logits), serving /healthz state changes
     "health": {"component": _f(STR, required=True),

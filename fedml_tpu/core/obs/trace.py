@@ -15,19 +15,35 @@ pub/sub broker all carry it for free).
 
 Spans are emitted as ``kind: span`` JSONL records through the mlops sink
 on :meth:`Span.end`; ``scripts/trace_report.py`` rebuilds the trees and
-prints the per-round critical path. Tracing is default-ON (it is cheap:
-a span is a dict and one JSONL line; there is no per-op instrumentation)
-and disabled with ``obs_tracing: false`` — every entry point then returns
-the shared no-op span, so instrumented code never branches.
+prints the per-round critical path. A finished span is also kept in a
+bounded in-process ring (:func:`finished`), so a benchmark or a test reads
+the program's own spans without parsing a log. Tracing is default-ON (it
+is cheap: a span is a dict, one ring append and one JSONL line; there is
+no per-op instrumentation) and disabled with ``obs_tracing: false`` —
+every entry point then returns the shared no-op span, so instrumented
+code never branches.
+
+A span used as a context manager additionally opens a
+``jax.profiler.TraceAnnotation("fed.<name>")`` for exactly its lifetime,
+which puts it on the device's timeline in any profiler trace captured
+around the run (TensorBoard, ``benchmarks/tools/program_gaps.py``). With
+no profiler session open the annotation is a flag check. The profiler
+counts nanoseconds from the start of its session and a span stamps
+``time.time_ns()``: the two relate by one constant a session, taken from
+any span present in both (the first ``fed.round`` of the trace).
+TraceMe is thread-scoped, so bare-handle spans (``start_span`` ...
+``end()``, possibly on another thread) get no annotation.
 """
 
 from __future__ import annotations
 
+import collections
 import os
+import random
 import re
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional
 
 # the Message param carrying the W3C context header
 TRACEPARENT_KEY = "traceparent"
@@ -46,8 +62,66 @@ def is_enabled() -> bool:
     return _cfg["enabled"]
 
 
+# A span makes no system call of its own: on the v5e's host one costs 5-6
+# microseconds (PERF.md, PR 26), and ``os.urandom`` for each id plus
+# ``os.getpid`` for each record were three a span. Ids come from a generator
+# seeded from ``os.urandom`` once a process (and again in a forked child,
+# which would otherwise repeat its parent's ids); the pid is kept likewise.
+_ids = random.Random(os.urandom(16))
+_proc = {"pid": os.getpid()}
+
+
+def _after_fork() -> None:
+    _ids.seed(os.urandom(16))
+    _proc["pid"] = os.getpid()
+
+
+os.register_at_fork(after_in_child=_after_fork)
+
+
 def _rand_hex(nbytes: int) -> str:
-    return os.urandom(nbytes).hex()
+    return "%0*x" % (2 * nbytes, _ids.getrandbits(8 * nbytes))
+
+
+# finished spans of this process, oldest first; the oldest fall off
+RING_SIZE = 8192
+_ring: Deque[Dict[str, Any]] = collections.deque(maxlen=RING_SIZE)
+_ring_lock = threading.Lock()
+
+
+def finished(name: Optional[str] = None) -> List[Dict[str, Any]]:
+    """The finished spans still in the ring, oldest first, as the records
+    :meth:`Span.end` emits (``name``, ``trace_id``, ``span_id``,
+    ``parent_id``, ``start_ns``, ``end_ns``, ``attrs`` when there are
+    any, ...); only those called ``name`` when one is given. Empty with
+    ``obs_tracing: false``."""
+    with _ring_lock:
+        recs = list(_ring)
+    if name is None:
+        return recs
+    return [r for r in recs if r["name"] == name]
+
+
+def clear_finished() -> None:
+    """Empty the ring (tests)."""
+    with _ring_lock:
+        _ring.clear()
+
+
+_annotation_cls = None
+
+
+def _annotation(name: str, attrs: Dict[str, Any]):
+    """``jax.profiler.TraceAnnotation("fed.<name>")``, with ``round_idx``
+    as a keyword where the span has it. ``jax.profiler`` is imported at the
+    first span, never with this module, and touches no backend."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    if "round_idx" in attrs:
+        return _annotation_cls("fed." + name, round_idx=attrs["round_idx"])
+    return _annotation_cls("fed." + name)
 
 
 class SpanContext:
@@ -109,8 +183,9 @@ class Span:
     as a bare handle (``start_span`` + ``end()`` — the pair-API shape the
     ``mlops.event`` shim rides)."""
 
-    __slots__ = ("name", "trace_id", "span_id", "parent_id", "start_ts",
-                 "end_ts", "attrs", "events", "links", "_lock", "_active")
+    __slots__ = ("name", "trace_id", "span_id", "parent_id", "start_ns",
+                 "end_ns", "attrs", "events", "links", "_lock", "_active",
+                 "_annotation")
 
     def __init__(self, name: str, trace_id: str, parent_id: Optional[str],
                  attrs: Optional[Dict[str, Any]] = None):
@@ -118,8 +193,8 @@ class Span:
         self.trace_id = trace_id
         self.span_id = _rand_hex(8)
         self.parent_id = parent_id
-        self.start_ts = time.time()
-        self.end_ts: Optional[float] = None
+        self.start_ns = time.time_ns()
+        self.end_ns: Optional[int] = None
         self.attrs: Dict[str, Any] = dict(attrs or {})
         self.events: List[Dict[str, Any]] = []
         self.links: List[Dict[str, Any]] = []
@@ -127,6 +202,22 @@ class Span:
         # annotate the server's wait span); end() is guarded idempotent
         self._lock = threading.Lock()
         self._active = False
+        self._annotation = None
+
+    # --- the float seconds the schema had first, as views of the stamps ---
+    @property
+    def start_ts(self) -> float:
+        return self.start_ns * 1e-9
+
+    @start_ts.setter
+    def start_ts(self, ts: float) -> None:
+        # the serving engine stitches a phase's start to its predecessor's
+        # end: the nanosecond stamp moves with it
+        self.start_ns = int(round(ts * 1e9))
+
+    @property
+    def end_ts(self) -> Optional[float]:
+        return None if self.end_ns is None else self.end_ns * 1e-9
 
     # --- identity -----------------------------------------------------------
     @property
@@ -140,6 +231,13 @@ class Span:
     def set_attr(self, key: str, value: Any) -> "Span":
         with self._lock:
             self.attrs[str(key)] = value
+        return self
+
+    def add_to_attr(self, key: str, amount: float) -> "Span":
+        """Add ``amount`` to a numeric attribute (absent counts as 0):
+        the compile listener sums a dispatch's phases this way."""
+        with self._lock:
+            self.attrs[key] = self.attrs.get(key, 0) + amount
         return self
 
     def add_event(self, name: str, **attrs: Any) -> "Span":
@@ -169,33 +267,42 @@ class Span:
         """Close the span and emit its record. Idempotent; returns the
         duration in seconds (None if already ended elsewhere)."""
         with self._lock:
-            if self.end_ts is not None:
+            if self.end_ns is not None:
                 return None
-            self.end_ts = time.time()
+            self.end_ns = time.time_ns()
             rec = {"name": self.name, "trace_id": self.trace_id,
                    "span_id": self.span_id, "parent_id": self.parent_id,
                    "start_ts": self.start_ts, "end_ts": self.end_ts,
-                   "duration_s": self.end_ts - self.start_ts,
-                   "pid": os.getpid()}
+                   "start_ns": self.start_ns, "end_ns": self.end_ns,
+                   "duration_s": (self.end_ns - self.start_ns) * 1e-9,
+                   "pid": _proc["pid"]}
             if self.attrs:
                 rec["attrs"] = dict(self.attrs)
             if self.events:
                 rec["events"] = list(self.events)
             if self.links:
                 rec["links"] = list(self.links)
+        with _ring_lock:
+            _ring.append(rec)
         _emit_span(rec)
         return rec["duration_s"]
 
     @property
     def duration_s(self) -> Optional[float]:
-        return None if self.end_ts is None else self.end_ts - self.start_ts
+        return (None if self.end_ns is None
+                else (self.end_ns - self.start_ns) * 1e-9)
 
     def __enter__(self) -> "Span":
         self._active = True
         _stack().append(self)
+        self._annotation = _annotation(self.name, self.attrs)
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         st = _stack()
         if self._active and self in st:
             # remove THIS span even if a child leaked (mis-nesting must

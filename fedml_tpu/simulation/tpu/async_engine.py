@@ -828,7 +828,6 @@ class AsyncBufferedSimulator(TPUSimulator):
             logger.info("resumed async state from checkpoint at pour %d "
                         "(version %d)", step, self.version)
         freq = int(getattr(args, "frequency_of_the_test", 5) or 5)
-        self._ensure_flops_model(hyper)
         self._bootstrap(hyper)
         stalls = 0
         while self.version < pours:

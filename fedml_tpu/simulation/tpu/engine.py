@@ -100,6 +100,15 @@ class TPUSimulator:
 
     def __init__(self, args, fed_dataset, bundle, optimizer, spec,
                  mesh: Optional[Mesh] = None, server_aggregator=None):
+        # set-up on the record: `setup.simulator` with the three children
+        # `_setup` opens (what a start pays before its first dispatch)
+        with obs_trace.span("setup.simulator", root=True,
+                            attrs={"role": "engine"}):
+            self._setup(args, fed_dataset, bundle, optimizer, spec, mesh,
+                        server_aggregator)
+
+    def _setup(self, args, fed_dataset, bundle, optimizer, spec, mesh,
+               server_aggregator) -> None:
         self.args = args
         # `round_mode: async_buffered` lives in the AsyncBufferedSimulator
         # subclass (simulation/tpu/async_engine.py); constructing the base
@@ -122,10 +131,6 @@ class TPUSimulator:
         self.n_devices = self.mesh.shape[AXIS_CLIENT]
         self.rng = jax.random.PRNGKey(int(getattr(args, "random_seed", 0)))
         init_rng, self.rng = jax.random.split(self.rng)
-
-        # ---- place data: [num_clients, ...] -> [D, cpd, ...] sharded on D.
-        train, self.cpd, self.total_clients = _pad_clients(
-            fed_dataset.train, fed_dataset.num_clients, self.n_devices)
         self.client_sharding = NamedSharding(self.mesh, P(AXIS_CLIENT))
         self.repl_sharding = NamedSharding(self.mesh, P())
 
@@ -137,20 +142,12 @@ class TPUSimulator:
         mlops.install_compile_counter()
         self.dispatch_stats: Dict[str, Any] = {"dispatches": 0,
                                                "compiles": 0}
-        # profiling plane (core/obs/profiler): OPT-IN host/device wall
-        # split + per-round MFU at the dispatch seam. Off by default
-        # because it blocks on dispatch results, defeating the async
-        # dispatch overlap (and its FLOPs-model lowering would perturb
-        # the compile-once counters tests pin).
-        self._obs_profile = bool(getattr(args, "obs_profile_device",
-                                         False))
-        self._flops_per_round: Optional[float] = None
         # compute plane (core/obs/roofline): per-dispatch abstract-shape
         # signatures feed always-on recompile forensics; `obs_roofline`
         # additionally AOT-captures each program's per-op roofline +
         # collective-traffic record (one extra backend compile per
-        # program — opt-in, like obs_profile_device, so the compile-once
-        # invariants hold at default knobs)
+        # program — opt-in, so the compile-once invariants hold at
+        # default knobs)
         self._roofline = obs_roofline.DispatchTracker(
             enabled=bool(getattr(args, "obs_roofline",
                                  obs_roofline.default_enabled())),
@@ -206,27 +203,35 @@ class TPUSimulator:
         self.attacker = FedMLAttacker(args)
         self.defender = FedMLDefender(args)
         self.dp = FedMLDifferentialPrivacy(args)
-        if self.attacker.is_data_attack():
-            from ..poisoning import poison_dataset
-            poisoned = poison_dataset(self.fed, self.attacker)
-            train = _pad_clients(poisoned.train, fed_dataset.num_clients,
-                                 self.n_devices)[0]
 
         def shard_clients(a):
             a = a.reshape((self.n_devices, self.cpd) + a.shape[1:])
             return jax.device_put(a, self.client_sharding)
-        self.train_data = jax.tree_util.tree_map(shard_clients, train)
 
-        sample = fed_dataset.train.x[0, 0]
-        self.params = jax.device_put(bundle.init(init_rng, sample),
-                                     self.repl_sharding)
-        self.server_state = jax.device_put(self.opt.server_init(self.params),
-                                           self.repl_sharding)
-        cstate0 = self.opt.client_state_init(self.params)
-        stacked_states = jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a[None], (self.total_clients,) + a.shape),
-            cstate0)
-        self.client_states = jax.tree_util.tree_map(shard_clients, stacked_states)
+        # ---- place data: [num_clients, ...] -> [D, cpd, ...] sharded on D.
+        with obs_trace.span("setup.place_data"):
+            train, self.cpd, self.total_clients = _pad_clients(
+                fed_dataset.train, fed_dataset.num_clients, self.n_devices)
+            if self.attacker.is_data_attack():
+                from ..poisoning import poison_dataset
+                poisoned = poison_dataset(self.fed, self.attacker)
+                train = _pad_clients(poisoned.train, fed_dataset.num_clients,
+                                     self.n_devices)[0]
+            self.train_data = jax.tree_util.tree_map(shard_clients, train)
+
+        with obs_trace.span("setup.init_state"):
+            sample = fed_dataset.train.x[0, 0]
+            self.params = jax.device_put(bundle.init(init_rng, sample),
+                                         self.repl_sharding)
+            self.server_state = jax.device_put(
+                self.opt.server_init(self.params), self.repl_sharding)
+            cstate0 = self.opt.client_state_init(self.params)
+            stacked_states = jax.tree_util.tree_map(
+                lambda a: jnp.broadcast_to(
+                    a[None], (self.total_clients,) + a.shape),
+                cstate0)
+            self.client_states = jax.tree_util.tree_map(shard_clients,
+                                                        stacked_states)
 
         self.contribution = ContributionAssessorManager(args)
         defended_mode = (self.attacker.is_model_attack()
@@ -282,15 +287,18 @@ class TPUSimulator:
         # perf knobs (ISSUE 16): both default-off, off = bit-identical
         # programs. Resolve BEFORE the round fns are built — the cores
         # close over the resolved values.
-        self._relayout_quant = self._resolve_relayout_quant()
-        self._slot_fold = self._resolve_slot_fold()
-        self._round_fn = (self._build_robust_fn() if self.robust_fused
-                          else self._build_collect_fn() if self.robust_mode
-                          else self._build_round_fn())
-        self._server_update = jax.jit(
-            self.opt.server_update,
-            donate_argnums=(0, 1) if self._donate else ())
-        self._evaluate = jax.jit(lambda p, x, y, m: evaluate(spec, p, x, y, m))
+        with obs_trace.span("setup.build_programs"):
+            self._relayout_quant = self._resolve_relayout_quant()
+            self._slot_fold = self._resolve_slot_fold()
+            self._round_fn = (
+                self._build_robust_fn() if self.robust_fused
+                else self._build_collect_fn() if self.robust_mode
+                else self._build_round_fn())
+            self._server_update = jax.jit(
+                self.opt.server_update,
+                donate_argnums=(0, 1) if self._donate else ())
+            self._evaluate = jax.jit(
+                lambda p, x, y, m: evaluate(spec, p, x, y, m))
         self.ckpt = RoundCheckpointer(
             getattr(args, "checkpoint_dir", None),
             int(getattr(args, "checkpoint_every_rounds", 0) or 0))
@@ -630,69 +638,36 @@ class TPUSimulator:
         donated — it is reused every round)."""
         return argnums if self._donate else ()
 
-    # dispatches that execute no client training: profiled for wall/wait
-    # but never converted to MFU (the FLOPs model is per training round)
-    _NON_TRAINING_DISPATCHES = frozenset({"server_update"})
-
-    def _ensure_flops_model(self, hyper) -> None:
-        """Profiling plane: lower the FLOPs model once per run (it is the
-        SAME ``round_cost_flops`` the bench reads, so MFU numbers stay
-        comparable across BENCH rounds). Only under ``obs_profile_device``
-        — the lowering compiles a throwaway program, which would otherwise
-        trip the compile-once regression counters."""
-        if self._obs_profile and self._flops_per_round is None:
-            self._flops_per_round = self.round_cost_flops(hyper)
-
-    def _traced(self, name: str, n_rounds: int, fn, *args):
+    def _traced(self, name: str, n_rounds: int, fn, *args,
+                round_idx: Optional[int] = None):
         """Per-dispatch observability at the mlops seam: a ``dispatch``
         span + wall time of the dispatch call (host-side cost; device
         work is async) plus the process-wide XLA-compile delta it
         triggered — the recompile counter that makes shape instability
-        loud instead of silent.
-
-        With ``obs_profile_device`` the dispatch additionally blocks on
-        its outputs to split wall time into host (enqueue) vs device-wait
-        (compute tail), wraps the call in a ``jax.profiler`` annotation,
-        and converts the FLOPs model into the per-round MFU gauge."""
+        loud instead of silent. Nothing here waits for the device. When
+        the call traced, lowered or compiled, the compile listener
+        (``mlops.install_compile_counter``) has put the seconds of each
+        phase on the span, which so names the round that paid them."""
         # compute plane: signature BEFORE the dispatch (donated buffers
         # die with it), capture BEFORE the counter snapshot (the opt-in
         # AOT compile must not be charged to the dispatch record)
         sig = obs_roofline.dispatch_signature(args)
         self._roofline.maybe_capture(name, fn, args, sig=sig)
-        c0 = mlops.compile_count()
-        with obs_trace.span("dispatch",
-                            attrs={"name": name,
-                                   "rounds": int(n_rounds)}) as sp:
+        before = mlops.compile_phases()
+        attrs = {"name": name, "rounds": int(n_rounds)}
+        if round_idx is not None:
+            attrs["round_idx"] = int(round_idx)
+        with obs_trace.span("dispatch", attrs=attrs):
             t0 = time.perf_counter()
-            if self._obs_profile:
-                with obs_profiler.trace_annotation(name):
-                    out = fn(*args)
-            else:
-                out = fn(*args)
+            out = fn(*args)
             wall = time.perf_counter() - t0
-            wait = None
-            if self._obs_profile:
-                t1 = time.perf_counter()
-                jax.block_until_ready(out)
-                wait = time.perf_counter() - t1
-                sp.set_attr("device_wait_s", round(wait, 6))
-        compiles = mlops.compile_count() - c0
+        phases = mlops.compile_phases_since(before)
+        compiles = phases.get("compiles", 0)
         self._roofline.observe(name, sig, compiles)
-        if self._obs_profile:
-            # the FLOPs model describes a TRAINING round: dispatches that
-            # carry no training (the host-robust path's server_update is
-            # a millisecond aggregation) must not be credited a round's
-            # FLOPs — the resulting >1.0 MFU would overwrite the real
-            # per-round gauge every round
-            fpr = (self._flops_per_round
-                   if name not in self._NON_TRAINING_DISPATCHES else None)
-            obs_profiler.record_dispatch_profile(
-                name, n_rounds, wall, wait, fpr,
-                self.n_devices, compiles=compiles)
-            obs_profiler.sample_hbm_peak_gb()
         self.dispatch_stats["dispatches"] += 1
         self.dispatch_stats["compiles"] += compiles
-        mlops.log_dispatch(name, wall, rounds=n_rounds, compiles=compiles)
+        mlops.log_dispatch(name, wall, rounds=n_rounds, compiles=compiles,
+                           phases=phases)
         return out
 
     def _build_round_fn(self):
@@ -1464,27 +1439,36 @@ class TPUSimulator:
             return 0.0
 
     def run_round(self, round_idx: int, hyper: TrainHyper) -> Dict[str, float]:
-        self._ensure_flops_model(hyper)
         with obs_trace.span("round", root=True,
                             attrs={"role": "engine",
-                                   "round_idx": int(round_idx)}):
-            return self._run_round_traced(round_idx, hyper)
+                                   "round_idx": int(round_idx)}) as sp:
+            metrics = self._run_round_traced(round_idx, hyper)
+            if sp is not obs_trace.NOOP_SPAN:  # sampled at a span's close
+                obs_profiler.sample_hbm_peak_gb()
+            return metrics
 
     def _run_round_traced(self, round_idx: int,
                           hyper: TrainHyper) -> Dict[str, float]:
+        # every host phase of a round is a child span of `round` carrying
+        # `round_idx`, so a device-idle gap in a profiler trace falls
+        # under the phase that caused it
+        at = {"round_idx": int(round_idx)}
         pad_to = self._canonical_width() if self.robust_fused else None
-        with obs_trace.span("host.input",
-                            attrs={"round_idx": int(round_idx)}):
-            sampled, (idx, active, work), faults = self._schedule_for(
-                round_idx, pad_to=pad_to)
-            self._ledger_round(round_idx, sampled, active, work, faults)
-            idx = jax.device_put(jnp.asarray(idx), self.client_sharding)
-            active = jax.device_put(jnp.asarray(active),
-                                    self.client_sharding)
-            work = jax.device_put(jnp.asarray(work), self.client_sharding)
-        round_key = jax.random.fold_in(self.rng, round_idx)
-        hyper_r = hyper.replace(round_idx=jnp.int32(round_idx))
-        placement = slot_placement(sampled, self.n_devices, self.cpd)
+        with obs_trace.span("host.input", attrs=at):
+            with obs_trace.span("host.schedule", attrs=at):
+                sampled, (idx, active, work), faults = self._schedule_for(
+                    round_idx, pad_to=pad_to)
+                self._ledger_round(round_idx, sampled, active, work, faults)
+            with obs_trace.span("host.stage", attrs=at):
+                idx = jax.device_put(jnp.asarray(idx), self.client_sharding)
+                active = jax.device_put(jnp.asarray(active),
+                                        self.client_sharding)
+                work = jax.device_put(jnp.asarray(work),
+                                      self.client_sharding)
+        with obs_trace.span("host.keys", attrs=at):
+            round_key = jax.random.fold_in(self.rng, round_idx)
+            hyper_r = hyper.replace(round_idx=jnp.int32(round_idx))
+            placement = slot_placement(sampled, self.n_devices, self.cpd)
         if self.robust_fused:
             rows, byz = self._robust_rows(sampled, int(idx.shape[1]))
             dstate = (self._defense_state if self._defense_state is not None
@@ -1496,7 +1480,7 @@ class TPUSimulator:
                 self.params, self.server_state, self.train_data,
                 self.client_states, idx, active, work, jnp.asarray(rows),
                 jnp.asarray(byz), jnp.asarray(sampled, jnp.int32), dstate,
-                round_key, hyper_r)
+                round_key, hyper_r, round_idx=round_idx)
             (self.params, self.server_state, self.client_states,
              new_dstate, metrics, slot_mets, verdict) = out[:7]
             if self._defense_state is not None:
@@ -1508,39 +1492,46 @@ class TPUSimulator:
                 # [K] scores come host-side
                 self._assess_contribution_fused(out[7], out[8], sampled,
                                                 round_idx, prev_params)
-            # device arrays only — materialized lazily at the next
-            # selection query, never a transfer inside run_round
-            self.selection.note_results(round_idx, sampled, placement,
-                                        slot_metrics=slot_mets,
-                                        verdict=verdict)
-            self.dp.record_round(len(sampled) / max(self.fed.num_clients, 1))
+            self._post_round(round_idx, sampled, placement, slot_mets,
+                             verdict=verdict)
             return metrics
         if self.robust_mode:
             (upd_stack, w_stack, agg_extras, self.client_states,
              metrics, slot_mets) = self._traced(
                 "robust_collect", 1, self._round_fn,
                 self.params, self.server_state, self.train_data,
-                self.client_states, idx, active, work, round_key, hyper_r)
-            self.selection.note_results(round_idx, sampled, placement,
-                                        slot_metrics=slot_mets)
+                self.client_states, idx, active, work, round_key, hyper_r,
+                round_idx=round_idx)
+            self._post_round(round_idx, sampled, placement, slot_mets)
             agg_update = self._robust_aggregate(
                 upd_stack, w_stack, sampled, int(idx.shape[1]),
                 round_key, round_idx)
             self.params, self.server_state = self._traced(
                 "server_update", 1, self._server_update,
                 self.params, self.server_state, agg_update, agg_extras,
-                jnp.int32(round_idx))
-            self.dp.record_round(len(sampled) / max(self.fed.num_clients, 1))
+                jnp.int32(round_idx), round_idx=round_idx)
             return metrics
         (self.params, self.server_state, self.client_states,
          metrics, slot_mets) = self._traced(
             "round", 1, self._round_fn,
             self.params, self.server_state, self.train_data,
-            self.client_states, idx, active, work, round_key, hyper_r)
-        self.selection.note_results(round_idx, sampled, placement,
-                                    slot_metrics=slot_mets)
-        self.dp.record_round(len(sampled) / max(self.fed.num_clients, 1))
+            self.client_states, idx, active, work, round_key, hyper_r,
+            round_idx=round_idx)
+        self._post_round(round_idx, sampled, placement, slot_mets)
         return metrics
+
+    def _post_round(self, round_idx: int, sampled, placement, slot_mets,
+                    verdict=None) -> None:
+        """Host bookkeeping after a round's dispatch: queue the slot
+        metrics (device arrays only — materialized lazily at the next
+        selection query, never a transfer inside run_round) and account
+        the round's privacy spend."""
+        with obs_trace.span("host.post",
+                            attrs={"round_idx": int(round_idx)}):
+            self.selection.note_results(round_idx, sampled, placement,
+                                        slot_metrics=slot_mets,
+                                        verdict=verdict)
+            self.dp.record_round(len(sampled) / max(self.fed.num_clients, 1))
 
     def _canonical_width(self) -> int:
         """The simulator-canonical schedule width: the cap build_schedule
@@ -1629,34 +1620,22 @@ class TPUSimulator:
                 or (self.robust_fused and self.contribution.enabled):
             return [self.run_round(start_round + i, hyper)
                     for i in range(n_rounds)]
-        self._ensure_flops_model(hyper)
         with obs_trace.span("block", root=True,
                             attrs={"role": "engine",
                                    "start_round": int(start_round),
-                                   "rounds": int(n_rounds)}):
-            return self._run_rounds_fused_traced(start_round, n_rounds,
-                                                 hyper)
-
-    def _run_rounds_fused_traced(self, start_round: int, n_rounds: int,
-                                 hyper: TrainHyper) -> List[Dict[str, float]]:
-        host_span = obs_trace.tracer.start_span(
-            "host.input", attrs={"start_round": int(start_round),
-                                 "rounds": int(n_rounds)})
-        try:
-            return self._run_rounds_fused_body(
-                start_round, n_rounds, hyper, host_span)
-        finally:
-            # schedule building can raise (device_put OOM, shape errors);
-            # the span must still flush so a failed run's log shows where
-            # the host time went. end() is idempotent — the success path
-            # already ended it right before dispatch.
-            host_span.end()
+                                   "rounds": int(n_rounds)}) as sp:
+            out = self._run_rounds_fused_body(start_round, n_rounds, hyper)
+            if sp is not obs_trace.NOOP_SPAN:  # sampled at a span's close
+                obs_profiler.sample_hbm_peak_gb()
+            return out
 
     def _run_rounds_fused_body(self, start_round: int, n_rounds: int,
-                               hyper: TrainHyper,
-                               host_span) -> List[Dict[str, float]]:
-        idxs, acts, works, keys, ridxs, rows_r, byz_r, ids_r = (
-            [], [], [], [], [], [], [], [])
+                               hyper: TrainHyper) -> List[Dict[str, float]]:
+        # the host phases of `_run_round_traced` under the same names, each
+        # once a block and covering all of the block's rounds
+        at = {"start_round": int(start_round), "rounds": int(n_rounds)}
+        idxs, acts, works, ridxs, rows_r, byz_r, ids_r = (
+            [], [], [], [], [], [], [])
         sampled_r = []
         # every round pads to the simulator-canonical width (padded slots
         # carry active=0 and are masked in the round body): build_schedule
@@ -1665,33 +1644,35 @@ class TPUSimulator:
         # on width — canonical padding compiles it exactly once per run
         width = self._canonical_width()
         part = 0.0
-        for r in range(start_round, start_round + n_rounds):
-            sampled, (idx, active, work), faults = self._schedule_for(
-                r, pad_to=width)
-            self._ledger_round(r, sampled, active, work, faults)
-            sampled_r.append(sampled)
-            idxs.append(idx)
-            acts.append(active)
-            works.append(work)
-            keys.append(jax.random.fold_in(self.rng, r))
-            ridxs.append(r)
-            if self.robust_fused:
-                rows, byz = self._robust_rows(sampled, width)
-                rows_r.append(rows)
-                byz_r.append(byz)
-                ids_r.append(np.asarray(sampled, np.int32))
-            part += len(sampled) / max(self.fed.num_clients, 1)
-        sched_sharding = NamedSharding(self.mesh, P(None, AXIS_CLIENT))
-        idxs = jax.device_put(jnp.stack([jnp.asarray(i) for i in idxs],
-                                        axis=0), sched_sharding)
-        acts = jax.device_put(jnp.stack([jnp.asarray(a) for a in acts],
-                                        axis=0), sched_sharding)
-        works = jax.device_put(jnp.stack([jnp.asarray(w) for w in works],
-                                         axis=0), sched_sharding)
-        keys = jnp.stack(keys)
-        ridxs = jnp.asarray(ridxs, jnp.int32)
-        hyper_0 = hyper.replace(round_idx=jnp.int32(start_round))
-        host_span.end()  # host-side schedule building done; dispatch next
+        with obs_trace.span("host.input", attrs=at):
+            with obs_trace.span("host.schedule", attrs=at):
+                for r in range(start_round, start_round + n_rounds):
+                    sampled, (idx, active, work), faults = \
+                        self._schedule_for(r, pad_to=width)
+                    self._ledger_round(r, sampled, active, work, faults)
+                    sampled_r.append(sampled)
+                    idxs.append(idx)
+                    acts.append(active)
+                    works.append(work)
+                    ridxs.append(r)
+                    if self.robust_fused:
+                        rows, byz = self._robust_rows(sampled, width)
+                        rows_r.append(rows)
+                        byz_r.append(byz)
+                        ids_r.append(np.asarray(sampled, np.int32))
+                    part += len(sampled) / max(self.fed.num_clients, 1)
+            with obs_trace.span("host.stage", attrs=at):
+                sched_sharding = NamedSharding(self.mesh,
+                                               P(None, AXIS_CLIENT))
+                idxs, acts, works = (
+                    jax.device_put(jnp.stack([jnp.asarray(a) for a in arrs],
+                                             axis=0), sched_sharding)
+                    for arrs in (idxs, acts, works))
+        with obs_trace.span("host.keys", attrs=at):
+            keys = jnp.stack([jax.random.fold_in(self.rng, r)
+                              for r in ridxs])
+            ridxs = jnp.asarray(ridxs, jnp.int32)
+            hyper_0 = hyper.replace(round_idx=jnp.int32(start_round))
         if self.robust_fused:
             if not hasattr(self, "_robust_fused_fn"):
                 self._robust_fused_fn = self._build_robust_fused_fn()
@@ -1705,7 +1686,7 @@ class TPUSimulator:
                 jnp.stack([jnp.asarray(r) for r in rows_r]),
                 jnp.stack([jnp.asarray(b) for b in byz_r]),
                 jnp.stack([jnp.asarray(i) for i in ids_r]),
-                dstate, keys, ridxs, hyper_0)
+                dstate, keys, ridxs, hyper_0, round_idx=start_round)
             if self._defense_state is not None:
                 self._defense_state = new_dstate
         else:
@@ -1716,21 +1697,23 @@ class TPUSimulator:
                 "rounds_fused", n_rounds, self._fused_fn,
                 self.params, self.server_state, self.train_data,
                 self.client_states, idxs, acts, works, keys, ridxs,
-                hyper_0)
+                hyper_0, round_idx=start_round)
             verdicts = None
-        if self.selection.track:
-            # queue each round's slice of the block outputs (lazy device
-            # slices; materialized at the next selection query)
-            for i, sampled in enumerate(sampled_r):
-                sm_i = jax.tree_util.tree_map(lambda a: a[i], slot_mets)
-                self.selection.note_results(
-                    start_round + i, sampled,
-                    slot_placement(sampled, self.n_devices, self.cpd),
-                    slot_metrics=sm_i,
-                    verdict=None if verdicts is None else verdicts[i])
-        for _ in range(n_rounds):  # DP accounting stays per-round
-            self.dp.record_round(part / n_rounds)
-        host = jax.device_get(metrics)
+        with obs_trace.span("host.post", attrs=at):
+            if self.selection.track:
+                # queue each round's slice of the block outputs (lazy
+                # device slices; materialized at the next selection query)
+                for i, sampled in enumerate(sampled_r):
+                    sm_i = jax.tree_util.tree_map(lambda a: a[i], slot_mets)
+                    self.selection.note_results(
+                        start_round + i, sampled,
+                        slot_placement(sampled, self.n_devices, self.cpd),
+                        slot_metrics=sm_i,
+                        verdict=None if verdicts is None else verdicts[i])
+            for _ in range(n_rounds):  # DP accounting stays per-round
+                self.dp.record_round(part / n_rounds)
+        with obs_trace.span("host.readback", attrs=at):
+            host = jax.device_get(metrics)
         return [{k: host[k][i] for k in host} for i in range(n_rounds)]
 
     def run(self, comm_round: Optional[int] = None) -> Dict[str, Any]:
@@ -1738,7 +1721,6 @@ class TPUSimulator:
         rounds = comm_round if comm_round is not None else int(args.comm_round)
         hyper = TrainHyper(learning_rate=jnp.float32(args.learning_rate),
                            epochs=int(args.epochs))
-        self._ensure_flops_model(hyper)
         t0 = time.time()
         start_round = 0
         restored = self._ckpt_latest()
@@ -1783,9 +1765,17 @@ class TPUSimulator:
             for i, metrics in enumerate(block):
                 r = round_idx + i
                 rec: Dict[str, Any] = {"round": r}
-                cnt = max(float(metrics["count"]), 1.0)
-                rec["train_loss"] = float(metrics["loss_sum"]) / cnt
-                rec["train_acc"] = float(metrics["correct"]) / cnt
+                # a round of its own dispatch ends here: these reads wait
+                # for its program. A fused block's metrics are host copies
+                # (read under the block's own `host.readback`): no span
+                waits = isinstance(metrics["count"], jax.Array)
+                with (obs_trace.span("host.readback", root=True,
+                                     attrs={"role": "engine",
+                                            "round_idx": r})
+                      if waits else obs_trace.NOOP_SPAN):
+                    cnt = max(float(metrics["count"]), 1.0)
+                    rec["train_loss"] = float(metrics["loss_sum"]) / cnt
+                    rec["train_acc"] = float(metrics["correct"]) / cnt
                 if freq > 0 and (r % freq == 0 or r == rounds - 1):
                     with obs_trace.span("eval", root=True,
                                         attrs={"role": "engine",

@@ -12,19 +12,34 @@ time went: straggler wait vs compute vs wire vs host.
     python scripts/trace_report.py run.jsonl --trace 4f2a...   # one tree
 
 For every ROOT span (``round`` / ``pour`` / ``block``, the engine's
-post-block per-round ``eval`` / ``checkpoint`` roots, plus orphans whose
+post-block per-round ``eval`` / ``checkpoint`` / ``host.readback`` roots,
+the once-a-start ``setup.simulator`` / ``setup.init``, plus orphans whose
 parent lives in a file you didn't pass) the report shows the duration,
-the per-category time (union of descendant span intervals clipped to the
-root window, so overlapping spans never double-count), the attributed
-fraction (the ≥95% acceptance bar: unattributed time is wall time no
-span explains), the slowest descendants, and — for pours — the linked
-contributing uploads with their per-link staleness.
+the per-category time, the attributed fraction (the ≥95% acceptance bar:
+unattributed time is wall time no span explains), the descendants with
+the most self time, and — for pours — the linked contributing uploads
+with their per-link staleness.
+
+A span's time is its SELF time: its interval minus what its own children
+cover. So ``host.input``, whose children ``host.schedule`` and
+``host.stage`` cover nearly all of it, is not counted twice, and a
+category's figure is the union of its spans' self intervals clipped to
+the root window (overlapping spans never double-count).
 
 Span-name → category map (keep in sync with the instrumentation):
   compute: train, dispatch, aggregate, eval
   wire:    comm.send, broadcast, upload, async.sync
   wait:    wait.uploads, wait.arrivals
-  host:    host.input, host.close, checkpoint
+  host:    host.input (> host.schedule: the client schedule and the fault
+           ledger; > host.stage: the schedule's device_puts), host.keys
+           (round key, hyper-parameters, slot placement), host.post
+           (selection and privacy bookkeeping), host.readback (the scalar
+           reads that end a round: the wait for its program), host.close,
+           checkpoint, setup.init, setup.simulator (> setup.place_data,
+           setup.init_state, setup.build_programs)
+A ``dispatch`` span is the jitted call's enqueue, not the program's run;
+when the call traced, lowered or compiled, its attrs say for how long
+(``trace_s``, ``lower_s``, ``compile_s``, ``cache_load_s``, ``cache_hits``).
 Container spans (round, pour, block, silo.round) attribute through their
 children, not themselves.
 """
@@ -43,12 +58,19 @@ CATEGORY = {
     "comm.send": "wire", "broadcast": "wire", "upload": "wire",
     "async.sync": "wire",
     "wait.uploads": "wait", "wait.arrivals": "wait",
-    "host.input": "host", "host.close": "host", "checkpoint": "host",
+    "host.input": "host", "host.schedule": "host", "host.stage": "host",
+    "host.keys": "host", "host.post": "host", "host.readback": "host",
+    "host.close": "host", "checkpoint": "host",
+    "setup.init": "host", "setup.simulator": "host",
+    "setup.place_data": "host", "setup.init_state": "host",
+    "setup.build_programs": "host",
 }
 CONTAINERS = {"round", "pour", "block", "silo.round"}
-# eval/checkpoint are the engine's post-block per-round roots (the fused
-# block span is closed by the time they run, so they cannot be children)
-ROOT_NAMES = ("round", "pour", "block", "eval", "checkpoint")
+# eval/checkpoint/host.readback are the engine's post-block per-round
+# roots (the fused block span is closed by the time they run, so they
+# cannot be children); the setup.* roots come once a start
+ROOT_NAMES = ("round", "pour", "block", "eval", "checkpoint",
+              "host.readback", "setup.init", "setup.simulator")
 
 
 def load_spans(paths: List[str]) -> List[Dict[str, Any]]:
@@ -109,17 +131,43 @@ def clip(span: Dict[str, Any], lo: float,
     return (s, e) if e > s else None
 
 
+def subtract(iv: Tuple[float, float],
+             holes: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
+    """The pieces of ``iv`` that no interval of ``holes`` covers."""
+    out, at = [], iv[0]
+    for s, e in sorted(holes):
+        if s > at:
+            out.append((at, min(s, iv[1])))
+        at = max(at, e)
+        if at >= iv[1]:
+            break
+    if at < iv[1]:
+        out.append((at, iv[1]))
+    return [(s, e) for s, e in out if e > s]
+
+
+def self_intervals(tree: Tree, span: Dict[str, Any], lo: float,
+                   hi: float) -> List[Tuple[float, float]]:
+    """``span``'s interval inside [lo, hi] minus what its children cover."""
+    iv = clip(span, lo, hi)
+    if iv is None:
+        return []
+    kids = [clip(c, lo, hi) for c in tree.children.get(span["span_id"], [])]
+    return subtract(iv, [k for k in kids if k is not None])
+
+
 def analyze_root(tree: Tree, root: Dict[str, Any]) -> Dict[str, Any]:
     lo, hi = float(root["start_ts"]), float(root["end_ts"])
     dur = max(hi - lo, 1e-12)
     per_cat: Dict[str, List[Tuple[float, float]]] = defaultdict(list)
     covered: List[Tuple[float, float]] = []
-    leaves: List[Dict[str, Any]] = []
+    leaves: List[Tuple[float, Dict[str, Any]]] = []
     if root["name"] not in CONTAINERS:
         # a leaf root (engine eval/checkpoint, an orphaned worker span)
         # IS its own attribution — containers attribute through children
         covered.append((lo, hi))
-        per_cat[CATEGORY.get(root["name"]) or "other"].append((lo, hi))
+        per_cat[CATEGORY.get(root["name"]) or "other"].extend(
+            self_intervals(tree, root, lo, hi))
     for d in tree.descendants(root):
         iv = clip(d, lo, hi)
         if iv is None:
@@ -132,10 +180,11 @@ def analyze_root(tree: Tree, root: Dict[str, Any]) -> Dict[str, Any]:
             covered.append(iv)
             continue
         covered.append(iv)
-        per_cat[cat or "other"].append(iv)
-        leaves.append(d)
+        own = self_intervals(tree, d, lo, hi)
+        per_cat[cat or "other"].extend(own)
+        leaves.append((sum(e - s for s, e in own), d))
     cats = {c: union_len(v) for c, v in per_cat.items()}
-    leaves.sort(key=lambda s: s["end_ts"] - s["start_ts"], reverse=True)
+    leaves.sort(key=lambda t: t[0], reverse=True)
     return {
         "root": root,
         "duration_s": dur,
@@ -197,9 +246,9 @@ def print_report(spans: List[Dict[str, Any]], only_trace: Optional[str],
               f"{c.get('wait', 0.0):>8.4f} {c.get('host', 0.0):>8.4f} "
               f"{100.0 * a['attributed_frac']:>5.1f}%  {trace_id[:12]}",
               file=out)
-        for t in a["top"]:
-            print(f"    └ {_label(t):<24} {t['end_ts'] - t['start_ts']:.4f}s",
-                  file=out)
+        for self_s, t in a["top"]:
+            print(f"    └ {_label(t):<24} {self_s:.4f}s self of "
+                  f"{t['end_ts'] - t['start_ts']:.4f}s", file=out)
         links = a["links"]
         if links:
             parts = []
