@@ -16,13 +16,13 @@
 """
 
 from .model import CausalLM, LLMConfig, init_llm
-from .lora import lora_init, lora_merge, make_lora_apply, lora_param_count
+from .lora import lora_init, lora_merge, lora_param_count
 from .trainer import CausalLMTrainer
 from .federated import LLMBundle, build_llm, llm_config_from_args, run_federated_llm
 
 __all__ = [
     "CausalLM", "LLMConfig", "init_llm",
-    "lora_init", "lora_merge", "make_lora_apply", "lora_param_count",
+    "lora_init", "lora_merge", "lora_param_count",
     "CausalLMTrainer",
     "LLMBundle", "build_llm", "llm_config_from_args", "run_federated_llm",
 ]

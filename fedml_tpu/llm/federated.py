@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from .data import ByteTokenizer, build_llm_federated
-from .lora import lora_init, make_lora_apply
+from .lora import lora_init
 from .model import CausalLM, LLMConfig, init_llm
 from .trainer import CausalLMTrainer
 
@@ -61,7 +61,13 @@ def llm_config_from_args(args) -> LLMConfig:
 @dataclasses.dataclass
 class LLMBundle:
     """ModelBundle-compatible wrapper whose trainable pytree is the LoRA
-    adapter tree (or the full params when ``lora_rank == 0``)."""
+    adapter tree (or the full params when ``lora_rank == 0``).
+
+    With a frozen base the adapters run as the model's factored side path
+    ``x W + ((x a) b) * (alpha / rank)`` and are never merged into ``W``:
+    the base is a constant of the forward, so the backward pass takes the
+    rank-r gradients of ``a`` and ``b`` and no ``[d_in, d_out]`` weight
+    gradient of a frozen kernel."""
 
     module: CausalLM
     cfg: LLMConfig
@@ -70,24 +76,18 @@ class LLMBundle:
     lora_alpha: float
     name: str = "causal_lm"
 
-    def __post_init__(self):
-        if self.base_params is not None:
-            self._apply = make_lora_apply(self._raw_apply, self.base_params,
-                                          self.lora_alpha)
-        else:
-            self._apply = self._raw_apply
-
-    def _raw_apply(self, params, x, rng=None, train=False):
-        del rng  # no dropout in the decoder
-        return self.module.apply({"params": params}, x, train=train)
-
     def init(self, rng: jax.Array, sample_input: jnp.ndarray) -> PyTree:
         if self.base_params is not None:
             return lora_init(rng, self.base_params, rank=self.lora_rank)
         return self.module.init(rng, sample_input[:1])["params"]
 
     def apply(self, params, x, rng=None, train=False):
-        return self._apply(params, x, rng=rng, train=train)
+        del rng  # no dropout in the decoder
+        if self.base_params is None:
+            return self.module.apply({"params": params}, x, train=train)
+        return self.module.apply(
+            {"params": self.base_params}, x, train=train, adapters=params,
+            lora_scale=self.lora_alpha / self.lora_rank)
 
 
 def build_llm_bundle(args) -> Tuple[LLMBundle, ByteTokenizer]:

@@ -5,16 +5,20 @@ Parity target: the reference's PEFT/LoRA integration
 ``peft_utils.py`` LORA_LAYER_TYPES) which wraps torch modules in-place.
 TPU-native design: LoRA is *data*, not module surgery — a small pytree of
 ``(lora_a, lora_b)`` factor pairs mirroring the targeted kernels. The
-forward merges ``W + (a @ b) * (alpha / rank)`` inside jit (XLA fuses the
-rank-r update into the matmul's producer), gradients flow only through the
-adapter tree, and federated aggregation ships the adapter tree alone — the
-cheap all-gather the reference approximates with ZeRO-3 gathered-parameter
-contexts (``train/llm/distributed.py:54-70``).
+forward keeps them factored: ``LLMBundle.apply`` hands the tree to
+``CausalLM(adapters=, lora_scale=alpha / rank)``, which computes
+``x W + ((x a) b) * (alpha / rank)`` at every adapted projection, so the
+frozen ``W`` is a constant of the program and the backward pass takes only
+the rank-r gradients of ``a`` and ``b``. Federated aggregation ships the
+adapter tree alone — the cheap all-gather the reference approximates with
+ZeRO-3 gathered-parameter contexts (``train/llm/distributed.py:54-70``).
+``lora_merge`` folds an adapter into the kernels for export; no training
+or serving path calls it.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Sequence, Tuple
+from typing import Any, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,7 +65,8 @@ def lora_init(rng: jax.Array, params: PyTree, rank: int = 8,
 
 def lora_merge(params: PyTree, lora: PyTree, alpha: float = 16.0) -> PyTree:
     """Return params with ``W + (a @ b) * (alpha / rank)`` at every adapted
-    kernel. Pure; safe under jit and grad."""
+    kernel: the export utility for a consumer that wants plain weights.
+    Pure; safe under jit."""
     flat = dict(traverse_util.flatten_dict(params))
     lflat = traverse_util.flatten_dict(lora)
     a_paths = [p for p in lflat if p[-1] == "lora_a"]
@@ -108,19 +113,3 @@ def lora_select(stack: PyTree, idx) -> PyTree:
 def lora_param_count(lora: PyTree) -> int:
     return int(sum(np.prod(p.shape)
                    for p in jax.tree_util.tree_leaves(lora)))
-
-
-def make_lora_apply(apply_fn: Callable[..., jnp.ndarray], base_params: PyTree,
-                    alpha: float = 16.0) -> Callable[..., jnp.ndarray]:
-    """Close over frozen base params: returns ``apply(lora, x, **kw)`` so the
-    adapter tree is the *only* trainable pytree the algorithm frame sees —
-    every federated optimizer / defense / DP hook then operates on adapters
-    alone, which is exactly the FedLLM aggregation contract
-    (UnitedLLM ships per-round adapter checkpoints,
-    ``spotlight_prj/unitedllm/src/unitedllm_trainer.py``)."""
-
-    def apply(lora: PyTree, x: jnp.ndarray, **kwargs) -> jnp.ndarray:
-        merged = lora_merge(base_params, lora, alpha)
-        return apply_fn(merged, x, **kwargs)
-
-    return apply

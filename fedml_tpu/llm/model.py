@@ -101,20 +101,36 @@ class RMSNorm(nn.Module):
         return (normed * scale).astype(x.dtype)
 
 
-def _lora_delta(x: jnp.ndarray, pair, scale: float) -> jnp.ndarray:
-    """Low-rank side path ``(x @ a) @ b * scale`` (the S-LoRA batched
-    apply: adapters stay factored instead of being merged into W, so a
-    per-slot adapter gather is two small einsums, not a weight copy).
+def _add_lora(x: jnp.ndarray, ys: dict, adapter, scale: float) -> dict:
+    """``ys = {name: x @ W_name}``, the frozen products of the projections
+    that read the same ``x``, each with its low-rank side path added:
+    ``x @ W + (x @ a) @ b * scale`` (the S-LoRA batched apply). Adapters stay
+    factored and are never merged into W, so a per-slot adapter gather is
+    two small einsums, not a weight copy, and a training step takes the
+    rank-r gradients of ``a`` and ``b`` and no weight gradient of the frozen
+    kernel. The ``a`` of the projections stand side by side in ONE product:
+    ``x`` is read once forward and once backward however many share it.
 
-    ``pair = {"lora_a", "lora_b"}`` with leaves either shared
-    ``[d_in, r]`` / ``[r, d_out]`` or per-slot ``[b, d_in, r]`` /
-    ``[b, r, d_out]`` (gathered from a stacked adapter bank)."""
-    a, bb = pair["lora_a"], pair["lora_b"]
+    ``adapter = {name: {"lora_a", "lora_b"}}`` (``None``, or a name left
+    out: no side path) with leaves either shared ``[d_in, r]`` /
+    ``[r, d_out]`` or per-slot ``[b, d_in, r]`` / ``[b, r, d_out]``
+    (gathered from a stacked adapter bank)."""
+    names = [n for n in ys if adapter is not None and n in adapter]
+    if not names:
+        return ys
+    a = jnp.concatenate([adapter[n]["lora_a"] for n in names], axis=-1)
     xf = x.astype(jnp.float32)
-    if a.ndim == 3:   # per-slot adapters
-        h = jnp.einsum("bsd,bdr->bsr", xf, a)
-        return jnp.einsum("bsr,bro->bso", h, bb) * scale
-    return ((xf @ a) @ bb) * scale
+    per_slot = a.ndim == 3
+    h = jnp.einsum("bsd,bdr->bsr", xf, a) if per_slot else xf @ a
+    out, lo = dict(ys), 0
+    for n in names:
+        bb = adapter[n]["lora_b"]
+        hn = h[..., lo:lo + bb.shape[-2]]
+        lo += bb.shape[-2]
+        delta = (jnp.einsum("bsr,bro->bso", hn, bb) if per_slot
+                 else hn @ bb) * scale
+        out[n] = ys[n] + delta.reshape(ys[n].shape).astype(ys[n].dtype)
+    return out
 
 
 class Attention(nn.Module):
@@ -138,16 +154,12 @@ class Attention(nn.Module):
             feats, axis=-1, use_bias=False, name=name,
             dtype=cfg.compute_dtype, param_dtype=jnp.float32)
 
-        def proj(name, feats):
-            y = dense(feats, name)(x)
-            if adapter is not None and name in adapter:
-                delta = _lora_delta(x, adapter[name], lora_scale)
-                y = y + delta.reshape(y.shape).astype(y.dtype)
-            return y
-
-        q = proj("q", (cfg.num_heads, cfg.head_dim))
-        k = proj("k", (cfg.kv_heads, cfg.head_dim))
-        v = proj("v", (cfg.kv_heads, cfg.head_dim))
+        qkv = _add_lora(x, {
+            "q": dense((cfg.num_heads, cfg.head_dim), "q")(x),
+            "k": dense((cfg.kv_heads, cfg.head_dim), "k")(x),
+            "v": dense((cfg.kv_heads, cfg.head_dim), "v")(x),
+        }, adapter, lora_scale)
+        q, k, v = qkv["q"], qkv["k"], qkv["v"]
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
 
@@ -178,10 +190,7 @@ class Attention(nn.Module):
         y = nn.DenseGeneral(cfg.hidden_size, use_bias=False, name="o",
                             dtype=cfg.compute_dtype,
                             param_dtype=jnp.float32)(out)
-        if adapter is not None and "o" in adapter:
-            y = y + _lora_delta(out, adapter["o"],
-                                lora_scale).reshape(y.shape).astype(y.dtype)
-        return y, new_kv
+        return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], new_kv
 
 
 class MLP(nn.Module):
@@ -194,16 +203,13 @@ class MLP(nn.Module):
             feats, use_bias=False, name=name, dtype=cfg.compute_dtype,
             param_dtype=jnp.float32)
 
-        def proj(name, feats, inp):
-            y = dense(feats, name)(inp)
-            if adapter is not None and name in adapter:
-                delta = _lora_delta(inp, adapter[name], lora_scale)
-                y = y + delta.reshape(y.shape).astype(y.dtype)
-            return y
-
-        gate = proj("gate", cfg.intermediate_size, x)
-        up = proj("up", cfg.intermediate_size, x)
-        return proj("down", cfg.hidden_size, nn.silu(gate) * up)
+        ys = _add_lora(x, {
+            "gate": dense(cfg.intermediate_size, "gate")(x),
+            "up": dense(cfg.intermediate_size, "up")(x),
+        }, adapter, lora_scale)
+        act = nn.silu(ys["gate"]) * ys["up"]
+        return _add_lora(act, {"down": dense(cfg.hidden_size, "down")(act)},
+                         adapter, lora_scale)["down"]
 
 
 class DecoderLayer(nn.Module):

@@ -82,7 +82,7 @@ class AdapterBank:
 
     @property
     def scale(self) -> float:
-        """The merged path's ``alpha / rank`` factor."""
+        """The side path's ``alpha / rank`` factor."""
         r = max(self.rank, 1)
         return self.alpha / r
 

@@ -13,8 +13,8 @@ its docs advertise). TPU-first redesign:
   program (no per-length recompiles; causal masking makes the padded
   tail inert);
 * the model is the repo's own flax ``CausalLM`` (optionally with LoRA
-  adapters merged via the bundle), loaded from a ``save_model`` artifact
-  — msgpack, never pickle;
+  adapters, which the bundle applies as factored side paths), loaded
+  from a ``save_model`` artifact — msgpack, never pickle;
 * the chat endpoint speaks ``POST /v1/chat/completions`` with the
   OpenAI request/response schema, so existing OpenAI clients can point
   at a served federated fine-tune unchanged.
@@ -119,7 +119,7 @@ class CausalLMPredictor(FedMLPredictor):
         if bundle.base_params is not None:
             # LoRA artifact: base model resident, the artifact's adapter
             # registered as "default" so adapter-less requests behave like
-            # the single path (modulo factored-vs-merged float paths)
+            # the single path (both apply it factored)
             base = bundle.base_params
             if self._bank is None:
                 self._bank = AdapterBank(
@@ -315,7 +315,7 @@ class CausalLMPredictor(FedMLPredictor):
         if adapter is not None:
             raise ValueError(
                 "per-request adapter selection needs llm_serving_mode: "
-                "batch (the single path serves one merged artifact)")
+                "batch (the single path serves the one artifact it loaded)")
         return self._generate_single(ids, max_new_tokens, temp, int(seed))
 
     def _generate_single(self, ids: List[int], max_new_tokens: int,

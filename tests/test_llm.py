@@ -9,7 +9,7 @@ import pytest
 
 from fedml_tpu.arguments import Arguments
 from fedml_tpu.llm import (
-    CausalLM, LLMConfig, init_llm, lora_init, lora_merge, make_lora_apply,
+    CausalLM, LLMBundle, LLMConfig, init_llm, lora_init, lora_merge,
     lora_param_count, CausalLMTrainer, build_llm, run_federated_llm,
 )
 from fedml_tpu.llm.attention import (
@@ -17,7 +17,9 @@ from fedml_tpu.llm.attention import (
     ring_axis,
 )
 
-pytestmark = pytest.mark.slow
+# the LoRA tests below run in tier-1 (small_lm, float32, a few seconds);
+# everything else in this file is the full gate's
+slow = pytest.mark.slow
 
 CFG = LLMConfig(vocab_size=64, hidden_size=32, intermediate_size=64,
                 num_layers=2, num_heads=4, max_seq_len=32)
@@ -28,6 +30,7 @@ def small_lm():
     return init_llm(CFG, jax.random.PRNGKey(0))
 
 
+@slow
 def test_forward_shape_and_causality(small_lm):
     model, params = small_lm
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 64)
@@ -41,6 +44,7 @@ def test_forward_shape_and_causality(small_lm):
     assert not np.allclose(logits[:, 10:], logits2[:, 10:])
 
 
+@slow
 def test_flash_matches_dense():
     rng = jax.random.PRNGKey(0)
     q, k, v = (jax.random.normal(jax.random.fold_in(rng, i), (2, 16, 2, 8))
@@ -60,6 +64,7 @@ def test_flash_matches_dense():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
+@slow
 def test_flash_key_padding_mask():
     """Flash supports key-padding masks in both directions; masked keys get
     zero probability (fwd parity vs dense) and zero dK/dV rows."""
@@ -87,6 +92,7 @@ def test_flash_key_padding_mask():
     assert np.all(dk[dead] == 0) and np.all(dv[dead] == 0)
 
 
+@slow
 def test_flash_all_masked_row_is_zero():
     """A query row whose every visible key is masked (mid-sequence key
     mask covering its own diagonal) must output exactly zero — not an
@@ -117,6 +123,7 @@ def test_flash_all_masked_row_is_zero():
                                np.asarray(ring)[:, 4:], atol=1e-5)
 
 
+@slow
 def test_nonaligned_seq_len_pads_to_lane_multiple():
     """s=100 (not a multiple of 128) must be handled by pad+slice, matching
     dense exactly on the real rows (ADVICE r3: 125-row blocks are not
@@ -131,6 +138,7 @@ def test_nonaligned_seq_len_pads_to_lane_multiple():
                                atol=1e-5)
 
 
+@slow
 def test_ring_matches_dense_multidevice():
     from jax.sharding import PartitionSpec as P
     from fedml_tpu.core.mesh import build_mesh
@@ -149,6 +157,7 @@ def test_ring_matches_dense_multidevice():
                                atol=1e-5)
 
 
+@slow
 def test_ring_gradients_match_dense():
     """Ring attention must be TRAINABLE: gradients through the ppermute
     accumulation (sequence-parallel backward) match the dense single-
@@ -183,6 +192,7 @@ def test_ring_gradients_match_dense():
                                    atol=2e-4)
 
 
+@slow
 def test_ring_bwd_residuals_stay_linear_in_s():
     """Training-memory contract for ring attention (VERDICT r4 item 3),
     mirroring test_chip_compile's flash memory contract: the fold is
@@ -230,6 +240,7 @@ def test_ring_bwd_residuals_stay_linear_in_s():
     assert mem8.temp_size_in_bytes < mem.temp_size_in_bytes
 
 
+@slow
 def test_ring_forward_full_model():
     """Sequence-parallel forward of the whole decoder matches the dense
     single-device forward (global RoPE positions + causal mask)."""
@@ -280,14 +291,16 @@ def test_lora_zero_init_and_delta(small_lm):
     assert not np.allclose(np.asarray(base_out), np.asarray(out2))
 
 
-def test_lora_training_reduces_loss(small_lm):
+def _lora_bundle(small_lm, rank):
     model, params = small_lm
-    apply_fn = make_lora_apply(
-        lambda p, x, rng=None, train=False: model.apply({"params": p}, x),
-        params)
-    spec = CausalLMTrainer(apply_fn)
-    lora = lora_init(jax.random.PRNGKey(2), params, rank=4)
+    return LLMBundle(model, CFG, params, lora_rank=rank, lora_alpha=16.0)
+
+
+def test_lora_training_reduces_loss(small_lm):
+    bundle = _lora_bundle(small_lm, 4)
+    spec = CausalLMTrainer(bundle.apply)
     x = jax.random.randint(jax.random.PRNGKey(3), (4, 16), 4, 64)
+    lora = bundle.init(jax.random.PRNGKey(2), x)
     batch = {"x": x, "y": x, "mask": jnp.ones(4)}
 
     import optax
@@ -309,6 +322,81 @@ def test_lora_training_reduces_loss(small_lm):
     assert float(loss) < loss0 * 0.9
 
 
+def _lora_losses(small_lm, rank=2):
+    """The LoRA loss twice over one batch: through ``LLMBundle.apply`` (the
+    factored side path training runs) and through ``lora_merge`` (the plain
+    statement of the same mathematics)."""
+    model, params = small_lm
+    bundle = _lora_bundle(small_lm, rank)
+    x = jax.random.randint(jax.random.PRNGKey(3), (2, 8), 4, 64)
+    batch = {"x": x, "y": x, "mask": jnp.ones(2)}
+    rng = jax.random.PRNGKey(0)
+    factored = CausalLMTrainer(bundle.apply)
+    merged = CausalLMTrainer(
+        lambda lora, x, rng=None, train=False: model.apply(
+            {"params": lora_merge(params, lora, bundle.lora_alpha)}, x))
+    return (bundle, lambda lora: factored.loss(lora, batch, rng)[0],
+            lambda lora: merged.loss(lora, batch, rng)[0])
+
+
+@pytest.mark.parametrize("b_std", [0.0, 0.05])
+def test_lora_factored_matches_merged(small_lm, b_std):
+    bundle, factored, merged = _lora_losses(small_lm)
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(
+        bundle.init(jax.random.PRNGKey(2), None))
+    assert len(leaves) == 2 * 7 * CFG.num_layers
+    lora = treedef.unflatten([
+        leaf if path[-1].key == "lora_a" else b_std * jax.random.normal(
+            jax.random.fold_in(jax.random.PRNGKey(4), i), leaf.shape)
+        for i, (path, leaf) in enumerate(leaves)])
+    lf, gf = jax.value_and_grad(factored)(lora)
+    lm, gm = jax.value_and_grad(merged)(lora)
+    np.testing.assert_allclose(float(lf), float(lm), atol=1e-5)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(gf),
+                            jax.tree_util.tree_leaves(gm)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5,
+                                   err_msg=jax.tree_util.keystr(path))
+    # at b = 0 every lora_a has a zero gradient and lora_b a live one
+    live = {jax.tree_util.keystr(p) for p, g in
+            jax.tree_util.tree_leaves_with_path(gf) if np.abs(g).max() > 0}
+    assert any("lora_b" in k for k in live)
+    assert any("lora_a" in k for k in live) == (b_std > 0)
+
+
+def _dot_result_shapes(jaxpr):
+    """Result shapes of every ``dot_general`` in a jaxpr, sub-jaxprs
+    (pjit, custom_vjp, remat, scan) included."""
+    shapes = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            shapes += [tuple(v.aval.shape) for v in eqn.outvars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            shapes += _dot_result_shapes(sub)
+    return shapes
+
+
+def test_lora_grad_takes_no_frozen_weight_gradient(small_lm):
+    """The backward pass of the LoRA loss may produce no matrix of a frozen
+    kernel's shape: that is the full weight gradient LoRA exists to avoid.
+    The merged formulation does produce them, which checks the detector."""
+    bundle, factored, merged = _lora_losses(small_lm)
+    lora = bundle.init(jax.random.PRNGKey(2), None)
+    frozen = set()
+    for leaf in jax.tree_util.tree_leaves(bundle.base_params):
+        if leaf.ndim >= 2:
+            two_d = (leaf.shape[0], int(np.prod(leaf.shape[1:])))
+            frozen |= {tuple(leaf.shape), two_d, two_d[::-1]}
+
+    def weight_shaped(loss):
+        dots = _dot_result_shapes(jax.make_jaxpr(jax.grad(loss))(lora).jaxpr)
+        assert dots
+        return [s for s in dots if s in frozen]
+
+    assert weight_shaped(factored) == []
+    assert len(weight_shaped(merged)) >= 7 * CFG.num_layers
+
+
+@slow
 def test_fsdp_tp_sharded_step():
     """Train step jitted over a fsdp×tensor mesh compiles, executes, and
     matches the unsharded step numerically."""
@@ -349,6 +437,7 @@ def test_fsdp_tp_sharded_step():
                                    atol=1e-4)
 
 
+@slow
 def test_federated_lora_two_silos_parity():
     """2 silos with FedAvg over adapters: with full participation and equal
     shards, the federated run must track single-silo training on the union
@@ -370,6 +459,7 @@ def test_federated_lora_two_silos_parity():
     assert abs(r2["final_test_loss"] - r1["final_test_loss"]) < 0.35
 
 
+@slow
 def test_hf_llama_import_roundtrip():
     """Fabricated Llama-named torch state dict → flax params → forward."""
     import torch

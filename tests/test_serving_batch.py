@@ -53,9 +53,9 @@ def _rand_adapter(template, seed):
 @pytest.fixture(scope="module")
 def lora_setup():
     """LoRA artifact (bundle.base_params frozen, params = adapter tree):
-    the single path serves it MERGED, the batch path serves it FACTORED
-    from the adapter bank — parity across that split is the acceptance
-    pin."""
+    the single path applies it through ``bundle.apply``, the batch path
+    from the adapter bank, both as factored side paths — parity across
+    that split is the acceptance pin."""
     import jax
     args = _args()
     _, bundle, _, tok = build_llm(args)
