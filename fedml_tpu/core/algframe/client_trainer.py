@@ -31,7 +31,16 @@ class TrainerSpec:
     """Pure-function trainer: subclass or compose to customize the loss.
 
     ``apply_fn(params, x, rng=...)`` is the model forward (flax ``apply``).
+    ``extra_metrics`` names further scalars a subclass's ``loss`` puts into
+    its aux dict beside ``loss_sum`` / ``correct`` / ``count``; local
+    training and the round sum them like those, and the TPU engine hands a
+    round's sums, once they are on the host, to ``record_round_counters``.
     """
+
+    extra_metrics: Tuple[str, ...] = ()
+
+    def record_round_counters(self, sums: Dict[str, float]) -> None:
+        """A finished round's ``extra_metrics`` sums (no-op by default)."""
 
     def __init__(self, apply_fn: Callable[..., jnp.ndarray]):
         self.apply_fn = apply_fn

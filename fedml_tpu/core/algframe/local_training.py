@@ -28,6 +28,13 @@ PyTree = Any
 GradTransform = Callable[[PyTree, PyTree, Dict[str, Any]], PyTree]
 
 
+def zero_train_metrics(spec: TrainerSpec) -> Dict[str, jnp.ndarray]:
+    """The summed training metrics at zero: the three every trainer
+    reports and the spec's ``extra_metrics``."""
+    return {k: jnp.float32(0) for k in
+            ("loss_sum", "correct", "count") + tuple(spec.extra_metrics)}
+
+
 def run_local_sgd(
     spec: TrainerSpec,
     inner_opt: optax.GradientTransformation,
@@ -75,8 +82,7 @@ def run_local_sgd(
     denom = jnp.maximum(real_batches, 1)
     data_rng, loop_rng = jax.random.split(rng)
     ctx = ctx or {}
-    zero_metrics = {"loss_sum": jnp.float32(0), "correct": jnp.float32(0),
-                    "count": jnp.float32(0)}
+    zero_metrics = zero_train_metrics(spec)
 
     def epoch_order(epoch):
         keys = jax.random.uniform(jax.random.fold_in(data_rng, epoch),

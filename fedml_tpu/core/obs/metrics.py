@@ -476,6 +476,36 @@ def record_hbm_peak(in_use_gb: float, reserved_gb: float) -> None:
                    "(GiB)").set(float(in_use_gb) + float(reserved_gb))
 
 
+def record_moe_round(slots_held: float, load_max_sum: float,
+                     layer_steps: float, expert_steps: float,
+                     dropped: float) -> None:
+    """Router load of one finished round, from the sums the round program
+    itself reported over its expert layers and train steps: token-slots
+    routed to the experts held here, the fullest held expert's tokens and
+    the mean held expert's (a layer and step), slots that found no row."""
+    if not _cfg["enabled"]:
+        return
+    REGISTRY.gauge("fed_moe_slots_held",
+                   "token-slots routed to held experts, last round"
+                   ).set(float(slots_held))
+    REGISTRY.gauge("fed_moe_load_max",
+                   "tokens of the fullest held expert, mean over layers "
+                   "and steps of the last round"
+                   ).set(float(load_max_sum) / max(float(layer_steps), 1.0))
+    REGISTRY.gauge("fed_moe_load_mean",
+                   "tokens a held expert, mean over experts, layers and "
+                   "steps of the last round"
+                   ).set(float(slots_held) / max(float(expert_steps), 1.0))
+    REGISTRY.counter("fed_moe_slots_held_total",
+                     "token-slots routed to held experts, every recorded "
+                     "round").inc(float(slots_held))
+    REGISTRY.counter("fed_moe_rounds_total",
+                     "rounds whose router load was recorded").inc(1.0)
+    REGISTRY.counter("fed_moe_dropped",
+                     "held token-slots that found no row (must stay 0)"
+                     ).inc(float(dropped))
+
+
 def record_roofline(program: str, predicted_mfu: Optional[float],
                     memory_bound_share: Optional[float],
                     collective_wire_bytes: Optional[float]) -> None:

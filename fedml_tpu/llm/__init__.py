@@ -1,10 +1,13 @@
 """FedLLM — the LLM fine-tuning pillar (reference ``train/llm/`` +
 ``spotlight_prj/unitedllm/``), rebuilt TPU-first:
 
-- ``model``: flax Llama-style decoder (RMSNorm/rotary/SwiGLU), bf16
-  compute, MXU-shaped matmuls.
-- ``attention``: dense golden + Pallas flash kernel + ring attention over
-  the ``sp`` mesh axis for long context.
+- ``model``: flax decoder (RMSNorm/rotary/SwiGLU) with grouped-query or
+  latent attention and dense or sparse-expert layers, bf16 compute,
+  MXU-shaped matmuls.
+- ``moe``: routing over all experts, the dropless plan for the experts a
+  rank holds, the Pallas grouped product over them.
+- ``attention``: dense golden + Pallas flash kernels (``d_qk != d_v``
+  too) + ring attention over the ``sp`` mesh axis for long context.
 - ``lora``: adapters as a pure pytree transform; federated rounds ship
   adapters only.
 - ``sharding``: FSDP/TP partition specs (XLA-FSDP, the DeepSpeed ZeRO
@@ -18,11 +21,13 @@
 from .model import CausalLM, LLMConfig, init_llm
 from .lora import lora_init, lora_merge, lora_param_count
 from .trainer import CausalLMTrainer
-from .federated import LLMBundle, build_llm, llm_config_from_args, run_federated_llm
+from .federated import (LLMBundle, build_llm, llm_config_from_args,
+                        llm_config_from_hf, run_federated_llm)
 
 __all__ = [
     "CausalLM", "LLMConfig", "init_llm",
     "lora_init", "lora_merge", "lora_param_count",
     "CausalLMTrainer",
-    "LLMBundle", "build_llm", "llm_config_from_args", "run_federated_llm",
+    "LLMBundle", "build_llm", "llm_config_from_args", "llm_config_from_hf",
+    "run_federated_llm",
 ]

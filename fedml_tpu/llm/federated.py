@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from .data import ByteTokenizer, build_llm_federated
 from .lora import lora_init
 from .model import CausalLM, LLMConfig, init_llm
+from .moe import STATS as MOE_STATS
 from .trainer import CausalLMTrainer
 
 logger = logging.getLogger(__name__)
@@ -58,6 +59,56 @@ def llm_config_from_args(args) -> LLMConfig:
     )
 
 
+def llm_config_from_hf(config: dict, *, max_seq_len: int,
+                       dtype: str = "float32", attention_impl: str = "dense",
+                       first_expert: int = 0,
+                       experts_held: int = 0) -> LLMConfig:
+    """An :class:`LLMConfig` from a published ``config.json`` dict (the keys
+    of the Llama/Mistral family and of the DeepSeek-V3 family, which
+    ``axk1`` shares: latent attention, sigmoid-routed experts with shared
+    ones, leading dense layers, YaRN). ``first_expert`` / ``experts_held``
+    say which routed experts this expert-parallel rank holds (0 = all)."""
+    get = config.get
+    scaling = get("rope_scaling")
+    if get("topk_method", "none") not in ("none", "greedy"):
+        raise NotImplementedError(
+            f"topk_method {get('topk_method')!r}: group-limited and "
+            "bias-corrected routing are not built")
+    if get("n_routed_experts") and get("scoring_func", "sigmoid") != "sigmoid":
+        raise NotImplementedError(f"scoring_func {get('scoring_func')!r}")
+    if get("n_routed_experts") and get("moe_layer_freq", 1) != 1:
+        raise NotImplementedError("moe_layer_freq != 1")
+    if scaling and scaling.get("mscale", 1) != scaling.get("mscale_all_dim", 1):
+        raise NotImplementedError("rotary cos/sin scale mscale / "
+                                  "mscale_all_dim != 1")
+    return LLMConfig(
+        vocab_size=int(config["vocab_size"]),
+        hidden_size=int(config["hidden_size"]),
+        intermediate_size=int(config["intermediate_size"]),
+        num_layers=int(config["num_hidden_layers"]),
+        num_heads=int(config["num_attention_heads"]),
+        num_kv_heads=get("num_key_value_heads"),
+        max_seq_len=int(max_seq_len),
+        rope_theta=float(get("rope_theta", 10000.0)),
+        rms_eps=float(get("rms_norm_eps", 1e-6)),
+        dtype=dtype, attention_impl=attention_impl,
+        tie_embeddings=bool(get("tie_word_embeddings", False)),
+        rope_scaling=dict(scaling) if scaling else None,
+        routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        **{k: int(get(k) or 0) for k in (
+            "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+            "qk_rope_head_dim", "v_head_dim", "n_routed_experts",
+            "num_experts_per_tok", "moe_intermediate_size",
+            "n_shared_experts", "first_k_dense_replace")},
+        first_expert=int(first_expert), experts_held=int(experts_held))
+
+
+# sums a model with experts reports a step through the ``moe_stats``
+# collection, and the trainer's metrics carry out of the round program
+MOE_METRICS = tuple("moe_" + k for k in MOE_STATS)
+
+
 @dataclasses.dataclass
 class LLMBundle:
     """ModelBundle-compatible wrapper whose trainable pytree is the LoRA
@@ -67,7 +118,9 @@ class LLMBundle:
     ``x W + ((x a) b) * (alpha / rank)`` and are never merged into ``W``:
     the base is a constant of the forward, so the backward pass takes the
     rank-r gradients of ``a`` and ``b`` and no ``[d_in, d_out]`` weight
-    gradient of a frozen kernel."""
+    gradient of a frozen kernel. The base stays in the dtype it is given in
+    (a bfloat16 checkpoint is held once, in bfloat16: the forward casts
+    kernels to the compute dtype, which is then no copy)."""
 
     module: CausalLM
     cfg: LLMConfig
@@ -81,13 +134,36 @@ class LLMBundle:
             return lora_init(rng, self.base_params, rank=self.lora_rank)
         return self.module.init(rng, sample_input[:1])["params"]
 
-    def apply(self, params, x, rng=None, train=False):
+    def __post_init__(self):
+        if self.base_params is None and self.cfg.n_routed_experts:
+            raise NotImplementedError(
+                "routed experts are frozen (llm/moe.py takes no weight "
+                "gradient of them): fine-tune a model with experts through "
+                "adapters (lora_rank > 0)")
+
+    @property
+    def extra_metrics(self):
+        """Names of the sums ``apply(with_stats=True)`` returns."""
+        return MOE_METRICS if self.cfg.n_routed_experts else ()
+
+    def apply(self, params, x, rng=None, train=False, with_stats=False):
+        """-> logits, or ``(logits, {name: sum})`` over
+        :attr:`extra_metrics` with ``with_stats``."""
         del rng  # no dropout in the decoder
-        if self.base_params is None:
-            return self.module.apply({"params": params}, x, train=train)
-        return self.module.apply(
-            {"params": self.base_params}, x, train=train, adapters=params,
-            lora_scale=self.lora_alpha / self.lora_rank)
+        variables, kwargs = {"params": params}, {}
+        if self.base_params is not None:
+            variables = {"params": self.base_params}
+            kwargs = {"adapters": params,
+                      "lora_scale": self.lora_alpha / self.lora_rank}
+        if not with_stats:
+            return self.module.apply(variables, x, train=train, **kwargs)
+        logits, state = self.module.apply(variables, x, train=train,
+                                          mutable=["moe_stats"], **kwargs)
+        sums = {}
+        for layer in state.get("moe_stats", {}).values():
+            for k, v in layer["moe"].items():
+                sums["moe_" + k] = sums.get("moe_" + k, 0.0) + v
+        return logits, sums
 
 
 def build_llm_bundle(args) -> Tuple[LLMBundle, ByteTokenizer]:
@@ -110,7 +186,7 @@ def build_llm(args) -> Tuple[Any, LLMBundle, CausalLMTrainer, ByteTokenizer]:
     n_silos = int(getattr(args, "client_num_in_total", 2))
     fed, tokenizer = build_llm_federated(args, n_silos,
                                          bundle.cfg.max_seq_len)
-    spec = CausalLMTrainer(bundle.apply)
+    spec = CausalLMTrainer(bundle.apply, bundle.extra_metrics)
     return fed, bundle, spec, tokenizer
 
 

@@ -27,8 +27,12 @@ from flax import traverse_util
 
 PyTree = Any
 
-# kernel parents targeted by default: attention projections + MLP
-DEFAULT_TARGETS: Tuple[str, ...] = ("q", "k", "v", "o", "gate", "up", "down")
+# kernel parents targeted by default: attention projections (grouped-query
+# q k v o; latent attention's q_a q_b kv_a kv_b o) + the dense MLP and the
+# shared expert (gate up down). Routed experts and the router carry no
+# "kernel" leaf under these names and stay frozen without adapters.
+DEFAULT_TARGETS: Tuple[str, ...] = ("q", "k", "v", "o", "gate", "up", "down",
+                                    "q_a", "q_b", "kv_a", "kv_b")
 
 
 def _target_paths(params: PyTree, targets: Sequence[str]):

@@ -48,10 +48,42 @@ class LLMConfig:
     attention_impl: str = "dense"
     # tie input embedding and LM head (small models)
     tie_embeddings: bool = True
+    # rotary frequencies' rescaling, the published ``rope_scaling`` group
+    # (``type: yarn``) or None
+    rope_scaling: Optional[dict] = None
+    # latent attention (``kv_lora_rank > 0``): queries and keys/values pass
+    # through low-rank latents with their own RMSNorms; a head's query and
+    # key carry ``qk_nope_head_dim`` content dims and ``qk_rope_head_dim``
+    # rotary dims (the rotary key is one vector a token, shared by all
+    # heads), its value ``v_head_dim``
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # sparse experts (``n_routed_experts > 0``): the first
+    # ``first_k_dense_replace`` layers keep the dense MLP, the rest route
+    # each token to ``num_experts_per_tok`` of ``n_routed_experts`` SwiGLU
+    # experts of ``moe_intermediate_size`` beside ``n_shared_experts`` that
+    # every token passes. This rank of an expert-parallel layer holds
+    # ``experts_held`` of them from ``first_expert`` on (0 = all)
+    n_routed_experts: int = 0
+    num_experts_per_tok: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    first_k_dense_replace: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    first_expert: int = 0
+    experts_held: int = 0
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_routed_experts
 
     @property
     def kv_heads(self) -> int:
@@ -61,14 +93,23 @@ class LLMConfig:
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
+    def _dense_only(self, what: str) -> None:
+        if self.kv_lora_rank or self.n_routed_experts:
+            raise NotImplementedError(
+                f"LLMConfig.{what} counts the dense grouped-query decoder "
+                "alone; a configuration with latent attention or experts "
+                "is counted from its shapes under benchmarks/flops/")
+
     def flops_per_token(self) -> float:
         """Approximate fwd+bwd FLOPs per token (6 * params + attention),
-        used by the bench's MFU report."""
+        used by the bench's MFU report. Dense decoder only."""
+        self._dense_only("flops_per_token")
         p = self.param_count()
         attn = 12 * self.num_layers * self.hidden_size * self.max_seq_len
         return 6.0 * p + attn
 
     def param_count(self) -> int:
+        self._dense_only("param_count")
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         per_layer = (h * h * 2 +                       # q, o
                      2 * h * self.kv_heads * self.head_dim +  # k, v
@@ -78,10 +119,45 @@ class LLMConfig:
         return self.num_layers * per_layer + emb + h
 
 
-def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Rotary position embedding. x: [b, s, heads, head_dim]."""
-    half = x.shape[-1] // 2
+def rope_frequencies(dim: int, theta: float,
+                     scaling: Optional[dict] = None) -> jnp.ndarray:
+    """The ``dim // 2`` rotary frequencies. ``scaling`` (``type: yarn``;
+    Peng et al. 2023 as the DeepSeek-V3 modelling code computes it) blends
+    each plain frequency with its ``factor``-times-slower interpolation:
+    dimensions that turn more than ``beta_fast`` times within the original
+    context keep theirs, those under ``beta_slow`` turns are interpolated,
+    a linear ramp between."""
+    half = dim // 2
     freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is None:
+        return freq
+    if scaling.get("type", scaling.get("rope_type")) != "yarn":
+        raise NotImplementedError(f"rope_scaling {scaling!r}")
+    orig = scaling["original_max_position_embeddings"]
+
+    def turns_dim(turns):
+        return dim * np.log(orig / (turns * 2 * np.pi)) / (2 * np.log(theta))
+
+    low = max(np.floor(turns_dim(scaling["beta_fast"])), 0)
+    high = min(np.ceil(turns_dim(scaling["beta_slow"])), dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0, 1)
+    return freq / scaling["factor"] * ramp + freq * (1 - ramp)
+
+
+def yarn_mscale(scaling: Optional[dict], key: str) -> float:
+    """YaRN's magnitude term ``0.1 * m * ln(factor) + 1`` for the group's
+    ``mscale`` or ``mscale_all_dim`` (1 without scaling or at m = 0)."""
+    if scaling is None or scaling.get("factor", 1) <= 1:
+        return 1.0
+    return 0.1 * scaling.get(key, 0) * float(np.log(scaling["factor"])) + 1.0
+
+
+def _rope(x: jnp.ndarray, positions: jnp.ndarray, freq) -> jnp.ndarray:
+    """Rotary position embedding in the half-split convention.
+    x: [b, s, heads, head_dim]; ``freq``: its ``head_dim // 2``
+    frequencies."""
+    half = x.shape[-1] // 2
     ang = positions[..., None].astype(jnp.float32) * freq  # [b, s, half]
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
@@ -160,8 +236,10 @@ class Attention(nn.Module):
             "v": dense((cfg.kv_heads, cfg.head_dim), "v")(x),
         }, adapter, lora_scale)
         q, k, v = qkv["q"], qkv["k"], qkv["v"]
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
+                                cfg.rope_scaling)
+        q = _rope(q, positions, freq)
+        k = _rope(k, positions, freq)
 
         from .attention import cached_attention, causal_attention
         if kv_view is not None:
@@ -193,41 +271,152 @@ class Attention(nn.Module):
         return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], new_kv
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3): ``c_q = norm(x W_qa)``,
+    ``q = c_q W_qb`` -> heads of ``nope + rope`` dims; ``[c_kv | k_r] =
+    x W_kva``, ``[k_nope | v] = norm(c_kv) W_kvb``; rotary on ``q_rope`` and
+    on the one ``k_r`` all heads share; scores scaled by ``(nope +
+    rope) ** -0.5`` times YaRN's ``mscale_all_dim`` term squared. Training
+    path only: a cache of latents is serving work."""
+
+    cfg: LLMConfig
+
+    @nn.compact
+    def __call__(self, x, positions, attn_mask=None, kv_view=None,
+                 adapter=None, lora_scale: float = 1.0):
+        if kv_view is not None:
+            raise NotImplementedError(
+                "latent attention has no cache path: llm/kv_cache.py holds "
+                "per-head keys and values, not the kv_lora_rank latent and "
+                "the shared rotary key a latent cache stores")
+        cfg = self.cfg
+        b, s, _ = x.shape
+        nh, nope, rope, dv = (cfg.num_heads, cfg.qk_nope_head_dim,
+                              cfg.qk_rope_head_dim, cfg.v_head_dim)
+        dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
+            feats, axis=-1, use_bias=False, name=name,
+            dtype=cfg.compute_dtype, param_dtype=jnp.float32)
+
+        with jax.named_scope("attn.latent"):
+            down = _add_lora(x, {
+                "q_a": dense(cfg.q_lora_rank, "q_a")(x),
+                "kv_a": dense(cfg.kv_lora_rank + rope, "kv_a")(x),
+            }, adapter, lora_scale)
+            c_q = RMSNorm(cfg.rms_eps, name="q_norm")(down["q_a"])
+            c_kv = RMSNorm(cfg.rms_eps, name="kv_norm")(
+                down["kv_a"][..., :cfg.kv_lora_rank])
+            k_r = down["kv_a"][..., cfg.kv_lora_rank:]
+            q = _add_lora(c_q, {"q_b": dense((nh, nope + rope), "q_b")(c_q)},
+                          adapter, lora_scale)["q_b"]
+            kv = _add_lora(c_kv, {"kv_b": dense((nh, nope + dv), "kv_b")(c_kv)},
+                           adapter, lora_scale)["kv_b"]
+            freq = rope_frequencies(rope, cfg.rope_theta, cfg.rope_scaling)
+            q = jnp.concatenate(
+                [q[..., :nope], _rope(q[..., nope:], positions, freq)], -1)
+            k_r = _rope(k_r[:, :, None, :], positions, freq)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_r, (b, s, nh, rope))], -1)
+            m = yarn_mscale(cfg.rope_scaling, "mscale_all_dim")
+
+            from .attention import causal_attention
+            out = causal_attention(q, k, kv[..., nope:],
+                                   impl=cfg.attention_impl,
+                                   attn_mask=attn_mask,
+                                   scale=(nope + rope) ** -0.5 * m * m)
+            out = out.reshape(b, s, nh * dv)
+            y = nn.DenseGeneral(cfg.hidden_size, use_bias=False, name="o",
+                                dtype=cfg.compute_dtype,
+                                param_dtype=jnp.float32)(out)
+            return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], None
+
+
 class MLP(nn.Module):
     cfg: LLMConfig
+    width: Optional[int] = None   # None: cfg.intermediate_size
 
     @nn.compact
     def __call__(self, x, adapter=None, lora_scale: float = 1.0):
         cfg = self.cfg
+        width = self.width or cfg.intermediate_size
         dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
             feats, use_bias=False, name=name, dtype=cfg.compute_dtype,
             param_dtype=jnp.float32)
 
         ys = _add_lora(x, {
-            "gate": dense(cfg.intermediate_size, "gate")(x),
-            "up": dense(cfg.intermediate_size, "up")(x),
+            "gate": dense(width, "gate")(x),
+            "up": dense(width, "up")(x),
         }, adapter, lora_scale)
         act = nn.silu(ys["gate"]) * ys["up"]
         return _add_lora(act, {"down": dense(cfg.hidden_size, "down")(act)},
                          adapter, lora_scale)["down"]
 
 
-class DecoderLayer(nn.Module):
+class MoE(nn.Module):
+    """``shared(x) + sum over the top-k experts this rank holds of g_e
+    E_e(x)``: sigmoid scores in float32 over ALL ``n_routed_experts``, plain
+    top-k, the chosen scores normalised and scaled; the rank computes the
+    part of its own ``cfg.held`` experts (``first_expert`` on) and leaves
+    out what absent experts would add. Dropless. The routed experts and the
+    router are frozen (no adapters, no weight gradient); the shared expert
+    is an :class:`MLP` and takes ``adapter["shared"]``. Router load leaves
+    through the ``moe_stats`` collection as sums."""
+
     cfg: LLMConfig
+
+    @nn.compact
+    def __call__(self, x, adapter=None, lora_scale: float = 1.0):
+        from . import moe
+
+        cfg = self.cfg
+        b, s, h = x.shape
+        held, width = cfg.held, cfg.moe_intermediate_size
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        w_gate = self.param("experts_gate", init, (held, h, width))
+        w_up = self.param("experts_up", init, (held, h, width))
+        w_down = self.param("experts_down", init, (held, width, h))
+        shared = MLP(cfg, width * cfg.n_shared_experts, name="shared")(
+            x, adapter=None if adapter is None else adapter.get("shared"),
+            lora_scale=lora_scale)
+        flat = x.reshape(b * s, h)
+        with jax.named_scope("moe.route"):
+            logits = nn.DenseGeneral(
+                cfg.n_routed_experts, use_bias=False, name="router",
+                dtype=jnp.float32, param_dtype=jnp.float32)(
+                flat.astype(jnp.float32))
+            gates, chosen = moe.route(logits, cfg.num_experts_per_tok,
+                                      cfg.routed_scaling_factor,
+                                      cfg.norm_topk_prob)
+        with jax.named_scope("moe.experts"):
+            routed, stats = moe.routed_experts(
+                flat, gates, chosen, w_gate, w_up, w_down, cfg.first_expert)
+        for k, v in stats.items():
+            self.sow("moe_stats", k, v, init_fn=lambda: jnp.float32(0),
+                     reduce_fn=jnp.add)
+        return shared + routed.reshape(b, s, h).astype(shared.dtype)
+
+
+class DecoderLayer(nn.Module):
+    """One pre-norm layer; the configuration says which attention it has
+    (grouped-query or latent) and ``sparse`` whether its feed-forward is the
+    expert block (``moe``) or the dense MLP (``mlp``)."""
+
+    cfg: LLMConfig
+    sparse: bool = False
 
     @nn.compact
     def __call__(self, x, positions, attn_mask=None, kv_view=None,
                  adapter=None, lora_scale: float = 1.0):
-        attn = adapter.get("attn") if adapter is not None else None
-        mlp = adapter.get("mlp") if adapter is not None else None
-        a_out, new_kv = Attention(self.cfg, name="attn")(
+        adapter = adapter or {}
+        attention = LatentAttention if self.cfg.kv_lora_rank else Attention
+        a_out, new_kv = attention(self.cfg, name="attn")(
             RMSNorm(self.cfg.rms_eps, name="ln_attn")(x), positions,
-            attn_mask, kv_view=kv_view, adapter=attn,
+            attn_mask, kv_view=kv_view, adapter=adapter.get("attn"),
             lora_scale=lora_scale)
         h = x + a_out
-        h = h + MLP(self.cfg, name="mlp")(
-            RMSNorm(self.cfg.rms_eps, name="ln_mlp")(h), adapter=mlp,
-            lora_scale=lora_scale)
+        ff, name = (MoE, "moe") if self.sparse else (MLP, "mlp")
+        h = h + ff(self.cfg, name=name)(
+            RMSNorm(self.cfg.rms_eps, name="ln_mlp")(h),
+            adapter=adapter.get(name), lora_scale=lora_scale)
         return h, new_kv
 
 
@@ -267,7 +456,9 @@ class CausalLM(nn.Module):
             positions = jnp.broadcast_to(pos[None, :], tokens.shape)
         new_kvs = []
         for i in range(cfg.num_layers):
-            x, new_kv = DecoderLayer(cfg, name=f"layer_{i}")(
+            sparse = bool(cfg.n_routed_experts) and \
+                i >= cfg.first_k_dense_replace
+            x, new_kv = DecoderLayer(cfg, sparse, name=f"layer_{i}")(
                 x, positions, attn_mask,
                 kv_view=None if kv_view is None else kv_view[i],
                 adapter=None if adapters is None

@@ -26,13 +26,35 @@ PyTree = Any
 class CausalLMTrainer(TrainerSpec):
     """Next-token CE. Batch: ``x`` [bs, L] int tokens, ``y`` [bs, L] labels
     with ``-1`` = ignore (prompt tokens under completion-only masking,
-    right-padding), ``mask`` [bs] per-sample realness."""
+    right-padding), ``mask`` [bs] per-sample realness.
+
+    ``extra_metrics``: names of further sums the model reports a step
+    (``LLMBundle.extra_metrics``: router load of a model with experts);
+    ``apply_fn(..., with_stats=True)`` then returns them beside the logits,
+    the training metrics carry them out of the round program, and
+    :meth:`record_round_counters` turns a round's sums into ``fed_moe_*``."""
+
+    def __init__(self, apply_fn, extra_metrics=()):
+        super().__init__(apply_fn)
+        self.extra_metrics = tuple(extra_metrics)
+
+    def record_round_counters(self, sums):
+        from ..core.obs import metrics as obs_metrics
+        obs_metrics.record_moe_round(
+            sums["moe_slots_held"], sums["moe_load_max"],
+            sums["moe_layer_steps"], sums["moe_expert_steps"],
+            sums["moe_dropped"])
 
     def _stats(self, params, batch, rng, train):
         kwargs = {"train": train}
         if rng is not None:
             kwargs["rng"] = rng
-        logits = self.apply_fn(params, batch["x"], **kwargs)
+        extra = {}
+        if train and self.extra_metrics:
+            logits, extra = self.apply_fn(params, batch["x"],
+                                          with_stats=True, **kwargs)
+        else:
+            logits = self.apply_fn(params, batch["x"], **kwargs)
         labels = batch["y"].astype(jnp.int32)
         tok_w = ((labels >= 0).astype(jnp.float32)
                  * batch["mask"].astype(jnp.float32)[:, None])
@@ -41,14 +63,14 @@ class CausalLMTrainer(TrainerSpec):
         loss_sum = jnp.sum(per_tok * tok_w)
         correct = jnp.sum((jnp.argmax(logits, -1) == safe) * tok_w)
         count = jnp.sum(tok_w)
-        return loss_sum, correct, count
+        return loss_sum, correct, count, extra
 
     def loss(self, params, batch, rng):
-        loss_sum, correct, count = self._stats(params, batch, rng, True)
+        loss_sum, correct, count, extra = self._stats(params, batch, rng, True)
         loss = loss_sum / jnp.maximum(count, 1.0)
-        return loss, {"loss_sum": loss_sum, "correct": correct,
-                      "count": count}
+        return loss, dict(extra, loss_sum=loss_sum, correct=correct,
+                          count=count)
 
     def eval_stats(self, params, batch):
-        loss_sum, correct, count = self._stats(params, batch, None, False)
+        loss_sum, correct, count, _ = self._stats(params, batch, None, False)
         return {"loss_sum": loss_sum, "correct": correct, "count": count}

@@ -25,11 +25,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ...constants import AXIS_CLIENT
 from ...core.algframe.types import ClientData, TrainHyper
-from ...core.algframe.local_training import evaluate
+from ...core.algframe.local_training import evaluate, zero_train_metrics
 from ...core.collectives import (
     psum_tree, tree_scale, tree_zeros_like, vector_to_tree_like)
 from ...core.dp import FedMLDifferentialPrivacy
 from ...core import mlops
+from ...core.obs import metrics as obs_metrics
 from ...core.obs import profiler as obs_profiler
 from ...core.obs import roofline as obs_roofline
 from ...core.obs import trace as obs_trace
@@ -420,8 +421,7 @@ class TPUSimulator:
             dev = jax.lax.axis_index(AXIS_CLIENT)
             zero_update = tree_zeros_like(params)
             zero_extras = opt.server_extras_zero(params)
-            zero_metrics = {"loss_sum": jnp.float32(0), "correct": jnp.float32(0),
-                            "count": jnp.float32(0)}
+            zero_metrics = zero_train_metrics(self.spec)
 
             def run_slot(states, li, active, ws):
                 """Train one schedule slot. CDP soundness note: the
@@ -772,8 +772,7 @@ class TPUSimulator:
                  sched_idx, sched_active, sched_work, round_key, hyper):
             dev = jax.lax.axis_index(AXIS_CLIENT)
             zero_extras = opt.server_extras_zero(params)
-            zero_metrics = {"loss_sum": jnp.float32(0), "correct": jnp.float32(0),
-                            "count": jnp.float32(0)}
+            zero_metrics = zero_train_metrics(self.spec)
 
             def slot(carry, s):
                 states, acc_ex, acc_w, acc_m = carry
@@ -1445,7 +1444,37 @@ class TPUSimulator:
             metrics = self._run_round_traced(round_idx, hyper)
             if sp is not obs_trace.NOOP_SPAN:  # sampled at a span's close
                 obs_profiler.sample_hbm_peak_gb()
+            self._hold_program_counters(metrics)
             return metrics
+
+    def _hold_program_counters(self, metrics) -> None:
+        """Counters the round program computed itself (the spec's
+        ``extra_metrics``: router load of a model with experts) reach the
+        registry from the program's own result and never by a wait of
+        their own: a round's sums are held as they come back (device
+        arrays after ``run_round``) until :meth:`flush_program_counters`
+        or the next round's dispatch. Nothing is held or read with the
+        metrics registry off."""
+        keys = self.spec.extra_metrics
+        if not keys or not obs_metrics.is_enabled():
+            return
+        self.flush_program_counters(wait=False)
+        self._program_counters = {k: metrics[k] for k in keys}
+
+    def flush_program_counters(self, wait: bool = True) -> None:
+        """Record the held round's counters. For the caller that has read
+        that round's loss (``run()`` after its readback, a driver of
+        ``run_round`` after its own): the sums are ready then. With
+        ``wait`` false, sums that are not ready yet stay held."""
+        sums = getattr(self, "_program_counters", None)
+        if sums is None:
+            return
+        if not wait and not all(v.is_ready() for v in sums.values()
+                                if isinstance(v, jax.Array)):
+            return
+        self._program_counters = None
+        self.spec.record_round_counters(
+            {k: float(v) for k, v in sums.items()})
 
     def _run_round_traced(self, round_idx: int,
                           hyper: TrainHyper) -> Dict[str, float]:
@@ -1776,6 +1805,9 @@ class TPUSimulator:
                     cnt = max(float(metrics["count"]), 1.0)
                     rec["train_loss"] = float(metrics["loss_sum"]) / cnt
                     rec["train_acc"] = float(metrics["correct"]) / cnt
+                if not waits:  # a fused block's rounds never passed run_round
+                    self._hold_program_counters(metrics)
+                self.flush_program_counters()
                 if freq > 0 and (r % freq == 0 or r == rounds - 1):
                     with obs_trace.span("eval", root=True,
                                         attrs={"role": "engine",
@@ -1817,8 +1849,7 @@ class TPUSimulator:
         self.ckpt.flush()
         # final metrics snapshot: the cadence flush misses everything
         # after its last boundary — the run log must be self-contained
-        from ...core.obs import metrics as _obs_metrics
-        _obs_metrics.flush_final(step=rounds - 1)
+        obs_metrics.flush_final(step=rounds - 1)
         wall = time.time() - t0
         last_eval = next((r for r in reversed(self.history) if "test_acc" in r),
                          None)

@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 from fedml_tpu.core import kernels
 from fedml_tpu.core.kernels.conv_block import fused_block
 from fedml_tpu.core.obs import roofline
-from fedml_tpu.llm.attention import flash_causal_attention
+from fedml_tpu.llm import moe
+from fedml_tpu.llm.attention import FLASH_KERNEL_NAMES, flash_causal_attention
 
 pytestmark = pytest.mark.pallas
 
@@ -55,6 +56,40 @@ def test_flash_fwd_bwd_compiles_for_v5e(v5e, shape):
     compiled = _compile(_flash_train, v5e, q, q, q)
     # forward, dQ and dK/dV kernels, compiled by Mosaic — not interpreted
     assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_flash_unequal_head_sizes_compile_for_v5e(v5e):
+    """Latent attention's shape in the benchmark: 64 heads of d_qk 192
+    (not a multiple of the 128 lanes) and d_v 128 at 4,096 positions; the
+    same three kernels, found in the HLO under their names."""
+    q = jax.ShapeDtypeStruct((1, 4096, 64, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 4096, 64, 128), jnp.bfloat16)
+    text = _compile(
+        lambda q, k, v: jax.value_and_grad(
+            lambda q, k, v: flash_causal_attention(
+                q, k, v, scale=0.13).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v), v5e, q, q, v).as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in FLASH_KERNEL_NAMES:
+        assert name in text
+
+
+@pytest.mark.parametrize("transpose", [False, True])
+def test_grouped_matmul_compiles_for_v5e(v5e, transpose):
+    """The expert layer's grouped product at the benchmark's widths (12
+    held experts of 7168 x 2048, the worst-case row buffer of one step),
+    forward and activation gradient."""
+    rows, tile_m = moe.buffer_rows(4096, 8, 12, 256), 256
+    x = jax.ShapeDtypeStruct((rows, 2048 if transpose else 7168),
+                             jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((12, 7168, 2048), jnp.bfloat16)
+    tg = jax.ShapeDtypeStruct((rows // tile_m,), jnp.int32)
+    nt = jax.ShapeDtypeStruct((1,), jnp.int32)
+    text = _compile(lambda x, w, tg, nt: moe._gmm(x, w, tg, nt, tile_m,
+                                                  transpose),
+                    v5e, x, w, tg, nt).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert moe.GROUPED_KERNEL_NAMES[int(transpose)] in text
 
 
 def test_flash_bwd_never_materializes_scores(v5e):
