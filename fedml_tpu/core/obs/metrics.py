@@ -478,11 +478,12 @@ def record_hbm_peak(in_use_gb: float, reserved_gb: float) -> None:
 
 def record_moe_round(slots_held: float, load_max_sum: float,
                      layer_steps: float, expert_steps: float,
-                     dropped: float) -> None:
+                     dropped: float, compact_steps: float = 0.0) -> None:
     """Router load of one finished round, from the sums the round program
     itself reported over its expert layers and train steps: token-slots
     routed to the experts held here, the fullest held expert's tokens and
-    the mean held expert's (a layer and step), slots that found no row."""
+    the mean held expert's (a layer and step), slots that found no row,
+    passes whose row buffers had the compact size."""
     if not _cfg["enabled"]:
         return
     REGISTRY.gauge("fed_moe_slots_held",
@@ -504,6 +505,13 @@ def record_moe_round(slots_held: float, load_max_sum: float,
     REGISTRY.counter("fed_moe_dropped",
                      "held token-slots that found no row (must stay 0)"
                      ).inc(float(dropped))
+    REGISTRY.counter("fed_moe_layer_steps_total",
+                     "passes through an expert layer (a layer and train "
+                     "step), every recorded round").inc(float(layer_steps))
+    REGISTRY.counter("fed_moe_compact_steps_total",
+                     "of those, passes whose routing fit the compact row "
+                     "buffers (llm/moe.py::compact_rows)"
+                     ).inc(float(compact_steps))
 
 
 def record_roofline(program: str, predicted_mfu: Optional[float],

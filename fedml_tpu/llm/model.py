@@ -388,7 +388,8 @@ class MoE(nn.Module):
                                       cfg.norm_topk_prob)
         with jax.named_scope("moe.experts"):
             routed, stats = moe.routed_experts(
-                flat, gates, chosen, w_gate, w_up, w_down, cfg.first_expert)
+                flat, gates, chosen, w_gate, w_up, w_down, cfg.first_expert,
+                cfg.n_routed_experts)
         for k, v in stats.items():
             self.sow("moe_stats", k, v, init_fn=lambda: jnp.float32(0),
                      reduce_fn=jnp.add)

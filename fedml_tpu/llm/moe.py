@@ -8,14 +8,23 @@ of the layer's result; what absent experts would add is left out (their
 ranks add it in a deployment; nothing here stands in for them).
 
 Dropless with static shapes: the held slots are laid out expert by expert,
-each expert's rows starting on a ``tile_m`` boundary, in a buffer sized for
-the worst case (every slot of every token held). A row tile so belongs to
-one expert, and the grouped product is a tiled matmul whose weight block is
+each expert's rows starting on a ``tile_m`` boundary (``plan``: a slot's
+row does not depend on the buffer's size). A row tile so belongs to one
+expert, and the grouped product is a tiled matmul whose weight block is
 chosen per row tile (``grouped_matmul``, a Pallas kernel; tiles past the
 last used one are skipped and cost no HBM traffic). Tokens reach their rows
 and results return to their tokens by gathers in both directions of the
 autodiff (a slot and its row are a permutation of each other), never by a
 scatter-add.
+
+The row buffers follow the rows in use. ``compact_rows`` is twice the held
+slots a uniform router would send plus every expert's tail tile, a
+function of shapes alone; ``buffer_rows`` is the worst case (every slot of
+every token held). Where the first is the smaller, ``routed_experts`` runs
+the pass under ``jax.lax.cond(rows in use <= compact_rows)``: the same
+rows in the same order through the same products at either size, so the
+result is the same to the last bit, and a routing that overflows the
+compact buffer takes the worst-case one and drops nothing.
 """
 
 from __future__ import annotations
@@ -32,19 +41,24 @@ from ..core import kernels
 GROUPED_KERNEL_NAMES = ("moe_grouped_fwd", "moe_grouped_dx")
 # what ``routed_experts`` reports of one pass through one expert layer; the
 # caller sums them over layers and steps
-STATS = ("slots_held", "load_max", "dropped", "layer_steps", "expert_steps")
+STATS = ("slots_held", "load_max", "dropped", "layer_steps", "expert_steps",
+         "compact_steps")
 
 
 class Plan(NamedTuple):
-    """Where each held slot lives in the row buffer."""
-    row_token: jnp.ndarray    # [R] int32: the token a row reads (0 if unused)
-    row_used: jnp.ndarray     # [R] bool
+    """Where each held slot lives, whatever the row buffer's size."""
     slot_row: jnp.ndarray     # [T, k] int32: a held slot's row (0 if not held)
     slot_held: jnp.ndarray    # [T, k] bool
-    tile_group: jnp.ndarray   # [R / tile_m] int32: the local expert of a tile
+    ends: jnp.ndarray         # [held] int32: where each expert's tiles end
     num_tiles: jnp.ndarray    # [1] int32: tiles in use
     load: jnp.ndarray         # [held] int32: slots per held expert
-    dropped: jnp.ndarray      # () int32: held slots without a row (always 0)
+
+
+class Rows(NamedTuple):
+    """A plan laid into a buffer of R rows."""
+    row_token: jnp.ndarray    # [R] int32: the token a row reads (0 if unused)
+    row_used: jnp.ndarray     # [R] bool
+    tile_group: jnp.ndarray   # [R / tile_m] int32: the local expert of a tile
 
 
 def route(scores_in: jnp.ndarray, top_k: int, scaling: float,
@@ -59,19 +73,30 @@ def route(scores_in: jnp.ndarray, top_k: int, scaling: float,
     return vals * scaling, idx.astype(jnp.int32)
 
 
+def _ceil_to(n, tile_m: int):
+    return -(-n // tile_m) * tile_m
+
+
 def buffer_rows(tokens: int, top_k: int, held: int, tile_m: int) -> int:
     """Rows that hold any routing: every slot held, each expert's tail
     padded to a tile."""
-    rows = tokens * min(top_k, held) + held * tile_m
-    return -(-rows // tile_m) * tile_m
+    return _ceil_to(tokens * min(top_k, held) + held * tile_m, tile_m)
+
+
+def compact_rows(tokens: int, top_k: int, held: int, n_experts: int,
+                 tile_m: int) -> int:
+    """Rows that hold twice the slots a uniform router sends to ``held`` of
+    ``n_experts``, each expert's tail padded to a tile."""
+    return _ceil_to(-(-2 * tokens * top_k * held // n_experts), tile_m) \
+        + held * tile_m
 
 
 def plan(experts: jnp.ndarray, first_expert: int, held: int,
          tile_m: int) -> Plan:
-    """Lay the slots that chose experts ``first_expert .. first_expert +
-    held - 1`` into the row buffer, expert by expert, in token order."""
+    """Give the slots that chose experts ``first_expert .. first_expert +
+    held - 1`` their rows, expert by expert, in token order, each expert's
+    rows starting on a tile; ``ends[-1]`` is the rows in use."""
     t, k = experts.shape
-    rows = buffer_rows(t, k, held, tile_m)
     local = experts - first_expert
     is_held = (local >= 0) & (local < held)
     flat = jnp.where(is_held, local, held).reshape(-1)          # [T*k]
@@ -79,24 +104,26 @@ def plan(experts: jnp.ndarray, first_expert: int, held: int,
     rank = jnp.take_along_axis(jnp.cumsum(onehot, 0),
                                jnp.minimum(flat, held - 1)[:, None], 1)[:, 0] - 1
     load = jnp.sum(onehot, 0)                                   # [held]
-    padded = -(-load // tile_m) * tile_m
+    padded = _ceil_to(load, tile_m)
     ends = jnp.cumsum(padded)
-    starts = ends - padded
-    held_flat = is_held.reshape(-1)
-    row = jnp.where(held_flat,
-                    starts[jnp.minimum(flat, held - 1)] + rank, rows)
-    token = jnp.arange(t * k, dtype=jnp.int32) // k
+    row = (ends - padded)[jnp.minimum(flat, held - 1)] + rank
+    return Plan(jnp.where(is_held, row.reshape(t, k), 0).astype(jnp.int32),
+                is_held, ends.astype(jnp.int32),
+                (ends[-1] // tile_m).astype(jnp.int32).reshape(1), load)
+
+
+def place(p: Plan, rows: int, tile_m: int) -> Rows:
+    """Lay the plan into a buffer of ``rows`` rows (a held slot whose row
+    lies past the buffer gets none: the caller sizes it by ``ends[-1]``)."""
+    row = jnp.where(p.slot_held, p.slot_row, rows).reshape(-1)
+    token = jnp.arange(row.size, dtype=jnp.int32) // p.slot_row.shape[1]
     row_token = jnp.zeros((rows,), jnp.int32).at[row].set(token, mode="drop")
     row_used = jnp.zeros((rows,), bool).at[row].set(True, mode="drop")
     tile_start = jnp.arange(rows // tile_m, dtype=jnp.int32) * tile_m
     tile_group = jnp.minimum(
-        jnp.searchsorted(ends, tile_start, side="right"), held - 1)
-    placed = held_flat & (row < rows)
-    return Plan(row_token, row_used,
-                jnp.where(placed, row, 0).reshape(t, k).astype(jnp.int32),
-                placed.reshape(t, k), tile_group.astype(jnp.int32),
-                (ends[-1] // tile_m).astype(jnp.int32).reshape(1), load,
-                jnp.sum(held_flat & ~placed).astype(jnp.int32))
+        jnp.searchsorted(p.ends, tile_start, side="right"),
+        p.ends.shape[0] - 1)
+    return Rows(row_token, row_used, tile_group.astype(jnp.int32))
 
 
 # ------------------------------------------------------- tokens <-> rows ---
@@ -258,8 +285,7 @@ def _gmm(x, w, tile_group, num_tiles, tile_m: int, transpose_rhs: bool):
 def grouped_matmul(x, w, tile_group, num_tiles, tile_m: int):
     """Rows [R, C] through FROZEN expert kernels ``w`` [G, C, O], the kernel
     of each ``tile_m`` rows named by ``tile_group``. Differentiable in ``x``
-    alone: the experts train no weight here, so no weight gradient is taken
-    (the caller stops the gradient at ``w``)."""
+    alone: the experts train no weight here, so ``w`` gets no cotangent."""
     return _gmm(x, w, tile_group, num_tiles, tile_m, False)
 
 
@@ -270,8 +296,7 @@ def _grouped_fwd(x, w, tile_group, num_tiles, tile_m):
 
 def _grouped_bwd(tile_m, res, g):
     w, tile_group, num_tiles = res
-    return (_gmm(g, w, tile_group, num_tiles, tile_m, True),
-            jnp.zeros_like(w), None, None)
+    return _gmm(g, w, tile_group, num_tiles, tile_m, True), None, None, None
 
 
 grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
@@ -286,30 +311,96 @@ def tile_rows(slots: int) -> int:
     return t
 
 
-def routed_experts(x, gates, experts, w_gate, w_up, w_down, first_expert: int):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _pass_at(rows: int, tile_m: int, x, gates, w_gate, w_up, w_down, p: Plan):
+    """One pass of the held experts over a buffer of ``rows`` rows. Jitted,
+    as ``_pass_grads`` is, so that a model's expert layers of one shape
+    are traced and lowered once a size and direction, not once a layer."""
+    r = place(p, rows, tile_m)
+    xs = dispatch(x, r.row_token, p.slot_row, p.slot_held)
+    mm = functools.partial(grouped_matmul, tile_group=r.tile_group,
+                           num_tiles=p.num_tiles, tile_m=tile_m)
+    h = jax.nn.silu(mm(xs, w_gate)) * mm(xs, w_up)
+    return combine(mm(h, w_down), gates, r.row_token, r.row_used,
+                   p.slot_row, p.slot_held)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _pass_grads(rows: int, tile_m: int, x, gates, w_gate, w_up, w_down,
+                p: Plan, g):
+    """The pass rebuilt, and ``g`` pulled back to ``x`` and ``gates``."""
+    return jax.vjp(lambda x, gates: _pass_at(
+        rows, tile_m, x, gates, w_gate, w_up, w_down, p), x, gates)[1](g)
+
+
+def _fits(p: Plan, compact: int):
+    return p.ends[-1] <= compact
+
+
+def _sized(fn, p: Plan, compact: int, full: int):
+    """``fn(rows)`` at the compact size where the rows in use fit it, at
+    the worst-case size where they do not: a conditional on a scalar of the
+    input, of which one branch runs; no conditional where the worst case
+    is no larger than the compact size."""
+    if compact >= full:
+        return fn(full)
+    return jax.lax.cond(_fits(p, compact),
+                        lambda: fn(compact), lambda: fn(full))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def expert_pass(compact: int, full: int, tile_m: int, x, gates, w_gate, w_up,
+                w_down, p: Plan):
+    """The pass at the size the plan needs. Differentiable in ``x`` and
+    ``gates`` alone: the experts' kernels are frozen and get no cotangent.
+    The backward pass keeps ``x``, ``gates`` and the plan, makes
+    the same choice of size again and rebuilds the row buffers there (no
+    row buffer stays alive between the passes, and the conditional hands
+    nothing of the untaken branch's size from one to the other)."""
+    return _sized(lambda rows: _pass_at(rows, tile_m, x, gates, w_gate, w_up,
+                                        w_down, p), p, compact, full)
+
+
+def _expert_pass_fwd(compact, full, tile_m, x, gates, w_gate, w_up, w_down, p):
+    return (expert_pass(compact, full, tile_m, x, gates, w_gate, w_up,
+                        w_down, p), (x, gates, w_gate, w_up, w_down, p))
+
+
+def _expert_pass_bwd(compact, full, tile_m, res, g):
+    x, gates, w_gate, w_up, w_down, p = res
+    # as jax.checkpoint does: the rebuilt pass must not be merged with the
+    # forward one, which would keep the forward's row buffers alive
+    x, gates, g = jax.lax.optimization_barrier((x, gates, g))
+    dx, d_gates = _sized(lambda rows: _pass_grads(
+        rows, tile_m, x, gates, w_gate, w_up, w_down, p, g), p, compact, full)
+    return dx, d_gates, None, None, None, None
+
+
+expert_pass.defvjp(_expert_pass_fwd, _expert_pass_bwd)
+
+
+def routed_experts(x, gates, experts, w_gate, w_up, w_down,
+                   first_expert: int, n_experts: int):
     """The held experts' part of the layer: x [T, H], the routing of every
-    token (``gates``, ``experts`` [T, k]) and this rank's frozen SwiGLU
-    kernels ``w_gate`` / ``w_up`` [G, H, I], ``w_down`` [G, I, H] ->
-    ([T, H] float32, stats). Rematerialised: the backward pass rebuilds the
-    row buffers from ``x`` and the plan instead of keeping three
-    worst-case-sized buffers a layer alive."""
+    token (``gates``, ``experts`` [T, k]) over ``n_experts`` and this
+    rank's frozen SwiGLU kernels ``w_gate`` / ``w_up`` [G, H, I], ``w_down``
+    [G, I, H] -> ([T, H] float32, stats). The row buffers have
+    ``compact_rows`` rows where this routing fits them and ``buffer_rows``
+    where it does not."""
     held = w_gate.shape[0]
+    t, k = experts.shape
     tile_m = tile_rows(experts.size)
+    full = buffer_rows(t, k, held, tile_m)
+    compact = compact_rows(t, k, held, n_experts, tile_m)
     p = plan(experts, first_expert, held, tile_m)
-    w_gate, w_up, w_down = (jax.lax.stop_gradient(w)
-                            for w in (w_gate, w_up, w_down))
-
-    @jax.checkpoint
-    def rows_through_experts(x, gates):
-        xs = dispatch(x, p.row_token, p.slot_row, p.slot_held)
-        mm = functools.partial(grouped_matmul, tile_group=p.tile_group,
-                               num_tiles=p.num_tiles, tile_m=tile_m)
-        h = jax.nn.silu(mm(xs, w_gate)) * mm(xs, w_up)
-        return combine(mm(h, w_down), gates, p.row_token, p.row_used,
-                       p.slot_row, p.slot_held)
-
+    routed = expert_pass(compact, full, tile_m, x, gates, w_gate, w_up,
+                         w_down, p)
+    fits = _fits(p, compact) & (compact < full)
+    rows = jnp.where(fits, compact, full)
+    dropped = jnp.sum(p.slot_held & (p.slot_row >= rows))
     stats = {"slots_held": jnp.sum(p.load).astype(jnp.float32),
              "load_max": jnp.max(p.load).astype(jnp.float32),
-             "dropped": p.dropped.astype(jnp.float32),
-             "layer_steps": jnp.float32(1), "expert_steps": jnp.float32(held)}
-    return rows_through_experts(x, gates), stats
+             "dropped": dropped.astype(jnp.float32),
+             "layer_steps": jnp.float32(1), "expert_steps": jnp.float32(held),
+             "compact_steps": fits.astype(jnp.float32)}
+    return routed, stats

@@ -43,7 +43,7 @@ class CausalLMTrainer(TrainerSpec):
         obs_metrics.record_moe_round(
             sums["moe_slots_held"], sums["moe_load_max"],
             sums["moe_layer_steps"], sums["moe_expert_steps"],
-            sums["moe_dropped"])
+            sums["moe_dropped"], sums["moe_compact_steps"])
 
     def _stats(self, params, batch, rng, train):
         kwargs = {"train": train}

@@ -92,6 +92,37 @@ def test_grouped_matmul_compiles_for_v5e(v5e, transpose):
     assert moe.GROUPED_KERNEL_NAMES[int(transpose)] in text
 
 
+def test_expert_pass_keeps_its_conditional_on_the_v5e(v5e):
+    """The expert layer at the benchmark's shapes (4,096 tokens top-8 of
+    192, 12 held: worst case 35,840 rows, compact 7,168), forward and
+    backward: the compiled program holds one real conditional a direction
+    (not a select over both sizes), every grouped product of both sizes in
+    them (3 forward; 2 rebuilt and 3 ``dx`` backward, the ``down`` product
+    is rebuilt for the gates' gradient alone), and nothing of the
+    worst-case size outside their branches."""
+    t, h, width, held, k, experts = 4096, 7168, 2048, 12, 8, 192
+    assert moe.buffer_rows(t, k, held, 256) == 35840
+    assert moe.compact_rows(t, k, held, experts, 256) == 7168
+    x = jax.ShapeDtypeStruct((t, h), jnp.bfloat16)
+    logits = jax.ShapeDtypeStruct((t, experts), jnp.float32)
+    w_in = jax.ShapeDtypeStruct((held, h, width), jnp.bfloat16)
+    w_out = jax.ShapeDtypeStruct((held, width, h), jnp.bfloat16)
+
+    def step(x, logits, w_gate, w_up, w_down):
+        def loss(x):
+            gates, chosen = moe.route(logits, k, 2.5)
+            y, stats = moe.routed_experts(x, gates, chosen, w_gate, w_up,
+                                          w_down, 60, experts)
+            return jnp.sum(jnp.sin(y)), stats
+        return jax.grad(loss, has_aux=True)(x)
+
+    text = _compile(step, v5e, x, logits, w_in, w_in, w_out).as_text()
+    assert text.count(" conditional(") == 2
+    assert text.count("tpu_custom_call") == 2 * 3 + 2 * (2 + 3)
+    entry = text[text.index("\nENTRY "):]
+    assert "[35840" not in entry and "[35840" in text
+
+
 def test_flash_bwd_never_materializes_scores(v5e):
     """Training-memory contract: at s=4096 the compiled fwd+bwd must not
     allocate an [s, s] f32 buffer (64 MiB); flash peak temp stays under a

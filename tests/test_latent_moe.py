@@ -7,6 +7,7 @@ experts than top-k, fewer held than experts, one leading dense layer."""
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import os
 
@@ -182,70 +183,228 @@ def test_shares_add_up_to_the_uncut_layer():
     assert full.shape == (2, 16, whole["vocab_size"])
 
 
+def dense_loop(x, gates, chosen, w, first):
+    """The held experts' part as plain autodiff sees it: every held expert
+    over every token, weighted by the gate of the slot that chose it."""
+    y = jnp.zeros_like(x)
+    for e in range(w[0].shape[0]):
+        g = jnp.sum(jnp.where(chosen == first + e, gates, 0.0), -1)
+        y += (jax.nn.silu(x @ w[0][e]) * (x @ w[1][e])) @ w[2][e] * g[:, None]
+    return y
+
+
 def test_dropless_under_a_skewed_router():
-    """One held expert takes nearly every token, another none: every held
-    slot finds a row, the result still equals the reference's."""
+    """Every slot of every token is held, one held expert and a second take
+    every token, the other two split the rest unevenly: more rows than the
+    compact buffers have, so the pass runs at the worst-case size; every
+    held slot finds a row, the result still equals the dense loop's."""
     cfg = small_cfg(layers=2)
-    base, lora = weights(cfg)
-    router = np.array(base["layer_1"]["moe"]["router"]["kernel"]) * 0.01
-    hot, cold = cfg["first_expert"], cfg["first_expert"] + 1
-    router[:, cold] = 0.0
-    base["layer_1"]["moe"]["router"]["kernel"] = jnp.asarray(router)
-    x = tokens(cfg, rows=2, seq=32)[:, :-1]
-    # a constant pull toward `hot` through the normed input's mean direction
+    base, _ = weights(cfg)
     lc = system_cfg(cfg, 32)
-    flat = jax.random.normal(jax.random.PRNGKey(1), (64, cfg["hidden_size"]))
-    logits = flat @ jnp.asarray(router)
-    logits = logits.at[:, hot].set(10.0).at[:, cold].set(-10.0)
-    gates, chosen = moe.route(logits, lc.num_experts_per_tok, 2.5)
-    p = moe.plan(chosen, lc.first_expert, lc.held, 16)
-    assert int(p.load[0]) == 64 and int(p.load[1]) == 0
-    assert int(p.dropped) == 0
+    first, held, t, k = lc.first_expert, lc.held, 64, lc.num_experts_per_tok
+    flat = jax.random.normal(jax.random.PRNGKey(1), (t, cfg["hidden_size"]))
+    logits = jnp.full((t, lc.n_routed_experts), -10.0)
+    logits = logits.at[:, first].set(10.0).at[:, first + 1].set(9.0)
+    logits = logits.at[:30, first + 2].set(5.0).at[30:, first + 3].set(5.0)
+    gates, chosen = moe.route(logits, k, 2.5)
+    p = moe.plan(chosen, first, held, 16)
+    assert np.asarray(p.load).tolist() == [64, 64, 30, 34]
+    full = moe.buffer_rows(t, k, held, 16)
+    compact = moe.compact_rows(t, k, held, lc.n_routed_experts, 16)
+    assert compact < int(p.ends[-1]) == 64 + 64 + 32 + 48 <= full
     assert int(p.slot_held.sum()) == int(p.load.sum())
     rows = np.asarray(p.slot_row)[np.asarray(p.slot_held)]
     assert len(set(rows.tolist())) == len(rows)          # one row a slot
-    assert np.asarray(p.row_used).sum() == len(rows)
-    w = [jnp.asarray(base["layer_1"]["moe"][k]) for k in
+    assert np.asarray(moe.place(p, full, 16).row_used).sum() == len(rows)
+    # a buffer too small for the plan places what fits and no row twice
+    assert np.asarray(moe.place(p, compact, 16).row_used).sum() == int(
+        (rows < compact).sum())
+    w = [jnp.asarray(base["layer_1"]["moe"][n]) for n in
          ("experts_gate", "experts_up", "experts_down")]
-    got, stats = moe.routed_experts(flat, gates, chosen, *w, lc.first_expert)
-    want = jnp.zeros_like(flat)
-    for e in range(lc.held):
-        g = jnp.sum(jnp.where(chosen == lc.first_expert + e, gates, 0.0), -1)
-        want += (jax.nn.silu(flat @ w[0][e]) * (flat @ w[1][e])) @ w[2][e] \
-            * g[:, None]
-    assert rel(got, want) < 1e-5
+    got, stats = moe.routed_experts(flat, gates, chosen, *w, first,
+                                    lc.n_routed_experts)
+    assert rel(got, dense_loop(flat, gates, chosen, w, first)) < 1e-5
     assert float(stats["dropped"]) == 0 and float(stats["load_max"]) == 64
-    del x
+    assert float(stats["compact_steps"]) == 0
 
 
-def test_routed_experts_gradients_match_a_dense_loop():
-    """Gradients through dispatch, the grouped products and combine, toward
-    the tokens and toward the gates, against plain autodiff of the loop."""
-    key = jax.random.PRNGKey(5)
-    t, h, width, held, first, k = 40, 32, 16, 3, 2, 2
-    x = jax.random.normal(key, (t, h))
-    logits = jax.random.normal(jax.random.fold_in(key, 1), (t, 8))
+# t, h, width, held, first, k over 12 experts at row tiles of 16: the
+# worst-case buffers have 176 rows, the compact ones 112
+PASS = dict(t=64, h=32, width=16, held=3, first=2, k=2, experts=12, tile_m=16)
+
+
+def pass_inputs(held_bias=0.0):
+    c, key = PASS, jax.random.PRNGKey(5)
+    x = jax.random.normal(key, (c["t"], c["h"]))
+    logits = jax.random.normal(jax.random.fold_in(key, 1),
+                               (c["t"], c["experts"]))
+    logits = logits.at[:, c["first"]:c["first"] + c["held"]].add(held_bias)
     w = [jax.random.normal(jax.random.fold_in(key, 2 + i), s) * 0.2
-         for i, s in enumerate([(held, h, width), (held, h, width),
-                                (held, width, h)])]
+         for i, s in enumerate([(c["held"], c["h"], c["width"])] * 2
+                               + [(c["held"], c["width"], c["h"])])]
+    return x, logits, w
+
+
+def pass_sizes():
+    c = PASS
+    return (moe.compact_rows(c["t"], c["k"], c["held"], c["experts"],
+                             c["tile_m"]),
+            moe.buffer_rows(c["t"], c["k"], c["held"], c["tile_m"]))
+
+
+@pytest.mark.parametrize("path,held_bias,n_experts", [
+    ("compact", 0.0, 12), ("full", 8.0, 12), ("one_path", 0.0, 4)])
+def test_routed_experts_gradients_match_a_dense_loop(path, held_bias,
+                                                     n_experts):
+    """Gradients through dispatch, the grouped products and combine, toward
+    the tokens and toward the gates, against plain autodiff of the loop: a
+    routing that fits the compact buffers, one that overflows them (every
+    token's slots pulled to held experts), and a rank that holds 3 of 4
+    experts, whose compact size is no smaller than the worst case."""
+    c = PASS
+    x, logits, w = pass_inputs(held_bias)
+    logits = logits[:, :n_experts] if n_experts < 12 else logits
+    k, first = c["k"], (0 if n_experts < 12 else c["first"])
+    seen = {}
 
     def mine(x, logits):
         gates, chosen = moe.route(logits, k, 2.5)
-        return jnp.sum(jnp.sin(moe.routed_experts(
-            x, gates, chosen, *w, first)[0]))
+        y, stats = moe.routed_experts(x, gates, chosen, *w, first, n_experts)
+        return jnp.sum(jnp.sin(y)), stats
 
     def plain(x, logits):
         gates, chosen = moe.route(logits, k, 2.5)
-        y = jnp.zeros_like(x)
-        for e in range(held):
-            g = jnp.sum(jnp.where(chosen == first + e, gates, 0.0), -1)
-            y += (jax.nn.silu(x @ w[0][e]) * (x @ w[1][e])) @ w[2][e] \
-                * g[:, None]
-        return jnp.sum(jnp.sin(y))
+        return jnp.sum(jnp.sin(dense_loop(x, gates, chosen, w, first)))
 
-    got = jax.grad(mine, argnums=(0, 1))(x, logits)
+    jaxpr = jax.make_jaxpr(jax.grad(mine, argnums=(0, 1), has_aux=True))(
+        x, logits)
+    assert len(conds(jaxpr.jaxpr)) == (0 if path == "one_path" else 2)
+    got, seen = jax.grad(mine, argnums=(0, 1), has_aux=True)(x, logits)
     want = jax.grad(plain, argnums=(0, 1))(x, logits)
     assert rel(got[0], want[0]) < 1e-5 and rel(got[1], want[1]) < 1e-5
+    assert float(seen["compact_steps"]) == (1 if path == "compact" else 0)
+    assert float(seen["dropped"]) == 0
+
+
+@pytest.mark.parametrize("sizes", ["compact", "full", "one_path"])
+def test_the_pass_is_the_same_to_the_last_bit_at_either_size(sizes):
+    """One routing through the pass at the compact size, at the worst-case
+    size under the conditional (a compact size of one tile does not fit it)
+    and at the worst-case size with no conditional, each a jitted program
+    as the train step is: output and both gradients equal bit for bit (same
+    rows, same tile order, same slot order)."""
+    c = PASS
+    x, logits, w = pass_inputs()
+    gates, chosen = moe.route(logits, c["k"], 2.5)
+    p = moe.plan(chosen, c["first"], c["held"], c["tile_m"])
+    compact, full = pass_sizes()
+    assert int(p.ends[-1]) <= compact < full
+
+    @functools.partial(jax.jit, static_argnums=(0, 1))
+    def run(compact, full, x, gates):
+        y, vjp = jax.vjp(lambda x, gates: moe.expert_pass(
+            compact, full, c["tile_m"], x, gates, *w, p), x, gates)
+        return (y,) + vjp(jnp.cos(y))
+
+    want = run(full + c["tile_m"], full + c["tile_m"], x, gates)
+    got = run(*{"compact": (compact, full), "full": (c["tile_m"], full),
+                "one_path": (full, full)}[sizes], x, gates)
+    for g, v in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(v))
+    assert rel(got[0], dense_loop(x, gates, chosen, w, c["first"])) < 1e-5
+
+
+@pytest.mark.parametrize("extra,compact_steps", [(0, 1.0), (1, 0.0)])
+def test_the_compact_size_is_taken_up_to_its_last_row(extra, compact_steps):
+    """Loads of 48, 32, 32 fill the compact buffers' 112 rows exactly and
+    take them; one more slot opens an eighth tile and takes the worst-case
+    buffers. Nothing is dropped either way."""
+    c = PASS
+    x, _, w = pass_inputs()
+    a, b, cc, away = c["first"], c["first"] + 1, c["first"] + 2, 0
+    chosen = np.full((c["t"], c["k"]), away, np.int32)
+    chosen[:48, 0] = a
+    chosen[:32, 1], chosen[32:, 1] = b, cc
+    chosen[48:48 + extra, 0] = b
+    chosen = jnp.asarray(chosen)
+    gates = jax.random.uniform(jax.random.PRNGKey(2), chosen.shape) + 0.5
+    compact, _ = pass_sizes()
+    p = moe.plan(chosen, c["first"], c["held"], c["tile_m"])
+    assert int(p.ends[-1]) == compact + 16 * extra
+    got, stats = moe.routed_experts(x, gates, chosen, *w, c["first"],
+                                    c["experts"])
+    assert rel(got, dense_loop(x, gates, chosen, w, c["first"])) < 1e-5
+    assert float(stats["compact_steps"]) == compact_steps
+    assert float(stats["dropped"]) == 0
+    assert float(stats["slots_held"]) == 112 + extra
+
+
+# ----------------------------------------------------- the lowered program ---
+
+def conds(jaxpr):
+    """Every ``cond`` of a jaxpr outside its Pallas kernels, outermost
+    first (a ``cond`` inside another's branch is not looked for)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            found.append(eqn)
+        elif eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += conds(sub)
+    return found
+
+
+def row_buffers(jaxpr, cfg, into_conds=True):
+    """Row counts of everything shaped like a row buffer that a jaxpr makes
+    (``[R]``, ``[R, hidden]``, ``[R, expert width]``), its Pallas kernels'
+    insides left out."""
+    widths = ((), (cfg["hidden_size"],), (cfg["moe_intermediate_size"],))
+    rows = set()
+    for eqn in jaxpr.eqns:
+        rows |= {v.aval.shape[0] for v in eqn.outvars if getattr(
+            v.aval, "shape", ()) and v.aval.shape[1:] in widths}
+        if eqn.primitive.name == "pallas_call" or (
+                eqn.primitive.name == "cond" and not into_conds):
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            rows |= row_buffers(sub, cfg, into_conds)
+    return rows
+
+
+def test_the_train_step_holds_one_conditional_a_layer_and_direction():
+    """The small model's train step (2 rows x 16 tokens, 4 of 12 experts
+    held: worst case 160 rows, compact 128): each of the 2 expert layers is
+    a real conditional on an unbatched scalar in the forward and in the
+    backward pass (a ``select`` over both branches would leave no ``cond``
+    in the jaxpr), no conditional hands on anything of a row buffer's
+    size, the compact branch makes nothing of the worst-case size, and
+    nothing outside the conditionals has either size."""
+    cfg = small_cfg()
+    base, lora = weights(cfg)
+    tok = tokens(cfg)
+    batch = {"x": tok[:, :-1], "y": tok[:, 1:], "mask": jnp.ones((2,))}
+    bundle = bundle_for(cfg, base, 16)
+    spec = CausalLMTrainer(bundle.apply, bundle.extra_metrics)
+    lc = bundle.cfg
+    t, k = 32, lc.num_experts_per_tok
+    tile_m = moe.tile_rows(t * k)
+    full = moe.buffer_rows(t, k, lc.held, tile_m)
+    compact = moe.compact_rows(t, k, lc.held, lc.n_routed_experts, tile_m)
+    assert (compact, full) == (128, 160)
+    step = jax.grad(lambda p: spec.loss(p, batch, None)[0])
+    jaxpr = jax.make_jaxpr(step)(lora).jaxpr
+    found = conds(jaxpr)
+    assert len(found) == 2 * 2
+    for eqn in found:
+        assert eqn.invars[0].aval.shape == ()            # the predicate
+        assert not {full, compact} & {
+            v.aval.shape[0] for v in eqn.outvars if v.aval.shape}
+        worst, small = (b.jaxpr for b in eqn.params["branches"])
+        assert full in row_buffers(worst, cfg)
+        assert compact in row_buffers(small, cfg)
+        assert full not in row_buffers(small, cfg)
+    assert not {full, compact} & row_buffers(jaxpr, cfg, into_conds=False)
+    assert jax.jit(step).lower(lora).as_text().count("stablehlo.case") >= 4
 
 
 # ------------------------------------------------------------ flash kernels ---
@@ -372,7 +531,10 @@ def test_round_counters_reach_the_registry_from_the_round_program():
     assert float(m0["moe_layer_steps"]) == 8.0
     assert float(m0["moe_expert_steps"]) == 8.0 * cfg["n_routed_experts"]
     assert 0 < float(m0["moe_slots_held"]) <= 8 * 16 * 3
+    assert 0 <= float(m0["moe_compact_steps"]) <= 8.0
     rounds_before = REGISTRY.counter("fed_moe_rounds_total").value()
+    passes_before = REGISTRY.counter("fed_moe_layer_steps_total").value()
+    compact_before = REGISTRY.counter("fed_moe_compact_steps_total").value()
     m1 = sim.run_round(1, hyper)   # records round 0's sums, now ready
     assert REGISTRY.gauge("fed_moe_slots_held").value() == float(
         m0["moe_slots_held"])
@@ -387,6 +549,11 @@ def test_round_counters_reach_the_registry_from_the_round_program():
     assert REGISTRY.gauge("fed_moe_slots_held").value() == float(
         m1["moe_slots_held"])
     assert REGISTRY.counter("fed_moe_dropped").value() == dropped_before
+    assert REGISTRY.counter("fed_moe_layer_steps_total").value() == \
+        passes_before + 16
+    assert REGISTRY.counter("fed_moe_compact_steps_total").value() == \
+        compact_before + float(m0["moe_compact_steps"]) + float(
+            m1["moe_compact_steps"])
     # with the registry off nothing is held, so nothing is read back
     obs_metrics.set_enabled(False)
     try:
