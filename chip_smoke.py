@@ -226,20 +226,11 @@ def phase_llm_lora_rounds(sz, keep):
 
     from fedml_tpu.core import kernels
     from fedml_tpu.core.algframe.types import TrainHyper
-    from fedml_tpu.core.obs import roofline
     from fedml_tpu.llm.federated import build_llm
     from fedml_tpu.optimizers.registry import create_optimizer
     from fedml_tpu.simulation.tpu.engine import TPUSimulator
 
     lowered = {}
-
-    class LoweringSpy(roofline.DispatchTracker):
-        """Keeps the StableHLO of each program at the engine's dispatch
-        seam (where the opt-in roofline capture lowers it too)."""
-
-        def maybe_capture(self, program, fn, args, sig=None):
-            if program not in lowered:
-                lowered[program] = fn.lower(*args).as_text()
 
     args = _llm_args(sz)
     fed, bundle, spec, _ = build_llm(args)
@@ -248,7 +239,16 @@ def phase_llm_lora_rounds(sz, keep):
         check(impl == "flash", f"attention impl on the chip is {impl!r}")
         check(not kernels.interpret(), "Pallas kernels would be interpreted")
     sim = TPUSimulator(args, fed, bundle, create_optimizer(args, spec), spec)
-    sim._roofline = LoweringSpy()
+    traced = sim._traced
+
+    def lowering_traced(name, n_rounds, fn, *fn_args, **kw):
+        """Keeps the StableHLO of each program, lowered with the
+        arguments of its first dispatch (before it donates them)."""
+        if name not in lowered:
+            lowered[name] = fn.lower(*fn_args).as_text()
+        return traced(name, n_rounds, fn, *fn_args, **kw)
+
+    sim._traced = lowering_traced
     hyper = TrainHyper(learning_rate=jnp.float32(args.learning_rate),
                        epochs=1)
     losses, count = [], 0.0
@@ -272,13 +272,13 @@ def phase_llm_lora_rounds(sz, keep):
 def phase_llm_long_context(sz):
     """Full fine-tune SGD steps at bs 1 x the long sequence (the flash
     kernels' raised VMEM limit matters here), timed two ways: ended by
-    block_until_ready, and ended by a scalar readback as bench.py does."""
+    block_until_ready, and ended by a scalar readback as a round's driver
+    does."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     import optax
 
-    from fedml_tpu.core.obs import profiler
     from fedml_tpu.llm.federated import llm_config_from_args
     from fedml_tpu.llm.model import init_llm
     from fedml_tpu.llm.trainer import CausalLMTrainer
@@ -316,7 +316,7 @@ def phase_llm_long_context(sz):
         if i % 2:
             jax.block_until_ready((params, loss))
             ready.append(time.perf_counter() - t0)
-        else:   # bench.py's _force: a scalar read back from the new params
+        else:   # a scalar read back from the new params
             float(jax.tree_util.tree_leaves(params)[0].sum())
             readback.append(time.perf_counter() - t0)
             jax.block_until_ready(params)   # drain before the next step
@@ -324,12 +324,6 @@ def phase_llm_long_context(sz):
     check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
     check(losses[-1] < losses[0], f"loss did not fall: {losses}")
     dt = statistics.median(ready)
-    peak = profiler.peak_tflops(jax.devices()[0])
-    if on_chip():
-        check(peak is not None, "no peak for device kind "
-              f"{jax.devices()[0].device_kind!r} in profiler.DEVICE_PEAKS")
-    mfu = profiler.mfu_value(cfg.flops_per_token() * m["long_seq"], dt, 1,
-                             peak)
     return {"seq": m["long_seq"], "attention_impl": cfg.attention_impl,
             "loss_first_last": [round(losses[0], 4), round(losses[-1], 4)],
             **chip_timings(
@@ -337,8 +331,7 @@ def phase_llm_long_context(sz):
                 step_s_block_until_ready=round(dt, 5),
                 step_s_scalar_readback=round(statistics.median(readback), 5),
                 sync_agree_ratio=round(statistics.median(readback) / dt, 3),
-                tokens_per_s=round(m["long_seq"] / dt, 0),
-                mfu=mfu and round(mfu, 4))}
+                tokens_per_s=round(m["long_seq"] / dt, 0))}
 
 
 def phase_serving(sz, keep):

@@ -333,9 +333,7 @@ _SCHEMA: Dict[str, Any] = {
     "log_server_url": None,      # remote log shipper endpoint (log_daemon)
     "sys_perf_profiling": False,  # host/device sampler thread (mlops)
     # observability (core/obs): tracing + metrics are default-on-cheap
-    # (spans are dicts + one JSONL line; metric hooks are dict lookups);
-    # device profiling is OPT-IN because it blocks on dispatch results,
-    # defeating the engines' host/device overlap
+    # (spans are dicts + one JSONL line; metric hooks are dict lookups)
     "obs_tracing": True,          # spans + traceparent wire propagation
     "obs_metrics": True,          # typed counter/gauge/histogram registry
     "obs_metrics_flush_rounds": 10,  # metrics_snapshot JSONL cadence
@@ -343,12 +341,6 @@ _SCHEMA: Dict[str, Any] = {
     # workloads that never cross a round boundary — serving, the
     # cross-device handshake, agents; skips when nothing changed
     "obs_metrics_flush_s": 60.0,
-    # compute-plane roofline capture (core/obs/roofline): AOT-compiles
-    # each dispatched program once per abstract-shape signature and
-    # emits the per-op roofline + collective-traffic record — OPT-IN
-    # because the extra backend compile would trip the compile-once
-    # counters (recompile FORENSICS is always on and compile-free)
-    "obs_roofline": False,
     "log_file_dir": "~/.cache/fedml_tpu/logs",
     "save_model_path": None,     # persist final params (serving artifact)
     "checkpoint_dir": None,

@@ -180,9 +180,8 @@ class FaultPlan:
     def expected_work_fraction(self) -> float:
         """Mean fraction of its SCHEDULED local work a client actually runs
         under the availability knobs: dropped clients run 0, stragglers
-        ``straggler_work``, the rest 1.0. This is what the bench's FLOPs
-        costing must scale by — counting full epochs for clients the plan
-        drops would overstate MFU under injection."""
+        ``straggler_work``, the rest 1.0: what a count of a round's work
+        under injection scales by."""
         alive = 1.0 - min(max(self.dropout_prob, 0.0), 1.0)
         p_s = min(max(self.straggler_prob, 0.0), 1.0)
         return alive * (1.0 - p_s + p_s * self.straggler_work)

@@ -1,7 +1,7 @@
 """Fused conv -> GroupNorm -> residual-add -> ReLU block (Pallas TPU).
 
-The flagship roofline (BASELINE §"Compute-plane roofline", ISSUE 14/16)
-shows the ResNet-56 16-channel stage 100% memory-bound: every GroupNorm
+A static count of the ResNet-56 round (ISSUE 14/16, before the chip)
+had the 16-channel stage memory-bound: every GroupNorm
 and residual elementwise op round-trips the full activation through HBM
 at AI ~ 0.55-0.60. This kernel keeps the whole ``BasicBlock`` chain —
 

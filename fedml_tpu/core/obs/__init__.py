@@ -10,22 +10,16 @@
    selection decisions, compile count and seconds by phase, dispatch
    wall time, checkpoint flush time, HBM peak — with Prometheus text
    exposition and a periodic JSONL snapshot.
-3. **Device facts** (:mod:`.profiler`): the per-chip peaks table with the
-   MFU arithmetic over it, and the device-memory sample the engine takes
-   at the close of every round. Host time lands on the device's timeline
-   through the tracer: a context-manager span is also a
-   ``jax.profiler`` annotation.
+3. **Device facts** (:mod:`.profiler`): the device-memory sample the
+   engine takes at the close of every round. Host time lands on the
+   device's timeline through the tracer: a context-manager span is also
+   a ``jax.profiler`` annotation. Peaks and FLOPs counts live with the
+   benchmark (``benchmarks/harness/peaks.json``, ``benchmarks/flops/``).
 
-4. **Compute plane** (:mod:`.roofline`): per-op roofline attribution of
-   compiled programs (opt-in ``obs_roofline`` — one AOT compile per
-   program), collective-traffic accounting, and always-on recompile
-   forensics that name the changed abstract shapes when a dispatch
-   compiles past its pinned expectation.
-
-``scripts/trace_report.py`` reads a run's JSONL and prints the per-round
-critical path; ``scripts/roofline_report.py`` renders the compute
-plane's records. :mod:`.schema` is the one table every record kind
-validates against.
+:mod:`.recompile` names the argument shapes that moved when a dispatch
+compiles past its program's first compile. ``scripts/trace_report.py``
+reads a run's JSONL and prints the per-round critical path.
+:mod:`.schema` is the one table every record kind validates against.
 
 Knobs (``arguments.py``): tracing + metrics default ON (cheap — spans
 are dicts, metric hooks are dict lookups). ``configure(args)`` is called
@@ -35,7 +29,7 @@ init still traces.
 
 from __future__ import annotations
 
-from . import flight, metrics, profiler, roofline, schema, trace  # noqa: F401
+from . import flight, metrics, profiler, recompile, schema, trace  # noqa: F401
 from .flight import FlightRecorder, Watchdog                    # noqa: F401
 from .metrics import REGISTRY                                   # noqa: F401
 from .trace import (NOOP_SPAN, SpanContext, add_event, current_span,  # noqa: F401
@@ -54,8 +48,3 @@ def configure(args=None) -> None:
     # never fires there, so a crash would lose everything since init
     metrics.set_flush_interval(
         float(getattr(args, "obs_metrics_flush_s", 60.0) or 0.0))
-    # compute-plane roofline capture (opt-in: costs one AOT backend
-    # compile per program); engines read their own args knob first —
-    # this default covers seams without an args object (serving)
-    roofline.set_default_enabled(
-        bool(getattr(args, "obs_roofline", False)))

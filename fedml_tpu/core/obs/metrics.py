@@ -514,37 +514,6 @@ def record_moe_round(slots_held: float, load_max_sum: float,
                      ).inc(float(compact_steps))
 
 
-def record_roofline(program: str, predicted_mfu: Optional[float],
-                    memory_bound_share: Optional[float],
-                    collective_wire_bytes: Optional[float]) -> None:
-    """Compute-plane roofline capture (core/obs/roofline): predicted
-    program MFU, time share classified memory-bound, and the per-device
-    collective wire bytes one execution moves."""
-    if not _cfg["enabled"]:
-        return
-    if predicted_mfu is not None:
-        REGISTRY.gauge("roofline_predicted_mfu",
-                       "roofline-predicted program MFU",
-                       labels=("program",)).set(float(predicted_mfu),
-                                                program=str(program))
-    if memory_bound_share is not None:
-        REGISTRY.gauge("roofline_memory_bound_share",
-                       "share of predicted device time in memory-bound "
-                       "ops", labels=("program",)).set(
-                           float(memory_bound_share),
-                           program=str(program))
-    if collective_wire_bytes is not None:
-        REGISTRY.gauge("roofline_collective_wire_bytes",
-                       "predicted per-device collective wire bytes per "
-                       "program execution",
-                       labels=("program",)).set(
-                           float(collective_wire_bytes),
-                           program=str(program))
-    REGISTRY.counter("roofline_captures_total",
-                     "compiled programs analyzed by the roofline "
-                     "plane").inc(1)
-
-
 def record_recompile(program: str) -> None:
     """Recompile forensics: a program compiled PAST its pinned
     expectation (the steady-state invariant is zero)."""

@@ -129,33 +129,7 @@ RECORD_SCHEMAS: Dict[str, Dict[str, FieldSpec]] = {
                "seq": _f(INT, required=True),
                "event": _f(STR, required=True),
                "data": _f(DICT)},
-    # core/obs/roofline.py per-program compute-plane capture: one record
-    # per (program, abstract-shape signature), opt-in (obs_roofline).
-    # ``ops`` rows carry name/op/out/operands/flops/bytes/intensity/
-    # bound/time_s/share; ``collectives`` rows op/group/count/wire_bytes
-    "roofline": {"program": _f(STR, required=True),
-                 "device_kind": _f(STR, required=True),
-                 "n_devices": _f(INT, required=True),
-                 "static_only": _f(BOOL, required=True),
-                 "peak_tflops": _f(NUM, nullable=True),
-                 "hbm_gbps": _f(NUM, nullable=True),
-                 "balance_flops_per_byte": _f(NUM, nullable=True),
-                 "total_flops": _f(NUM, required=True),
-                 "total_bytes": _f(NUM, required=True),
-                 "predicted_s": _f(NUM, required=True),
-                 "predicted_mfu": _f(NUM, required=True, nullable=True),
-                 "attributed_share": _f(NUM, required=True),
-                 "memory_bound_share": _f(NUM, required=True),
-                 "compute_bound_share": _f(NUM),
-                 "collective_wire_bytes": _f(NUM, required=True),
-                 "xla_flops": _f(NUM, nullable=True),
-                 "xla_bytes": _f(NUM, nullable=True),
-                 "arg_bytes": _f(NUM),
-                 "output_bytes": _f(NUM),
-                 "temp_bytes": _f(NUM),
-                 "ops": _f(LIST, required=True),
-                 "collectives": _f(LIST, required=True)},
-    # core/obs/roofline.py recompile forensics: the compile counter
+    # core/obs/recompile.py recompile forensics: the compile counter
     # incremented past the pinned one-compile-per-program expectation;
     # ``changed`` names the abstract arg shapes that moved (empty =
     # cache miss with identical shapes — new callable / jit options)

@@ -1,8 +1,8 @@
 """Federated LLM fine-tuning — the UnitedLLM/FedLLM analogue.
 
 Parity target: ``spotlight_prj/unitedllm/src/unitedllm_trainer.py:57``
-(HFTrainer used as the FedML ClientTrainer in a cross-silo job) and the
-BASELINE.md ``FedLLM LoRA`` config. TPU-native: the trainable pytree each
+(HFTrainer used as the FedML ClientTrainer in a cross-silo job) and its
+``FedLLM LoRA`` config. TPU-native: the trainable pytree each
 silo ships is the LoRA adapter tree alone (base weights frozen and never
 communicated), so a federated round aggregates kilobytes instead of the
 full model — the design SURVEY §7 calls for ("get_model_params … cheap

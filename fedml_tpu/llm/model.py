@@ -93,23 +93,12 @@ class LLMConfig:
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
-    def _dense_only(self, what: str) -> None:
+    def param_count(self) -> int:
         if self.kv_lora_rank or self.n_routed_experts:
             raise NotImplementedError(
-                f"LLMConfig.{what} counts the dense grouped-query decoder "
-                "alone; a configuration with latent attention or experts "
-                "is counted from its shapes under benchmarks/flops/")
-
-    def flops_per_token(self) -> float:
-        """Approximate fwd+bwd FLOPs per token (6 * params + attention),
-        used by the bench's MFU report. Dense decoder only."""
-        self._dense_only("flops_per_token")
-        p = self.param_count()
-        attn = 12 * self.num_layers * self.hidden_size * self.max_seq_len
-        return 6.0 * p + attn
-
-    def param_count(self) -> int:
-        self._dense_only("param_count")
+                "LLMConfig.param_count counts the dense grouped-query "
+                "decoder alone; a configuration with latent attention or "
+                "experts is counted from its shapes under benchmarks/flops/")
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         per_layer = (h * h * 2 +                       # q, o
                      2 * h * self.kv_heads * self.head_dim +  # k, v

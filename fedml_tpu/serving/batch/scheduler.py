@@ -40,7 +40,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ...core.obs import metrics as obs_metrics
-from ...core.obs import roofline as obs_roofline
+from ...core.obs import recompile as obs_recompile
 from ...llm import kv_cache as kvc
 
 PyTree = Any
@@ -126,26 +126,20 @@ class DecodeScheduler:
         # True until a decode step observes NaN/inf in an active slot's
         # logits — the watchdog's poison signal
         self.last_step_finite = True
-        # compute plane at the serving dispatch seam: always-on recompile
-        # forensics for the decode/prefill programs (steady-state zero
-        # recompiles is the engine's core invariant) + opt-in roofline
-        # capture (obs_roofline via core/obs configure — the scheduler
-        # has no args object, so the module default is the knob)
+        # recompile forensics at the serving dispatch seam (steady-state
+        # zero recompiles is the engine's core invariant)
         from ...core import mlops
         mlops.install_compile_counter()
-        self._roofline = obs_roofline.DispatchTracker(
-            n_devices=max(len(self._jax.devices()), 1))
+        self._recompiles = obs_recompile.RecompileTracker()
         self._build_programs()
 
     def _dispatch(self, name: str, fn, *args):
-        """Run one jitted serving program through the compute-plane seam:
-        signature before the call (kp/vp are donated), forensics after."""
+        """Run one jitted serving program through the recompile-forensics
+        seam: the arguments are looked at only if the call compiled."""
         from ...core import mlops
-        sig = obs_roofline.dispatch_signature(args)
-        self._roofline.maybe_capture(name, fn, args, sig=sig)
         c0 = mlops.compile_count()
         out = fn(*args)
-        self._roofline.observe(name, sig, mlops.compile_count() - c0)
+        self._recompiles.observe(name, args, mlops.compile_count() - c0)
         return out
 
     # ------------------------------------------------------------- reset --
@@ -640,15 +634,19 @@ class DecodeScheduler:
         jnp = self._jnp
         if not self._active.any():
             return {}
-        tables_d = jnp.asarray(self._tables)
-        pos_d = jnp.asarray(self._pos)
-        active_d = jnp.asarray(self._active)
+        # jnp.array, not asarray: the mirrors are advanced in place below,
+        # while the write program may still be running, and on the CPU
+        # backend asarray aliases a numpy buffer that happens to lie on a
+        # 64-byte boundary (the write then lands one position late)
+        tables_d = jnp.array(self._tables)
+        pos_d = jnp.array(self._pos)
+        active_d = jnp.array(self._active)
         nxt, finite, kcs, vcs = self._dispatch(
             "llm_decode_step", self._step_fn,
             self.params, self._stack(), self._kp, self._vp,
-            tables_d, pos_d, active_d, jnp.asarray(self._aidx),
-            jnp.asarray(self._last), jnp.asarray(self._temp),
-            jnp.asarray(self._seed))
+            tables_d, pos_d, active_d, jnp.array(self._aidx),
+            jnp.array(self._last), jnp.array(self._temp),
+            jnp.array(self._seed))
         self._kp, self._vp = self._dispatch(
             "llm_decode_write", self._step_write_fn,
             self._kp, self._vp, tables_d, pos_d, active_d, kcs, vcs)

@@ -26,11 +26,10 @@ How the async world maps onto a synchronous mesh:
   aggregation of cohort N — the double-buffered dispatch: two model slots
   (the donated pre-pour params in, the post-pour params out), and the
   program compiles exactly once (schedules pad to one canonical width, all
-  staleness math is data). The pour programs ride the inherited
-  ``_traced`` compute-plane seam (``core/obs/roofline``): recompile
-  forensics on every dispatch, and under ``obs_roofline: true`` a per-op
-  roofline + collective-traffic record per pour program
-  (``async_pour`` / ``async_pour_defended``).
+  staleness math is data). The pour programs (``async_pour`` /
+  ``async_pour_defended``) go through the inherited ``_traced`` seam,
+  so a pour that recompiles names the shape that moved
+  (``core/obs/recompile``).
 
 * **A client trains on the model it was handed.** Its update is computed
   at dispatch (mathematically identical to computing it at arrival, since

@@ -32,7 +32,7 @@ from ...core.dp import FedMLDifferentialPrivacy
 from ...core import mlops
 from ...core.obs import metrics as obs_metrics
 from ...core.obs import profiler as obs_profiler
-from ...core.obs import roofline as obs_roofline
+from ...core.obs import recompile as obs_recompile
 from ...core.obs import trace as obs_trace
 from ...core.chaos import ChaosCrash, FaultLedger, FaultPlan
 from ...core.checkpoint import RoundCheckpointer
@@ -143,17 +143,10 @@ class TPUSimulator:
         mlops.install_compile_counter()
         self.dispatch_stats: Dict[str, Any] = {"dispatches": 0,
                                                "compiles": 0}
-        # compute plane (core/obs/roofline): per-dispatch abstract-shape
-        # signatures feed always-on recompile forensics; `obs_roofline`
-        # additionally AOT-captures each program's per-op roofline +
-        # collective-traffic record (one extra backend compile per
-        # program — opt-in, so the compile-once invariants hold at
-        # default knobs)
-        self._roofline = obs_roofline.DispatchTracker(
-            enabled=bool(getattr(args, "obs_roofline",
-                                 obs_roofline.default_enabled())),
-            n_devices=self.n_devices,
-            device=self.mesh.devices.flat[0])
+        # recompile forensics (core/obs/recompile): a dispatch that
+        # compiles past the program's first compile names the argument
+        # leaves whose shape moved; one that compiles nothing pays nothing
+        self._recompiles = obs_recompile.RecompileTracker()
 
         # chaos: seeded fault injection (off by default). Availability
         # faults ride the round programs as DATA (per-slot work fractions
@@ -395,7 +388,8 @@ class TPUSimulator:
 
         Schedule slots run SEQUENTIALLY per chip (lax.scan) with full
         per-op batches. A client-lockstep vmap mode was built and measured
-        in rounds 3-4 (scripts/vmap_vs_scan.py): XLA lowers
+        on the chip (PERF.md section 6, "July 2026, shared v5e, before
+        PR 1"): XLA lowers
         per-client-weight batched convs to per-group execution with a
         fixed ~10-25 us/group overhead, and the mode LOST to scan on every
         shipped model — 16..64-channel ResNet-56 (r3) AND MXU-wide
@@ -648,11 +642,6 @@ class TPUSimulator:
         the call traced, lowered or compiled, the compile listener
         (``mlops.install_compile_counter``) has put the seconds of each
         phase on the span, which so names the round that paid them."""
-        # compute plane: signature BEFORE the dispatch (donated buffers
-        # die with it), capture BEFORE the counter snapshot (the opt-in
-        # AOT compile must not be charged to the dispatch record)
-        sig = obs_roofline.dispatch_signature(args)
-        self._roofline.maybe_capture(name, fn, args, sig=sig)
         before = mlops.compile_phases()
         attrs = {"name": name, "rounds": int(n_rounds)}
         if round_idx is not None:
@@ -663,7 +652,7 @@ class TPUSimulator:
             wall = time.perf_counter() - t0
         phases = mlops.compile_phases_since(before)
         compiles = phases.get("compiles", 0)
-        self._roofline.observe(name, sig, compiles)
+        self._recompiles.observe(name, args, compiles)
         self.dispatch_stats["dispatches"] += 1
         self.dispatch_stats["compiles"] += compiles
         mlops.log_dispatch(name, wall, rounds=n_rounds, compiles=compiles,
@@ -1030,7 +1019,7 @@ class TPUSimulator:
     def _build_robust_fused_fn(self):
         """R defended rounds in ONE dispatch: the robust core under an
         outer ``lax.scan``, mirroring :meth:`_build_fused_fn` — defended
-        runs amortize the same ~120 ms dispatch constant (BASELINE.md §3b)
+        runs amortize the same per-dispatch constant
         the undefended fused path already eliminates. Cross-round defense
         state rides the scan CARRY (foolsgold's round-R history feeds round
         R+1 inside the same dispatch), sampled ids ride the xs."""
@@ -1385,57 +1374,6 @@ class TPUSimulator:
 
         self.contribution.assess({"v": pvec}, {"v": mat}, w, eval_fn,
                                  client_ids=sampled, round_idx=round_idx)
-
-    def round_cost_flops(self, hyper: TrainHyper) -> float:
-        """FLOPs one round of this workload executes (all devices), for the
-        bench's MFU metric. XLA's cost analysis counts a loop body ONCE
-        regardless of trip count, so instead of lowering the whole round
-        program we cost a single loop-free fwd+bwd batch step and multiply
-        by the number of REAL local steps a round runs. On hetero partitions
-        clients are padded to the largest client's batch count, and the
-        dynamic local loop (``run_local_sgd``) skips padded batches — so the
-        step count here is the mask-derived mean real batches per client,
-        not the padded shape, or MFU would count padding as useful work."""
-        try:
-            batch = {
-                "x": jnp.zeros_like(self.fed.train.x[0, 0]),
-                "y": jnp.zeros_like(self.fed.train.y[0, 0]),
-                "mask": jnp.zeros_like(self.fed.train.mask[0, 0]),
-            }
-            rng = jax.random.PRNGKey(0)
-
-            def one_step(params, batch, rng):
-                (_, aux), grads = jax.value_and_grad(
-                    self.spec.loss, has_aux=True)(params, batch, rng)
-                return grads
-
-            compiled = jax.jit(one_step).lower(
-                self.params, batch, rng).compile()
-            cost = compiled.cost_analysis()
-            if isinstance(cost, list):
-                cost = cost[0] if cost else {}
-            per_batch = float(cost.get("flops", 0.0) or 0.0)
-            n_sampled = int(self.args.client_num_per_round)
-            mask = np.asarray(self.fed.train.mask)  # [clients, batches, bs]
-            real_batches = mask.reshape(mask.shape[0], mask.shape[1], -1)
-            mean_real = float(np.mean(np.sum(
-                np.any(real_batches > 0, axis=-1), axis=-1)))
-            steps = n_sampled * int(hyper.epochs) * mean_real
-            # chaos: dropped clients run zero steps, stragglers a fraction
-            # — scale by the plan's mean work fraction or MFU under
-            # injection would count never-executed steps as useful work
-            if self.chaos.injects_availability:
-                steps *= self.chaos.expected_work_fraction
-            return per_batch * steps
-        except Exception as e:
-            # never crash a bench over cost analysis — but a silent 0.0
-            # zeroes the MFU column with no trace, so say why ONCE
-            if not getattr(self, "_flops_cost_warned", False):
-                self._flops_cost_warned = True
-                logger.warning(
-                    "round_cost_flops failed (MFU will report 0): %s: %s",
-                    type(e).__name__, e, exc_info=True)
-            return 0.0
 
     def run_round(self, round_idx: int, hyper: TrainHyper) -> Dict[str, float]:
         with obs_trace.span("round", root=True,

@@ -10,6 +10,7 @@ does have a chip still runs the tests on the CPU mesh.
 """
 
 import atexit
+import collections
 import os
 import shutil
 import subprocess
@@ -110,8 +111,8 @@ class _CompileDelta(int):
         n = int(self)
         if n == 0:
             return str(n)
-        from fedml_tpu.core.obs import roofline
-        recs = roofline.recent_recompiles()
+        from fedml_tpu.core.obs import recompile
+        recs = recompile.recent_recompiles()
         if not recs:
             return (f"{n} (no recompile-forensics record — the compile "
                     "came from a seam outside the dispatch trackers)")
@@ -131,7 +132,7 @@ def xla_compile_counter():
     ``delta() == 0`` across steady-state work — a nonzero delta is a
     shape-instability regression that would otherwise recompile silently
     every round. On failure the delta's repr prints the recompile
-    forensics (core/obs/roofline), naming the shapes that moved."""
+    forensics (core/obs/recompile), naming the shapes that moved."""
     from fedml_tpu.core import mlops
 
     mlops.install_compile_counter()
@@ -147,3 +148,29 @@ def xla_compile_counter():
             return _CompileDelta(mlops.compile_count() - self._start)
 
     return _Counter()
+
+
+@pytest.fixture
+def tracking_counts(monkeypatch):
+    """``{"spans": n, "sink": m, "names": Counter}``: spans built (and
+    how many of each name) and records handed to the JSONL sink since
+    the fixture was made. What tracking costs is held by these counts,
+    not by a timing on a loaded machine."""
+    from fedml_tpu.core import mlops
+    from fedml_tpu.core.obs import trace as obs_trace
+
+    counts = {"spans": 0, "sink": 0, "names": collections.Counter()}
+    span_init, sink_emit = obs_trace.Span.__init__, mlops.JsonSink.emit
+
+    def counting_init(self, name, *a, **kw):
+        counts["spans"] += 1
+        counts["names"][str(name)] += 1
+        span_init(self, name, *a, **kw)
+
+    def counting_emit(self, record):
+        counts["sink"] += 1
+        sink_emit(self, record)
+
+    monkeypatch.setattr(obs_trace.Span, "__init__", counting_init)
+    monkeypatch.setattr(mlops.JsonSink, "emit", counting_emit)
+    return counts

@@ -189,36 +189,6 @@ class TestSimulatorFaults:
         for a, b in zip(before, leaves(sim.params)):
             np.testing.assert_allclose(a, b, rtol=0, atol=0)
 
-    def test_round_cost_flops_scales_with_chaos_work(self):
-        """MFU honesty (ISSUE 4 satellite): under dropout/straggler
-        injection the costed step count must shrink by the plan's mean
-        work fraction — full-schedule costing would overstate MFU."""
-        from fedml_tpu import data as data_mod
-        from fedml_tpu import model as model_mod
-        from fedml_tpu.core.algframe.client_trainer import (
-            ClassificationTrainer)
-        from fedml_tpu.core.algframe.types import TrainHyper
-        from fedml_tpu.optimizers.registry import create_optimizer
-        from fedml_tpu.simulation.tpu.engine import TPUSimulator
-        import jax.numpy as jnp
-
-        def flops(**kw):
-            args = make_args(**kw)
-            fed, output_dim = data_mod.load(args)
-            bundle = model_mod.create(args, output_dim)
-            spec = ClassificationTrainer(bundle.apply)
-            sim = TPUSimulator(args, fed, bundle,
-                               create_optimizer(args, spec), spec)
-            return sim.round_cost_flops(
-                TrainHyper(learning_rate=jnp.float32(0.1), epochs=1))
-
-        base = flops()
-        injected = flops(chaos_dropout_prob=0.5, chaos_straggler_prob=0.5,
-                         chaos_straggler_work=0.5)
-        assert base > 0
-        # expected fraction: (1 - 0.5) * (0.5 + 0.5 * 0.5) = 0.375
-        assert abs(injected / base - 0.375) < 1e-6
-
     def test_dropout_renormalizes_to_survivor_average(self):
         """Tolerance on: a round with clients {dropped} must equal a round
         where only the survivors were sampled — masking + in-program

@@ -19,7 +19,6 @@ from jax.sharding import SingleDeviceSharding
 
 from fedml_tpu.core import kernels
 from fedml_tpu.core.kernels.conv_block import fused_block
-from fedml_tpu.core.obs import roofline
 from fedml_tpu.llm import moe
 from fedml_tpu.llm.attention import FLASH_KERNEL_NAMES, flash_causal_attention
 
@@ -151,17 +150,3 @@ def test_conv_block_compiles_for_v5e(v5e, hw, cin, c, strides):
     compiled = _compile(
         lambda x, p: fused_block(x, p, strides=strides), v5e, x, p)
     assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_roofline_walker_reads_tpu_hlo_text(v5e):
-    """The static HLO walker on the text of a program compiled for the
-    v5e (tiled layouts, operands printed by name): exact matmul FLOPs,
-    the chip's own row of the peaks table, not static-only."""
-    a = jax.ShapeDtypeStruct((512, 1024), jnp.bfloat16)
-    b = jax.ShapeDtypeStruct((1024, 256), jnp.bfloat16)
-    compiled = _compile(lambda a, b: jnp.tanh(a @ b), v5e, a, b)
-    rec = roofline.analyze_compiled("mm", compiled, device=v5e)
-    assert rec["device_kind"] == "TPU v5 lite" and not rec["static_only"]
-    assert (rec["peak_tflops"], rec["hbm_gbps"]) == (197.0, 819.0)
-    assert rec["total_flops"] == 2 * 512 * 1024 * 256 + 512 * 256
-    assert rec["attributed_share"] == 1.0

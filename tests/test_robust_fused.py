@@ -481,21 +481,3 @@ class TestObservability:
         assert disp, records
         assert {"dispatch", "wall_s", "rounds", "compiles"} <= set(disp[0])
         assert disp[0]["rounds"] == 2
-
-    def test_round_cost_flops_warns_once(self, caplog):
-        from types import SimpleNamespace
-        args = sim_args()
-        sim = build_sim(args)
-
-        def boom(*a, **k):
-            raise RuntimeError("boom")
-
-        sim.spec = SimpleNamespace(loss=boom)
-        with caplog.at_level(logging.WARNING,
-                             logger="fedml_tpu.simulation.tpu.engine"):
-            assert sim.round_cost_flops(hyper_for(args)) == 0.0
-            assert sim.round_cost_flops(hyper_for(args)) == 0.0
-        warned = [r for r in caplog.records
-                  if "round_cost_flops" in r.getMessage()]
-        assert len(warned) == 1
-        assert "boom" in warned[0].getMessage()
