@@ -514,6 +514,22 @@ def record_moe_round(slots_held: float, load_max_sum: float,
                      ).inc(float(compact_steps))
 
 
+def record_flash_plan(interior_share: float, key_mask: bool) -> None:
+    """The flash kernels' block plan of the call just traced (host side,
+    once a trace; ``llm/attention.py::flash_block_plan``): the share of the
+    forward's computed blocks that lie wholly under the diagonal and run no
+    compare, and whether the call carries a key mask (one passed, or a
+    sequence padded to the 128 grid)."""
+    if not _cfg["enabled"]:
+        return
+    REGISTRY.gauge("fed_flash_interior_block_share",
+                   "interior blocks / computed blocks of the last traced "
+                   "flash call's forward plan").set(float(interior_share))
+    REGISTRY.gauge("fed_flash_key_mask",
+                   "1 if the last traced flash call carries a key mask, "
+                   "else 0").set(1.0 if key_mask else 0.0)
+
+
 def record_recompile(program: str) -> None:
     """Recompile forensics: a program compiled PAST its pinned
     expectation (the steady-state invariant is zero)."""

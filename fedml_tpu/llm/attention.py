@@ -13,7 +13,15 @@ counterparts here are first-class:
   and training memory is O(s·d + s·block). Key-padding masks are
   supported. This is the fwd+bwd fused flash-attn the reference gets from
   its CUDA monkey-patch (``train/llm/models/attention.py:30-67``), built
-  for the MXU.
+  for the MXU. What a score block costs beyond its products is kept
+  small (PR 31): one plan (:func:`flash_block_plan`) tells every kernel
+  which blocks lie wholly under the diagonal, and those run no compare
+  and no select; a call without a key mask (none passed, s on the 128
+  grid) carries no mask operand at all, and one with a mask makes ONE
+  compare of positions serve for both masks; dK/dV works on the
+  transposed scores, so nothing score-sized is transposed; bf16 operands
+  go to the MXU as they lie, statistics and accumulators stay float32 in
+  VMEM scratch; the exponentials are base 2 (the scale carries log2 e).
 - ``ring``: ring attention over the ``sp`` mesh axis — sequence shards
   rotate K/V (and the key-padding mask) via ``ppermute`` while
   accumulating online-softmax state, so context length scales with the
@@ -27,12 +35,13 @@ import contextlib
 import contextvars
 import functools
 import math
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..core import kernels
+from ..core.obs import metrics as obs_metrics
 
 NEG_INF = -1e30
 # the three kernels' names in a device trace (forward, dQ, dK/dV)
@@ -129,143 +138,279 @@ def cached_attention(q, k_all, v_all, q_positions):
 # kernels recompute P = exp(QK^T·scale − LSE) blockwise in VMEM, so neither
 # direction materializes [s, s] in HBM. Key padding rides a [b, s] mask.
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *,
-                      block_k: int, seq_len: int, scale: float):
+class FlashBlockPlan(NamedTuple):
+    """Which [block_q, block_k] score blocks of the causal ``s x s`` square
+    a kernel computes, and which of them the diagonal crosses. A block
+    wholly under the diagonal (*interior*) needs no causal compare; one
+    wholly above it is skipped. The bounds take a Python int or a traced
+    ``program_id`` alike, for any ``block_q``, ``block_k`` that divide s."""
+    n_q: int
+    n_k: int
+    block_q: int
+    block_k: int
+
+    def q_major(self, i):
+        """``(n_full, n_live)`` for q block ``i`` (forward, dQ): kv blocks
+        ``[0, n_full)`` are interior, ``[n_full, n_live)`` hold the
+        diagonal, the rest see none of this q block."""
+        bq, bk = self.block_q, self.block_k
+        # interior: the block's last key <= its first query
+        n_full = _least(self.n_k, (i * bq + 1) // bk)
+        # live: the block's first key <= its last query
+        n_live = _least(self.n_k, ((i + 1) * bq + bk - 1) // bk)
+        return n_full, n_live
+
+    def k_major(self, j):
+        """``(j0, j_full)`` for kv block ``j`` (dK/dV): q blocks
+        ``[j0, j_full)`` hold the diagonal, ``[j_full, n_q)`` are interior,
+        those before ``j0`` see none of this kv block."""
+        bq, bk = self.block_q, self.block_k
+        j0 = (j * bk) // bq
+        j_full = _least(self.n_q, ((j + 1) * bk + bq - 2) // bq)
+        return j0, j_full
+
+    def counts(self, k_major: bool = False):
+        """(interior, diagonal, skipped) blocks a head, q-major or k-major
+        (the same blocks, counted along the other axis)."""
+        interior = diagonal = 0
+        if k_major:
+            for j in range(self.n_k):
+                j0, j_full = self.k_major(j)
+                interior += self.n_q - j_full
+                diagonal += j_full - j0
+        else:
+            for i in range(self.n_q):
+                n_full, n_live = self.q_major(i)
+                interior += n_full
+                diagonal += n_live - n_full
+        return interior, diagonal, self.n_q * self.n_k - interior - diagonal
+
+
+def _least(a, b):
+    return min(a, b) if isinstance(b, int) else jnp.minimum(a, b)
+
+
+def flash_block_plan(s: int, block_q: int, block_k: int) -> FlashBlockPlan:
+    """The plan all three kernels take their loop bounds from (``s`` a
+    multiple of both blocks)."""
+    return FlashBlockPlan(s // block_q, s // block_k, block_q, block_k)
+
+
+def _split_refs(refs, n_lead: int, key_mask: bool):
+    """A kernel's refs: ``n_lead`` leading operands, the key mask only when
+    the call has one, then the rest."""
+    lead, rest = refs[:n_lead], refs[n_lead:]
+    return (*lead, rest[0] if key_mask else None,
+            *(rest[1:] if key_mask else rest))
+
+
+# a @ b.T for the MXU: contract the last dimension of both
+_NT = (((1,), (1,)), ((), ()))
+
+# a masked key is given a position after every query, so ONE compare of
+# positions is both the causal and the key mask
+_NEVER = 2 ** 30
+
+# the kernels work in base 2: the scores carry log2(e) in q's scale, so
+# P = exp2(S2 - LSE2) is the exponential unit's own operation and no
+# multiply an element; LSE is stored in natural units all the same
+_LOG2E = 1.4426950408889634
+_LN2 = 0.6931471805599453
+
+
+def _live(q_pos, k_pos, keep):
+    """Which scores count: ``q_pos >= k_pos``, one a column and one a row.
+    ``keep`` (bool, ``k_pos``'s shape, or None) is the key mask."""
+    if keep is not None:
+        k_pos = jnp.where(keep, k_pos, _NEVER)
+    return q_pos >= k_pos
+
+
+def _scaled(q, factor: float):
+    """q times ``factor`` in float32, rounded once to q's own dtype: every
+    kernel's score product reads the same numbers."""
+    return (q.astype(jnp.float32) * factor).astype(q.dtype)
+
+
+def _across(stat, width: int):
+    """A row statistic held lane-replicated as ``[rows, 128]``, at
+    ``width`` columns (whole registers again when 128 divides it)."""
+    if width % 128 == 0:
+        return jnp.tile(stat, (1, width // 128))
+    return jnp.broadcast_to(stat[:, :1], (stat.shape[0], width))
+
+
+def _fold_blocks(pl, lo, mid, hi, interior_first: bool, block: int, fold,
+                 key_mask: bool):
+    """``fold(start, compare_here)`` over blocks ``[lo, hi)`` of a ref's
+    long axis, in index order: ``[lo, mid)`` interior (no compare) then
+    ``[mid, hi)`` diagonal, or the diagonal first. With a key mask every
+    block compares positions (:func:`_live`), so one loop runs them all.
+    The state lives in VMEM scratch; nothing is carried from loop to loop."""
+    def run(first, last, compare_here):
+        def body(i, _):
+            fold(pl.multiple_of(i * block, block), compare_here)
+        jax.lax.fori_loop(first, last, body, None)
+
+    if key_mask:
+        run(lo, hi, True)
+    else:
+        run(lo, mid, not interior_first)
+        run(mid, hi, interior_first)
+
+
+def _flash_fwd_kernel(*refs, plan: FlashBlockPlan, scale: float,
+                      key_mask: bool):
     """One (batch*head, q-block) program: online softmax over KV blocks.
 
-    q_ref: [block_q, d_qk]; k_ref: [s, d_qk]; v_ref: [s, d_v];
-    mask_ref: [s, 1]; o_ref: [block_q, d_v]; lse_ref: [block_q, 1].
+    q_ref: [block_q, d_qk]; k_ref: [s, d_qk]; v_ref: [s, d_v]; mask_ref
+    (only with a key mask): [1, s]; o_ref: [block_q, d_v]; lse_ref:
+    [block_q, 1]; scratch, float32: acc_ref [block_q, d_v], m_ref and
+    l_ref [block_q, 128] (a row's statistic in every lane).
     """
     import jax.experimental.pallas as pl
 
-    block_q = q_ref.shape[0]
-    d = v_ref.shape[1]
-    q_blk_idx = pl.program_id(1)
-    q_pos = q_blk_idx * block_q + jax.lax.broadcasted_iota(
+    q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = \
+        _split_refs(refs, 3, key_mask)
+    block_q, block_k = plan.block_q, plan.block_k
+    d_v = acc_ref.shape[1]
+    q_pos = pl.program_id(1) * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, 1), 0)
+    q = _scaled(q_ref[:], scale * _LOG2E)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
 
-    q = q_ref[:].astype(jnp.float32) * scale
-
-    def body(i, carry):
-        o_acc, m, l = carry
-        k_blk = k_ref[pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s_blk = jnp.dot(q, k_blk.T,
-                        preferred_element_type=jnp.float32)  # [bq, bk]
-        k_pos = i * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        live = q_pos >= k_pos
-        kmask = mask_ref[pl.ds(i * block_k, block_k), 0]
-        live = jnp.logical_and(live, (kmask > 0)[None, :])
-        s_blk = jnp.where(live, s_blk, NEG_INF)
+    def fold(start, compare_here):
+        """Keys ``[start, start + block_k)`` into the running softmax."""
+        keys = pl.ds(start, block_k)
+        k_blk, v_blk = k_ref[keys, :], v_ref[keys, :]
+        s_blk = jax.lax.dot_general(                    # [bq, bk]
+            q, k_blk, _NT, preferred_element_type=jnp.float32)
+        if compare_here:
+            k_pos = start + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_k), 1)
+            live = _live(q_pos, k_pos,
+                         mask_ref[:, keys] > 0 if key_mask else None)
+            s_blk = jnp.where(live, s_blk, NEG_INF)
+        m = m_ref[:]
         m_new = jnp.maximum(m, jnp.max(s_blk, -1, keepdims=True))
-        # gate on `live`, not just the exp: for a row with NO live keys
-        # m_new stays NEG_INF, so exp(s_blk - m_new) = exp(0) = 1 at every
-        # masked position and O would silently become an unmasked average
-        # of V; gating keeps l = 0 so the row's output is exactly zero and
-        # its stored LSE ≈ NEG_INF (flagging the row) instead
-        p = jnp.where(live, jnp.exp(s_blk - m_new), 0.0)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, -1, keepdims=True)
-        o_new = o_acc * alpha + jnp.dot(p, v_blk,
-                                        preferred_element_type=jnp.float32)
-        return o_new, m_new, l_new
+        p = jnp.exp2(s_blk - _across(m_new, block_k))
+        if key_mask:
+            # gate on `live`, not just the exp: for a row with NO live
+            # keys m_new stays NEG_INF, so exp2(s_blk - m_new) = 1 at every
+            # masked position and O would silently become an unmasked
+            # average of V; gating keeps l = 0 so the row's output is
+            # exactly zero and its stored LSE ≈ NEG_INF (flagging the row)
+            # instead. Without a key mask every row has seen key 0 by its
+            # first block, so m_new is finite and the exp2 of a masked
+            # score is already 0.
+            p = jnp.where(live, p, 0.0)
+        alpha = jnp.exp2(m - m_new)
+        m_ref[:] = m_new
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, -1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * _across(alpha, d_v) + jnp.dot(
+            p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32)
 
-    n_k = pl.cdiv(seq_len, block_k)
-    # causal: kv blocks strictly after this q block contribute nothing;
-    # the last live block is the one containing this q block's final query
-    n_live = jnp.minimum(
-        n_k, ((q_blk_idx + 1) * block_q + block_k - 1) // block_k)
-    o_acc = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    o_acc, m, l = jax.lax.fori_loop(0, n_live, body, (o_acc, m0, l0))
-    o_ref[:] = (o_acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    lse_ref[:] = m + jnp.log(jnp.maximum(l, 1e-30))
+    n_full, n_live = plan.q_major(pl.program_id(1))
+    _fold_blocks(pl, 0, n_full, n_live, True, block_k, fold, key_mask)
+    l = jnp.maximum(l_ref[:], 1e-30)
+    o_ref[:] = (acc_ref[:] * _across(1.0 / l, d_v)).astype(o_ref.dtype)
+    lse_ref[:] = ((m_ref[:] + jnp.log2(l)) * _LN2)[:, :1]
 
 
-def _flash_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, dd_ref,
-                     dq_ref, *, block_k: int, seq_len: int, scale: float):
-    """dQ for one q block: dS = P ∘ (dO·Vᵀ − D); dQ = scale · dS·K."""
+def _flash_dq_kernel(*refs, plan: FlashBlockPlan, scale: float,
+                     key_mask: bool):
+    """dQ for one q block: dS = P ∘ (dO·Vᵀ − D); dQ = scale · dS·K.
+    Scratch acc_ref: [block_q, d_qk] float32."""
     import jax.experimental.pallas as pl
 
-    block_q, d = q_ref.shape              # d = d_qk; v and dO carry d_v
-    q_blk_idx = pl.program_id(1)
-    q_pos = q_blk_idx * block_q + jax.lax.broadcasted_iota(
+    q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, dd_ref, dq_ref, acc_ref = \
+        _split_refs(refs, 3, key_mask)
+    block_q, block_k = plan.block_q, plan.block_k
+    q_pos = pl.program_id(1) * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, 1), 0)
-    q = q_ref[:].astype(jnp.float32) * scale
-    do = do_ref[:].astype(jnp.float32)
-    lse = lse_ref[:]                      # [block_q, 1]
+    q = _scaled(q_ref[:], scale * _LOG2E)
+    do = do_ref[:]
+    lse2 = lse_ref[:] * _LOG2E            # [block_q, 1]
     dd = dd_ref[:]                        # [block_q, 1]
+    acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def body(i, dq_acc):
-        k_blk = k_ref[pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s_blk = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        k_pos = i * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        live = q_pos >= k_pos
-        kmask = mask_ref[pl.ds(i * block_k, block_k), 0]
-        live = jnp.logical_and(live, (kmask > 0)[None, :])
-        p = jnp.where(live, jnp.exp(s_blk - lse), 0.0)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
+    def fold(start, compare_here):
+        """Keys ``[start, start + block_k)`` into dQ."""
+        keys = pl.ds(start, block_k)
+        k_blk, v_blk = k_ref[keys, :], v_ref[keys, :]
+        s_blk = jax.lax.dot_general(
+            q, k_blk, _NT, preferred_element_type=jnp.float32)
+        p = jnp.exp2(s_blk - lse2)
+        if compare_here:
+            k_pos = start + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_k), 1)
+            live = _live(q_pos, k_pos,
+                         mask_ref[:, keys] > 0 if key_mask else None)
+            p = jnp.where(live, p, 0.0)
+        dp = jax.lax.dot_general(
+            do, v_blk, _NT, preferred_element_type=jnp.float32)
         ds = p * (dp - dd)
-        return dq_acc + jnp.dot(ds, k_blk,
-                                preferred_element_type=jnp.float32)
+        acc_ref[:] += jnp.dot(ds.astype(k_blk.dtype), k_blk,
+                              preferred_element_type=jnp.float32)
 
-    n_k = pl.cdiv(seq_len, block_k)
-    n_live = jnp.minimum(
-        n_k, ((q_blk_idx + 1) * block_q + block_k - 1) // block_k)
-    dq = jax.lax.fori_loop(0, n_live, body,
-                           jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[:] = (dq * scale).astype(dq_ref.dtype)
+    n_full, n_live = plan.q_major(pl.program_id(1))
+    _fold_blocks(pl, 0, n_full, n_live, True, block_k, fold, key_mask)
+    dq_ref[:] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
-def _flash_dkv_kernel(k_ref, v_ref, q_ref, mask_ref, do_ref, lse_ref,
-                      dd_ref, dk_ref, dv_ref, *, block_q: int, seq_len: int,
-                      scale: float):
-    """dK/dV for one kv block: dV = Pᵀ·dO; dK = scale · dSᵀ·Q."""
+def _flash_dkv_kernel(*refs, plan: FlashBlockPlan, scale: float,
+                      key_mask: bool):
+    """dK/dV for one kv block, in the transposed orientation: the scores
+    as ``[block_k, block_q]`` from ``K·Qᵀ``, so that dV = Pᵀ·dO and
+    dK = scale · dSᵀ·Q are plain products of what the block already holds
+    and nothing score-sized is transposed.
+
+    k_ref: [block_k, d_qk]; v_ref: [block_k, d_v]; q_ref: [s, d_qk];
+    mask_ref (only with a key mask): [block_k, 1]; do_ref: [s, d_v];
+    lse_ref, dd_ref: [1, s] rows; dk_ref: [block_k, d_qk]; dv_ref:
+    [block_k, d_v]; scratch dk_acc, dv_acc: the results' shapes, float32.
+    """
     import jax.experimental.pallas as pl
 
-    block_k, d = k_ref.shape              # d = d_qk
-    d_v = v_ref.shape[1]
-    k_blk_idx = pl.program_id(1)
-    k_pos = k_blk_idx * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_k), 1)
-    k = k_ref[:].astype(jnp.float32)
-    v = v_ref[:].astype(jnp.float32)
-    kmask = (mask_ref[:, 0] > 0)[None, :]  # this kv block's slice via BlockSpec
+    (k_ref, v_ref, q_ref, mask_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref,
+     dk_acc, dv_acc) = _split_refs(refs, 3, key_mask)
+    block_q, block_k = plan.block_q, plan.block_k
+    k_pos = pl.program_id(1) * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_k, 1), 0)
+    if key_mask:                          # this kv block's, via BlockSpec
+        k_pos = jnp.where(mask_ref[:] > 0, k_pos, _NEVER)
+    k, v = k_ref[:], v_ref[:]
+    dk_acc[:] = jnp.zeros_like(dk_acc)
+    dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def body(j, carry):
-        dk_acc, dv_acc = carry
-        q_blk = q_ref[pl.ds(j * block_q, block_q), :].astype(
-            jnp.float32) * scale
-        do_blk = do_ref[pl.ds(j * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[pl.ds(j * block_q, block_q), :]
-        dd = dd_ref[pl.ds(j * block_q, block_q), :]
-        s_blk = jnp.dot(q_blk, k.T, preferred_element_type=jnp.float32)
-        q_pos = j * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
-        live = jnp.logical_and(q_pos >= k_pos, kmask)
-        p = jnp.where(live, jnp.exp(s_blk - lse), 0.0)       # [bq, bk]
-        dv_acc = dv_acc + jnp.dot(p.T, do_blk,
-                                  preferred_element_type=jnp.float32)
-        dp = jnp.dot(do_blk, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - dd)
-        dk_acc = dk_acc + jnp.dot(ds.T, q_blk,
-                                  preferred_element_type=jnp.float32)
-        return dk_acc, dv_acc
+    def fold(start, compare_here):
+        """Queries ``[start, start + block_q)`` into dK and dV."""
+        rows = pl.ds(start, block_q)
+        # the forward's and dQ's q block, to the last bit
+        q_blk = _scaled(q_ref[rows, :], scale * _LOG2E)
+        do_blk = do_ref[rows, :]
+        s_t = jax.lax.dot_general(                      # [bk, bq]
+            k, q_blk, _NT, preferred_element_type=jnp.float32)
+        p_t = jnp.exp2(s_t - lse_ref[:, rows] * _LOG2E)
+        if compare_here:
+            q_pos = start + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_q), 1)
+            p_t = jnp.where(q_pos >= k_pos, p_t, 0.0)
+        dv_acc[:] += jnp.dot(p_t.astype(do_blk.dtype), do_blk,
+                             preferred_element_type=jnp.float32)
+        dp_t = jax.lax.dot_general(
+            v, do_blk, _NT, preferred_element_type=jnp.float32)
+        ds_t = p_t * (dp_t - dd_ref[:, rows])
+        dk_acc[:] += jnp.dot(ds_t.astype(q_blk.dtype), q_blk,
+                             preferred_element_type=jnp.float32)
 
-    n_q = pl.cdiv(seq_len, block_q)
-    # causal: q blocks strictly before this kv block see none of it
-    j0 = (k_blk_idx * block_k) // block_q
-    dk, dv = jax.lax.fori_loop(
-        j0, n_q, body, (jnp.zeros((block_k, d), jnp.float32),
-                        jnp.zeros((block_k, d_v), jnp.float32)))
-    # dk absorbs the q-side scale (q was pre-scaled), which equals the
-    # symmetric scale on s = scale·q·kᵀ
-    dk_ref[:] = dk.astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
+    j0, j_full = plan.k_major(pl.program_id(1))
+    _fold_blocks(pl, j0, j_full, plan.n_q, False, block_q, fold, key_mask)
+    # q carried scale * log2(e); dK wants the scale alone
+    dk_ref[:] = (dk_acc[:] * _LN2).astype(dk_ref.dtype)
+    dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _heads_first(a):
@@ -274,22 +419,37 @@ def _heads_first(a):
     return a.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
 
+
+def _row_mask(pl, mask, h: int):
+    """(operands, specs) of the key mask for the q-major kernels: a
+    ``[1, s]`` row a batch row, lane-dense beside the ``[block_q, block_k]``
+    scores whose keys lie along the lanes; nothing without a mask."""
+    if mask is None:
+        return [], []
+    b, s, _ = mask.shape
+    return ([mask.reshape(b, 1, s)],
+            [pl.BlockSpec((None, 1, s), lambda i, j: (i // h, 0, 0))])
+
+
 def _flash_fwd(q, k, v, mask, block_q: int, block_k: int, scale: float):
     import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
 
     b, s, h, d = q.shape
     dv = v.shape[-1]
+    key_mask = mask is not None
+    plan = flash_block_plan(s, block_q, block_k)
     qf, kf, vf = _heads_first(q), _heads_first(k), _heads_first(v)
-    grid = (b * h, pl.cdiv(s, block_q))
+    mask_rows, mask_specs = _row_mask(pl, mask, h)
     out, lse = pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, block_k=block_k, seq_len=s,
-                          scale=scale),
-        grid=grid,
+        functools.partial(_flash_fwd_kernel, plan=plan, scale=scale,
+                          key_mask=key_mask),
+        grid=(b * h, plan.n_q),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, s, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((None, s, dv), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, s, 1), lambda i, j, h=h: (i // h, 0, 0)),
+            *mask_specs,
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, dv), lambda i, j: (i, j, 0)),
@@ -299,57 +459,66 @@ def _flash_fwd(q, k, v, mask, block_q: int, block_k: int, scale: float):
             jax.ShapeDtypeStruct((b * h, s, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((block_q, dv), jnp.float32),
+                        pltpu.VMEM((block_q, 128), jnp.float32),
+                        pltpu.VMEM((block_q, 128), jnp.float32)],
         interpret=kernels.interpret(),
         compiler_params=kernels.tpu_compiler_params(),
         name=FLASH_KERNEL_NAMES[0],
-    )(qf, kf, vf, mask)
+    )(qf, kf, vf, *mask_rows)
     return out.reshape(b, h, s, dv).transpose(0, 2, 1, 3), lse
 
 
 def _flash_bwd(q, k, v, mask, o, lse, g, block_q: int, block_k: int,
                scale: float):
     import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
 
     b, s, h, d = q.shape
     dv = v.shape[-1]
+    key_mask = mask is not None
+    plan = flash_block_plan(s, block_q, block_k)
     qf, kf, vf = _heads_first(q), _heads_first(k), _heads_first(v)
     gf, of = _heads_first(g), _heads_first(o)
     # D_i = Σ_d dO_i ∘ O_i — one cheap elementwise pass in XLA
     dd = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32),
                  axis=-1, keepdims=True)
+    mask_rows, mask_specs = _row_mask(pl, mask, h)
 
     dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, block_k=block_k, seq_len=s,
-                          scale=scale),
-        grid=(b * h, pl.cdiv(s, block_q)),
+        functools.partial(_flash_dq_kernel, plan=plan, scale=scale,
+                          key_mask=key_mask),
+        grid=(b * h, plan.n_q),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, s, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((None, s, dv), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, s, 1), lambda i, j, h=h: (i // h, 0, 0)),
+            *mask_specs,
             pl.BlockSpec((None, block_q, dv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, block_q, 1), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, block_q, 1), lambda i, j: (i, j, 0)),
         ],
         out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=kernels.interpret(),
         compiler_params=kernels.tpu_compiler_params(),
         name=FLASH_KERNEL_NAMES[1],
-    )(qf, kf, vf, mask, gf, lse, dd)
+    )(qf, kf, vf, *mask_rows, gf, lse, dd)
 
     dk, dv_ = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, block_q=block_q, seq_len=s,
-                          scale=scale),
-        grid=(b * h, pl.cdiv(s, block_k)),
+        functools.partial(_flash_dkv_kernel, plan=plan, scale=scale,
+                          key_mask=key_mask),
+        grid=(b * h, plan.n_k),
         in_specs=[
             pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, block_k, dv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, s, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, block_k, 1), lambda i, j, h=h: (i // h, j, 0)),
+            *([pl.BlockSpec((None, block_k, 1), lambda i, j: (i // h, j, 0))]
+              if key_mask else []),
             pl.BlockSpec((None, s, dv), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, s, 1), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, s, 1), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, 1, s), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, 1, s), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
@@ -359,10 +528,13 @@ def _flash_bwd(q, k, v, mask, o, lse, g, block_q: int, block_k: int,
             jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, s, dv), q.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         interpret=kernels.interpret(),
         compiler_params=kernels.tpu_compiler_params(),
         name=FLASH_KERNEL_NAMES[2],
-    )(kf, vf, qf, mask, gf, lse, dd)
+    )(kf, vf, qf, *([mask] if key_mask else []), gf,
+      lse.reshape(b * h, 1, s), dd.reshape(b * h, 1, s))
 
     unflat = lambda a: a.reshape(b, h, s, -1).transpose(0, 2, 1, 3)
     return unflat(dq), unflat(dk), unflat(dv_)
@@ -382,7 +554,7 @@ def _flash_bwd_rule(block_q, block_k, scale, res, g):
     q, k, v, mask, out, lse = res
     dq, dk, dv = _flash_bwd(q, k, v, mask, out, lse, g, block_q, block_k,
                             scale)
-    return dq, dk, dv, jnp.zeros_like(mask)
+    return dq, dk, dv, None if mask is None else jnp.zeros_like(mask)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -397,10 +569,12 @@ def flash_causal_attention(q, k, v, block_q: int = 512, block_k: int = 512,
     ``attn_mask``: optional [b, s] key-padding mask (1 = real); ``scale``
     multiplies the scores (default ``d_qk ** -0.5``).
 
-    Default blocks are 512x512 — measured on v5e (h=8, d=128): 1.5x
-    faster than 128x128 at s=4096 and 2.7x at s=8192 (bigger MXU tiles,
-    fewer grid programs); ``_fit_block`` shrinks them automatically for
-    shorter sequences.
+    Blocks are 512x512 where s allows (``_fit_block``). On the v5e (PR 31,
+    kernels alone, bf16, ms a call forward / dQ / dK/dV; the kernels before
+    it in brackets): ``[1,4096,64,192/128]`` 3.02 / 4.51 / 5.49 (4.09 /
+    4.82 / 6.68), ``[8,1024,32,128]`` 0.93 / 1.10 / 1.38 (1.41 / 1.10 /
+    2.04), ``[1,8192,2,128]`` 0.25 / 0.33 / 0.42 (0.38 / 0.33 / 0.53); dQ
+    and dK/dV then sit at the MXU's own time for the blocks they compute.
 
     Sequences are padded up to a multiple of 128 so every Pallas block is
     lane/sublane-aligned on real TPU hardware (a non-power-of-two s like
@@ -412,18 +586,23 @@ def flash_causal_attention(q, k, v, block_q: int = 512, block_k: int = 512,
     b, s, h, d = q.shape
     scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
     s_pad = -(-s // 128) * 128
-    if attn_mask is None:
-        mask = jnp.ones((b, s, 1), jnp.float32)
-    else:
-        mask = attn_mask.astype(jnp.float32)[:, :, None]
+    # the key mask is a static property of the call: without one, and with
+    # nothing padded, the kernels carry no key-mask operand or arithmetic
+    mask = None
+    if attn_mask is not None or s_pad != s:
+        mask = (jnp.ones((b, s), jnp.float32) if attn_mask is None
+                else attn_mask.astype(jnp.float32))[:, :, None]
     if s_pad != s:
         pad = [(0, 0), (0, s_pad - s), (0, 0), (0, 0)]
         q = jnp.pad(q, pad)
         k = jnp.pad(k, pad)
         v = jnp.pad(v, pad)
         mask = jnp.pad(mask, [(0, 0), (0, s_pad - s), (0, 0)])
-    out = _flash(q, k, v, mask, _fit_block(s_pad, block_q),
-                 _fit_block(s_pad, block_k), scale)
+    block_q, block_k = _fit_block(s_pad, block_q), _fit_block(s_pad, block_k)
+    interior, diagonal, _ = flash_block_plan(s_pad, block_q, block_k).counts()
+    obs_metrics.record_flash_plan(interior / (interior + diagonal),
+                                  mask is not None)
+    out = _flash(q, k, v, mask, block_q, block_k, scale)
     return out[:, :s] if s_pad != s else out
 
 

@@ -73,6 +73,22 @@ def test_flash_unequal_head_sizes_compile_for_v5e(v5e):
         assert name in text
 
 
+def test_flash_with_a_key_mask_compiles_for_v5e(v5e):
+    """The other variant of the three kernels: a call with ``attn_mask``
+    carries the mask operand and compares positions in every block. Both
+    variants are known to lower through Mosaic before a chip is asked."""
+    q = jax.ShapeDtypeStruct((2, 1024, 8, 128), jnp.bfloat16)
+    mask = jax.ShapeDtypeStruct((2, 1024), jnp.float32)
+    text = _compile(
+        lambda q, k, v, mask: jax.value_and_grad(
+            lambda q, k, v: flash_causal_attention(
+                q, k, v, attn_mask=mask).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v), v5e, q, q, q, mask).as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in FLASH_KERNEL_NAMES:
+        assert name in text
+
+
 @pytest.mark.parametrize("transpose", [False, True])
 def test_grouped_matmul_compiles_for_v5e(v5e, transpose):
     """The expert layer's grouped product at the benchmark's widths (12

@@ -138,6 +138,218 @@ def test_nonaligned_seq_len_pads_to_lane_multiple():
                                atol=1e-5)
 
 
+# ------------------------------------------------------------------------
+# The flash kernels' block plan and both of their variants (tier-1, the
+# Pallas interpreter on the CPU): without a key mask and with s on the
+# 128 grid the kernels carry no mask arithmetic; with a mask or a padded s
+# every block runs the masked arithmetic; in both, only the blocks the
+# diagonal crosses run the causal compare.
+
+@pytest.mark.parametrize("s,block_q,block_k,want", [
+    (4096, 512, 512, (28, 8, 28)),      # the axk1 cell: 28/36 interior
+    (1024, 512, 512, (1, 2, 1)),        # the Mistral cell: 1/3
+    (8192, 512, 512, (120, 16, 120)),   # chip_smoke's long context
+    (1024, 256, 512, (2, 4, 2)),
+    (1024, 512, 128, (4, 8, 4)),
+])
+def test_flash_block_plan_by_hand(s, block_q, block_k, want):
+    from fedml_tpu.llm.attention import flash_block_plan
+    plan = flash_block_plan(s, block_q, block_k)
+    assert plan.counts() == want
+    assert sum(want) == (s // block_q) * (s // block_k)
+    # the k-major kernel walks the same blocks along the other axis
+    assert plan.counts(k_major=True) == want
+    # and block by block: a block is interior iff its last key <= its
+    # first query, live iff its first key <= its last query
+    for i in range(plan.n_q):
+        n_full, n_live = plan.q_major(i)
+        for j in range(plan.n_k):
+            interior = (j + 1) * block_k - 1 <= i * block_q
+            live = j * block_k <= (i + 1) * block_q - 1
+            assert (j < n_full) == interior and (j < n_live) == live
+            j0, j_full = plan.k_major(j)
+            assert (i >= j_full) == interior and (i >= j0) == live
+
+
+def _flash_case(s, d_qk=8, d_v=8, b=2, h=2, dtype=jnp.float32, seed=0):
+    key = jax.random.PRNGKey(seed)
+    q, k = (jax.random.normal(jax.random.fold_in(key, i),
+                              (b, s, h, d_qk)).astype(dtype) for i in (0, 1))
+    v, c = (jax.random.normal(jax.random.fold_in(key, i),
+                              (b, s, h, d_v)).astype(dtype) for i in (2, 3))
+    return q, k, v, c.astype(jnp.float32)
+
+
+def _out_and_grads(fn, q, k, v, c):
+    def loss(q, k, v):
+        out = fn(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) * c), out
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    return (out,) + grads
+
+
+def _worst_rel(got, want):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    assert np.isfinite(got).all()
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+FLASH_CASES = {
+    # name: (s, block_q, block_k, key mask?, d_qk, d_v, scale)
+    "unmasked_on_grid": (256, 128, 128, False, 8, 8, None),
+    "key_mask": (256, 128, 128, True, 8, 8, None),
+    "padded_s1000": (1000, 512, 512, False, 8, 8, None),
+    "block_q_2x_block_k": (512, 256, 128, False, 8, 8, None),
+    "block_k_2x_block_q": (512, 128, 256, False, 8, 8, None),
+    "block_k_2x_block_q_key_mask": (512, 128, 256, True, 8, 8, None),
+    "latent_192_128_scaled": (256, 128, 128, False, 192, 128, 0.11),
+}
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("case", sorted(FLASH_CASES))
+def test_flash_variants_match_dense(case):
+    """Output and all three gradients against dense attention at float32,
+    to the 1e-5 the older flash tests hold."""
+    s, block_q, block_k, masked, d_qk, d_v, scale = FLASH_CASES[case]
+    b = 1 if s >= 1000 or d_qk > 8 else 2
+    q, k, v, c = _flash_case(s, d_qk, d_v, b=b)
+    mask = None
+    if masked:
+        mask = (jax.random.uniform(jax.random.PRNGKey(7), (b, s)) > 0.3
+                ).astype(jnp.float32).at[:, 0].set(1.0)
+    flash = lambda q, k, v: flash_causal_attention(  # noqa: E731
+        q, k, v, block_q, block_k, attn_mask=mask, scale=scale)
+    dense = lambda q, k, v: dense_causal_attention(  # noqa: E731
+        q, k, v, attn_mask=mask, scale=scale)
+    for got, want in zip(_out_and_grads(flash, q, k, v, c),
+                         _out_and_grads(dense, q, k, v, c)):
+        assert got.shape == want.shape
+        assert _worst_rel(got, want) < 1e-5
+    if masked:      # masked keys take no gradient at all
+        _, _, dk, dv = _out_and_grads(flash, q, k, v, c)
+        dead = np.asarray(mask) == 0
+        assert np.all(np.asarray(dk)[dead] == 0)
+        assert np.all(np.asarray(dv)[dead] == 0)
+
+
+@pytest.mark.pallas
+def test_flash_bf16_operands_stay_within_bf16_of_the_float32_result():
+    """bfloat16 q, k, v go to the products as they lie (float32 statistics
+    and accumulators; ``p`` and ``dS`` rounded once to bf16 for their
+    second product). Against DENSE attention of the same values at float32
+    the worst element of the output and of each gradient stays under 1e-2
+    of the largest: bf16 keeps 8 bits, 4e-3 a rounding. The interpreter
+    reads 0.0027, 0.0037, 0.0049, 0.0028 (out, dq, dk, dv) here; the
+    parent's kernels, whose float32 products the interpreter does not
+    round as the MXU does, read 0.0022, 0.0023, 0.0039, 0.0025. On the
+    v5e both round alike: kernels alone at [1,4096,64,192/128], L2 error
+    against float32 dense 0.00349, 0.00386, 0.00418, 0.00369 now and
+    0.00348, 0.00386, 0.00421, 0.00371 before (PERF.md, PR 31)."""
+    q, k, v, c = _flash_case(512, 64, 64, b=1, dtype=jnp.bfloat16, seed=3)
+    flash = lambda q, k, v: flash_causal_attention(  # noqa: E731
+        q, k, v, 256, 256)
+    got = _out_and_grads(flash, q, k, v, c)
+    assert all(g.dtype == jnp.bfloat16 for g in got)
+    f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+    want = _out_and_grads(dense_causal_attention, *f32, c)
+    for g, w in zip(got, want):
+        assert _worst_rel(g, w) < 1e-2
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("s,masked,share,flag", [
+    (1024, False, 1 / 3, 0.0), (4096, False, 28 / 36, 0.0),
+    (1024, True, 1 / 3, 1.0), (1000, False, 1 / 3, 1.0)])
+def test_flash_plan_gauges_are_set_when_a_call_is_traced(s, masked, share,
+                                                         flag):
+    """``fed_flash_interior_block_share`` and ``fed_flash_key_mask`` say
+    which variant a traced call got (a padded s carries a key mask); with
+    the registry's hooks off nothing is recorded."""
+    from fedml_tpu.core.obs import REGISTRY, metrics
+    q = jax.ShapeDtypeStruct((1, s, 1, 8), jnp.float32)
+    mask = jnp.ones((1, s), jnp.float32) if masked else None
+    trace = lambda: jax.eval_shape(  # noqa: E731
+        lambda q, k, v: flash_causal_attention(q, k, v, attn_mask=mask),
+        q, q, q)
+    names = ("fed_flash_interior_block_share", "fed_flash_key_mask")
+    was = metrics.is_enabled()
+    try:
+        metrics.set_enabled(True)
+        trace()
+        got = [REGISTRY.gauge(n).value() for n in names]
+        assert got == pytest.approx([share, flag])
+        for n in names:
+            REGISTRY.gauge(n).set(-1.0)
+        metrics.set_enabled(False)
+        trace()
+        assert [REGISTRY.gauge(n).value() for n in names] == [-1.0, -1.0]
+    finally:
+        metrics.set_enabled(was)
+
+
+@pytest.mark.pallas
+def test_flash_all_masked_rows_read_zero_with_a_finite_gradient():
+    """A query row whose every visible key is masked (the guarantee lives
+    in the masked variant alone: without a key mask every row sees key 0)
+    reads exactly zero, passes no gradient on, and the live rows still
+    match dense attention."""
+    s = 256
+    q, k, v, c = _flash_case(s, b=1, h=1)
+    mask = jnp.ones((1, s), jnp.float32).at[:, :130].set(0.0)
+    flash = lambda q, k, v: flash_causal_attention(  # noqa: E731
+        q, k, v, 128, 128, attn_mask=mask)
+    dense = lambda q, k, v: dense_causal_attention(  # noqa: E731
+        q, k, v, attn_mask=mask)
+    out, dq, dk, dv = _out_and_grads(flash, q, k, v, c)
+    assert np.all(np.asarray(out)[:, :130] == 0.0)
+    for g in (dq, dk, dv):
+        assert np.isfinite(np.asarray(g)).all()
+    assert np.all(np.asarray(dq)[:, :130] == 0.0)
+    assert np.all(np.asarray(dk)[:, :130] == 0.0)
+    # dense attention spreads an all-masked row evenly over every key, so
+    # it is asked with those rows' cotangent at zero
+    want = _out_and_grads(dense, q, k, v, c.at[:, :130].set(0.0))
+    assert _worst_rel(out[:, 130:], want[0][:, 130:]) < 1e-5
+    assert _worst_rel(dq[:, 130:], want[1][:, 130:]) < 1e-5
+    assert _worst_rel(dk[:, 130:], want[2][:, 130:]) < 1e-5
+    assert _worst_rel(dv[:, 130:], want[3][:, 130:]) < 1e-5
+
+
+def test_flash_bundles_reads_loops_out_of_a_bundle_dump(tmp_path):
+    """``scripts/flash_bundles.py`` counts a kernel's loops in libtpu's
+    final-bundles text: a back edge is a loop, its length the bundles it
+    spans, nested loops counted inside the outer one (a hand-made dump in
+    the dump's own syntax; the compile that writes a real one is run by
+    hand, it takes a quarter of a minute)."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts"))
+    import flash_bundles
+    dump = tmp_path / "k-71-final_bundles.txt"
+    dump.write_text("\n".join([
+        "     0   :  { %s1_s0 = inlined_call_operand.vmem [shape: bf16[8]] }",
+        "   0x1 LB: > { %s7_s26 = sadd.s32 1, %s4_s25 }",
+        "   0x2 LB: >> { %v5_v1 = vld [vmem:[#allocation2_spill] sm:$0xff]"
+        "  ;;  %9 = vmatmul.bf16.gmra.mxu0 %v5_v1 }",
+        "   0x3   : >> { %v6_v2 = vpop.f32.mrf.mxu0  ;;  %11 = vst"
+        " [vmem:[#allocation3_spill] sm:$0xff] %v6_v2 }",
+        "   0x4   : >> { %12 = sbr.rel (!%p3_p4) target bundleno = 2 (0x2),"
+        " region = 7 }",
+        "   0x5   : > { %v8_v3 = vsel %vm1_vm0, %v6_v2, 0.0 }",
+        "   0x6   : > { %13 = sbr.rel (!%p2_p2) target bundleno = 1 (0x1),"
+        " region = 3 }",
+    ]))
+    (outer_lo, outer_hi, outer), (lo, hi, inner) = flash_bundles.loops(
+        str(dump))
+    assert (outer_lo, outer_hi, lo, hi) == (1, 6, 2, 4)
+    assert {k: inner[k] for k in ("vld", "vmatmul", "vpop", "vst")} == {
+        "vld": 1, "vmatmul": 1, "vpop": 1, "vst": 1}
+    assert outer["vmatmul"] == 1 and outer["vsel"] == 1 and inner["vsel"] == 0
+
+
 @slow
 def test_ring_matches_dense_multidevice():
     from jax.sharding import PartitionSpec as P
