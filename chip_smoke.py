@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import argparse
 import concurrent.futures as cf
+import functools
 import importlib.metadata
 import json
 import os
@@ -54,6 +55,7 @@ FULL = {
     "serve": dict(slots=8, max_new=16,
                   prompt_chars=(3, 40, 150, 400, 700, 12)),
     "flash_shapes": ((8, 1024, 8, 128), (1, 8192, 2, 128)),
+    "kda_shape": (1, 2048, 4, 128),
     "conv_batch": 32,
     "ring_seq": 8192,
 }
@@ -64,6 +66,7 @@ TOY = {
                 rounds=3, long_seq=256, long_vocab=512, long_steps=4),
     "serve": dict(slots=4, max_new=4, prompt_chars=(3, 20, 60, 9)),
     "flash_shapes": ((2, 128, 2, 32),),
+    "kda_shape": (1, 128, 2, 128),
     "conv_batch": 8,
     "ring_seq": 256,
 }
@@ -460,6 +463,52 @@ def phase_kernels(sz):
     return out
 
 
+def phase_kda(sz):
+    """The chunked delta rule (the Pallas kernels on a chip, interpreted
+    elsewhere) forward and backward against the token recurrence, on
+    bfloat16 operands with log-decays all over (-5, 0)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.llm.linear_attention import kda_attention, kda_recurrence
+
+    b, s, h, d = sz["kda_shape"]
+    ks = jax.random.split(jax.random.PRNGKey(11), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa
+    q = (unit(jax.random.normal(ks[0], (b, s, h, d))) * d ** -0.5
+         ).astype(jnp.bfloat16)
+    k = unit(jax.random.normal(ks[1], (b, s, h, d))).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, s, h, d), jnp.bfloat16)
+    g = jax.random.uniform(ks[3], (b, s, h, d), minval=-5.0, maxval=0.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
+    ct = jax.random.normal(ks[5], (b, s, h, d), jnp.float32)
+
+    def grads(fn):
+        return jax.grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * ct),
+            argnums=(0, 1, 2, 3, 4))
+
+    def gap(a, w):
+        a, w = (np.asarray(x, np.float32) for x in (a, w))
+        check(np.isfinite(a).all(), "non-finite KDA result")
+        return float(np.linalg.norm(a - w) / (np.linalg.norm(w) + 1e-6))
+
+    xs = (q, k, v, g, beta)
+    kernels = functools.partial(kda_attention, impl="flash")
+    errs = [gap(jax.jit(kernels)(*xs), jax.jit(kda_recurrence)(*xs))]
+    errs += [gap(a, w) for a, w in zip(jax.jit(grads(kernels))(*xs),
+                                       jax.jit(grads(kda_recurrence))(*xs))]
+    check(max(errs) < 0.06, f"KDA kernels vs the recurrence: {errs}")
+    out = {"kda_rel_err_" + "x".join(map(str, sz["kda_shape"])):
+           round(max(errs), 5)}
+    if on_chip():
+        out["kda_fwd_bwd_ms_kernels_vs_recurrence"] = [
+            round(1e3 * statistics.median(round_trips(grads(f), *xs, n=5)), 3)
+            for f in (kernels, kda_recurrence)]
+    return out
+
+
 def phase_four_chip_llm(sz):
     """The two LLM programs __graft_entry__._dryrun_llm_sharded runs at
     h32, here at full width: the {data 1, fsdp 2, tensor 2} train step and
@@ -582,6 +631,7 @@ def main():
     run_phase("serving", phase_serving, sz, keep)
     keep.clear()
     run_phase("kernels", phase_kernels, sz)
+    run_phase("kda", phase_kda, sz)
     if len(devices) == 4:
         run_phase("four_chip_llm", phase_four_chip_llm, sz)
     say(phase="compile_cache", ok=True, compile_cache_dir=cache_dir,

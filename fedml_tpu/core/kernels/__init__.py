@@ -39,8 +39,11 @@ def compile_for_tpu():
         _TPU_TARGET.reset(token)
 
 
-def tpu_compiler_params():
-    """Mosaic parameters shared by the kernels (``None`` when interpreted).
+def tpu_compiler_params(dimension_semantics=None):
+    """Mosaic parameters shared by the kernels (``None`` when interpreted);
+    ``dimension_semantics`` names each grid axis ``parallel`` or
+    ``arbitrary`` (a kernel that carries state along an axis in scratch
+    needs that axis run in order).
 
     The scoped-VMEM cap is raised above the 16 MiB default: the flash
     kernels keep the full-length K/V refs resident, and at seq 8192 with
@@ -51,4 +54,5 @@ def tpu_compiler_params():
     if interpret():
         return None
     import jax.experimental.pallas.tpu as pltpu
-    return pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
+    return pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024,
+                                dimension_semantics=dimension_semantics)

@@ -478,12 +478,14 @@ def record_hbm_peak(in_use_gb: float, reserved_gb: float) -> None:
 
 def record_moe_round(slots_held: float, load_max_sum: float,
                      layer_steps: float, expert_steps: float,
-                     dropped: float, compact_steps: float = 0.0) -> None:
+                     dropped: float, compact_steps: float = 0.0,
+                     tokens_here=None) -> None:
     """Router load of one finished round, from the sums the round program
     itself reported over its expert layers and train steps: token-slots
     routed to the experts held here, the fullest held expert's tokens and
     the mean held expert's (a layer and step), slots that found no row,
-    passes whose row buffers had the compact size."""
+    passes whose row buffers had the compact size; under a group limit
+    also ``tokens_here``, the tokens with at least one held slot."""
     if not _cfg["enabled"]:
         return
     REGISTRY.gauge("fed_moe_slots_held",
@@ -512,6 +514,21 @@ def record_moe_round(slots_held: float, load_max_sum: float,
                      "of those, passes whose routing fit the compact row "
                      "buffers (llm/moe.py::compact_rows)"
                      ).inc(float(compact_steps))
+    if tokens_here is not None:
+        REGISTRY.counter("fed_moe_tokens_here_total",
+                         "tokens with at least one slot on a held expert, "
+                         "over layers and steps, every recorded round"
+                         ).inc(float(tokens_here))
+
+
+def record_kda_round(layer_steps: float) -> None:
+    """Passes through a linear-attention layer (a layer and train step) in
+    one finished round, as the round program itself counted them."""
+    if not _cfg["enabled"]:
+        return
+    REGISTRY.counter("fed_kda_layer_steps_total",
+                     "passes through a linear-attention (KDA) layer, every "
+                     "recorded round").inc(float(layer_steps))
 
 
 def record_flash_plan(interior_share: float, key_mask: bool) -> None:
@@ -528,6 +545,16 @@ def record_flash_plan(interior_share: float, key_mask: bool) -> None:
     REGISTRY.gauge("fed_flash_key_mask",
                    "1 if the last traced flash call carries a key mask, "
                    "else 0").set(1.0 if key_mask else 0.0)
+
+
+def record_kda_plan(chunk: int) -> None:
+    """The chunk size of the linear-attention call just traced (host side,
+    once a trace; ``llm/linear_attention.py::chunk_size``)."""
+    if not _cfg["enabled"]:
+        return
+    REGISTRY.gauge("fed_kda_chunk",
+                   "positions a chunk of the last traced KDA call"
+                   ).set(float(chunk))
 
 
 def record_recompile(program: str) -> None:

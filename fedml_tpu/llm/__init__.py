@@ -1,11 +1,14 @@
 """FedLLM — the LLM fine-tuning pillar (reference ``train/llm/`` +
 ``spotlight_prj/unitedllm/``), rebuilt TPU-first:
 
-- ``model``: flax decoder (RMSNorm/rotary/SwiGLU) with grouped-query or
-  latent attention and dense or sparse-expert layers, bf16 compute,
-  MXU-shaped matmuls.
-- ``moe``: routing over all experts, the dropless plan for the experts a
-  rank holds, the Pallas grouped product over them.
+- ``model``: flax decoder (RMSNorm/rotary/SwiGLU) with grouped-query,
+  latent or linear (Kimi delta) attention, one kind a layer, and dense or
+  sparse-expert layers, bf16 compute, MXU-shaped matmuls.
+- ``moe``: routing over all experts (plain or group-limited with a
+  score-correction bias), the dropless plan for the experts a rank holds,
+  the Pallas grouped product over them.
+- ``linear_attention``: the chunked gated delta rule, forward and the
+  backward of its scan, as Pallas kernels and as ``jax.numpy``.
 - ``attention``: dense golden + Pallas flash kernels (``d_qk != d_v``
   too) + ring attention over the ``sp`` mesh axis for long context.
 - ``lora``: adapters as a pure pytree transform; federated rounds ship

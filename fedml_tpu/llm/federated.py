@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from .data import ByteTokenizer, build_llm_federated
+from .linear_attention import MIN_LOG_DECAY, SHORT_CONV_TAPS
 from .lora import lora_init
 from .model import CausalLM, LLMConfig, init_llm
 from .moe import STATS as MOE_STATS
@@ -64,28 +65,61 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
                        first_expert: int = 0,
                        experts_held: int = 0) -> LLMConfig:
     """An :class:`LLMConfig` from a published ``config.json`` dict (the keys
-    of the Llama/Mistral family and of the DeepSeek-V3 family, which
-    ``axk1`` shares: latent attention, sigmoid-routed experts with shared
-    ones, leading dense layers, YaRN). ``first_expert`` / ``experts_held``
-    say which routed experts this expert-parallel rank holds (0 = all)."""
+    of the Llama/Mistral family, of the DeepSeek-V3 family, which ``axk1``
+    shares: latent attention, sigmoid-routed experts with shared ones,
+    leading dense layers, YaRN; and of the Bailing hybrid family, which
+    names the experts ``num_experts`` / ``num_shared_experts`` /
+    ``score_function``, routes with ``noaux_tc`` and mixes Kimi delta
+    attention layers with gated latent ones by ``layer_group_size``).
+    ``first_expert`` / ``experts_held`` say which routed experts this
+    expert-parallel rank holds (0 = all)."""
     get = config.get
     scaling = get("rope_scaling")
-    if get("topk_method", "none") not in ("none", "greedy"):
-        raise NotImplementedError(
-            f"topk_method {get('topk_method')!r}: group-limited and "
-            "bias-corrected routing are not built")
-    if get("n_routed_experts") and get("scoring_func", "sigmoid") != "sigmoid":
-        raise NotImplementedError(f"scoring_func {get('scoring_func')!r}")
-    if get("n_routed_experts") and get("moe_layer_freq", 1) != 1:
+    experts = get("n_routed_experts") or get("num_experts")
+    scoring = get("scoring_func") or get("score_function") or "sigmoid"
+    method = get("topk_method", "none")
+    if method not in ("none", "greedy", "noaux_tc"):
+        raise NotImplementedError(f"topk_method {method!r}")
+    if experts and scoring != "sigmoid":
+        raise NotImplementedError(f"scoring_func {scoring!r}")
+    if experts and get("moe_layer_freq", 1) != 1:
         raise NotImplementedError("moe_layer_freq != 1")
     if scaling and scaling.get("mscale", 1) != scaling.get("mscale_all_dim", 1):
         raise NotImplementedError("rotary cos/sin scale mscale / "
                                   "mscale_all_dim != 1")
+    layers = int(config["num_hidden_layers"])
+    for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+        if any(get(key, [])[:layers]):
+            raise NotImplementedError(
+                f"{key} is nonzero in a layer held: the clamped SwiGLU is "
+                "not built")
+    if get("num_nextn_predict_layers"):
+        raise NotImplementedError(
+            "multi-token prediction layers are not built: leave them out "
+            "(num_nextn_predict_layers 0) where their loss weight is 0")
+    group = int(get("layer_group_size") or 0)
+    if group and not (get("kda_safe_gate") and get("linear_silu", True)
+                      and get("no_kda_lora", True)):
+        raise NotImplementedError(
+            "linear attention is built as Kimi delta attention with the "
+            "bounded gate (kda_safe_gate), SiLU after the short "
+            "convolution and a full-rank decay projection (no_kda_lora)")
+    lower = float(get("kda_lower_bound", -5.0))
+    if group and not MIN_LOG_DECAY <= lower < 0:
+        raise NotImplementedError(
+            f"kda_lower_bound {lower}: the chunked delta rule is exact for "
+            f"log-decays in [{MIN_LOG_DECAY}, 0)")
+    if group and int(get("short_conv_kernel_size") or SHORT_CONV_TAPS
+                     ) != SHORT_CONV_TAPS:
+        raise NotImplementedError(
+            f"short_conv_kernel_size {get('short_conv_kernel_size')}: the "
+            f"short convolution is built over {SHORT_CONV_TAPS} positions")
+    noaux = method == "noaux_tc"
     return LLMConfig(
         vocab_size=int(config["vocab_size"]),
         hidden_size=int(config["hidden_size"]),
         intermediate_size=int(config["intermediate_size"]),
-        num_layers=int(config["num_hidden_layers"]),
+        num_layers=layers,
         num_heads=int(config["num_attention_heads"]),
         num_kv_heads=get("num_key_value_heads"),
         max_seq_len=int(max_seq_len),
@@ -98,10 +132,21 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
         norm_topk_prob=bool(get("norm_topk_prob", True)),
         **{k: int(get(k) or 0) for k in (
             "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-            "qk_rope_head_dim", "v_head_dim", "n_routed_experts",
-            "num_experts_per_tok", "moe_intermediate_size",
-            "n_shared_experts", "first_k_dense_replace")},
-        first_expert=int(first_expert), experts_held=int(experts_held))
+            "qk_rope_head_dim", "v_head_dim", "num_experts_per_tok",
+            "moe_intermediate_size", "first_k_dense_replace")},
+        n_routed_experts=int(experts or 0),
+        n_shared_experts=int(get("n_shared_experts")
+                             or get("num_shared_experts") or 0),
+        first_expert=int(first_expert), experts_held=int(experts_held),
+        n_group=int(get("n_group") or 0) if noaux else 0,
+        topk_group=int(get("topk_group") or 0) if noaux else 0,
+        router_bias=noaux and bool(
+            get("moe_router_enable_expert_bias", True)),
+        layer_group_size=group,
+        linear_head_dim=int(get("head_dim") or 0) if group else 0,
+        kda_lower_bound=lower,
+        attn_output_gate=bool(group) and get(
+            "gated_attention_proj_granularity_type") == "head_wise")
 
 
 # sums a model with experts reports a step through the ``moe_stats``
@@ -144,7 +189,11 @@ class LLMBundle:
     @property
     def extra_metrics(self):
         """Names of the sums ``apply(with_stats=True)`` returns."""
-        return MOE_METRICS if self.cfg.n_routed_experts else ()
+        cfg = self.cfg
+        return ((MOE_METRICS if cfg.n_routed_experts else ())
+                + (("moe_tokens_here",) if cfg.n_routed_experts
+                   and cfg.n_group > 1 else ())
+                + (("kda_layer_steps",) if cfg.layer_group_size else ()))
 
     def apply(self, params, x, rng=None, train=False, with_stats=False):
         """-> logits, or ``(logits, {name: sum})`` over
@@ -157,12 +206,15 @@ class LLMBundle:
                       "lora_scale": self.lora_alpha / self.lora_rank}
         if not with_stats:
             return self.module.apply(variables, x, train=train, **kwargs)
-        logits, state = self.module.apply(variables, x, train=train,
-                                          mutable=["moe_stats"], **kwargs)
+        logits, state = self.module.apply(
+            variables, x, train=train, mutable=["moe_stats", "kda_stats"],
+            **kwargs)
         sums = {}
-        for layer in state.get("moe_stats", {}).values():
-            for k, v in layer["moe"].items():
-                sums["moe_" + k] = sums.get("moe_" + k, 0.0) + v
+        for prefix, module in (("moe", "moe"), ("kda", "attn")):
+            for layer in state.get(prefix + "_stats", {}).values():
+                for k, v in layer[module].items():
+                    name = f"{prefix}_{k}"
+                    sums[name] = sums.get(name, 0.0) + v
         return logits, sums
 
 

@@ -28,11 +28,14 @@ from flax import traverse_util
 PyTree = Any
 
 # kernel parents targeted by default: attention projections (grouped-query
-# q k v o; latent attention's q_a q_b kv_a kv_b o) + the dense MLP and the
-# shared expert (gate up down). Routed experts and the router carry no
-# "kernel" leaf under these names and stay frozen without adapters.
+# q k v o; latent attention's q_a q_b kv_a kv_b o, or q where it has no
+# query latent; linear attention's q k v o and its decay projection f) + the
+# dense MLP and the shared expert (gate up down). Routed experts, the
+# router, the attention gates (g), linear attention's beta projection (b)
+# and its convolutions carry no "kernel" leaf under these names and stay
+# frozen without adapters.
 DEFAULT_TARGETS: Tuple[str, ...] = ("q", "k", "v", "o", "gate", "up", "down",
-                                    "q_a", "q_b", "kv_a", "kv_b")
+                                    "q_a", "q_b", "kv_a", "kv_b", "f")
 
 
 def _target_paths(params: PyTree, targets: Sequence[str]):

@@ -18,6 +18,7 @@ in ``attention.py``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -76,6 +77,25 @@ class LLMConfig:
     norm_topk_prob: bool = True
     first_expert: int = 0
     experts_held: int = 0
+    # group-limited routing with a score-correction bias (``noaux_tc``):
+    # the ``n_group`` consecutive groups of experts are scored by their two
+    # best ``score + bias``, ``topk_group`` of them stay (``n_group <= 1``:
+    # plain top-k); ``router_bias`` gives the router its frozen bias
+    n_group: int = 0
+    topk_group: int = 0
+    router_bias: bool = False
+    # layers of two attention kinds (``layer_group_size > 0``): layer i has
+    # the configuration's softmax attention where ``(i + 1) %
+    # layer_group_size == 0`` and Kimi delta (linear) attention elsewhere:
+    # heads of ``linear_head_dim``, q k v through a causal depthwise
+    # convolution over 4 positions, a log-decay for every key channel in
+    # ``(kda_lower_bound, 0)``
+    layer_group_size: int = 0
+    linear_head_dim: int = 0
+    kda_lower_bound: float = -5.0
+    # a sigmoid gate a head on the attention's output, before ``o``
+    # (latent attention; linear attention always has one)
+    attn_output_gate: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -89,16 +109,22 @@ class LLMConfig:
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
 
+    def is_linear(self, layer: int) -> bool:
+        """Whether layer ``layer`` has linear (Kimi delta) attention."""
+        return bool(self.layer_group_size) and \
+            (layer + 1) % self.layer_group_size != 0
+
     @property
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
     def param_count(self) -> int:
-        if self.kv_lora_rank or self.n_routed_experts:
+        if self.kv_lora_rank or self.n_routed_experts or self.layer_group_size:
             raise NotImplementedError(
                 "LLMConfig.param_count counts the dense grouped-query "
-                "decoder alone; a configuration with latent attention or "
-                "experts is counted from its shapes under benchmarks/flops/")
+                "decoder alone; a configuration with latent or linear "
+                "attention or experts is counted from its shapes under "
+                "benchmarks/flops/")
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         per_layer = (h * h * 2 +                       # q, o
                      2 * h * self.kv_heads * self.head_dim +  # k, v
@@ -260,13 +286,105 @@ class Attention(nn.Module):
         return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], new_kv
 
 
+def _head_gate(out, logits):
+    """out [b, s, h, d] times ``sigmoid(logits)`` [b, s, h], one gate a
+    head, in float32."""
+    gate = jax.nn.sigmoid(logits.astype(jnp.float32))[..., None]
+    return (out.astype(jnp.float32) * gate).astype(out.dtype)
+
+
+class LinearAttention(nn.Module):
+    """Kimi delta attention (``llm/linear_attention.py``): ``q, k, v =
+    SiLU(conv(x W))`` through a causal depthwise convolution, ``q`` and
+    ``k`` L2-normalised a head and ``q`` scaled by ``d ** -0.5``; ``beta =
+    sigmoid(x W_b)`` a head; log-decay ``g = lower * sigmoid(exp(A_log) *
+    (x W_f + dt_bias))`` for every key channel, so ``exp(g)`` lies in
+    ``(e^lower, 1)``; the gated delta rule over the row from a zero
+    state; ``y = (RMSNorm_head(o) * sigmoid(x W_g)_h) W_o``. Adapters on
+    ``q k v f o``; the convolutions, ``W_b``, ``W_g``, ``A_log`` and
+    ``dt_bias`` are frozen. A masked key neither writes nor decays the
+    state. Training path only: a recurrent state is no list of cached
+    blocks."""
+
+    cfg: LLMConfig
+
+    @nn.compact
+    def __call__(self, x, positions, attn_mask=None, kv_view=None,
+                 adapter=None, lora_scale: float = 1.0):
+        del positions   # the recurrence carries order; no rotary
+        if kv_view is not None:
+            raise NotImplementedError(
+                "linear attention has no cache path: llm/kv_cache.py holds "
+                "a list of key/value blocks a position, not the one "
+                "recurrent state and convolution tail a row of a KDA layer "
+                "carries")
+        from .attention import _scaled
+        from .linear_attention import (MIN_LOG_DECAY, SHORT_CONV_TAPS,
+                                       kda_attention, short_conv)
+
+        cfg = self.cfg
+        if not MIN_LOG_DECAY <= cfg.kda_lower_bound < 0:
+            raise ValueError(
+                f"kda_lower_bound {cfg.kda_lower_bound}: the chunked delta "
+                f"rule is exact for log-decays in [{MIN_LOG_DECAY}, 0)")
+        b, s, _ = x.shape
+        nh, d, taps = cfg.num_heads, cfg.linear_head_dim, SHORT_CONV_TAPS
+        dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
+            feats, axis=-1, use_bias=False, name=name,
+            dtype=cfg.compute_dtype, param_dtype=jnp.float32)
+        heads = lambda a: a.reshape(b, s, nh, d)  # noqa: E731
+
+        with jax.named_scope("attn.linear"):
+            # the decay projection leaves its product in float32: the gate
+            # multiplies it by up to exp(A_log) = 16 inside a sigmoid
+            wide = nn.DenseGeneral(
+                nh * d, use_bias=False, name="f", dtype=cfg.compute_dtype,
+                param_dtype=jnp.float32, dot_general=functools.partial(
+                    jax.lax.dot_general,
+                    preferred_element_type=jnp.float32))
+            ys = _add_lora(x, {**{n: dense(nh * d, n)(x) for n in "qkv"},
+                               "f": wide(x)}, adapter, lora_scale)
+            q, k, v = (heads(nn.silu(short_conv(ys[n], self.param(
+                f"conv_{n}", nn.initializers.lecun_normal(),
+                (taps, nh * d))))) for n in "qkv")
+            q, k = (_l2_normalised(a) for a in (q, k))
+            a_log = self.param("A_log", nn.initializers.zeros, (nh,))
+            dt_bias = self.param("dt_bias", nn.initializers.zeros, (nh * d,))
+            g = cfg.kda_lower_bound * jax.nn.sigmoid(
+                jnp.exp(a_log.astype(jnp.float32))[:, None]
+                * heads(ys["f"].astype(jnp.float32)
+                        + dt_bias.astype(jnp.float32)))
+            beta = jax.nn.sigmoid(dense(nh, "b")(x).astype(jnp.float32))
+            if attn_mask is not None:
+                keep = attn_mask.astype(jnp.float32)[:, :, None]
+                g, beta = g * keep[..., None], beta * keep
+            out = kda_attention(_scaled(q, d ** -0.5), k, v, g, beta,
+                                impl=cfg.attention_impl)
+            out = RMSNorm(cfg.rms_eps, name="o_norm")(out)
+            out = _head_gate(out, dense(nh, "g")(x)).reshape(b, s, nh * d)
+            y = dense(cfg.hidden_size, "o")(out)
+            self.sow("kda_stats", "layer_steps", jnp.float32(1),
+                     init_fn=lambda: jnp.float32(0), reduce_fn=jnp.add)
+            return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], None
+
+
+def _l2_normalised(a):
+    """a / sqrt(sum a^2 + 1e-6) over the last axis, in float32."""
+    f = a.astype(jnp.float32)
+    return (f * jax.lax.rsqrt(jnp.sum(f * f, -1, keepdims=True) + 1e-6)
+            ).astype(a.dtype)
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention (DeepSeek-V2/V3): ``c_q = norm(x W_qa)``,
-    ``q = c_q W_qb`` -> heads of ``nope + rope`` dims; ``[c_kv | k_r] =
+    ``q = c_q W_qb`` -> heads of ``nope + rope`` dims (``q = x W_q``
+    where ``q_lora_rank`` is 0: no query latent); ``[c_kv | k_r] =
     x W_kva``, ``[k_nope | v] = norm(c_kv) W_kvb``; rotary on ``q_rope`` and
     on the one ``k_r`` all heads share; scores scaled by ``(nope +
-    rope) ** -0.5`` times YaRN's ``mscale_all_dim`` term squared. Training
-    path only: a cache of latents is serving work."""
+    rope) ** -0.5`` times YaRN's ``mscale_all_dim`` term squared; with
+    ``attn_output_gate`` a head's output is scaled by ``sigmoid(x W_g)_h``
+    before ``W_o``. Training path only: a cache of latents is serving
+    work."""
 
     cfg: LLMConfig
 
@@ -287,16 +405,20 @@ class LatentAttention(nn.Module):
             dtype=cfg.compute_dtype, param_dtype=jnp.float32)
 
         with jax.named_scope("attn.latent"):
+            first = ({"q_a": dense(cfg.q_lora_rank, "q_a")(x)}
+                     if cfg.q_lora_rank
+                     else {"q": dense((nh, nope + rope), "q")(x)})
             down = _add_lora(x, {
-                "q_a": dense(cfg.q_lora_rank, "q_a")(x),
-                "kv_a": dense(cfg.kv_lora_rank + rope, "kv_a")(x),
+                **first, "kv_a": dense(cfg.kv_lora_rank + rope, "kv_a")(x),
             }, adapter, lora_scale)
-            c_q = RMSNorm(cfg.rms_eps, name="q_norm")(down["q_a"])
+            c_q = (RMSNorm(cfg.rms_eps, name="q_norm")(down["q_a"])
+                   if cfg.q_lora_rank else None)
             c_kv = RMSNorm(cfg.rms_eps, name="kv_norm")(
                 down["kv_a"][..., :cfg.kv_lora_rank])
             k_r = down["kv_a"][..., cfg.kv_lora_rank:]
-            q = _add_lora(c_q, {"q_b": dense((nh, nope + rope), "q_b")(c_q)},
-                          adapter, lora_scale)["q_b"]
+            q = (_add_lora(c_q, {"q_b": dense((nh, nope + rope), "q_b")(c_q)},
+                           adapter, lora_scale)["q_b"]
+                 if cfg.q_lora_rank else down["q"])
             kv = _add_lora(c_kv, {"kv_b": dense((nh, nope + dv), "kv_b")(c_kv)},
                            adapter, lora_scale)["kv_b"]
             freq = rope_frequencies(rope, cfg.rope_theta, cfg.rope_scaling)
@@ -312,6 +434,8 @@ class LatentAttention(nn.Module):
                                    impl=cfg.attention_impl,
                                    attn_mask=attn_mask,
                                    scale=(nope + rope) ** -0.5 * m * m)
+            if cfg.attn_output_gate:
+                out = _head_gate(out, dense(nh, "g")(x))
             out = out.reshape(b, s, nh * dv)
             y = nn.DenseGeneral(cfg.hidden_size, use_bias=False, name="o",
                                 dtype=cfg.compute_dtype,
@@ -343,7 +467,9 @@ class MLP(nn.Module):
 class MoE(nn.Module):
     """``shared(x) + sum over the top-k experts this rank holds of g_e
     E_e(x)``: sigmoid scores in float32 over ALL ``n_routed_experts``, plain
-    top-k, the chosen scores normalised and scaled; the rank computes the
+    top-k (group-limited with a score-correction bias where ``n_group`` or
+    ``router_bias`` is set: ``moe.route``), the chosen scores normalised
+    and scaled; the rank computes the
     part of its own ``cfg.held`` experts (``first_expert`` on) and leaves
     out what absent experts would add. Dropless. The routed experts and the
     router are frozen (no adapters, no weight gradient); the shared expert
@@ -372,13 +498,22 @@ class MoE(nn.Module):
                 cfg.n_routed_experts, use_bias=False, name="router",
                 dtype=jnp.float32, param_dtype=jnp.float32)(
                 flat.astype(jnp.float32))
+            bias = (self.param("router_bias", nn.initializers.zeros,
+                               (cfg.n_routed_experts,))
+                    if cfg.router_bias else None)
             gates, chosen = moe.route(logits, cfg.num_experts_per_tok,
                                       cfg.routed_scaling_factor,
-                                      cfg.norm_topk_prob)
+                                      cfg.norm_topk_prob, bias, cfg.n_group,
+                                      cfg.topk_group)
         with jax.named_scope("moe.experts"):
             routed, stats = moe.routed_experts(
                 flat, gates, chosen, w_gate, w_up, w_down, cfg.first_expert,
                 cfg.n_routed_experts)
+        if cfg.n_group > 1:
+            # under a group limit a token may send this rank nothing
+            local = chosen - cfg.first_expert
+            stats["tokens_here"] = jnp.sum(jnp.any(
+                (local >= 0) & (local < held), -1)).astype(jnp.float32)
         for k, v in stats.items():
             self.sow("moe_stats", k, v, init_fn=lambda: jnp.float32(0),
                      reduce_fn=jnp.add)
@@ -386,18 +521,21 @@ class MoE(nn.Module):
 
 
 class DecoderLayer(nn.Module):
-    """One pre-norm layer; the configuration says which attention it has
-    (grouped-query or latent) and ``sparse`` whether its feed-forward is the
-    expert block (``moe``) or the dense MLP (``mlp``)."""
+    """One pre-norm layer; ``linear`` says whether its attention is the
+    linear (Kimi delta) kind, else the configuration does (grouped-query or
+    latent); ``sparse`` whether its feed-forward is the expert block
+    (``moe``) or the dense MLP (``mlp``)."""
 
     cfg: LLMConfig
     sparse: bool = False
+    linear: bool = False
 
     @nn.compact
     def __call__(self, x, positions, attn_mask=None, kv_view=None,
                  adapter=None, lora_scale: float = 1.0):
         adapter = adapter or {}
-        attention = LatentAttention if self.cfg.kv_lora_rank else Attention
+        attention = (LinearAttention if self.linear else
+                     LatentAttention if self.cfg.kv_lora_rank else Attention)
         a_out, new_kv = attention(self.cfg, name="attn")(
             RMSNorm(self.cfg.rms_eps, name="ln_attn")(x), positions,
             attn_mask, kv_view=kv_view, adapter=adapter.get("attn"),
@@ -448,7 +586,8 @@ class CausalLM(nn.Module):
         for i in range(cfg.num_layers):
             sparse = bool(cfg.n_routed_experts) and \
                 i >= cfg.first_k_dense_replace
-            x, new_kv = DecoderLayer(cfg, sparse, name=f"layer_{i}")(
+            x, new_kv = DecoderLayer(cfg, sparse, cfg.is_linear(i),
+                                     name=f"layer_{i}")(
                 x, positions, attn_mask,
                 kv_view=None if kv_view is None else kv_view[i],
                 adapter=None if adapters is None

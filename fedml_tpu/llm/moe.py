@@ -62,12 +62,33 @@ class Rows(NamedTuple):
 
 
 def route(scores_in: jnp.ndarray, top_k: int, scaling: float,
-          norm_topk: bool = True):
+          norm_topk: bool = True, bias=None, n_group: int = 0,
+          topk_group: int = 0):
     """``scores_in`` [T, E] router logits -> (gates [T, k] float32, experts
-    [T, k] int32): sigmoid scores in float32, plain top-k over all experts,
-    the chosen scores normalised to sum 1 and scaled."""
+    [T, k] int32): sigmoid scores in float32, top-k over all experts, the
+    chosen scores normalised to sum 1 and scaled.
+
+    With ``bias`` [E] (a score-correction bias) or ``n_group > 1`` the
+    choice is DeepSeek-V3's ``noaux_tc``: experts are chosen by ``s + b``,
+    the ``n_group`` consecutive groups are scored by the sum of their two
+    best ``s + b``, the best ``topk_group`` groups stay and the top-k is
+    taken among their experts; the gates come from ``s`` without ``b``.
+    Without either (a static branch) this is plain top-k on ``s``."""
     s = jax.nn.sigmoid(scores_in.astype(jnp.float32))
-    vals, idx = jax.lax.top_k(s, top_k)
+    if bias is None and n_group <= 1:
+        vals, idx = jax.lax.top_k(s, top_k)
+    else:
+        choice = s if bias is None else s + bias.astype(jnp.float32)
+        if n_group > 1:
+            t, e = choice.shape
+            groups = choice.reshape(t, n_group, e // n_group)
+            group_score = jnp.sum(jax.lax.top_k(groups, 2)[0], -1)
+            _, kept = jax.lax.top_k(group_score, topk_group)     # [T, g]
+            stays = jnp.any(kept[:, :, None] == jnp.arange(n_group), 1)
+            choice = jnp.where(stays[:, :, None], groups,
+                               -jnp.inf).reshape(t, e)
+        _, idx = jax.lax.top_k(choice, top_k)
+        vals = jnp.take_along_axis(s, idx, -1)
     if norm_topk:
         vals = vals / (jnp.sum(vals, -1, keepdims=True) + 1e-20)
     return vals * scaling, idx.astype(jnp.int32)

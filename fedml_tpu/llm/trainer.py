@@ -29,10 +29,12 @@ class CausalLMTrainer(TrainerSpec):
     right-padding), ``mask`` [bs] per-sample realness.
 
     ``extra_metrics``: names of further sums the model reports a step
-    (``LLMBundle.extra_metrics``: router load of a model with experts);
+    (``LLMBundle.extra_metrics``: router load of a model with experts,
+    passes through linear-attention layers);
     ``apply_fn(..., with_stats=True)`` then returns them beside the logits,
     the training metrics carry them out of the round program, and
-    :meth:`record_round_counters` turns a round's sums into ``fed_moe_*``."""
+    :meth:`record_round_counters` turns a round's sums into ``fed_moe_*``
+    and ``fed_kda_*``."""
 
     def __init__(self, apply_fn, extra_metrics=()):
         super().__init__(apply_fn)
@@ -40,10 +42,14 @@ class CausalLMTrainer(TrainerSpec):
 
     def record_round_counters(self, sums):
         from ..core.obs import metrics as obs_metrics
-        obs_metrics.record_moe_round(
-            sums["moe_slots_held"], sums["moe_load_max"],
-            sums["moe_layer_steps"], sums["moe_expert_steps"],
-            sums["moe_dropped"], sums["moe_compact_steps"])
+        if "moe_slots_held" in sums:
+            obs_metrics.record_moe_round(
+                sums["moe_slots_held"], sums["moe_load_max"],
+                sums["moe_layer_steps"], sums["moe_expert_steps"],
+                sums["moe_dropped"], sums["moe_compact_steps"],
+                sums.get("moe_tokens_here"))
+        if "kda_layer_steps" in sums:
+            obs_metrics.record_kda_round(sums["kda_layer_steps"])
 
     def _stats(self, params, batch, rng, train):
         kwargs = {"train": train}

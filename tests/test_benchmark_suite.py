@@ -7,7 +7,8 @@ files unedited and re-exports their tests (parametrisation and fixtures as
 the files have them) as ``<file>__<test>``, so each counts and names
 itself when it fails. The tests that run whole rounds or subprocesses
 (``test_correct.py``, ``test_rehearsal.py``, the first four of
-``test_axk1.py``: over a minute each on a CPU) stay outside tier-1.
+``test_axk1.py``, the first two of ``test_ling3.py``: over a minute each on
+a CPU) stay outside tier-1.
 """
 
 import importlib
@@ -35,12 +36,22 @@ _TAKEN = {
                   "test_grouped_reader_needs_the_counter_and_the_named_"
                   "kernels",
                   "test_load_reader_reads_the_gauges_or_nothing"),
+    "test_ling3": ("test_work_functions_against_hand_counts",
+                   "test_kda_reader_finds_kernels_by_name_only",
+                   "test_tokens_here_reader_reads_the_counters_or_nothing"),
 }
 _LEFT_OUT = {
     # pins PR 26's five entries as the LAST five of BENCHMARK.json's
     # per_layer list; PRs 28 and 29 appended four more, so it fails since
     # then (PERF.md section 7 (e)) and only a `benchmark` PR may edit it
     ("test_program_metrics", "test_manifest_takes_the_new_entries"),
+    # pins ``moe_compact_share``'s ``workloads`` to the axk1 cell alone;
+    # PR 32 appended the second cell with experts, as the contract lets a
+    # model_config PR do, and may not edit the file:
+    # ``test_moe_compact_share_entry_is_as_accepted_but_for_its_cells``
+    # stands in and pins every other key of the entry
+    ("test_moe_compact_share",
+     "test_manifest_names_the_reader_for_the_axk1_cell_alone"),
 }
 
 
@@ -85,6 +96,49 @@ def _leave_no_state():
     obs.configure(None)
     obs_trace.clear_finished()
     REGISTRY.reset()
+
+
+def test_expert_layer_metrics_list_the_cells_with_experts():
+    """Every metric of the expert layer lists the cells whose configuration
+    has routed experts, the axk1 cell first as PR 29 left it, and no
+    other; each such cell's configuration brings the work functions the
+    grouped reader calls."""
+    sys.path.insert(0, os.path.dirname(_BENCH_TESTS))
+    from harness import manifest
+    bench = manifest.benchmark()
+    with_experts = [
+        w["name"] for w in bench["workloads"]
+        if any(k in manifest.load_json("configs", w["config"] + ".json")
+               for k in ("n_routed_experts", "num_experts"))]
+    assert with_experts[0] == "axk1_lora_silo2_seq4096"
+    for m in bench["per_layer"]:
+        if m["layer"] == "expert layer" and m["name"] != \
+                "moe_tokens_here_share":
+            assert m["workloads"] == with_experts, m["name"]
+            assert m["moves"] == "round_s"
+    for cell in with_experts:
+        flops = manifest.load_module(
+            "flops", manifest.Cell(cell).entry["config"])
+        assert hasattr(flops, "grouped_expert_work")
+        assert hasattr(flops, "expert_layer_steps")
+
+
+def test_moe_compact_share_entry_is_as_accepted_but_for_its_cells():
+    """What ``test_manifest_names_the_reader_for_the_axk1_cell_alone``
+    pinned, key by key, but for ``workloads``: that list begins with the
+    axk1 cell and holds cells with experts alone (the test above)."""
+    sys.path.insert(0, os.path.dirname(_BENCH_TESTS))
+    from harness import manifest
+    entry = [dict(m) for m in manifest.benchmark()["per_layer"]
+             if m["name"] == "moe_compact_share"]
+    assert len(entry) == 1
+    cells = entry[0].pop("workloads")
+    assert entry == [{
+        "name": "moe_compact_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "expert layer",
+        "moves": "round_s"}]
+    assert cells[0] == "axk1_lora_silo2_seq4096"
+    assert len(cells) == len(set(cells))
 
 
 def test_the_suite_takes_what_it_says():

@@ -21,6 +21,7 @@ from fedml_tpu.core import kernels
 from fedml_tpu.core.kernels.conv_block import fused_block
 from fedml_tpu.llm import moe
 from fedml_tpu.llm.attention import FLASH_KERNEL_NAMES, flash_causal_attention
+from fedml_tpu.llm.linear_attention import KDA_KERNEL_NAMES, kda_attention
 
 pytestmark = pytest.mark.pallas
 
@@ -136,6 +137,26 @@ def test_expert_pass_keeps_its_conditional_on_the_v5e(v5e):
     assert text.count("tpu_custom_call") == 2 * 3 + 2 * (2 + 3)
     entry = text[text.index("\nENTRY "):]
     assert "[35840" not in entry and "[35840" in text
+
+
+@pytest.mark.parametrize("dtype,heads", [(jnp.bfloat16, 32),
+                                         (jnp.float32, 2)])
+def test_kda_kernels_compile_for_v5e(v5e, dtype, heads):
+    """The linear-attention layer's shape in the benchmark (32 heads of
+    128 at 4,096 positions, bfloat16) and float32 operands: the forward
+    kernel and the backward one, whose body is ``jax.vjp`` of the chunk
+    step, compiled by Mosaic and found in the HLO under their names."""
+    qk = jax.ShapeDtypeStruct((1, 4096, heads, 128), dtype)
+    g = jax.ShapeDtypeStruct((1, 4096, heads, 128), jnp.float32)
+    beta = jax.ShapeDtypeStruct((1, 4096, heads), jnp.float32)
+    text = _compile(
+        lambda *a: jax.value_and_grad(
+            lambda *a: kda_attention(*a, impl="flash").astype(
+                jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))(*a),
+        v5e, qk, qk, qk, g, beta).as_text()
+    assert text.count("tpu_custom_call") == 2
+    for name in KDA_KERNEL_NAMES:
+        assert name in text, name
 
 
 def test_flash_bwd_never_materializes_scores(v5e):

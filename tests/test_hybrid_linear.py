@@ -1,0 +1,410 @@
+"""Layers of two attention kinds (Kimi delta attention 5:1 with gated latent
+attention), group-limited bias-corrected routing and the chunked delta rule,
+against the plain reference (``benchmarks/reference/ling3flash_ep8_l7.py``:
+float32, the recurrence token by token, imports nothing of ``fedml_tpu``) at
+small widths that keep the published model's ratios: a whole period of six
+expert layers after one dense layer, 8 router groups of which 4 stay, a rank
+that holds exactly one group."""
+
+from __future__ import annotations
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fedml_tpu.core.obs import REGISTRY
+from fedml_tpu.llm import linear_attention as la
+from fedml_tpu.llm import moe
+from fedml_tpu.llm.federated import LLMBundle, llm_config_from_hf
+from fedml_tpu.llm.lora import lora_init
+from fedml_tpu.llm.model import CausalLM
+from fedml_tpu.llm.trainer import CausalLMTrainer
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _reference():
+    path = os.path.join(REPO, "benchmarks", "reference",
+                        "ling3flash_ep8_l7.py")
+    spec = importlib.util.spec_from_file_location("ref_ling3", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _reference()
+
+
+def small_cfg(held=4, first=12, experts=32, layers=7, **over):
+    cfg = {
+        "vocab_size": 96, "hidden_size": 48, "intermediate_size": 80,
+        "num_hidden_layers": layers, "num_attention_heads": 4,
+        "num_key_value_heads": 4, "head_dim": 16, "q_lora_rank": None,
+        "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+        "v_head_dim": 16, "layer_group_size": 6, "short_conv_kernel_size": 4,
+        "kda_lower_bound": -5, "kda_safe_gate": True, "linear_silu": True,
+        "no_kda_lora": True,
+        "gated_attention_proj_granularity_type": "head_wise",
+        "num_experts": held, "published": {"num_experts": experts},
+        "first_expert": first, "num_experts_per_tok": 4,
+        "moe_intermediate_size": 24,
+        "moe_shared_expert_intermediate_size": 24, "num_shared_experts": 1,
+        "first_k_dense_replace": 1, "routed_scaling_factor": 2.5,
+        "norm_topk_prob": True, "score_function": "sigmoid",
+        "moe_router_enable_expert_bias": True, "topk_method": "noaux_tc",
+        "n_group": 8, "topk_group": 4, "router_bias_range": 0.05,
+        "kda_decay_proj_scale": 0.1,
+        "rms_norm_eps": 1e-6, "rope_theta": 6000000, "rope_scaling": None,
+        "tie_word_embeddings": False, "initializer_range": 0.2,
+        "lora_rank": 4, "lora_alpha": 8.0, "lora_b_std": 0.05,
+        "reference_heads_per_group": 2, "reference_kda_segment": 8,
+        "expert_swiglu_limit_list": [0] * 7 + [4] * 3,
+        "share_expert_swiglu_limit_list": [0] * 7 + [5] * 3}
+    cfg.update(over)
+    return cfg
+
+
+def system_cfg(cfg, seq, dtype="float32", impl="dense"):
+    published = dict(cfg, num_experts=cfg["published"]["num_experts"])
+    return llm_config_from_hf(
+        published, max_seq_len=seq, dtype=dtype, attention_impl=impl,
+        first_expert=cfg["first_expert"], experts_held=cfg["num_experts"])
+
+
+def weights(cfg, seed=0):
+    key = jax.random.PRNGKey(seed)
+    base = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                  REF.init_frozen(key, cfg))
+    return base, REF.init_trainable(jax.random.fold_in(key, 7), cfg)
+
+
+def tokens(cfg, rows=2, seq=32, seed=3):
+    return jax.random.randint(jax.random.PRNGKey(seed), (rows, seq + 1), 0,
+                              cfg["vocab_size"]).astype(jnp.int32)
+
+
+def bundle_for(cfg, base, seq, **kw):
+    lc = system_cfg(cfg, seq, **kw)
+    return LLMBundle(CausalLM(lc), lc, base, cfg["lora_rank"],
+                     cfg["lora_alpha"])
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+# ---------------------------------------------- system against reference ---
+
+def test_logits_loss_and_adapter_gradients_match_the_reference():
+    """Layer 0 dense with KDA, expert layers 1-4 and 6 KDA, layer 5 latent:
+    the system's logits, loss and every adapter leaf's gradient against the
+    independent reference, whose KDA is the token recurrence (float32
+    ``highest``; the gap is summation order and the chunked algebra)."""
+    cfg = small_cfg()
+    base, lora = weights(cfg)
+    tok = tokens(cfg)
+    x, y = tok[:, :-1], tok[:, 1:]
+    bundle = bundle_for(cfg, base, 32)
+    assert [bundle.cfg.is_linear(i) for i in range(7)] == \
+        [True] * 5 + [False, True]
+    grad_fn = REF.make_model(cfg)
+    batch = {"x": x, "y": y, "mask": jnp.ones((2,))}
+    with jax.default_matmul_precision("highest"):
+        want_logits = grad_fn.forward(lora, base, x, None)
+        want_g, want_ls, want_n = grad_fn(lora, base, batch, None)
+        got_logits = bundle.apply(lora, x)
+        spec = CausalLMTrainer(bundle.apply, bundle.extra_metrics)
+        (_, aux), got_g = jax.value_and_grad(spec.loss, has_aux=True)(
+            lora, batch, None)
+    assert rel(got_logits, want_logits) < 5e-5
+    assert abs(float(aux["loss_sum"]) - float(want_ls)) < 1e-4 * float(want_ls)
+    assert float(aux["count"]) == float(want_n) == 64.0
+    assert float(aux["kda_layer_steps"]) == 6.0
+    assert float(aux["moe_layer_steps"]) == 6.0
+    assert 0 < float(aux["moe_tokens_here"]) <= 6 * 64
+    flat_w = jax.tree_util.tree_leaves_with_path(want_g)
+    flat_g = dict(jax.tree_util.tree_leaves_with_path(got_g))
+    # 6 KDA layers x 5 targets, 1 latent x 4, 1 dense and 6 shared x 3
+    assert len(flat_w) == len(flat_g) == 2 * (6 * 5 + 4 + 7 * 3)
+    for path, w in flat_w:
+        assert float(jnp.abs(w).max()) > 0, path      # no blind leaf
+        assert rel(flat_g[path], w) < 5e-4, jax.tree_util.keystr(path)
+
+
+def test_the_reference_computes_an_overflowing_expert_over_every_token():
+    """An expert that drew more tokens than ``reference_expert_rows`` runs
+    over all of them: the same logits as with room for everyone."""
+    cfg = small_cfg(layers=2)
+    base, lora = weights(cfg)
+    x = tokens(cfg)[:, :-1]
+    with jax.default_matmul_precision("highest"):
+        roomy = REF.make_model(dict(cfg, reference_expert_rows=64)).forward(
+            lora, base, x, None)
+        tight = REF.make_model(dict(cfg, reference_expert_rows=2)).forward(
+            lora, base, x, None)
+    assert rel(tight, roomy) < 1e-6
+
+
+def test_adapter_tree_is_the_references():
+    cfg = small_cfg()
+    base, lora = weights(cfg)
+    mine = lora_init(jax.random.PRNGKey(0), base, rank=cfg["lora_rank"])
+    assert (jax.tree_util.tree_structure(mine)
+            == jax.tree_util.tree_structure(lora))
+    for a, b in zip(jax.tree_util.tree_leaves(mine),
+                    jax.tree_util.tree_leaves(lora)):
+        assert a.shape == b.shape
+    assert set(mine["layer_1"]["attn"]) == set("qkvfo")      # KDA
+    assert set(mine["layer_5"]["attn"]) == {"q", "kv_a", "kv_b", "o"}
+    assert set(mine["layer_1"]["moe"]) == {"shared"}
+
+
+# ----------------------------------------- the chunked delta rule itself ---
+
+def kda_inputs(s, lo, hi, b=1, h=2, dk=128, dv=128, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed + s), 5)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa
+    q = unit(jax.random.normal(ks[0], (b, s, h, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (b, s, h, dk)))
+    v = jax.random.normal(ks[2], (b, s, h, dv))
+    g = jax.random.uniform(ks[3], (b, s, h, dk), minval=lo, maxval=hi)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("impl", ["dense", "flash"])
+@pytest.mark.parametrize("s,lo,hi", [
+    (64, -5.0, 0.0),        # one chunk, decays all over the range
+    (128, -5.0, -4.9),      # two chunks, every channel at the fast end
+    (200, -0.01, 0.0),      # padded to four chunks, hardly any decay
+    (48, -5.0, 0.0),        # a short row: one chunk of three sub-chunks
+])
+def test_chunked_kda_matches_the_recurrence(impl, s, lo, hi):
+    """Both chunked forms (``jax.numpy`` under a scan; the Pallas kernels,
+    interpreted here) against the token recurrence: outputs and the
+    gradients toward q, k, v, the log-decay and beta."""
+    args = kda_inputs(s, lo, hi)
+    want = la.kda_recurrence(*args)
+    w = jax.random.normal(jax.random.PRNGKey(7), want.shape)
+    grads = lambda f: jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
+    want_g = grads(la.kda_recurrence)
+    got = la.kda_attention(*args, impl=impl)
+    assert rel(got, want) < 1e-5
+    for name, a, b in zip("q k v g beta".split(), grads(
+            lambda *a: la.kda_attention(*a, impl=impl)), want_g):
+        # a gradient that all but cancels (the decay's, where every
+        # channel forgets within a token) is held to the largest one
+        scale = max(float(jnp.linalg.norm(b)),
+                    1e-2 * max(float(jnp.linalg.norm(x)) for x in want_g))
+        assert float(jnp.linalg.norm(a - b)) / scale < 5e-5, name
+
+
+def test_kda_in_bfloat16_stays_near_the_recurrence():
+    """bfloat16 operands, as the timed path has them: the products round
+    their operands, the state and the log-decays stay float32."""
+    q, k, v, g, beta = kda_inputs(128, -5.0, 0.0)
+    want = la.kda_recurrence(q, k, v, g, beta)
+    bf = lambda a: a.astype(jnp.bfloat16)  # noqa: E731
+    got = la.kda_attention(bf(q), bf(k), bf(v), g, beta, impl="flash")
+    assert got.dtype == jnp.bfloat16
+    assert rel(got.astype(jnp.float32), want) < 2e-2
+
+
+def test_a_masked_key_neither_writes_nor_decays():
+    cfg = small_cfg(layers=1)
+    base, _ = weights(cfg)
+    lc = system_cfg(cfg, 32)
+    x = tokens(cfg)[:, :-1]
+    mask = jnp.ones((2, 32)).at[:, 20:].set(0)
+    whole = CausalLM(lc).apply({"params": base}, x, attn_mask=mask)
+    short = CausalLM(lc).apply({"params": base}, x[:, :20])
+    assert rel(whole[:, :20], short) < 1e-5
+
+
+# ------------------------------------------------------------- routing ---
+
+def test_plain_routing_is_bit_equal_to_what_it_was():
+    """Without a bias and a group limit ``route`` is the program it was:
+    sigmoid, top-k, normalise, scale."""
+    logits = jax.random.normal(jax.random.PRNGKey(2), (64, 32)) * 2
+    gates, chosen = moe.route(logits, 4, 2.5)
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    vals, idx = jax.lax.top_k(s, 4)
+    want = vals / (jnp.sum(vals, -1, keepdims=True) + 1e-20) * 2.5
+    assert np.array_equal(np.asarray(gates), np.asarray(want))
+    assert np.array_equal(np.asarray(chosen), np.asarray(idx))
+    again, same = moe.route(logits, 4, 2.5, True, None, 1, 1)
+    assert np.array_equal(np.asarray(again), np.asarray(gates))
+    assert np.array_equal(np.asarray(same), np.asarray(chosen))
+
+
+def test_group_limited_routing_by_hand():
+    """8 groups of 4: a token keeps the 4 groups whose two best ``s + b``
+    sum highest and takes its top-4 among them by ``s + b``; the weights
+    come from ``s`` alone. Checked against a per-token loop in numpy and
+    against the reference's ``route``."""
+    key = jax.random.PRNGKey(4)
+    logits = jax.random.normal(key, (48, 32)) * 2
+    bias = jax.random.uniform(jax.random.fold_in(key, 1), (32,),
+                              minval=-0.3, maxval=0.3)
+    gates, chosen = moe.route(logits, 4, 2.5, True, bias, 8, 4)
+    s = np.asarray(jax.nn.sigmoid(logits), np.float64)
+    c = s + np.asarray(bias, np.float64)
+    changed = 0
+    for t in range(48):
+        groups = c[t].reshape(8, 4)
+        score = np.sort(groups, -1)[:, -2:].sum(-1)
+        keep = np.argsort(-score)[:4]
+        allowed = np.full(32, -np.inf)
+        for grp in keep:
+            allowed[grp * 4:(grp + 1) * 4] = c[t, grp * 4:(grp + 1) * 4]
+        want = np.argsort(-allowed)[:4]
+        assert sorted(want.tolist()) == sorted(np.asarray(chosen[t]).tolist())
+        w = s[t, np.asarray(chosen[t])]
+        np.testing.assert_allclose(np.asarray(gates[t]),
+                                   2.5 * w / w.sum(), rtol=1e-5)
+        changed += sorted(want.tolist()) != sorted(
+            np.argsort(-s[t])[:4].tolist())
+    assert changed > 10     # the limit and the bias do change choices
+    ref_gates, ref_idx = REF.route(
+        logits, bias, {"num_experts_per_tok": 4, "topk_method": "noaux_tc",
+                       "n_group": 8, "topk_group": 4,
+                       "routed_scaling_factor": 2.5})
+    assert np.array_equal(np.asarray(ref_idx), np.asarray(chosen))
+    np.testing.assert_allclose(np.asarray(ref_gates), np.asarray(gates),
+                               rtol=1e-6)
+
+
+def test_shares_add_up_to_the_uncut_layer_under_a_group_limit():
+    """The guide's share test for this model: the parts the 8 ranks give for
+    one expert layer (each holds one router group), the shared expert
+    counted once, add up to the uncut layer."""
+    experts, per_rank = 32, 4
+    whole = small_cfg(held=experts, first=0, layers=2)
+    base, lora = weights(whole)
+    x = tokens(whole)[:, :-1]
+
+    def layer_out(cfg, b):
+        mod = CausalLM(system_cfg(cfg, 32))
+        _, state = mod.apply({"params": b}, x, adapters=lora,
+                             lora_scale=2.0, capture_intermediates=(
+                                 lambda m, _: m.name == "layer_1"),
+                             mutable=["intermediates", "moe_stats",
+                                      "kda_stats"])
+        return state["intermediates"]["layer_1"]["__call__"][0][0]
+
+    def held(b, lo, hi):
+        b = dict(b)
+        m = dict(b["layer_1"]["moe"])
+        for k in ("experts_gate", "experts_up", "experts_down"):
+            m[k] = m[k][lo:hi] if hi > lo else jnp.zeros_like(m[k][:1])
+        b["layer_1"] = dict(b["layer_1"], moe=m)
+        return b
+
+    whole_out = layer_out(whole, base)
+    shared_only = layer_out(dict(whole, num_experts=1), held(base, 0, 0))
+    total = shared_only
+    for r in range(experts // per_rank):
+        cut = dict(whole, num_experts=per_rank, first_expert=r * per_rank)
+        total = total + (layer_out(cut, held(base, r * per_rank,
+                                             (r + 1) * per_rank))
+                         - shared_only)
+    assert rel(total, whole_out) < 1e-5
+
+
+# ---------------------------------------------------- counters and refusals ---
+
+def test_round_counters_reach_the_registry_from_the_round_program():
+    """A federated LoRA round of the small model through ``TPUSimulator``:
+    ``fed_kda_layer_steps_total`` and ``fed_moe_tokens_here_total`` come
+    from the round's own metrics, flushed by the caller who has read the
+    round's loss; the chunk gauge is set when the call is traced."""
+    import fedml_tpu
+    from fedml_tpu.arguments import Arguments
+    from fedml_tpu.core.algframe.types import ClientData, TrainHyper
+    from fedml_tpu.data.containers import FederatedDataset
+    from fedml_tpu.optimizers.registry import create_optimizer
+    from fedml_tpu.simulation.tpu.engine import TPUSimulator
+
+    cfg = small_cfg()
+    base, lora = weights(cfg)
+    args = fedml_tpu.init(Arguments(
+        backend="tpu", precision="float32", client_num_in_total=2,
+        client_num_per_round=2, batch_size=1, epochs=1, learning_rate=0.05,
+        client_optimizer="sgd", federated_optimizer="FedAvg",
+        comm_round=100, frequency_of_the_test=0, random_seed=3,
+        dataset="llm", model="causal_lm", llm_max_seq_len=32,
+        lora_rank=cfg["lora_rank"], lora_alpha=cfg["lora_alpha"]))
+    tok = np.asarray(jax.random.randint(jax.random.PRNGKey(9), (2, 2, 1, 33),
+                                        0, cfg["vocab_size"]), np.int32)
+    train = ClientData(x=jnp.asarray(tok[..., :-1]),
+                       y=jnp.asarray(tok[..., 1:]),
+                       mask=jnp.ones((2, 2, 1), jnp.float32),
+                       num_samples=jnp.asarray([2.0, 2.0]))
+    fed = FederatedDataset(
+        train=train, test={"x": train.x[0, :1], "y": train.y[0, :1],
+                           "mask": train.mask[0, :1]},
+        num_classes=cfg["vocab_size"], input_shape=(32,), num_clients=2,
+        client_num_samples=np.asarray([2, 2]), task="llm",
+        provenance="synthetic")
+    bundle = bundle_for(cfg, base, 32)
+    assert bundle.extra_metrics[-2:] == ("moe_tokens_here", "kda_layer_steps")
+    spec = CausalLMTrainer(bundle.apply, bundle.extra_metrics)
+    sim = TPUSimulator(args, fed, bundle, create_optimizer(args, spec), spec)
+    sim.params = jax.device_put(lora, sim.repl_sharding)
+    hyper = TrainHyper(learning_rate=jnp.float32(0.05), epochs=1)
+    kda_before = REGISTRY.counter("fed_kda_layer_steps_total").value()
+    here_before = REGISTRY.counter("fed_moe_tokens_here_total").value()
+    m0 = sim.run_round(0, hyper)
+    # 6 KDA layers and 6 expert layers x (2 silos x 2 steps)
+    assert float(m0["kda_layer_steps"]) == 24.0
+    assert float(m0["moe_layer_steps"]) == 24.0
+    assert 0 < float(m0["moe_tokens_here"]) <= 24 * 32
+    assert float(m0["moe_tokens_here"]) <= float(m0["moe_slots_held"])
+    float(m0["loss_sum"])
+    sim.flush_program_counters()
+    assert REGISTRY.counter("fed_kda_layer_steps_total").value() == \
+        kda_before + 24
+    assert REGISTRY.counter("fed_moe_tokens_here_total").value() == \
+        here_before + float(m0["moe_tokens_here"])
+    assert REGISTRY.gauge("fed_kda_chunk").value() == 32.0
+    assert la.chunk_size(4096) == 64 and la.chunk_size(40) == 48
+
+
+def test_the_cache_path_of_linear_attention_refuses_clearly():
+    cfg = small_cfg(layers=1)
+    base, _ = weights(cfg)
+    lc = system_cfg(cfg, 32)
+    x = tokens(cfg)[:, :-1]
+    view = [(jnp.zeros((2, 32, 4, 16)), jnp.zeros((2, 32, 4, 16)))]
+    with pytest.raises(NotImplementedError, match="linear attention"):
+        CausalLM(lc).apply({"params": base}, x, kv_view=view,
+                           positions=jnp.broadcast_to(jnp.arange(32), (2, 32)))
+
+
+@pytest.mark.parametrize("over,match", [
+    ({"expert_swiglu_limit_list": [0] * 6 + [4]}, "swiglu_limit"),
+    ({"share_expert_swiglu_limit_list": [5] * 7}, "swiglu_limit"),
+    ({"num_nextn_predict_layers": 1}, "multi-token"),
+    ({"kda_safe_gate": False}, "bounded gate"),
+    ({"topk_method": "group_limited_greedy"}, "topk_method"),
+    ({"kda_lower_bound": -8}, "kda_lower_bound"),
+    ({"kda_lower_bound": 0}, "kda_lower_bound"),
+    ({"short_conv_kernel_size": 2}, "short_conv_kernel_size"),
+])
+def test_what_is_not_built_is_refused(over, match):
+    with pytest.raises(NotImplementedError, match=match):
+        system_cfg(small_cfg(**over), 32)
+
+
+def test_a_limit_list_that_is_zero_in_the_layers_held_is_carried():
+    lc = system_cfg(small_cfg(), 32)      # nonzero from layer 7 on: not held
+    assert lc.num_layers == 7 and lc.n_group == 8 and lc.router_bias
+    assert lc.linear_head_dim == 16 and lc.attn_output_gate
+    assert lc.q_lora_rank == 0 and lc.n_shared_experts == 1

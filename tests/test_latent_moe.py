@@ -585,7 +585,7 @@ def test_dense_count_refuses_latent_attention_and_experts():
     assert LLMConfig().param_count() > 0
 
 
-@pytest.mark.parametrize("key,value", [("topk_method", "noaux_tc"),
+@pytest.mark.parametrize("key,value", [("topk_method", "group_limited_greedy"),
                                        ("scoring_func", "softmax")])
 def test_unbuilt_routing_variants_are_refused(key, value):
     with pytest.raises(NotImplementedError):
