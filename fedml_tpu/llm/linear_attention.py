@@ -18,8 +18,8 @@ Training never runs that token by token. In a chunk of C positions with
 ``exp(G_r - G_j)`` is at most 1, but its factors ``e^{G_r} e^{-G_j}`` leave
 float32 over a chunk, so each sub-chunk of ``SUB`` rows measures its decays
 from its own first row: with ``g >= -5`` (the model's bounded gate) no
-exponent passes ``SUB * 5 = 80``. ``(I + A)^-1`` is a product of ``log2 C``
-factors ``I + A^(2^i)`` (A is nilpotent), all on the MXU.
+exponent passes ``SUB * 5 = 80``. A is nilpotent, so ``(I + A)^-1`` is the
+sum of the powers of ``-A`` below C, doubled ``log2 C`` times on the MXU.
 
 :func:`_chunk` is that chunk step as plain ``jax.numpy``; both forms run it
 and its ``jax.vjp``: ``dense`` under ``lax.scan`` over the chunks, ``flash``
@@ -76,34 +76,58 @@ def _mm(a, b, dims, dtype):
                                preferred_element_type=jnp.float32)
 
 
-@jax.custom_vjp
 def _unit_lower_inverse(a):
-    """``(I + a)^-1`` for strictly lower triangular ``a`` [C, C]: ``a`` is
-    nilpotent, so the inverse is ``(I - a)(I + a^2)(I + a^4)...`` up to
-    ``a^(C/2)``."""
+    """``(I + a)^-1`` for strictly lower triangular ``a`` [C, C]. With
+    ``b = -a`` (nilpotent) the inverse is ``S_C = sum_{k<C} b^k``, and
+    ``S_2n = S_n + b^n S_n``. ``[b^n | S_n]`` stays one ``[C, 2C]`` array (at
+    C = 64 one weight tile, and no lane of it moves between doublings): one
+    product ``b^n @ [b^n | S_n]`` a doubling gives the next power and the
+    next sum's second half together. ``b^n`` is zero in its first ``n`` rows
+    and last ``n`` columns: whole registers of them (8 rows) stay out of
+    the product."""
     c = a.shape[0]
-    eye = (jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-           == jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-           ).astype(jnp.float32)
-    inv, power, order = eye - a, a, 2
-    while order < c:
-        power = _exact(power, power)
-        inv = inv + _exact(inv, power)
-        order *= 2
-    return inv
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, 2 * c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1)
+    both = jnp.where(col == row + c, 1.0,                      # [b | I]
+                     jnp.concatenate([-a, jnp.zeros_like(a)], 1))
+    n = 1
+    while n < c:
+        dead = n // 8 * 8
+        step = _exact(both[dead:, :c - dead], both[:c - dead])
+        if dead:
+            step = jnp.concatenate(
+                [jnp.zeros((dead, 2 * c), jnp.float32), step], 0)
+        both = step + jnp.where(col < c, 0.0, both)
+        n *= 2
+    return both[:, c:]
 
 
-def _unit_lower_inverse_fwd(a):
+@jax.custom_vjp
+def _unit_lower_solve(a, rhs):
+    """``u`` with ``(I + a) u = rhs`` for strictly lower triangular ``a``
+    [C, C] and ``rhs`` [C, d]. The pull-back is the solve's own (two
+    products with what the forward pass holds), not an inverse's."""
+    return _unit_lower_solve_fwd(a, rhs)[0]
+
+
+def _unit_lower_solve_fwd(a, rhs):
     inv = _unit_lower_inverse(a)
-    return inv, inv
+    u = _exact(inv, rhs)
+    return u, (inv, u)
 
 
-def _unit_lower_inverse_bwd(inv, g):
-    # d(X^-1) = -X^-1 dX X^-1, pulled back
-    return (-_exact(inv, _exact(g, inv, _NT), _TN),)
+def _unit_lower_solve_bwd(res, du):
+    # u = X^-1 r: dr = X^-T du, dX = -dr u^T, and only a's strict lower
+    # triangle is free
+    inv, u = res
+    c = inv.shape[0]
+    drhs = _exact(inv, du, _TN)
+    lower = (jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+             > jax.lax.broadcasted_iota(jnp.int32, (c, c), 1))
+    return jnp.where(lower, -_exact(drhs, u, _NT), 0.0), drhs
 
 
-_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+_unit_lower_solve.defvjp(_unit_lower_solve_fwd, _unit_lower_solve_bwd)
 
 
 def _chunk(q, k, kb, vb, gc, st, dtype):
@@ -140,7 +164,7 @@ def _chunk(q, k, kb, vb, gc, st, dtype):
     from_start = jnp.exp(gc)
     seen = _mm(jnp.concatenate([kb * from_start, q * from_start], 0), st,
                _NT, dtype)                                   # [2C, d_v]
-    u = _exact(_unit_lower_inverse(a_mat), vb - seen[:c])
+    u = _unit_lower_solve(a_mat, vb - seen[:c])
     o = seen[c:] + _mm(p_mat, u, (((1,), (0,)), ((), ())), dtype)
     last = gc[c - 1:c]
     st_new = st * jnp.exp(last) + _mm(u, k * jnp.exp(last - gc), _TN, dtype)

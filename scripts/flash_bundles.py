@@ -6,6 +6,12 @@ each kernel's final bundles, every loop's length and what fills it.
 
     python3 scripts/flash_bundles.py 1 4096 4 192 128     # b s h d_qk d_v
     python3 scripts/flash_bundles.py 2 1024 4 128 128 --mask --blocks 256,256
+    python3 scripts/flash_bundles.py --kda 1 4096 32 128  # b s h d
+
+``--kda`` does the same for the linear-attention kernels (``kda_fwd``,
+``kda_bwd``: ``llm/linear_attention.py``). Their body is straight-line code,
+one chunk of two heads, so the only loop is the grid and its length is a
+grid step.
 
 A bundle issues in a cycle unless it waits, so a loop's length is the least
 its iteration can take; PR 31's probes read 0.70-0.78 ns a bundle on the
@@ -57,19 +63,33 @@ def loops(path):
     return sorted(out)
 
 
-def compile_with_dump(args, dump):
-    sys.path.insert(0, REPO)
+def kda_train(args):
+    """(function, argument shapes): ``kda_attention`` through the kernels,
+    forward and backward, in bfloat16 as the benchmark runs it."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
-    from fedml_tpu.core import kernels
+    from fedml_tpu.llm.linear_attention import kda_attention
+
+    b, s, h, d = args.kda
+    qk = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+    g = jax.ShapeDtypeStruct((b, s, h, d), jnp.float32)
+    beta = jax.ShapeDtypeStruct((b, s, h), jnp.float32)
+
+    def train(*a):
+        return jax.value_and_grad(
+            lambda *a: kda_attention(*a, impl="flash").astype(
+                jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))(*a)
+
+    return train, (qk, qk, qk, g, beta)
+
+
+def flash_train(args):
+    import jax
+    import jax.numpy as jnp
+
     from fedml_tpu.llm.attention import flash_causal_attention
 
-    device = topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0]
-    where = SingleDeviceSharding(device)
     b, s, h, d_qk, d_v = args.shape
     q = jax.ShapeDtypeStruct((b, s, h, d_qk), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((b, s, h, d_v), jnp.bfloat16)
@@ -83,22 +103,41 @@ def compile_with_dump(args, dump):
                 attn_mask=mask if args.mask else None).astype(
                     jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
+    return train, (q, q, v, mask)
+
+
+def compile_with_dump(args):
+    sys.path.insert(0, REPO)
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from fedml_tpu.core import kernels
+
+    device = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0]
+    where = SingleDeviceSharding(device)
+    train, shapes = kda_train(args) if args.kda else flash_train(args)
     with kernels.compile_for_tpu():
         jax.jit(train, in_shardings=where, out_shardings=where).lower(
-            q, q, v, mask).compile()
+            *shapes).compile()
 
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("shape", type=int, nargs=5, metavar="N",
-                    help="b s h d_qk d_v")
+    ap.add_argument("shape", type=int, nargs="*", metavar="N",
+                    help="b s h d_qk d_v (the flash kernels)")
+    ap.add_argument("--kda", type=int, nargs=4, metavar="N",
+                    help="b s h d: the linear-attention kernels instead")
     ap.add_argument("--mask", action="store_true",
                     help="the variant with a key mask")
     ap.add_argument("--blocks", default="512,512")
     ap.add_argument("--dump", help=argparse.SUPPRESS)
     args = ap.parse_args()
+    if bool(args.kda) == bool(args.shape) or len(args.shape) not in (0, 5):
+        ap.error("give b s h d_qk d_v, or --kda b s h d")
     if args.dump:           # the child: libtpu reads its flags when it loads
-        return compile_with_dump(args, args.dump)
+        return compile_with_dump(args)
     with tempfile.TemporaryDirectory() as dump:
         env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
                    LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={dump} "
@@ -107,7 +146,8 @@ def main():
         # the bundles are written: the files decide, not the exit code
         subprocess.run([sys.executable, __file__, *sys.argv[1:],
                         "--dump", dump], env=env, capture_output=True)
-        for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        for kernel in (("kda_fwd", "kda_bwd") if args.kda else
+                       ("flash_fwd", "flash_dq", "flash_dkv")):
             files = [f for f in glob.glob(
                 f"{dump}/*{kernel}*final_bundles.txt")
                 if "schedule-analysis" not in f]

@@ -8,6 +8,7 @@ that holds exactly one group."""
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import os
 
@@ -214,6 +215,79 @@ def test_kda_in_bfloat16_stays_near_the_recurrence():
     got = la.kda_attention(bf(q), bf(k), bf(v), g, beta, impl="flash")
     assert got.dtype == jnp.bfloat16
     assert rel(got.astype(jnp.float32), want) < 2e-2
+
+
+def solve_inputs(c, d=128):
+    ks = jax.random.split(jax.random.PRNGKey(c), 3)
+    a = 0.3 * jax.random.normal(ks[0], (c, c))
+    return (jnp.tril(a, -1), jax.random.normal(ks[1], (c, d)),
+            jax.random.normal(ks[2], (c, d)))
+
+
+@pytest.mark.parametrize("c", [16, 32, 48, 64])
+def test_the_chunks_solve_matches_solve_triangular(c):
+    """``(I + a) u = rhs`` by doubling, and its pull-back (``custom_vjp``
+    over the solve: no derivative of an inverse), against
+    ``jax.scipy.linalg.solve_triangular`` and its autodiff: the value and
+    both gradients, at every chunk size a row can have."""
+    a, rhs, ct = solve_inputs(c)
+
+    def plain(a, rhs):
+        return jax.scipy.linalg.solve_triangular(
+            jnp.eye(c) + jnp.tril(a, -1), rhs, lower=True,
+            unit_diagonal=True)
+
+    with jax.default_matmul_precision("highest"):
+        want, pull = jax.vjp(plain, a, rhs)
+        want_a, want_rhs = pull(ct)
+    got, pull = jax.vjp(la._unit_lower_solve, a, rhs)
+    got_a, got_rhs = pull(ct)
+    assert rel(got, want) < 2e-6
+    assert rel(got_rhs, want_rhs) < 2e-6
+    assert rel(got_a, want_a) < 2e-6
+
+
+@pytest.mark.parametrize("c", [16, 32, 48, 64])
+def test_the_solves_gradient_toward_a_is_strictly_lower(c):
+    """Only ``a``'s strict lower triangle is free: the pull-back leaves the
+    diagonal and everything above it at exactly zero, whatever comes in."""
+    a, rhs, ct = solve_inputs(c)
+    got_a = jax.vjp(la._unit_lower_solve, a, rhs)[1](ct)[0]
+    assert float(jnp.abs(jnp.triu(got_a)).max()) == 0.0
+    assert float(jnp.abs(got_a)[jnp.tril_indices(c, -1)].min()) > 0.0
+
+
+def full_precision_products(jaxpr):
+    """``dot_general``s at ``Precision.HIGHEST`` in a jaxpr and in every
+    jaxpr its equations carry."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            prec = eqn.params["precision"]
+            prec = prec if isinstance(prec, tuple) else (prec,)
+            n += jax.lax.Precision.HIGHEST in prec
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += full_precision_products(sub)
+    return n
+
+
+def test_the_chunk_step_makes_seven_full_precision_products():
+    """At C = 64 in bfloat16 the solve is the step's only full-precision
+    work: six doublings and ``u`` forward; in the backward kernel's body
+    (the step rebuilt and pulled back) those seven and the pull-back's
+    two. A full-precision product is six MXU passes and a link of the
+    step's dependent chain: one more has to show here."""
+    c, d = 64, 128
+    x = jnp.zeros((c, d), jnp.bfloat16)
+    gc = jnp.zeros((c, d), jnp.float32)
+    st = jnp.zeros((d, d), jnp.float32)
+    fwd = jax.make_jaxpr(functools.partial(la._chunk, dtype=jnp.bfloat16))(
+        x, x, x, x, gc, st)
+    assert full_precision_products(fwd.jaxpr) == 7
+    bwd = jax.make_jaxpr(
+        functools.partial(la._chunk_grads, dtype=jnp.bfloat16))(
+            x, x, x, x, gc, st, x, st)
+    assert full_precision_products(bwd.jaxpr) == 9
 
 
 def test_a_masked_key_neither_writes_nor_decays():
