@@ -41,6 +41,7 @@ import concurrent.futures as cf
 import functools
 import importlib.metadata
 import json
+import math
 import os
 import statistics
 import sys
@@ -56,6 +57,8 @@ FULL = {
                   prompt_chars=(3, 40, 150, 400, 700, 12)),
     "flash_shapes": ((8, 1024, 8, 128), (1, 8192, 2, 128)),
     "kda_shape": (1, 2048, 4, 128),
+    # b, s, query heads, key-value heads, d_qk, d_v, window
+    "window_shape": (1, 4096, 64, 8, 192, 128, 128),
     "conv_batch": 32,
     "ring_seq": 8192,
 }
@@ -67,6 +70,7 @@ TOY = {
     "serve": dict(slots=4, max_new=4, prompt_chars=(3, 20, 60, 9)),
     "flash_shapes": ((2, 128, 2, 32),),
     "kda_shape": (1, 128, 2, 128),
+    "window_shape": (1, 256, 4, 2, 24, 16, 40),
     "conv_batch": 8,
     "ring_seq": 256,
 }
@@ -509,6 +513,71 @@ def phase_kda(sz):
     return out
 
 
+def phase_window(sz):
+    """The window kernels with a sink (Pallas on a chip, interpreted
+    elsewhere) at the window cell's shape, grouped heads repeated before
+    them as the model does, forward and the four gradients against the
+    dense path (one key-value head's query heads at a time: the dense
+    scores of all 64 would not fit), on bfloat16 operands; beside them the
+    time of the causal kernels at the same shape."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.llm.attention import causal_attention
+
+    b, s, h, kv, d_qk, d_v, window = sz["window_shape"]
+    rep = h // kv
+    ks = jax.random.split(jax.random.PRNGKey(13), 5)
+    q = jax.random.normal(ks[0], (b, s, h, d_qk), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, s, kv, d_qk), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, s, kv, d_v), jnp.bfloat16)
+    sink = math.log(window) + jax.random.uniform(ks[3], (h,), minval=-1.0,
+                                                 maxval=1.0)
+    ct = jax.random.normal(ks[4], (b, s, h, d_v), jnp.float32)
+
+    def attend(impl, **kw):
+        def fn(q, k, v, sink, ct):
+            def loss(q, k, v, sink):
+                out = causal_attention(
+                    q, jnp.repeat(k, q.shape[2] // k.shape[2], axis=2),
+                    jnp.repeat(v, q.shape[2] // v.shape[2], axis=2),
+                    impl=impl, sink=sink if kw else None, **kw)
+                return jnp.sum(out.astype(jnp.float32) * ct), out
+            (_, out), g = jax.value_and_grad(loss, argnums=(0, 1, 2, 3),
+                                             has_aux=True)(q, k, v, sink)
+            return (out,) + g
+        return jax.jit(fn)
+
+    flash = "flash" if on_chip() else "dense"
+    got = attend(flash, window=window)(q, k, v, sink, ct)
+    dense = attend("dense", window=window)
+    parts = [dense(q[:, :, g * rep:(g + 1) * rep], k[:, :, g:g + 1],
+                   v[:, :, g:g + 1], sink[g * rep:(g + 1) * rep],
+                   ct[:, :, g * rep:(g + 1) * rep]) for g in range(kv)]
+    want = [jnp.concatenate([p[i] for p in parts], 2 if i < 4 else 0)
+            for i in range(5)]
+
+    def gap(a, w):
+        a, w = (np.asarray(x, np.float32) for x in (a, w))
+        check(np.isfinite(a).all(), "non-finite window-attention result")
+        return float(np.linalg.norm(a - w) / (np.linalg.norm(w) + 1e-6))
+
+    errs = [gap(a, w) for a, w in zip(got, want)]
+    check(max(errs) < 0.03, f"window kernels vs the dense path: {errs}")
+    out = {"window_rel_err_" + "x".join(map(str, sz["window_shape"])):
+           round(max(errs), 5),
+           "window_norms_out_dq_dk_dv_dsink": [
+               round(float(jnp.linalg.norm(a.astype(jnp.float32))), 3)
+               for a in got]}
+    if on_chip():
+        out["fwd_bwd_ms_window_vs_causal"] = [
+            round(1e3 * statistics.median(round_trips(
+                f, q, k, v, sink, ct, n=5)), 3)
+            for f in (attend("flash", window=window), attend("flash"))]
+    return out
+
+
 def phase_four_chip_llm(sz):
     """The two LLM programs __graft_entry__._dryrun_llm_sharded runs at
     h32, here at full width: the {data 1, fsdp 2, tensor 2} train step and
@@ -632,6 +701,7 @@ def main():
     keep.clear()
     run_phase("kernels", phase_kernels, sz)
     run_phase("kda", phase_kda, sz)
+    run_phase("window", phase_window, sz)
     if len(devices) == 4:
         run_phase("four_chip_llm", phase_four_chip_llm, sz)
     say(phase="compile_cache", ok=True, compile_cache_dir=cache_dir,

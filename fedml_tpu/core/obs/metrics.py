@@ -531,6 +531,17 @@ def record_kda_round(layer_steps: float) -> None:
                      "recorded round").inc(float(layer_steps))
 
 
+def record_window_round(layer_steps: float) -> None:
+    """Passes through a sliding-window attention layer (a layer and train
+    step) in one finished round, as the round program itself counted
+    them."""
+    if not _cfg["enabled"]:
+        return
+    REGISTRY.counter("fed_attn_window_layer_steps_total",
+                     "passes through a sliding-window attention layer, "
+                     "every recorded round").inc(float(layer_steps))
+
+
 def record_flash_plan(interior_share: float, key_mask: bool) -> None:
     """The flash kernels' block plan of the call just traced (host side,
     once a trace; ``llm/attention.py::flash_block_plan``): the share of the
@@ -545,6 +556,27 @@ def record_flash_plan(interior_share: float, key_mask: bool) -> None:
     REGISTRY.gauge("fed_flash_key_mask",
                    "1 if the last traced flash call carries a key mask, "
                    "else 0").set(1.0 if key_mask else 0.0)
+
+
+def record_flash_window(window: int, block_share: float, sink: bool) -> None:
+    """The block plan of the flash call just traced that has a sliding
+    window or a sink (host side, once a trace;
+    ``llm/attention.py::flash_causal_attention``): the window (0: none),
+    the score blocks a head its plan computes over those the causal plan
+    computes at the same length and blocks, and whether the softmax has a
+    sink column."""
+    if not _cfg["enabled"]:
+        return
+    REGISTRY.gauge("fed_flash_window",
+                   "the sliding window of the last traced flash call that "
+                   "has a window or a sink (0: no window)").set(float(window))
+    REGISTRY.gauge("fed_flash_window_block_share",
+                   "computed score blocks of that call's plan / those of "
+                   "the causal plan at the same length and blocks"
+                   ).set(float(block_share))
+    REGISTRY.gauge("fed_flash_sink",
+                   "1 if that call's softmax has a learned sink column, "
+                   "else 0").set(1.0 if sink else 0.0)
 
 
 def record_kda_plan(chunk: int) -> None:

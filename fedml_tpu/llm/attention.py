@@ -22,6 +22,13 @@ counterparts here are first-class:
   transposed scores, so nothing score-sized is transposed; bf16 operands
   go to the MXU as they lie, statistics and accumulators stay float32 in
   VMEM scratch; the exponentials are base 2 (the scale carries log2 e).
+  A sliding ``window`` (a query sees its last ``window`` keys, itself
+  among them) gives the plan a first live block as well as a last one, so
+  the kernels compute the band's blocks alone, under names of their own
+  (``flash_win_*``); a learned ``sink`` (one logit a query head that takes
+  softmax mass and carries no value) is where the forward's running
+  maximum and sum start, so the stored logsumexp carries it and the
+  backward kernels need nothing new.
 - ``ring``: ring attention over the ``sp`` mesh axis — sequence shards
   rotate K/V (and the key-padding mask) via ``ppermute`` while
   accumulating online-softmax state, so context length scales with the
@@ -46,6 +53,8 @@ from ..core.obs import metrics as obs_metrics
 NEG_INF = -1e30
 # the three kernels' names in a device trace (forward, dQ, dK/dV)
 FLASH_KERNEL_NAMES = ("flash_fwd", "flash_dq", "flash_dkv")
+# the same three of a call with a sliding window
+WINDOW_KERNEL_NAMES = ("flash_win_fwd", "flash_win_dq", "flash_win_dkv")
 
 # (axis_name, axis_size) for ring attention; set by the sequence-parallel
 # wrapper (sharding.py) around the shard_map'd forward.
@@ -65,10 +74,14 @@ def ring_axis(name: str, size: int):
 def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      impl: str = "dense",
                      attn_mask: Optional[jnp.ndarray] = None,
-                     scale: Optional[float] = None) -> jnp.ndarray:
+                     scale: Optional[float] = None,
+                     window: Optional[int] = None,
+                     sink: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Dispatch. q/k: [b, s, h, d_qk], v: [b, s, h, d_v] → [b, s, h, d_v].
     ``scale`` multiplies the scores (default ``d_qk ** -0.5``); dense and
-    flash take ``d_qk != d_v`` (latent attention's 192/128)."""
+    flash take ``d_qk != d_v`` (latent attention's 192/128), a sliding
+    ``window`` (query i sees keys ``i - window < j <= i``) and a ``sink``
+    (``[h]`` logits: ``p_ij = exp(s_ij) / (exp(sink_h) + sum_j exp(s_ij))``)."""
     if impl == "ring":
         ax = _RING_AXIS.get()
         if ax is None:
@@ -76,29 +89,44 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 "attention_impl='ring' requires the sequence-parallel "
                 "context (fedml_tpu.llm.attention.ring_axis) — wrap the "
                 "forward in shard_map over the 'sp' axis")
-        if scale is not None or q.shape[-1] != v.shape[-1]:
+        if (scale is not None or q.shape[-1] != v.shape[-1]
+                or window is not None or sink is not None):
             raise NotImplementedError(
-                "ring attention takes one head size and its default scale")
+                "ring attention takes one head size and its default scale, "
+                "and neither a sliding window nor a sink")
         return ring_causal_attention(q, k, v, axis_name=ax[0],
                                      axis_size=ax[1], attn_mask=attn_mask)
     if impl == "flash":
         return flash_causal_attention(q, k, v, attn_mask=attn_mask,
-                                      scale=scale)
-    return dense_causal_attention(q, k, v, attn_mask=attn_mask, scale=scale)
+                                      scale=scale, window=window, sink=sink)
+    return dense_causal_attention(q, k, v, attn_mask=attn_mask, scale=scale,
+                                  window=window, sink=sink)
 
 
-def dense_causal_attention(q, k, v, attn_mask=None, scale=None):
-    """[b, s, h, d] — reference semantics, scores in f32."""
+def dense_causal_attention(q, k, v, attn_mask=None, scale=None, window=None,
+                           sink=None):
+    """[b, s, h, d] — reference semantics, scores in f32. ``window``: a
+    query sees its last ``window`` keys only; ``sink`` ([h] logits): one
+    more column of the row's softmax that carries no value."""
     _, s, _, d = q.shape
     scale = 1.0 / math.sqrt(d) if scale is None else scale
     scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     causal = jnp.tril(jnp.ones((s, s), bool))
+    if window is not None:
+        causal = causal & ~jnp.tril(jnp.ones((s, s), bool), -int(window))
     mask = causal[None, None]
     if attn_mask is not None:  # [b, s] key padding
         mask = mask & attn_mask[:, None, None, :].astype(bool)
     scores = jnp.where(mask, scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
+    if sink is None:
+        probs = jax.nn.softmax(scores, axis=-1)
+    else:
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None, None],
+            scores.shape[:3] + (1,))
+        probs = jax.nn.softmax(
+            jnp.concatenate([scores, column], -1), axis=-1)[..., :-1]
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
 
@@ -142,12 +170,16 @@ class FlashBlockPlan(NamedTuple):
     """Which [block_q, block_k] score blocks of the causal ``s x s`` square
     a kernel computes, and which of them the diagonal crosses. A block
     wholly under the diagonal (*interior*) needs no causal compare; one
-    wholly above it is skipped. The bounds take a Python int or a traced
+    wholly above it is skipped. With a sliding ``window`` (query i sees
+    keys ``i - window < j <= i``) a block wholly below the band is skipped
+    too, and an interior block that the band's lower edge crosses (*edge*)
+    compares ``i - j < window``. The bounds take a Python int or a traced
     ``program_id`` alike, for any ``block_q``, ``block_k`` that divide s."""
     n_q: int
     n_k: int
     block_q: int
     block_k: int
+    window: Optional[int] = None
 
     def q_major(self, i):
         """``(n_full, n_live)`` for q block ``i`` (forward, dQ): kv blocks
@@ -169,20 +201,54 @@ class FlashBlockPlan(NamedTuple):
         j_full = _least(self.n_q, ((j + 1) * bk + bq - 2) // bq)
         return j0, j_full
 
+    def q_major_window(self, i):
+        """``(n_first, n_edge, n_full, n_live)`` for q block ``i`` under
+        the plan's window (none: no block skipped below, no edge): kv blocks before ``n_first`` lie wholly below
+        the band and are skipped, ``[n_first, n_edge)`` are edge blocks,
+        ``[n_edge, n_full)`` interior, ``[n_full, n_live)`` hold the
+        diagonal (and, where the window is narrower than a block, the
+        band's lower edge as well)."""
+        bq, bk, w = self.block_q, self.block_k, self.window
+        n_full, n_live = self.q_major(i)
+        if w is None:           # no band: nothing skipped below, no edge
+            return 0, 0, n_full, n_live
+        # the block of the first query's first key
+        n_first = _most(i * bq - w + 1, 0) // bk
+        # no edge from the block on whose first key the LAST query's
+        # window opens: (i + 1) * bq - 1 - first key < w
+        n_edge = _least(n_full, (_most((i + 1) * bq - w, 0) + bk - 1) // bk)
+        return n_first, n_edge, n_full, n_live
+
+    def k_major_window(self, j):
+        """``(j0, j_full, j_edge, j_last)`` for kv block ``j`` under the
+        plan's window (none: ``j_edge = j_last = n_q``): q blocks ``[j0, j_full)`` hold the diagonal,
+        ``[j_full, j_edge)`` are interior, ``[j_edge, j_last)`` edge blocks,
+        those from ``j_last`` on lie wholly below the band."""
+        bq, bk, w = self.block_q, self.block_k, self.window
+        j0, j_full = self.k_major(j)
+        if w is None:
+            return j0, j_full, self.n_q, self.n_q
+        # live: the block's first query - this block's last key < w
+        j_last = _least(self.n_q, (w + (j + 1) * bk - 2) // bq + 1)
+        # no edge while the block's last query - this block's first key < w
+        j_edge = _least(j_last, _most((w + j * bk) // bq, j_full))
+        return j0, j_full, j_edge, j_last
+
     def counts(self, k_major: bool = False):
         """(interior, diagonal, skipped) blocks a head, q-major or k-major
-        (the same blocks, counted along the other axis)."""
+        (the same blocks, counted along the other axis). With a window the
+        edge blocks count with the diagonal ones: blocks that compare."""
         interior = diagonal = 0
         if k_major:
             for j in range(self.n_k):
-                j0, j_full = self.k_major(j)
-                interior += self.n_q - j_full
-                diagonal += j_full - j0
+                j0, j_full, j_edge, j_last = self.k_major_window(j)
+                interior += j_edge - j_full
+                diagonal += (j_full - j0) + (j_last - j_edge)
         else:
             for i in range(self.n_q):
-                n_full, n_live = self.q_major(i)
-                interior += n_full
-                diagonal += n_live - n_full
+                n_first, n_edge, n_full, n_live = self.q_major_window(i)
+                interior += n_full - n_edge
+                diagonal += (n_live - n_full) + (n_edge - n_first)
         return interior, diagonal, self.n_q * self.n_k - interior - diagonal
 
 
@@ -190,10 +256,20 @@ def _least(a, b):
     return min(a, b) if isinstance(b, int) else jnp.minimum(a, b)
 
 
-def flash_block_plan(s: int, block_q: int, block_k: int) -> FlashBlockPlan:
+def _most(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.maximum(a, b)
+
+
+def flash_block_plan(s: int, block_q: int, block_k: int,
+                     window: Optional[int] = None) -> FlashBlockPlan:
     """The plan all three kernels take their loop bounds from (``s`` a
-    multiple of both blocks)."""
-    return FlashBlockPlan(s // block_q, s // block_k, block_q, block_k)
+    multiple of both blocks). A window that reaches every earlier key
+    (``window >= s``) is no window."""
+    if window is not None and window >= s:
+        window = None
+    return FlashBlockPlan(s // block_q, s // block_k, block_q, block_k,
+                          window)
 
 
 def _split_refs(refs, n_lead: int, key_mask: bool):
@@ -218,12 +294,21 @@ _LOG2E = 1.4426950408889634
 _LN2 = 0.6931471805599453
 
 
-def _live(q_pos, k_pos, keep):
-    """Which scores count: ``q_pos >= k_pos``, one a column and one a row.
-    ``keep`` (bool, ``k_pos``'s shape, or None) is the key mask."""
+# what a block compares: nothing, the diagonal, the window's lower edge, both
+_NONE, _CAUSAL, _EDGE, _BOTH = 0, 1, 2, 3
+
+
+def _live(q_pos, k_pos, keep, compare=_CAUSAL, window=None):
+    """Which scores count: ``q_pos >= k_pos`` (``_CAUSAL``) and / or
+    ``q_pos - k_pos < window`` (``_EDGE``), one a column and one a row.
+    ``keep`` (bool, ``k_pos``'s shape, or None) is the key mask; with one,
+    ``compare`` has to include ``_CAUSAL``."""
     if keep is not None:
         k_pos = jnp.where(keep, k_pos, _NEVER)
-    return q_pos >= k_pos
+    if compare == _CAUSAL:
+        return q_pos >= k_pos
+    inside = q_pos < k_pos + window
+    return inside if compare == _EDGE else (q_pos >= k_pos) & inside
 
 
 def _scaled(q, factor: float):
@@ -240,58 +325,87 @@ def _across(stat, width: int):
     return jnp.broadcast_to(stat[:, :1], (stat.shape[0], width))
 
 
-def _fold_blocks(pl, lo, mid, hi, interior_first: bool, block: int, fold,
-                 key_mask: bool):
-    """``fold(start, compare_here)`` over blocks ``[lo, hi)`` of a ref's
-    long axis, in index order: ``[lo, mid)`` interior (no compare) then
-    ``[mid, hi)`` diagonal, or the diagonal first. With a key mask every
-    block compares positions (:func:`_live`), so one loop runs them all.
-    The state lives in VMEM scratch; nothing is carried from loop to loop."""
-    def run(first, last, compare_here):
-        def body(i, _):
-            fold(pl.multiple_of(i * block, block), compare_here)
+def _fold_segments(pl, segments, block: int, fold):
+    """``fold(start, compare)`` over the blocks of a ref's long axis, one
+    loop a ``(first, last, compare)`` segment, in order. The state lives in
+    VMEM scratch; nothing is carried from loop to loop."""
+    for first, last, compare in segments:
+        def body(i, _, compare=compare):
+            fold(pl.multiple_of(i * block, block), compare)
         jax.lax.fori_loop(first, last, body, None)
 
-    if key_mask:
-        run(lo, hi, True)
+
+def _fold_q_major(pl, plan: FlashBlockPlan, fold, key_mask: bool):
+    """The forward's and dQ's loops over kv blocks for this q block: edge
+    blocks, interior ones (no compare), then the diagonal. With a key mask
+    every block compares positions (:func:`_live`), so one loop runs them
+    all."""
+    n_first, n_edge, n_full, n_live = plan.q_major_window(pl.program_id(1))
+    if plan.window is None:
+        segments = ([(0, n_live, _CAUSAL)] if key_mask else
+                    [(0, n_full, _NONE), (n_full, n_live, _CAUSAL)])
     else:
-        run(lo, mid, not interior_first)
-        run(mid, hi, interior_first)
+        segments = ([(n_first, n_live, _BOTH)] if key_mask else
+                    [(n_first, n_edge, _EDGE), (n_edge, n_full, _NONE),
+                     (n_full, n_live, _BOTH)])
+    _fold_segments(pl, segments, plan.block_k, fold)
+
+
+def _fold_k_major(pl, plan: FlashBlockPlan, fold, key_mask: bool):
+    """dK/dV's loops over q blocks for this kv block: the diagonal,
+    interior blocks, then edge ones."""
+    j0, j_full, j_edge, j_last = plan.k_major_window(pl.program_id(1))
+    if plan.window is None:
+        segments = ([(j0, plan.n_q, _CAUSAL)] if key_mask else
+                    [(j0, j_full, _CAUSAL), (j_full, plan.n_q, _NONE)])
+    else:
+        segments = ([(j0, j_last, _BOTH)] if key_mask else
+                    [(j0, j_full, _BOTH), (j_full, j_edge, _NONE),
+                     (j_edge, j_last, _EDGE)])
+    _fold_segments(pl, segments, plan.block_q, fold)
 
 
 def _flash_fwd_kernel(*refs, plan: FlashBlockPlan, scale: float,
-                      key_mask: bool):
+                      key_mask: bool, sink: bool = False):
     """One (batch*head, q-block) program: online softmax over KV blocks.
 
     q_ref: [block_q, d_qk]; k_ref: [s, d_qk]; v_ref: [s, d_v]; mask_ref
-    (only with a key mask): [1, s]; o_ref: [block_q, d_v]; lse_ref:
+    (only with a key mask): [1, s]; sink_ref (only with a sink): [1, 128],
+    this head's logit in every lane; o_ref: [block_q, d_v]; lse_ref:
     [block_q, 1]; scratch, float32: acc_ref [block_q, d_v], m_ref and
     l_ref [block_q, 128] (a row's statistic in every lane).
     """
     import jax.experimental.pallas as pl
 
-    q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = \
-        _split_refs(refs, 3, key_mask)
+    q_ref, k_ref, v_ref, mask_ref, *rest = _split_refs(refs, 3, key_mask)
+    sink_ref = rest.pop(0) if sink else None
+    o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     block_q, block_k = plan.block_q, plan.block_k
     d_v = acc_ref.shape[1]
     q_pos = pl.program_id(1) * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, 1), 0)
     q = _scaled(q_ref[:], scale * _LOG2E)
     acc_ref[:] = jnp.zeros_like(acc_ref)
-    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[:] = jnp.zeros_like(l_ref)
+    if sink:
+        # the sink is a key every row has already seen: score sink, value 0
+        m_ref[:] = jnp.broadcast_to(sink_ref[:] * _LOG2E, m_ref.shape)
+        l_ref[:] = jnp.ones_like(l_ref)
+    else:
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
 
-    def fold(start, compare_here):
+    def fold(start, compare):
         """Keys ``[start, start + block_k)`` into the running softmax."""
         keys = pl.ds(start, block_k)
         k_blk, v_blk = k_ref[keys, :], v_ref[keys, :]
         s_blk = jax.lax.dot_general(                    # [bq, bk]
             q, k_blk, _NT, preferred_element_type=jnp.float32)
-        if compare_here:
+        if compare:
             k_pos = start + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_k), 1)
             live = _live(q_pos, k_pos,
-                         mask_ref[:, keys] > 0 if key_mask else None)
+                         mask_ref[:, keys] > 0 if key_mask else None,
+                         compare, plan.window)
             s_blk = jnp.where(live, s_blk, NEG_INF)
         m = m_ref[:]
         m_new = jnp.maximum(m, jnp.max(s_blk, -1, keepdims=True))
@@ -304,7 +418,10 @@ def _flash_fwd_kernel(*refs, plan: FlashBlockPlan, scale: float,
             # exactly zero and its stored LSE ≈ NEG_INF (flagging the row)
             # instead. Without a key mask every row has seen key 0 by its
             # first block, so m_new is finite and the exp2 of a masked
-            # score is already 0.
+            # score is already 0. (Under a window a row may meet blocks
+            # before its first live key; what they leave in l and acc is
+            # multiplied by alpha = exp2(NEG_INF - finite) = 0 at that key,
+            # which every row reaches: its own.)
             p = jnp.where(live, p, 0.0)
         alpha = jnp.exp2(m - m_new)
         m_ref[:] = m_new
@@ -312,8 +429,7 @@ def _flash_fwd_kernel(*refs, plan: FlashBlockPlan, scale: float,
         acc_ref[:] = acc_ref[:] * _across(alpha, d_v) + jnp.dot(
             p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32)
 
-    n_full, n_live = plan.q_major(pl.program_id(1))
-    _fold_blocks(pl, 0, n_full, n_live, True, block_k, fold, key_mask)
+    _fold_q_major(pl, plan, fold, key_mask)
     l = jnp.maximum(l_ref[:], 1e-30)
     o_ref[:] = (acc_ref[:] * _across(1.0 / l, d_v)).astype(o_ref.dtype)
     lse_ref[:] = ((m_ref[:] + jnp.log2(l)) * _LN2)[:, :1]
@@ -336,18 +452,19 @@ def _flash_dq_kernel(*refs, plan: FlashBlockPlan, scale: float,
     dd = dd_ref[:]                        # [block_q, 1]
     acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def fold(start, compare_here):
+    def fold(start, compare):
         """Keys ``[start, start + block_k)`` into dQ."""
         keys = pl.ds(start, block_k)
         k_blk, v_blk = k_ref[keys, :], v_ref[keys, :]
         s_blk = jax.lax.dot_general(
             q, k_blk, _NT, preferred_element_type=jnp.float32)
         p = jnp.exp2(s_blk - lse2)
-        if compare_here:
+        if compare:
             k_pos = start + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_k), 1)
             live = _live(q_pos, k_pos,
-                         mask_ref[:, keys] > 0 if key_mask else None)
+                         mask_ref[:, keys] > 0 if key_mask else None,
+                         compare, plan.window)
             p = jnp.where(live, p, 0.0)
         dp = jax.lax.dot_general(
             do, v_blk, _NT, preferred_element_type=jnp.float32)
@@ -355,8 +472,7 @@ def _flash_dq_kernel(*refs, plan: FlashBlockPlan, scale: float,
         acc_ref[:] += jnp.dot(ds.astype(k_blk.dtype), k_blk,
                               preferred_element_type=jnp.float32)
 
-    n_full, n_live = plan.q_major(pl.program_id(1))
-    _fold_blocks(pl, 0, n_full, n_live, True, block_k, fold, key_mask)
+    _fold_q_major(pl, plan, fold, key_mask)
     dq_ref[:] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
@@ -385,7 +501,7 @@ def _flash_dkv_kernel(*refs, plan: FlashBlockPlan, scale: float,
     dk_acc[:] = jnp.zeros_like(dk_acc)
     dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def fold(start, compare_here):
+    def fold(start, compare):
         """Queries ``[start, start + block_q)`` into dK and dV."""
         rows = pl.ds(start, block_q)
         # the forward's and dQ's q block, to the last bit
@@ -394,10 +510,11 @@ def _flash_dkv_kernel(*refs, plan: FlashBlockPlan, scale: float,
         s_t = jax.lax.dot_general(                      # [bk, bq]
             k, q_blk, _NT, preferred_element_type=jnp.float32)
         p_t = jnp.exp2(s_t - lse_ref[:, rows] * _LOG2E)
-        if compare_here:
+        if compare:
             q_pos = start + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_q), 1)
-            p_t = jnp.where(q_pos >= k_pos, p_t, 0.0)
+            p_t = jnp.where(_live(q_pos, k_pos, None, compare, plan.window),
+                            p_t, 0.0)
         dv_acc[:] += jnp.dot(p_t.astype(do_blk.dtype), do_blk,
                              preferred_element_type=jnp.float32)
         dp_t = jax.lax.dot_general(
@@ -406,8 +523,7 @@ def _flash_dkv_kernel(*refs, plan: FlashBlockPlan, scale: float,
         dk_acc[:] += jnp.dot(ds_t.astype(q_blk.dtype), q_blk,
                              preferred_element_type=jnp.float32)
 
-    j0, j_full = plan.k_major(pl.program_id(1))
-    _fold_blocks(pl, j0, j_full, plan.n_q, False, block_q, fold, key_mask)
+    _fold_k_major(pl, plan, fold, key_mask)
     # q carried scale * log2(e); dK wants the scale alone
     dk_ref[:] = (dk_acc[:] * _LN2).astype(dk_ref.dtype)
     dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
@@ -431,25 +547,35 @@ def _row_mask(pl, mask, h: int):
             [pl.BlockSpec((None, 1, s), lambda i, j: (i // h, 0, 0))])
 
 
-def _flash_fwd(q, k, v, mask, block_q: int, block_k: int, scale: float):
+def _kernel_names(plan: FlashBlockPlan):
+    return FLASH_KERNEL_NAMES if plan.window is None else WINDOW_KERNEL_NAMES
+
+
+def _flash_fwd(q, k, v, mask, sink, block_q: int, block_k: int, scale: float,
+               window: Optional[int]):
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
     b, s, h, d = q.shape
     dv = v.shape[-1]
     key_mask = mask is not None
-    plan = flash_block_plan(s, block_q, block_k)
+    plan = flash_block_plan(s, block_q, block_k, window)
     qf, kf, vf = _heads_first(q), _heads_first(k), _heads_first(v)
     mask_rows, mask_specs = _row_mask(pl, mask, h)
+    sink_rows, sink_specs = [], []
+    if sink is not None:    # a head's logit, lane-replicated as m and l are
+        sink_rows = [jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, None, None], (h, 1, 128))]
+        sink_specs = [pl.BlockSpec((None, 1, 128), lambda i, j: (i % h, 0, 0))]
     out, lse = pl.pallas_call(
         functools.partial(_flash_fwd_kernel, plan=plan, scale=scale,
-                          key_mask=key_mask),
+                          key_mask=key_mask, sink=sink is not None),
         grid=(b * h, plan.n_q),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, s, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((None, s, dv), lambda i, j: (i, 0, 0)),
-            *mask_specs,
+            *mask_specs, *sink_specs,
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, dv), lambda i, j: (i, j, 0)),
@@ -464,20 +590,21 @@ def _flash_fwd(q, k, v, mask, block_q: int, block_k: int, scale: float):
                         pltpu.VMEM((block_q, 128), jnp.float32)],
         interpret=kernels.interpret(),
         compiler_params=kernels.tpu_compiler_params(),
-        name=FLASH_KERNEL_NAMES[0],
-    )(qf, kf, vf, *mask_rows)
+        name=_kernel_names(plan)[0],
+    )(qf, kf, vf, *mask_rows, *sink_rows)
     return out.reshape(b, h, s, dv).transpose(0, 2, 1, 3), lse
 
 
-def _flash_bwd(q, k, v, mask, o, lse, g, block_q: int, block_k: int,
-               scale: float):
+def _flash_bwd(q, k, v, mask, sink, o, lse, g, block_q: int, block_k: int,
+               scale: float, window: Optional[int]):
+    """-> (dq, dk, dv, dsink); ``dsink`` None without a sink."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
     b, s, h, d = q.shape
     dv = v.shape[-1]
     key_mask = mask is not None
-    plan = flash_block_plan(s, block_q, block_k)
+    plan = flash_block_plan(s, block_q, block_k, window)
     qf, kf, vf = _heads_first(q), _heads_first(k), _heads_first(v)
     gf, of = _heads_first(g), _heads_first(o)
     # D_i = Σ_d dO_i ∘ O_i — one cheap elementwise pass in XLA
@@ -503,7 +630,7 @@ def _flash_bwd(q, k, v, mask, o, lse, g, block_q: int, block_k: int,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=kernels.interpret(),
         compiler_params=kernels.tpu_compiler_params(),
-        name=FLASH_KERNEL_NAMES[1],
+        name=_kernel_names(plan)[1],
     )(qf, kf, vf, *mask_rows, gf, lse, dd)
 
     dk, dv_ = pl.pallas_call(
@@ -532,42 +659,61 @@ def _flash_bwd(q, k, v, mask, o, lse, g, block_q: int, block_k: int,
                         pltpu.VMEM((block_k, dv), jnp.float32)],
         interpret=kernels.interpret(),
         compiler_params=kernels.tpu_compiler_params(),
-        name=FLASH_KERNEL_NAMES[2],
+        name=_kernel_names(plan)[2],
     )(kf, vf, qf, *([mask] if key_mask else []), gf,
       lse.reshape(b * h, 1, s), dd.reshape(b * h, 1, s))
 
+    dsink = None
+    if sink is not None:
+        # the sink's column of dS = P (dP - D) with dP = 0 (it has no
+        # value): -exp(sink - lse) D, summed over rows and the batch
+        p_sink = jnp.exp(sink.astype(jnp.float32)[None, :, None, None]
+                         - lse.reshape(b, h, s, 1))
+        dsink = -jnp.sum(p_sink * dd.reshape(b, h, s, 1),
+                         axis=(0, 2, 3)).astype(sink.dtype)
     unflat = lambda a: a.reshape(b, h, s, -1).transpose(0, 2, 1, 3)
-    return unflat(dq), unflat(dk), unflat(dv_)
+    return unflat(dq), unflat(dk), unflat(dv_), dsink
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, mask, block_q: int, block_k: int, scale: float):
-    return _flash_fwd(q, k, v, mask, block_q, block_k, scale)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash(q, k, v, mask, sink, block_q: int, block_k: int, scale: float,
+           window: Optional[int]):
+    return _flash_fwd(q, k, v, mask, sink, block_q, block_k, scale, window)[0]
 
 
-def _flash_fwd_rule(q, k, v, mask, block_q, block_k, scale):
-    out, lse = _flash_fwd(q, k, v, mask, block_q, block_k, scale)
-    return out, (q, k, v, mask, out, lse)
+def _flash_fwd_rule(q, k, v, mask, sink, block_q, block_k, scale, window):
+    out, lse = _flash_fwd(q, k, v, mask, sink, block_q, block_k, scale,
+                          window)
+    return out, (q, k, v, mask, sink, out, lse)
 
 
-def _flash_bwd_rule(block_q, block_k, scale, res, g):
-    q, k, v, mask, out, lse = res
-    dq, dk, dv = _flash_bwd(q, k, v, mask, out, lse, g, block_q, block_k,
-                            scale)
-    return dq, dk, dv, None if mask is None else jnp.zeros_like(mask)
+def _flash_bwd_rule(block_q, block_k, scale, window, res, g):
+    q, k, v, mask, sink, out, lse = res
+    dq, dk, dv, dsink = _flash_bwd(q, k, v, mask, sink, out, lse, g, block_q,
+                                   block_k, scale, window)
+    return (dq, dk, dv, None if mask is None else jnp.zeros_like(mask),
+            dsink)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def flash_causal_attention(q, k, v, block_q: int = 512, block_k: int = 512,
+def flash_causal_attention(q, k, v, block_q: Optional[int] = None,
+                           block_k: Optional[int] = None,
                            attn_mask: Optional[jnp.ndarray] = None,
-                           scale: Optional[float] = None):
+                           scale: Optional[float] = None,
+                           window: Optional[int] = None,
+                           sink: Optional[jnp.ndarray] = None):
     """Pallas flash attention, fused fwd+bwd (see module docstring).
     q/k: [b, s, h, d_qk], v: [b, s, h, d_v] -> [b, s, h, d_v]; one set of
     kernels serves ``d_qk == d_v`` (128) and latent attention's 192/128.
     ``attn_mask``: optional [b, s] key-padding mask (1 = real); ``scale``
-    multiplies the scores (default ``d_qk ** -0.5``).
+    multiplies the scores (default ``d_qk ** -0.5``). ``window``: query i
+    sees keys ``i - window < j <= i`` only (one that reaches every earlier
+    key is no window: the causal kernels run, under their names);
+    ``sink``: ``[h]`` logits, differentiable, a softmax column a head that
+    carries no value. Both are static properties of a call: without them
+    the kernels are the causal ones to the last instruction.
 
     Blocks are 512x512 where s allows (``_fit_block``). On the v5e (PR 31,
     kernels alone, bf16, ms a call forward / dQ / dK/dV; the kernels before
@@ -575,6 +721,10 @@ def flash_causal_attention(q, k, v, block_q: int = 512, block_k: int = 512,
     4.82 / 6.68), ``[8,1024,32,128]`` 0.93 / 1.10 / 1.38 (1.41 / 1.10 /
     2.04), ``[1,8192,2,128]`` 0.25 / 0.33 / 0.42 (0.38 / 0.33 / 0.53); dQ
     and dK/dV then sit at the MXU's own time for the blocks they compute.
+    A window call takes ``WINDOW_BLOCKS`` (256x256): at ``[1,4096,64,
+    192/128]``, window 128 with a sink (PR 34) 1.64 / 1.62 / 1.98 against
+    1.60 / 2.12 / 2.69 at 512x512 and 2.15 / 2.18 / 2.96 at 128x128; about
+    1 us of every grid step is its set-up, so smaller blocks stop paying.
 
     Sequences are padded up to a multiple of 128 so every Pallas block is
     lane/sublane-aligned on real TPU hardware (a non-power-of-two s like
@@ -586,6 +736,12 @@ def flash_causal_attention(q, k, v, block_q: int = 512, block_k: int = 512,
     b, s, h, d = q.shape
     scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
     s_pad = -(-s // 128) * 128
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"window {window}: a query sees itself at least")
+        window = int(window) if window < s else None
+    want_q, want_k = WINDOW_BLOCKS if window is not None else (512, 512)
+    block_q, block_k = block_q or want_q, block_k or want_k
     # the key mask is a static property of the call: without one, and with
     # nothing padded, the kernels carry no key-mask operand or arithmetic
     mask = None
@@ -600,10 +756,21 @@ def flash_causal_attention(q, k, v, block_q: int = 512, block_k: int = 512,
         mask = jnp.pad(mask, [(0, 0), (0, s_pad - s), (0, 0)])
     block_q, block_k = _fit_block(s_pad, block_q), _fit_block(s_pad, block_k)
     interior, diagonal, _ = flash_block_plan(s_pad, block_q, block_k).counts()
-    obs_metrics.record_flash_plan(interior / (interior + diagonal),
-                                  mask is not None)
-    out = _flash(q, k, v, mask, block_q, block_k, scale)
+    if window is None:
+        obs_metrics.record_flash_plan(interior / (interior + diagonal),
+                                      mask is not None)
+    if window is not None or sink is not None:
+        computed = sum(flash_block_plan(s_pad, block_q, block_k,
+                                        window).counts()[:2])
+        obs_metrics.record_flash_window(
+            window or 0, computed / (interior + diagonal), sink is not None)
+    out = _flash(q, k, v, mask, sink, block_q, block_k, scale, window)
     return out[:, :s] if s_pad != s else out
+
+
+# a window call's blocks where the caller names none (PERF.md section 6,
+# PR 34: the probe on the chip at [1,4096,64,192/128], window 128)
+WINDOW_BLOCKS = (256, 256)
 
 
 def _fit_block(s_pad: int, want: int) -> int:

@@ -70,9 +70,17 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
     leading dense layers, YaRN; and of the Bailing hybrid family, which
     names the experts ``num_experts`` / ``num_shared_experts`` /
     ``score_function``, routes with ``noaux_tc`` and mixes Kimi delta
-    attention layers with gated latent ones by ``layer_group_size``).
-    ``first_expert`` / ``experts_held`` say which routed experts this
-    expert-parallel rank holds (0 = all)."""
+    attention layers with gated latent ones by ``layer_group_size``; and of
+    ``mimo_v2_flash``: ``hybrid_layer_pattern`` (1 = a window layer) with
+    the window kind's ``sliding_window``, ``swa_num_key_value_heads``,
+    ``swa_rope_theta`` and the two ``add_*_attention_sink_bias`` flags,
+    ``head_dim`` / ``v_head_dim`` beside grouped-query heads,
+    ``partial_rotary_factor``, ``attention_value_scale``,
+    ``layernorm_epsilon``, ``moe_layer_freq`` as a list of zeros then ones).
+    ``sliding_window`` is read with a ``hybrid_layer_pattern`` only: alone
+    it is left unread, as it always was (full causal attention is the same
+    model up to that many positions). ``first_expert`` / ``experts_held``
+    say which routed experts this expert-parallel rank holds (0 = all)."""
     get = config.get
     scaling = get("rope_scaling")
     experts = get("n_routed_experts") or get("num_experts")
@@ -82,7 +90,15 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
         raise NotImplementedError(f"topk_method {method!r}")
     if experts and scoring != "sigmoid":
         raise NotImplementedError(f"scoring_func {scoring!r}")
-    if experts and get("moe_layer_freq", 1) != 1:
+    freq, leading_dense = get("moe_layer_freq", 1), None
+    if experts and isinstance(freq, (list, tuple)):
+        leading_dense = len(freq) - sum(freq)
+        if (len(freq) != int(config["num_hidden_layers"])
+                or list(freq) != [0] * leading_dense + [1] * sum(freq)):
+            raise NotImplementedError(
+                "moe_layer_freq as a list is read as zeros (dense layers) "
+                "then ones (expert layers), one entry a layer")
+    elif experts and freq != 1:
         raise NotImplementedError("moe_layer_freq != 1")
     if scaling and scaling.get("mscale", 1) != scaling.get("mscale_all_dim", 1):
         raise NotImplementedError("rotary cos/sin scale mscale / "
@@ -115,6 +131,32 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
             f"short_conv_kernel_size {get('short_conv_kernel_size')}: the "
             f"short convolution is built over {SHORT_CONV_TAPS} positions")
     noaux = method == "noaux_tc"
+    if get("attention_bias"):
+        raise NotImplementedError("attention_bias: the projections are "
+                                  "built without biases")
+    pattern = get("hybrid_layer_pattern")
+    if pattern is not None and len(pattern) != layers:
+        raise NotImplementedError(
+            f"hybrid_layer_pattern has {len(pattern)} entries for "
+            f"{layers} layers")
+    latent = bool(get("kv_lora_rank"))
+    # a stated head size beside grouped-query heads (latent attention has
+    # its own nope / rope / v sizes, linear attention ``linear_head_dim``)
+    quotient = int(config["hidden_size"]) // int(config["num_attention_heads"])
+    head_size = 0 if latent or group else int(get("head_dim") or 0)
+    if head_size == quotient:
+        head_size = 0
+    rotary_dim = 0
+    if get("partial_rotary_factor") is not None and not (latent or group):
+        per_head = head_size or quotient
+        rotary_dim = int(per_head * float(get("partial_rotary_factor")))
+        if rotary_dim % 2 or rotary_dim <= 0:
+            raise NotImplementedError(
+                f"partial_rotary_factor {get('partial_rotary_factor')} of a "
+                f"head of {per_head} gives {rotary_dim} rotary dims: rotary "
+                "turns pairs")
+    first_dense = (leading_dense if leading_dense is not None
+                   else int(get("first_k_dense_replace") or 0))
     return LLMConfig(
         vocab_size=int(config["vocab_size"]),
         hidden_size=int(config["hidden_size"]),
@@ -124,16 +166,26 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
         num_kv_heads=get("num_key_value_heads"),
         max_seq_len=int(max_seq_len),
         rope_theta=float(get("rope_theta", 10000.0)),
-        rms_eps=float(get("rms_norm_eps", 1e-6)),
+        rms_eps=float(get("rms_norm_eps", get("layernorm_epsilon", 1e-6))),
         dtype=dtype, attention_impl=attention_impl,
         tie_embeddings=bool(get("tie_word_embeddings", False)),
         rope_scaling=dict(scaling) if scaling else None,
-        routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+        routed_scaling_factor=float(get("routed_scaling_factor") or 1.0),
         norm_topk_prob=bool(get("norm_topk_prob", True)),
         **{k: int(get(k) or 0) for k in (
             "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
             "qk_rope_head_dim", "v_head_dim", "num_experts_per_tok",
-            "moe_intermediate_size", "first_k_dense_replace")},
+            "moe_intermediate_size")},
+        first_k_dense_replace=first_dense,
+        head_size=head_size, rotary_dim=rotary_dim,
+        attn_value_scale=float(get("attention_value_scale") or 1.0),
+        layer_pattern=None if pattern is None else tuple(
+            int(bool(p)) for p in pattern),
+        sliding_window=int(get("sliding_window") or 0) if pattern else 0,
+        window_kv_heads=int(get("swa_num_key_value_heads") or 0),
+        window_rope_theta=float(get("swa_rope_theta") or 0.0),
+        window_sink=bool(get("add_swa_attention_sink_bias")),
+        full_sink=bool(get("add_full_attention_sink_bias")),
         n_routed_experts=int(experts or 0),
         n_shared_experts=int(get("n_shared_experts")
                              or get("num_shared_experts") or 0),
@@ -193,7 +245,9 @@ class LLMBundle:
         return ((MOE_METRICS if cfg.n_routed_experts else ())
                 + (("moe_tokens_here",) if cfg.n_routed_experts
                    and cfg.n_group > 1 else ())
-                + (("kda_layer_steps",) if cfg.layer_group_size else ()))
+                + (("kda_layer_steps",) if cfg.layer_group_size else ())
+                + (("attn_window_layer_steps",) if cfg.window_layers
+                   else ()))
 
     def apply(self, params, x, rng=None, train=False, with_stats=False):
         """-> logits, or ``(logits, {name: sum})`` over
@@ -207,10 +261,11 @@ class LLMBundle:
         if not with_stats:
             return self.module.apply(variables, x, train=train, **kwargs)
         logits, state = self.module.apply(
-            variables, x, train=train, mutable=["moe_stats", "kda_stats"],
-            **kwargs)
+            variables, x, train=train,
+            mutable=["moe_stats", "kda_stats", "attn_stats"], **kwargs)
         sums = {}
-        for prefix, module in (("moe", "moe"), ("kda", "attn")):
+        for prefix, module in (("moe", "moe"), ("kda", "attn"),
+                               ("attn", "attn")):
             for layer in state.get(prefix + "_stats", {}).values():
                 for k, v in layer[module].items():
                     name = f"{prefix}_{k}"

@@ -31,9 +31,9 @@ PyTree = Any
 # q k v o; latent attention's q_a q_b kv_a kv_b o, or q where it has no
 # query latent; linear attention's q k v o and its decay projection f) + the
 # dense MLP and the shared expert (gate up down). Routed experts, the
-# router, the attention gates (g), linear attention's beta projection (b)
-# and its convolutions carry no "kernel" leaf under these names and stay
-# frozen without adapters.
+# router and its bias, a window layer's sink, the attention gates (g), linear
+# attention's beta projection (b) and its convolutions carry no "kernel" leaf
+# under these names and stay frozen without adapters.
 DEFAULT_TARGETS: Tuple[str, ...] = ("q", "k", "v", "o", "gate", "up", "down",
                                     "q_a", "q_b", "kv_a", "kv_b", "f")
 

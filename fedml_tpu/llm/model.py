@@ -17,6 +17,7 @@ in ``attention.py``.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Optional, Tuple
@@ -96,10 +97,29 @@ class LLMConfig:
     # a sigmoid gate a head on the attention's output, before ``o``
     # (latent attention; linear attention always has one)
     attn_output_gate: bool = False
+    # grouped-query attention with stated head sizes: a query and key head
+    # of ``head_size`` (0: ``hidden_size / num_heads``), a value head of
+    # ``v_head_dim`` (0: the key head's), rotary on the first ``rotary_dim``
+    # dims of a head (0: all of them), values times ``attn_value_scale``
+    head_size: int = 0
+    rotary_dim: int = 0
+    attn_value_scale: float = 1.0
+    # layers of two softmax kinds (``layer_pattern``: one entry a layer, 1 =
+    # window): a window layer's query sees its last ``sliding_window`` keys,
+    # itself among them, and has its own count of key-value heads and rotary
+    # base (0: the full layers'); ``window_sink`` / ``full_sink`` give a
+    # kind's softmax one learned logit a query head that takes mass and
+    # carries no value
+    layer_pattern: Optional[Tuple[int, ...]] = None
+    sliding_window: int = 0
+    window_kv_heads: int = 0
+    window_rope_theta: float = 0.0
+    window_sink: bool = False
+    full_sink: bool = False
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.head_size or self.hidden_size // self.num_heads
 
     @property
     def held(self) -> int:
@@ -114,17 +134,28 @@ class LLMConfig:
         return bool(self.layer_group_size) and \
             (layer + 1) % self.layer_group_size != 0
 
+    def is_window(self, layer: int) -> bool:
+        """Whether layer ``layer`` attends through the sliding window."""
+        return bool(self.layer_pattern) and bool(self.layer_pattern[layer])
+
+    @property
+    def window_layers(self) -> int:
+        return sum(map(bool, self.layer_pattern or ()))
+
     @property
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
     def param_count(self) -> int:
-        if self.kv_lora_rank or self.n_routed_experts or self.layer_group_size:
+        if (self.kv_lora_rank or self.n_routed_experts
+                or self.layer_group_size or self.layer_pattern
+                or self.head_size or self.v_head_dim):
             raise NotImplementedError(
                 "LLMConfig.param_count counts the dense grouped-query "
-                "decoder alone; a configuration with latent or linear "
-                "attention or experts is counted from its shapes under "
-                "benchmarks/flops/")
+                "decoder at head size hidden_size / num_heads alone; a "
+                "configuration with latent or linear attention, experts, "
+                "window layers or stated head sizes is counted from its "
+                "shapes under benchmarks/flops/")
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         per_layer = (h * h * 2 +                       # q, o
                      2 * h * self.kv_heads * self.head_dim +  # k, v
@@ -225,36 +256,74 @@ def _add_lora(x: jnp.ndarray, ys: dict, adapter, scale: float) -> dict:
 
 
 class Attention(nn.Module):
+    """Grouped-query softmax attention. ``window``: this layer is of the
+    configuration's window kind (its key-value heads, rotary base, sliding
+    window and sink flag). ``q = x W_q`` as heads of ``head_dim``, ``k``
+    likewise, ``v`` as heads of ``v_head_dim`` (default: the same size);
+    rotary on the first ``rotary_dim`` dims of every query and key head;
+    ``v`` times ``attn_value_scale``; scores at ``head_dim ** -0.5``; with a
+    sink, a frozen logit a query head beside the row's scores.
+
+    A cache path exists for full causal layers of one head size without a
+    sink (partial rotary and the value scale ride along); a window layer,
+    a sink or ``head_dim != v_head_dim`` are the training path's alone:
+    ``llm/kv_cache.py`` holds one shape of block for every layer and frees
+    none as a window slides."""
+
     cfg: LLMConfig
+    window: bool = False
 
     @nn.compact
     def __call__(self, x, positions, attn_mask=None, kv_view=None,
                  adapter=None, lora_scale: float = 1.0):
-        """Default path (``kv_view=None``): full causal self-attention,
-        returns ``(out, None)``. Cache path: ``kv_view = (k_all, v_all)``
-        position-ordered dense views ``[b, T, kv_heads, head_dim]`` of the
-        slot's cached keys/values; the current tokens' K/V are written
-        into the view at ``positions`` before attending, and returned as
-        ``(out, (k_cur, v_cur))`` for the caller to scatter into the
-        paged pool. ``adapter``: optional ``{q,k,v,o: {lora_a, lora_b}}``
-        low-rank side paths (per-slot when leaves carry a leading batch
-        axis)."""
+        """Default path (``kv_view=None``): causal self-attention over the
+        row, returns ``(out, None)``. Cache path: ``kv_view = (k_all,
+        v_all)`` position-ordered dense views ``[b, T, kv_heads,
+        head_dim]`` of the slot's cached keys/values; the current tokens'
+        K/V are written into the view at ``positions`` before attending,
+        and returned as ``(out, (k_cur, v_cur))`` for the caller to scatter
+        into the paged pool. ``adapter``: optional ``{q,k,v,o: {lora_a,
+        lora_b}}`` low-rank side paths (per-slot when leaves carry a
+        leading batch axis)."""
         cfg = self.cfg
         b, s, _ = x.shape
+        d_qk, d_v = cfg.head_dim, cfg.v_head_dim or cfg.head_dim
+        kv_heads = (cfg.window_kv_heads if self.window and cfg.window_kv_heads
+                    else cfg.kv_heads)
+        theta = (cfg.window_rope_theta if self.window and cfg.window_rope_theta
+                 else cfg.rope_theta)
+        window = cfg.sliding_window if self.window else None
+        has_sink = cfg.window_sink if self.window else cfg.full_sink
+        if kv_view is not None and (window or has_sink or d_qk != d_v):
+            raise NotImplementedError(
+                "attention with a sliding window, a sink or a value head "
+                "narrower than the key head has no cache path: "
+                "llm/kv_cache.py holds blocks of one shape for every layer "
+                "and keeps them all, where a window layer frees what slid "
+                "out")
         dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
             feats, axis=-1, use_bias=False, name=name,
             dtype=cfg.compute_dtype, param_dtype=jnp.float32)
 
         qkv = _add_lora(x, {
-            "q": dense((cfg.num_heads, cfg.head_dim), "q")(x),
-            "k": dense((cfg.kv_heads, cfg.head_dim), "k")(x),
-            "v": dense((cfg.kv_heads, cfg.head_dim), "v")(x),
+            "q": dense((cfg.num_heads, d_qk), "q")(x),
+            "k": dense((kv_heads, d_qk), "k")(x),
+            "v": dense((kv_heads, d_v), "v")(x),
         }, adapter, lora_scale)
         q, k, v = qkv["q"], qkv["k"], qkv["v"]
-        freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                cfg.rope_scaling)
-        q = _rope(q, positions, freq)
-        k = _rope(k, positions, freq)
+        rotary = cfg.rotary_dim or d_qk
+        freq = rope_frequencies(rotary, theta, cfg.rope_scaling)
+        if rotary == d_qk:
+            q = _rope(q, positions, freq)
+            k = _rope(k, positions, freq)
+        else:       # the head's first dims turn, the others pass
+            q, k = (jnp.concatenate(
+                [_rope(a[..., :rotary], positions, freq), a[..., rotary:]],
+                -1) for a in (q, k))
+        if cfg.attn_value_scale != 1.0:
+            v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
+        sink = (self.param("sink", nn.initializers.zeros, (cfg.num_heads,))
+                if has_sink else None)
 
         from .attention import cached_attention, causal_attention
         if kv_view is not None:
@@ -266,20 +335,28 @@ class Attention(nn.Module):
             bidx = jnp.arange(b)[:, None]
             k_all = k_all.at[bidx, positions].set(k, mode="drop")
             v_all = v_all.at[bidx, positions].set(v, mode="drop")
-            if cfg.kv_heads != cfg.num_heads:
-                rep = cfg.num_heads // cfg.kv_heads
+            if kv_heads != cfg.num_heads:
+                rep = cfg.num_heads // kv_heads
                 k_all = jnp.repeat(k_all, rep, axis=2)
                 v_all = jnp.repeat(v_all, rep, axis=2)
             out = cached_attention(q, k_all, v_all, positions)
         else:
             new_kv = None
-            if cfg.kv_heads != cfg.num_heads:
-                rep = cfg.num_heads // cfg.kv_heads
+            if kv_heads != cfg.num_heads:
+                rep = cfg.num_heads // kv_heads
                 k = jnp.repeat(k, rep, axis=2)
                 v = jnp.repeat(v, rep, axis=2)
-            out = causal_attention(q, k, v, impl=cfg.attention_impl,
-                                   attn_mask=attn_mask)
-        out = out.reshape(b, s, cfg.num_heads * cfg.head_dim)
+            extra = {"window": window} if window else {}
+            if has_sink:
+                extra["sink"] = sink.astype(jnp.float32)
+            with (jax.named_scope("attn.window") if window
+                  else contextlib.nullcontext()):
+                out = causal_attention(q, k, v, impl=cfg.attention_impl,
+                                       attn_mask=attn_mask, **extra)
+            if self.window:
+                self.sow("attn_stats", "window_layer_steps", jnp.float32(1),
+                         init_fn=lambda: jnp.float32(0), reduce_fn=jnp.add)
+        out = out.reshape(b, s, cfg.num_heads * d_v)
         y = nn.DenseGeneral(cfg.hidden_size, use_bias=False, name="o",
                             dtype=cfg.compute_dtype,
                             param_dtype=jnp.float32)(out)
@@ -466,7 +543,7 @@ class MLP(nn.Module):
 
 class MoE(nn.Module):
     """``shared(x) + sum over the top-k experts this rank holds of g_e
-    E_e(x)``: sigmoid scores in float32 over ALL ``n_routed_experts``, plain
+    E_e(x)`` (the routed sum alone where ``n_shared_experts`` is 0): sigmoid scores in float32 over ALL ``n_routed_experts``, plain
     top-k (group-limited with a score-correction bias where ``n_group`` or
     ``router_bias`` is set: ``moe.route``), the chosen scores normalised
     and scaled; the rank computes the
@@ -489,9 +566,11 @@ class MoE(nn.Module):
         w_gate = self.param("experts_gate", init, (held, h, width))
         w_up = self.param("experts_up", init, (held, h, width))
         w_down = self.param("experts_down", init, (held, width, h))
-        shared = MLP(cfg, width * cfg.n_shared_experts, name="shared")(
-            x, adapter=None if adapter is None else adapter.get("shared"),
-            lora_scale=lora_scale)
+        shared = None
+        if cfg.n_shared_experts:
+            shared = MLP(cfg, width * cfg.n_shared_experts, name="shared")(
+                x, adapter=None if adapter is None else adapter.get("shared"),
+                lora_scale=lora_scale)
         flat = x.reshape(b * s, h)
         with jax.named_scope("moe.route"):
             logits = nn.DenseGeneral(
@@ -517,25 +596,30 @@ class MoE(nn.Module):
         for k, v in stats.items():
             self.sow("moe_stats", k, v, init_fn=lambda: jnp.float32(0),
                      reduce_fn=jnp.add)
+        if shared is None:
+            return routed.reshape(b, s, h).astype(x.dtype)
         return shared + routed.reshape(b, s, h).astype(shared.dtype)
 
 
 class DecoderLayer(nn.Module):
     """One pre-norm layer; ``linear`` says whether its attention is the
-    linear (Kimi delta) kind, else the configuration does (grouped-query or
-    latent); ``sparse`` whether its feed-forward is the expert block
-    (``moe``) or the dense MLP (``mlp``)."""
+    linear (Kimi delta) kind, else the configuration does (grouped-query,
+    of the ``window`` kind or the full one, or latent); ``sparse`` whether
+    its feed-forward is the expert block (``moe``) or the dense MLP
+    (``mlp``)."""
 
     cfg: LLMConfig
     sparse: bool = False
     linear: bool = False
+    window: bool = False
 
     @nn.compact
     def __call__(self, x, positions, attn_mask=None, kv_view=None,
                  adapter=None, lora_scale: float = 1.0):
         adapter = adapter or {}
         attention = (LinearAttention if self.linear else
-                     LatentAttention if self.cfg.kv_lora_rank else Attention)
+                     LatentAttention if self.cfg.kv_lora_rank else
+                     functools.partial(Attention, window=self.window))
         a_out, new_kv = attention(self.cfg, name="attn")(
             RMSNorm(self.cfg.rms_eps, name="ln_attn")(x), positions,
             attn_mask, kv_view=kv_view, adapter=adapter.get("attn"),
@@ -587,7 +671,7 @@ class CausalLM(nn.Module):
             sparse = bool(cfg.n_routed_experts) and \
                 i >= cfg.first_k_dense_replace
             x, new_kv = DecoderLayer(cfg, sparse, cfg.is_linear(i),
-                                     name=f"layer_{i}")(
+                                     cfg.is_window(i), name=f"layer_{i}")(
                 x, positions, attn_mask,
                 kv_view=None if kv_view is None else kv_view[i],
                 adapter=None if adapters is None

@@ -7,8 +7,8 @@ files unedited and re-exports their tests (parametrisation and fixtures as
 the files have them) as ``<file>__<test>``, so each counts and names
 itself when it fails. The tests that run whole rounds or subprocesses
 (``test_correct.py``, ``test_rehearsal.py``, the first four of
-``test_axk1.py``, the first two of ``test_ling3.py``: over a minute each on
-a CPU) stay outside tier-1.
+``test_axk1.py``, the first two of ``test_ling3.py`` and of
+``test_mimo.py``: over a minute each on a CPU) stay outside tier-1.
 """
 
 import importlib
@@ -39,6 +39,11 @@ _TAKEN = {
     "test_ling3": ("test_work_functions_against_hand_counts",
                    "test_kda_reader_finds_kernels_by_name_only",
                    "test_tokens_here_reader_reads_the_counters_or_nothing"),
+    "test_mimo": ("test_manifest_entries_are_the_issues",
+                  "test_work_functions_against_hand_counts",
+                  "test_window_reader_finds_its_kernels_by_name_and_the_"
+                  "flash_reader_not",
+                  "test_block_share_reader_reads_the_gauge_or_nothing"),
 }
 _LEFT_OUT = {
     # pins PR 26's five entries as the LAST five of BENCHMARK.json's

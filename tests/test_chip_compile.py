@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 from fedml_tpu.core import kernels
 from fedml_tpu.core.kernels.conv_block import fused_block
 from fedml_tpu.llm import moe
-from fedml_tpu.llm.attention import FLASH_KERNEL_NAMES, flash_causal_attention
+from fedml_tpu.llm.attention import (FLASH_KERNEL_NAMES, WINDOW_KERNEL_NAMES,
+                                     flash_causal_attention)
 from fedml_tpu.llm.linear_attention import KDA_KERNEL_NAMES, kda_attention
 
 pytestmark = pytest.mark.pallas
@@ -72,6 +73,72 @@ def test_flash_unequal_head_sizes_compile_for_v5e(v5e):
     assert text.count("tpu_custom_call") == 3
     for name in FLASH_KERNEL_NAMES:
         assert name in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_window_kernels_with_a_sink_compile_for_v5e(v5e, dtype):
+    """The window cell's shape: 64 query heads of 192 / 128 on 8 key-value
+    heads repeated before the kernels, 4,096 positions, a window of 128 and
+    a sink: three kernels under their own names, and the sink's gradient
+    beside them, from what the backward already keeps."""
+    q = jax.ShapeDtypeStruct((1, 4096, 64, 192), dtype)
+    k = jax.ShapeDtypeStruct((1, 4096, 8, 192), dtype)
+    v = jax.ShapeDtypeStruct((1, 4096, 8, 128), dtype)
+    sink = jax.ShapeDtypeStruct((64,), jnp.float32)
+
+    def train(q, k, v, sink):
+        return jax.value_and_grad(
+            lambda q, k, v, sink: flash_causal_attention(
+                q, jnp.repeat(k, 8, axis=2), jnp.repeat(v, 8, axis=2),
+                window=128, sink=sink).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3))(q, k, v, sink)
+
+    text = _compile(train, v5e, q, k, v, sink).as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in WINDOW_KERNEL_NAMES:
+        assert name in text
+
+
+def _mosaic_kernels(text):
+    """The Mosaic modules of a lowered text, printed without locations (a
+    module's bytecode carries the file's path and line numbers)."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    out = []
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text):
+        ctx = jax_mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            out.append(ir.Module.parse(base64.b64decode(body)).operation
+                       .get_asm(enable_debug_info=False))
+    return out
+
+
+def test_the_causal_kernels_are_the_ones_before_the_window(v5e):
+    """A call without a window or a sink lowers to the kernels it did at
+    the parent of PR 34 (commit bbeebdd, this container's jax 0.9.0), to the
+    last instruction: the three Mosaic modules at the latent-attention
+    cells' shape, locations stripped. A change that means to alter the
+    causal kernels brings its new hashes."""
+    import hashlib
+
+    q = jax.ShapeDtypeStruct((1, 4096, 64, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 4096, 64, 128), jnp.bfloat16)
+    s = SingleDeviceSharding(v5e)
+    with kernels.compile_for_tpu():
+        text = jax.jit(lambda q, k, v: jax.value_and_grad(
+            lambda q, k, v: flash_causal_attention(
+                q, k, v, scale=0.13).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v), in_shardings=s,
+            out_shardings=s).lower(q, q, v).as_text()
+    got = [hashlib.sha256(m.encode()).hexdigest()[:16]
+           for m in _mosaic_kernels(text)]
+    assert got == ["3d52f89ca3d428ba", "3758e89e94394271",
+                   "6c1ea5df628634eb"], got
 
 
 def test_flash_with_a_key_mask_compiles_for_v5e(v5e):
