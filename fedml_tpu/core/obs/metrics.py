@@ -479,13 +479,14 @@ def record_hbm_peak(in_use_gb: float, reserved_gb: float) -> None:
 def record_moe_round(slots_held: float, load_max_sum: float,
                      layer_steps: float, expert_steps: float,
                      dropped: float, compact_steps: float = 0.0,
-                     tokens_here=None) -> None:
+                     tokens_here=None, kept_steps: float = 0.0) -> None:
     """Router load of one finished round, from the sums the round program
     itself reported over its expert layers and train steps: token-slots
     routed to the experts held here, the fullest held expert's tokens and
     the mean held expert's (a layer and step), slots that found no row,
-    passes whose row buffers had the compact size; under a group limit
-    also ``tokens_here``, the tokens with at least one held slot."""
+    passes whose row buffers had the compact size, passes whose backward
+    pass worked from the forward's gate and up products; under a group
+    limit also ``tokens_here``, the tokens with at least one held slot."""
     if not _cfg["enabled"]:
         return
     REGISTRY.gauge("fed_moe_slots_held",
@@ -514,6 +515,10 @@ def record_moe_round(slots_held: float, load_max_sum: float,
                      "of those, passes whose routing fit the compact row "
                      "buffers (llm/moe.py::compact_rows)"
                      ).inc(float(compact_steps))
+    REGISTRY.counter("fed_moe_kept_steps_total",
+                     "of those, passes that kept their gate and up products "
+                     "for the backward pass (no grouped product rebuilt)"
+                     ).inc(float(kept_steps))
     if tokens_here is not None:
         REGISTRY.counter("fed_moe_tokens_here_total",
                          "tokens with at least one slot on a held expert, "

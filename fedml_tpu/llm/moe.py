@@ -25,6 +25,13 @@ the pass under ``jax.lax.cond(rows in use <= compact_rows)``: the same
 rows in the same order through the same products at either size, so the
 result is the same to the last bit, and a routing that overflows the
 compact buffer takes the worst-case one and drops nothing.
+
+A train step runs six grouped products a layer where the rows fit the
+compact size: three forward, and three ``dx`` in the backward pass, which
+works from the gate and up products the forward pass kept (two ``[compact_rows,
+width]`` arrays a layer and step stay alive between the passes). At the
+worst-case size nothing of that size is kept: the backward pass rebuilds
+the two products there and runs the same pull-back (2 + 3).
 """
 
 from __future__ import annotations
@@ -42,7 +49,7 @@ GROUPED_KERNEL_NAMES = ("moe_grouped_fwd", "moe_grouped_dx")
 # what ``routed_experts`` reports of one pass through one expert layer; the
 # caller sums them over layers and steps
 STATS = ("slots_held", "load_max", "dropped", "layer_steps", "expert_steps",
-         "compact_steps")
+         "compact_steps", "kept_steps")
 
 
 class Plan(NamedTuple):
@@ -149,16 +156,6 @@ def place(p: Plan, rows: int, tile_m: int) -> Rows:
 
 # ------------------------------------------------------- tokens <-> rows ---
 
-@jax.custom_vjp
-def dispatch(x, row_token, slot_row, slot_held):
-    """x [T, H] -> rows [R, H]: each row reads its token."""
-    return x[row_token]
-
-
-def _dispatch_fwd(x, row_token, slot_row, slot_held):
-    return x[row_token], (slot_row, slot_held)
-
-
 def _slot_sum(rows, weights, slot_row):
     """[T, H] float32: sum over a token's k slots of weight * its row, one
     slot at a time (a [T, k, H] gather would be k times the activations).
@@ -170,50 +167,10 @@ def _slot_sum(rows, weights, slot_row):
                for j in range(slot_row.shape[1]))
 
 
-def _dispatch_bwd(res, g):
-    slot_row, slot_held = res
-    dx = _slot_sum(g, slot_held.astype(jnp.float32), slot_row)
-    return dx.astype(g.dtype), None, None, None
-
-
-dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@jax.custom_vjp
-def combine(rows, gates, row_token, row_used, slot_row, slot_held):
+def _combine(rows, gates, p: Plan):
     """rows [R, H], gates [T, k] -> [T, H] float32: each token's gated sum
     over its held slots."""
-    return _combine(rows, gates, slot_row, slot_held)
-
-
-def _combine(rows, gates, slot_row, slot_held):
-    return _slot_sum(rows, jnp.where(slot_held, gates, 0.0), slot_row)
-
-
-def _combine_fwd(rows, gates, row_token, row_used, slot_row, slot_held):
-    return (_combine(rows, gates, slot_row, slot_held),
-            (rows, gates, row_token, row_used, slot_row, slot_held))
-
-
-def _combine_bwd(res, g):
-    rows, gates, row_token, row_used, slot_row, slot_held = res
-    w = jnp.where(slot_held, gates, 0.0).reshape(-1)
-    row_w = jnp.zeros(rows.shape[:1], jnp.float32).at[
-        jnp.where(slot_held, slot_row, rows.shape[0]).reshape(-1)].set(
-        w, mode="drop")
-    d_rows = jnp.where(
-        row_used[:, None],
-        g.astype(rows.dtype)[row_token].astype(jnp.float32) * row_w[:, None],
-        0.0)
-    d_gates = jnp.stack(
-        [jnp.sum(rows[slot_row[:, j]].astype(jnp.float32) * g, -1)
-         for j in range(slot_row.shape[1])], -1)
-    d_gates = jnp.where(slot_held, d_gates, 0.0)
-    return (d_rows.astype(rows.dtype), d_gates.astype(gates.dtype),
-            None, None, None, None)
-
-
-combine.defvjp(_combine_fwd, _combine_bwd)
+    return _slot_sum(rows, jnp.where(p.slot_held, gates, 0.0), p.slot_row)
 
 
 # --------------------------------------------------------- grouped matmul ---
@@ -302,27 +259,6 @@ def _gmm(x, w, tile_group, num_tiles, tile_m: int, transpose_rhs: bool):
     )(tile_group, num_tiles, x, w)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def grouped_matmul(x, w, tile_group, num_tiles, tile_m: int):
-    """Rows [R, C] through FROZEN expert kernels ``w`` [G, C, O], the kernel
-    of each ``tile_m`` rows named by ``tile_group``. Differentiable in ``x``
-    alone: the experts train no weight here, so ``w`` gets no cotangent."""
-    return _gmm(x, w, tile_group, num_tiles, tile_m, False)
-
-
-def _grouped_fwd(x, w, tile_group, num_tiles, tile_m):
-    return (_gmm(x, w, tile_group, num_tiles, tile_m, False),
-            (w, tile_group, num_tiles))
-
-
-def _grouped_bwd(tile_m, res, g):
-    w, tile_group, num_tiles = res
-    return _gmm(g, w, tile_group, num_tiles, tile_m, True), None, None, None
-
-
-grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
-
-
 def tile_rows(slots: int) -> int:
     """Rows a tile: 256 at training sizes (an expert's few hundred tokens
     read its kernels once), a power of two down to 16 for small inputs."""
@@ -332,68 +268,139 @@ def tile_rows(slots: int) -> int:
     return t
 
 
+# --------------------------------------------------------------- the pass ---
+
+def _mm(r: Rows, p: Plan, tile_m: int, transpose_rhs: bool = False):
+    """The grouped product over the plan's tiles: forward, or with
+    ``transpose_rhs`` a cotangent pulled back to the product's rows."""
+    return lambda x, w: _gmm(x, w, r.tile_group, p.num_tiles, tile_m,
+                             transpose_rhs)
+
+
+def _gate_up(rows: int, tile_m: int, x, w_gate, w_up, p: Plan):
+    """The plan laid into ``rows`` rows, and the gate and up products of
+    the rows' tokens, ``a = xs W_gate`` and ``b = xs W_up`` [rows, width]."""
+    r = place(p, rows, tile_m)
+    xs = x[r.row_token]
+    mm = _mm(r, p, tile_m)
+    return r, mm(xs, w_gate), mm(xs, w_up)
+
+
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _pass_at(rows: int, tile_m: int, x, gates, w_gate, w_up, w_down, p: Plan):
-    """One pass of the held experts over a buffer of ``rows`` rows. Jitted,
-    as ``_pass_grads`` is, so that a model's expert layers of one shape
-    are traced and lowered once a size and direction, not once a layer."""
-    r = place(p, rows, tile_m)
-    xs = dispatch(x, r.row_token, p.slot_row, p.slot_held)
-    mm = functools.partial(grouped_matmul, tile_group=r.tile_group,
-                           num_tiles=p.num_tiles, tile_m=tile_m)
-    h = jax.nn.silu(mm(xs, w_gate)) * mm(xs, w_up)
-    return combine(mm(h, w_down), gates, r.row_token, r.row_used,
-                   p.slot_row, p.slot_held)
+    """One pass of the held experts over a buffer of ``rows`` rows -> the
+    [T, H] float32 result and the gate and up products it came from. Jitted,
+    as ``_pull_back`` and ``_rebuilt_pull_back`` are, so that a model's
+    expert layers of one shape are traced and lowered once a size and
+    direction, not once a layer."""
+    r, a, b = _gate_up(rows, tile_m, x, w_gate, w_up, p)
+    y = _mm(r, p, tile_m)(jax.nn.silu(a) * b, w_down)
+    return _combine(y, gates, p), a, b
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
-def _pass_grads(rows: int, tile_m: int, x, gates, w_gate, w_up, w_down,
-                p: Plan, g):
-    """The pass rebuilt, and ``g`` pulled back to ``x`` and ``gates``."""
-    return jax.vjp(lambda x, gates: _pass_at(
-        rows, tile_m, x, gates, w_gate, w_up, w_down, p), x, gates)[1](g)
+def _pull_back(rows: int, tile_m: int, a, b, g, gates, w_gate, w_up, w_down,
+               p: Plan):
+    """``g`` [T, H], the cotangent of the pass's result, pulled back to
+    ``x`` and ``gates`` from the gate and up products ``a``, ``b`` [rows,
+    width] alone: three ``dx`` products and no forward one. ``u``, the
+    UNWEIGHTED cotangent rows through ``W_down^T``, serves both gradients:
+    a slot's gate gets ``<h[row], u[row]>``, which is ``<y[row], g[token]>``
+    re-associated through ``W_down`` (so ``y`` is not rebuilt), and ``h``
+    gets ``u`` times the row's gate. Elementwise work and sums in float32,
+    the products' operands in the compute dtype; a row's numbers stay in
+    its row until ``_slot_sum`` and the gates' gather select the held
+    slots' rows, so an unwritten row reaches no sum."""
+    f32, dtype = jnp.float32, a.dtype
+    r = place(p, rows, tile_m)
+    mm_t = _mm(r, p, tile_m, transpose_rhs=True)
+    g_rows = jnp.where(r.row_used[:, None], g.astype(dtype)[r.row_token], 0)
+    u = mm_t(g_rows, w_down).astype(f32)
+    a, b = a.astype(f32), b.astype(f32)
+    sig = jax.nn.sigmoid(a)
+    d_gates = jnp.where(p.slot_held,
+                        jnp.sum(a * sig * b * u, -1)[p.slot_row], 0.0)
+    row_w = jnp.zeros((rows,), f32).at[
+        jnp.where(p.slot_held, p.slot_row, rows).reshape(-1)].set(
+        gates.astype(f32).reshape(-1), mode="drop")
+    d_h = u * row_w[:, None]
+    d_a = d_h * b * sig * (1 + a * (1 - sig))
+    d_b = d_h * a * sig
+    d_xs = mm_t(d_a.astype(dtype), w_gate) + mm_t(d_b.astype(dtype), w_up)
+    dx = _slot_sum(d_xs, p.slot_held.astype(f32), p.slot_row)
+    return dx.astype(dtype), d_gates.astype(gates.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _rebuilt_pull_back(rows: int, tile_m: int, x, g, gates, w_gate, w_up,
+                       w_down, p: Plan):
+    """The same pull-back behind a rebuild of the gate and up products: what
+    a pass that kept nothing runs."""
+    _, a, b = _gate_up(rows, tile_m, x, w_gate, w_up, p)
+    return _pull_back(rows, tile_m, a, b, g, gates, w_gate, w_up, w_down, p)
 
 
 def _fits(p: Plan, compact: int):
     return p.ends[-1] <= compact
 
 
-def _sized(fn, p: Plan, compact: int, full: int):
-    """``fn(rows)`` at the compact size where the rows in use fit it, at
-    the worst-case size where they do not: a conditional on a scalar of the
-    input, of which one branch runs; no conditional where the worst case
+def _sized(p: Plan, compact: int, full: int, small, worst):
+    """``small()`` where the rows in use fit the compact size, ``worst()``
+    where they do not: a conditional on a scalar of the input, of which one
+    branch runs; ``small()`` alone, and no conditional, where the worst case
     is no larger than the compact size."""
     if compact >= full:
-        return fn(full)
-    return jax.lax.cond(_fits(p, compact),
-                        lambda: fn(compact), lambda: fn(full))
+        return small()
+    return jax.lax.cond(_fits(p, compact), small, worst)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def expert_pass(compact: int, full: int, tile_m: int, x, gates, w_gate, w_up,
                 w_down, p: Plan):
-    """The pass at the size the plan needs. Differentiable in ``x`` and
-    ``gates`` alone: the experts' kernels are frozen and get no cotangent.
-    The backward pass keeps ``x``, ``gates`` and the plan, makes
-    the same choice of size again and rebuilds the row buffers there (no
-    row buffer stays alive between the passes, and the conditional hands
-    nothing of the untaken branch's size from one to the other)."""
-    return _sized(lambda rows: _pass_at(rows, tile_m, x, gates, w_gate, w_up,
-                                        w_down, p), p, compact, full)
+    """The pass at the size the plan needs, ``min(compact, full)`` rows or
+    ``full``. Differentiable in ``x`` and ``gates`` alone: the experts'
+    kernels are frozen and get no cotangent.
+
+    Under differentiation a pass at the smaller size keeps its gate and up
+    products (two ``[rows, width]`` arrays in the compute dtype) beside
+    ``x``, ``gates`` and the plan, and the backward pass pulls the
+    cotangent back from them (``_pull_back``: no forward product runs
+    again). A pass at the worst-case size keeps nothing of its own size
+    (the conditional's branches must agree in shape, so it hands on blanks
+    of the compact shape); its backward pass makes the same choice of size
+    again, rebuilds the two products there and runs the same pull-back."""
+    args = (x, gates, w_gate, w_up, w_down, p)
+    return _sized(p, compact, full,
+                  lambda: _pass_at(min(compact, full), tile_m, *args)[0],
+                  lambda: _pass_at(full, tile_m, *args)[0])
 
 
 def _expert_pass_fwd(compact, full, tile_m, x, gates, w_gate, w_up, w_down, p):
-    return (expert_pass(compact, full, tile_m, x, gates, w_gate, w_up,
-                        w_down, p), (x, gates, w_gate, w_up, w_down, p))
+    args = (x, gates, w_gate, w_up, w_down, p)
+    rows = min(compact, full)
+
+    def worst():
+        blank = jnp.zeros((rows, w_gate.shape[2]), x.dtype)
+        return _pass_at(full, tile_m, *args)[0], blank, blank
+
+    y, a, b = _sized(p, compact, full,
+                     lambda: _pass_at(rows, tile_m, *args), worst)
+    return y, args + (a, b)
 
 
 def _expert_pass_bwd(compact, full, tile_m, res, g):
-    x, gates, w_gate, w_up, w_down, p = res
-    # as jax.checkpoint does: the rebuilt pass must not be merged with the
-    # forward one, which would keep the forward's row buffers alive
-    x, gates, g = jax.lax.optimization_barrier((x, gates, g))
-    dx, d_gates = _sized(lambda rows: _pass_grads(
-        rows, tile_m, x, gates, w_gate, w_up, w_down, p, g), p, compact, full)
+    x, gates, w_gate, w_up, w_down, p, a, b = res
+    rest = (gates, w_gate, w_up, w_down, p)
+
+    def worst():
+        # as jax.checkpoint does: the rebuilt products must not be merged
+        # with the forward ones, which would keep those alive
+        x_again, g_again = jax.lax.optimization_barrier((x, g))
+        return _rebuilt_pull_back(full, tile_m, x_again, g_again, *rest)
+
+    dx, d_gates = _sized(
+        p, compact, full,
+        lambda: _pull_back(min(compact, full), tile_m, a, b, g, *rest), worst)
     return dx, d_gates, None, None, None, None
 
 
@@ -407,7 +414,9 @@ def routed_experts(x, gates, experts, w_gate, w_up, w_down,
     rank's frozen SwiGLU kernels ``w_gate`` / ``w_up`` [G, H, I], ``w_down``
     [G, I, H] -> ([T, H] float32, stats). The row buffers have
     ``compact_rows`` rows where this routing fits them and ``buffer_rows``
-    where it does not."""
+    where it does not; ``kept_steps`` is 1 where a backward pass will work
+    from the forward's products (at the compact size, and where the worst
+    case is no larger and ``compact_steps`` so reads 0)."""
     held = w_gate.shape[0]
     t, k = experts.shape
     tile_m = tile_rows(experts.size)
@@ -416,12 +425,14 @@ def routed_experts(x, gates, experts, w_gate, w_up, w_down,
     p = plan(experts, first_expert, held, tile_m)
     routed = expert_pass(compact, full, tile_m, x, gates, w_gate, w_up,
                          w_down, p)
-    fits = _fits(p, compact) & (compact < full)
+    kept = _fits(p, compact)          # always, where compact >= full
+    fits = kept & (compact < full)
     rows = jnp.where(fits, compact, full)
     dropped = jnp.sum(p.slot_held & (p.slot_row >= rows))
     stats = {"slots_held": jnp.sum(p.load).astype(jnp.float32),
              "load_max": jnp.max(p.load).astype(jnp.float32),
              "dropped": dropped.astype(jnp.float32),
              "layer_steps": jnp.float32(1), "expert_steps": jnp.float32(held),
-             "compact_steps": fits.astype(jnp.float32)}
+             "compact_steps": fits.astype(jnp.float32),
+             "kept_steps": kept.astype(jnp.float32)}
     return routed, stats

@@ -47,7 +47,8 @@ class CausalLMTrainer(TrainerSpec):
                 sums["moe_slots_held"], sums["moe_load_max"],
                 sums["moe_layer_steps"], sums["moe_expert_steps"],
                 sums["moe_dropped"], sums["moe_compact_steps"],
-                sums.get("moe_tokens_here"))
+                sums.get("moe_tokens_here"),
+                kept_steps=sums["moe_kept_steps"])
         if "kda_layer_steps" in sums:
             obs_metrics.record_kda_round(sums["kda_layer_steps"])
         if "attn_window_layer_steps" in sums:

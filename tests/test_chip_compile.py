@@ -178,32 +178,43 @@ def test_grouped_matmul_compiles_for_v5e(v5e, transpose):
 def test_expert_pass_keeps_its_conditional_on_the_v5e(v5e):
     """The expert layer at the benchmark's shapes (4,096 tokens top-8 of
     192, 12 held: worst case 35,840 rows, compact 7,168), forward and
-    backward: the compiled program holds one real conditional a direction
+    backward, the gates coming from the tokens through a router as the
+    model's do: the compiled program holds one real conditional a direction
     (not a select over both sizes), every grouped product of both sizes in
-    them (3 forward; 2 rebuilt and 3 ``dx`` backward, the ``down`` product
-    is rebuilt for the gates' gradient alone), and nothing of the
-    worst-case size outside their branches."""
+    them (3 forward at either size; backward 3 ``dx`` from the kept gate
+    and up products at the compact size, those two rebuilt and 3 ``dx`` at
+    the worst-case size), and nothing of the worst-case size outside their
+    branches. Its temporaries: 1,305,763,840 bytes as this was written
+    (1,498,645,504 while the backward pass rebuilt the whole pass and
+    gathered its result for the gates' gradient); held to that plus the
+    59 MB of the two kept ``[7168, 2048]`` arrays, so that one
+    ``[35840, 2048]`` array (147 MB) kept alive between the passes fails
+    here and not on the chip."""
     t, h, width, held, k, experts = 4096, 7168, 2048, 12, 8, 192
     assert moe.buffer_rows(t, k, held, 256) == 35840
     assert moe.compact_rows(t, k, held, experts, 256) == 7168
     x = jax.ShapeDtypeStruct((t, h), jnp.bfloat16)
-    logits = jax.ShapeDtypeStruct((t, experts), jnp.float32)
+    router = jax.ShapeDtypeStruct((h, experts), jnp.float32)
     w_in = jax.ShapeDtypeStruct((held, h, width), jnp.bfloat16)
     w_out = jax.ShapeDtypeStruct((held, width, h), jnp.bfloat16)
 
-    def step(x, logits, w_gate, w_up, w_down):
+    def step(x, router, w_gate, w_up, w_down):
         def loss(x):
-            gates, chosen = moe.route(logits, k, 2.5)
+            gates, chosen = moe.route(x.astype(jnp.float32) @ router, k, 2.5)
             y, stats = moe.routed_experts(x, gates, chosen, w_gate, w_up,
                                           w_down, 60, experts)
             return jnp.sum(jnp.sin(y)), stats
         return jax.grad(loss, has_aux=True)(x)
 
-    text = _compile(step, v5e, x, logits, w_in, w_in, w_out).as_text()
+    compiled = _compile(step, v5e, x, router, w_in, w_in, w_out)
+    text = compiled.as_text()
     assert text.count(" conditional(") == 2
-    assert text.count("tpu_custom_call") == 2 * 3 + 2 * (2 + 3)
+    assert text.count("tpu_custom_call") == 2 * 3 + 3 + (2 + 3)
     entry = text[text.index("\nENTRY "):]
     assert "[35840" not in entry and "[35840" in text
+    kept = 2 * 7168 * width * 2
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 1_305_763_840 + kept
 
 
 @pytest.mark.parametrize("dtype,heads", [(jnp.bfloat16, 32),

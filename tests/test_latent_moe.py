@@ -357,18 +357,110 @@ def conds(jaxpr):
 def row_buffers(jaxpr, cfg, into_conds=True):
     """Row counts of everything shaped like a row buffer that a jaxpr makes
     (``[R]``, ``[R, hidden]``, ``[R, expert width]``), its Pallas kernels'
-    insides left out."""
+    insides left out; without ``into_conds`` also its conditionals and
+    what they hand on."""
     widths = ((), (cfg["hidden_size"],), (cfg["moe_intermediate_size"],))
     rows = set()
     for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond" and not into_conds:
+            continue
         rows |= {v.aval.shape[0] for v in eqn.outvars if getattr(
             v.aval, "shape", ()) and v.aval.shape[1:] in widths}
-        if eqn.primitive.name == "pallas_call" or (
-                eqn.primitive.name == "cond" and not into_conds):
+        if eqn.primitive.name == "pallas_call":
             continue
         for sub in jax.core.jaxprs_in_params(eqn.params):
             rows |= row_buffers(sub, cfg, into_conds)
     return rows
+
+
+def grouped_calls(jaxpr):
+    """How often each grouped product is called in a jaxpr, the branches of
+    its conditionals included."""
+    count = dict.fromkeys(moe.GROUPED_KERNEL_NAMES, 0)
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            count[eqn.params["name"]] += 1
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            for name, n in grouped_calls(sub).items():
+                count[name] += n
+    return count
+
+
+@pytest.mark.parametrize("path,held_bias,n_experts,rebuilt,kept", [
+    ("compact", 0.0, 12, 0, 1.0), ("full", 8.0, 12, 2, 0.0),
+    ("one_path", 0.0, 4, 0, 1.0)])
+def test_the_backward_pass_rebuilds_no_product_it_kept(path, held_bias,
+                                                       n_experts, rebuilt,
+                                                       kept):
+    """The backward pass alone (``jax.vjp``'s pull-back, the residuals its
+    constants), in the branch that runs: three ``dx`` products, and a
+    forward product only where the pass took the worst-case size under the
+    conditional (gate and up, rebuilt); the untaken branch of a compact
+    pass is that rebuild. ``kept_steps`` says which it will be."""
+    c = PASS
+    x, logits, w = pass_inputs(held_bias)
+    logits = logits[:, :n_experts] if n_experts < 12 else logits
+    first = 0 if n_experts < 12 else c["first"]
+    gates, chosen = moe.route(logits, c["k"], 2.5)
+    y, pull, stats = jax.vjp(
+        lambda x, gates: moe.routed_experts(x, gates, chosen, *w, first,
+                                            n_experts), x, gates,
+        has_aux=True)
+    jaxpr = jax.make_jaxpr(pull)(jnp.ones_like(y)).jaxpr
+    fwd_name, dx_name = moe.GROUPED_KERNEL_NAMES
+    if path == "one_path":
+        assert not conds(jaxpr)
+        assert grouped_calls(jaxpr) == {fwd_name: 0, dx_name: 3}
+    else:
+        (cond,) = conds(jaxpr)
+        worst, small = (grouped_calls(b.jaxpr)
+                        for b in cond.params["branches"])
+        assert worst == {fwd_name: 2, dx_name: 3}
+        assert small == {fwd_name: 0, dx_name: 3}
+        assert grouped_calls(jaxpr) == {fwd_name: 2, dx_name: 6}
+        runs = small if path == "compact" else worst
+        assert runs[fwd_name] == rebuilt
+    assert float(stats["kept_steps"]) == kept
+    assert float(stats["compact_steps"]) == (1 if path == "compact" else 0)
+
+
+@pytest.mark.parametrize("sizes", ["compact", "full", "one_path"])
+def test_the_pull_back_reads_no_unwritten_row(sizes):
+    """A skewed routing: 40 slots on the first held expert, none on the
+    second, 20 on the third, so 5 of the compact buffers' 7 tiles (and of
+    the worst case's 11) are in use and the interpreted kernels leave the
+    others' rows NaN, in the kept products too. ``dx`` and ``d_gates`` of
+    the pull-back, from kept products and behind the rebuild, against plain
+    autodiff of the dense loop: no unwritten row reaches a sum."""
+    c = PASS
+    x, _, w = pass_inputs()
+    chosen = np.zeros((c["t"], c["k"]), np.int32)     # expert 0: not held
+    chosen[:40, 0], chosen[10:30, 1] = c["first"], c["first"] + 2
+    chosen = jnp.asarray(chosen)
+    gates = jax.random.uniform(jax.random.PRNGKey(4), chosen.shape) + 0.5
+    p = moe.plan(chosen, c["first"], c["held"], c["tile_m"])
+    compact, full = pass_sizes()
+    assert np.asarray(p.load).tolist() == [40, 0, 20]
+    assert int(p.num_tiles[0]) == 5 < compact // c["tile_m"]
+    _, a, _ = moe._pass_at(compact, c["tile_m"], x, gates, *w, p)
+    assert np.isnan(np.asarray(a)[5 * c["tile_m"]:]).any()
+    size = {"compact": (compact, full), "full": (c["tile_m"], full),
+            "one_path": (full, full)}[sizes]
+
+    def mine(x, gates):
+        return jnp.sum(jnp.sin(moe.expert_pass(*size, c["tile_m"], x, gates,
+                                               *w, p)))
+
+    def plain(x, gates):
+        return jnp.sum(jnp.sin(dense_loop(x, gates, chosen, w, c["first"])))
+
+    got = jax.grad(mine, argnums=(0, 1))(x, gates)
+    want = jax.grad(plain, argnums=(0, 1))(x, gates)
+    assert np.isfinite(np.asarray(got[0])).all()
+    assert np.isfinite(np.asarray(got[1])).all()
+    assert rel(got[0], want[0]) < 1e-5 and rel(got[1], want[1]) < 1e-5
+    assert not np.asarray(got[1])[~np.asarray(p.slot_held)].any()
 
 
 def test_the_train_step_holds_one_conditional_a_layer_and_direction():
@@ -376,9 +468,12 @@ def test_the_train_step_holds_one_conditional_a_layer_and_direction():
     held: worst case 160 rows, compact 128): each of the 2 expert layers is
     a real conditional on an unbatched scalar in the forward and in the
     backward pass (a ``select`` over both branches would leave no ``cond``
-    in the jaxpr), no conditional hands on anything of a row buffer's
-    size, the compact branch makes nothing of the worst-case size, and
-    nothing outside the conditionals has either size."""
+    in the jaxpr), nothing of the worst-case size leaves a conditional,
+    the forward one hands on exactly two ``[compact, width]`` arrays (the
+    gate and up products the backward pass works from) and the backward
+    one nothing of a row buffer's size, the compact branch makes nothing
+    of the worst-case size, and nothing outside the conditionals makes an
+    array of either size."""
     cfg = small_cfg()
     base, lora = weights(cfg)
     tok = tokens(cfg)
@@ -395,10 +490,11 @@ def test_the_train_step_holds_one_conditional_a_layer_and_direction():
     jaxpr = jax.make_jaxpr(step)(lora).jaxpr
     found = conds(jaxpr)
     assert len(found) == 2 * 2
-    for eqn in found:
+    kept = [(compact, cfg["moe_intermediate_size"])] * 2
+    for eqn, hands_on in zip(found, [kept, kept, [], []]):
         assert eqn.invars[0].aval.shape == ()            # the predicate
-        assert not {full, compact} & {
-            v.aval.shape[0] for v in eqn.outvars if v.aval.shape}
+        assert [v.aval.shape for v in eqn.outvars
+                if v.aval.shape[:1] in ((full,), (compact,))] == hands_on
         worst, small = (b.jaxpr for b in eqn.params["branches"])
         assert full in row_buffers(worst, cfg)
         assert compact in row_buffers(small, cfg)
@@ -532,9 +628,11 @@ def test_round_counters_reach_the_registry_from_the_round_program():
     assert float(m0["moe_expert_steps"]) == 8.0 * cfg["n_routed_experts"]
     assert 0 < float(m0["moe_slots_held"]) <= 8 * 16 * 3
     assert 0 <= float(m0["moe_compact_steps"]) <= 8.0
+    assert float(m0["moe_kept_steps"]) == float(m0["moe_compact_steps"])
     rounds_before = REGISTRY.counter("fed_moe_rounds_total").value()
     passes_before = REGISTRY.counter("fed_moe_layer_steps_total").value()
     compact_before = REGISTRY.counter("fed_moe_compact_steps_total").value()
+    kept_before = REGISTRY.counter("fed_moe_kept_steps_total").value()
     m1 = sim.run_round(1, hyper)   # records round 0's sums, now ready
     assert REGISTRY.gauge("fed_moe_slots_held").value() == float(
         m0["moe_slots_held"])
@@ -554,6 +652,9 @@ def test_round_counters_reach_the_registry_from_the_round_program():
     assert REGISTRY.counter("fed_moe_compact_steps_total").value() == \
         compact_before + float(m0["moe_compact_steps"]) + float(
             m1["moe_compact_steps"])
+    assert REGISTRY.counter("fed_moe_kept_steps_total").value() == \
+        kept_before + float(m0["moe_kept_steps"]) + float(
+            m1["moe_kept_steps"])
     # with the registry off nothing is held, so nothing is read back
     obs_metrics.set_enabled(False)
     try:
