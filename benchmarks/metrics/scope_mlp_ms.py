@@ -1,0 +1,12 @@
+"""``scope_mlp_ms``: device milliseconds a traced round in the scope
+``mlp``: the dense SwiGLU MLP and the shared expert, the ``lora`` side paths
+inside them left out.
+An operation counts under its innermost scope only
+(``harness/scope_time.py``). Source: device trace. Moves ``round_s``. Reads
+nothing without the program's scope table or a trace."""
+
+from harness import scope_time
+
+
+def read(ctx):
+    return scope_time.ms_a_round(ctx, "mlp")

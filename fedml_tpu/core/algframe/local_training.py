@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ..obs.scopes import scope
 from .types import ClientData, TrainHyper
 from .client_trainer import TrainerSpec
 
@@ -94,17 +95,21 @@ def run_local_sgd(
 
     def body(carry):
         t, params, opt_state, rng, metrics = carry
-        rng, step_rng = jax.random.split(rng)
-        idx = epoch_order(t // denom)[t % denom]
-        batch = {"x": cdata.x[idx], "y": cdata.y[idx], "mask": cdata.mask[idx]}
-        (loss, aux), grads = jax.value_and_grad(spec.loss, has_aux=True)(
-            params, batch, step_rng)
-        if grad_transform is not None:
-            grads = grad_transform(grads, params, ctx)
-        updates, opt_state = inner_opt.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        metrics = {k: metrics[k] + aux[k].astype(jnp.float32)
-                   for k in zero_metrics}
+        with scope("local.batch"):
+            rng, step_rng = jax.random.split(rng)
+            idx = epoch_order(t // denom)[t % denom]
+            batch = {"x": cdata.x[idx], "y": cdata.y[idx],
+                     "mask": cdata.mask[idx]}
+        with scope("local.grad"):
+            (loss, aux), grads = jax.value_and_grad(spec.loss, has_aux=True)(
+                params, batch, step_rng)
+        with scope("local.update"):
+            if grad_transform is not None:
+                grads = grad_transform(grads, params, ctx)
+            updates, opt_state = inner_opt.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            metrics = {k: metrics[k] + aux[k].astype(jnp.float32)
+                       for k in zero_metrics}
         return (t + 1, params, opt_state, rng, metrics)
 
     (_, params, opt_state, _, metrics) = jax.lax.while_loop(
@@ -140,8 +145,9 @@ def full_batch_grad_sum(
     def body(carry, inp):
         i, batch = inp
         acc_g, acc_m = carry
-        grads, aux = jax.grad(spec.loss, has_aux=True)(
-            params, batch, jax.random.fold_in(rng, i))
+        with scope("local.grad"):
+            grads, aux = jax.grad(spec.loss, has_aux=True)(
+                params, batch, jax.random.fold_in(rng, i))
         n = aux["count"]
         acc_g = jax.tree_util.tree_map(
             lambda a, g: a + g * n.astype(g.dtype), acc_g, grads)

@@ -17,7 +17,11 @@
    benchmark (``benchmarks/harness/peaks.json``, ``benchmarks/flops/``).
 
 :mod:`.recompile` names the argument shapes that moved when a dispatch
-compiles past its program's first compile. ``scripts/trace_report.py``
+compiles past its program's first compile. :mod:`.scopes` holds the closed
+vocabulary of ``jax.named_scope`` names the round program is written under
+and makes, from a program's own compiled text, the table that says which
+scope each HLO instruction belongs to: joined to a profiler trace it gives
+device time by scope (``benchmarks/tools/scope_table.py``). ``scripts/trace_report.py``
 reads a run's JSONL and prints the per-round critical path.
 :mod:`.schema` is the one table every record kind validates against.
 
@@ -29,7 +33,8 @@ init still traces.
 
 from __future__ import annotations
 
-from . import flight, metrics, profiler, recompile, schema, trace  # noqa: F401
+from . import (flight, metrics, profiler, recompile, schema, scopes,  # noqa: F401
+               trace)
 from .flight import FlightRecorder, Watchdog                    # noqa: F401
 from .metrics import REGISTRY                                   # noqa: F401
 from .trace import (NOOP_SPAN, SpanContext, add_event, current_span,  # noqa: F401

@@ -17,7 +17,6 @@ in ``attention.py``.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 from typing import Any, Optional, Tuple
@@ -26,6 +25,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..core.obs.scopes import scope
 
 PyTree = Any
 
@@ -240,18 +241,19 @@ def _add_lora(x: jnp.ndarray, ys: dict, adapter, scale: float) -> dict:
     names = [n for n in ys if adapter is not None and n in adapter]
     if not names:
         return ys
-    a = jnp.concatenate([adapter[n]["lora_a"] for n in names], axis=-1)
-    xf = x.astype(jnp.float32)
-    per_slot = a.ndim == 3
-    h = jnp.einsum("bsd,bdr->bsr", xf, a) if per_slot else xf @ a
-    out, lo = dict(ys), 0
-    for n in names:
-        bb = adapter[n]["lora_b"]
-        hn = h[..., lo:lo + bb.shape[-2]]
-        lo += bb.shape[-2]
-        delta = (jnp.einsum("bsr,bro->bso", hn, bb) if per_slot
-                 else hn @ bb) * scale
-        out[n] = ys[n] + delta.reshape(ys[n].shape).astype(ys[n].dtype)
+    with scope("lora"):
+        a = jnp.concatenate([adapter[n]["lora_a"] for n in names], axis=-1)
+        xf = x.astype(jnp.float32)
+        per_slot = a.ndim == 3
+        h = jnp.einsum("bsd,bdr->bsr", xf, a) if per_slot else xf @ a
+        out, lo = dict(ys), 0
+        for n in names:
+            bb = adapter[n]["lora_b"]
+            hn = h[..., lo:lo + bb.shape[-2]]
+            lo += bb.shape[-2]
+            delta = (jnp.einsum("bsr,bro->bso", hn, bb) if per_slot
+                     else hn @ bb) * scale
+            out[n] = ys[n] + delta.reshape(ys[n].shape).astype(ys[n].dtype)
     return out
 
 
@@ -349,10 +351,8 @@ class Attention(nn.Module):
             extra = {"window": window} if window else {}
             if has_sink:
                 extra["sink"] = sink.astype(jnp.float32)
-            with (jax.named_scope("attn.window") if window
-                  else contextlib.nullcontext()):
-                out = causal_attention(q, k, v, impl=cfg.attention_impl,
-                                       attn_mask=attn_mask, **extra)
+            out = causal_attention(q, k, v, impl=cfg.attention_impl,
+                                   attn_mask=attn_mask, **extra)
             if self.window:
                 self.sow("attn_stats", "window_layer_steps", jnp.float32(1),
                          init_fn=lambda: jnp.float32(0), reduce_fn=jnp.add)
@@ -411,38 +411,37 @@ class LinearAttention(nn.Module):
             dtype=cfg.compute_dtype, param_dtype=jnp.float32)
         heads = lambda a: a.reshape(b, s, nh, d)  # noqa: E731
 
-        with jax.named_scope("attn.linear"):
-            # the decay projection leaves its product in float32: the gate
-            # multiplies it by up to exp(A_log) = 16 inside a sigmoid
-            wide = nn.DenseGeneral(
-                nh * d, use_bias=False, name="f", dtype=cfg.compute_dtype,
-                param_dtype=jnp.float32, dot_general=functools.partial(
-                    jax.lax.dot_general,
-                    preferred_element_type=jnp.float32))
-            ys = _add_lora(x, {**{n: dense(nh * d, n)(x) for n in "qkv"},
-                               "f": wide(x)}, adapter, lora_scale)
-            q, k, v = (heads(nn.silu(short_conv(ys[n], self.param(
-                f"conv_{n}", nn.initializers.lecun_normal(),
-                (taps, nh * d))))) for n in "qkv")
-            q, k = (_l2_normalised(a) for a in (q, k))
-            a_log = self.param("A_log", nn.initializers.zeros, (nh,))
-            dt_bias = self.param("dt_bias", nn.initializers.zeros, (nh * d,))
-            g = cfg.kda_lower_bound * jax.nn.sigmoid(
-                jnp.exp(a_log.astype(jnp.float32))[:, None]
-                * heads(ys["f"].astype(jnp.float32)
-                        + dt_bias.astype(jnp.float32)))
-            beta = jax.nn.sigmoid(dense(nh, "b")(x).astype(jnp.float32))
-            if attn_mask is not None:
-                keep = attn_mask.astype(jnp.float32)[:, :, None]
-                g, beta = g * keep[..., None], beta * keep
-            out = kda_attention(_scaled(q, d ** -0.5), k, v, g, beta,
-                                impl=cfg.attention_impl)
-            out = RMSNorm(cfg.rms_eps, name="o_norm")(out)
-            out = _head_gate(out, dense(nh, "g")(x)).reshape(b, s, nh * d)
-            y = dense(cfg.hidden_size, "o")(out)
-            self.sow("kda_stats", "layer_steps", jnp.float32(1),
-                     init_fn=lambda: jnp.float32(0), reduce_fn=jnp.add)
-            return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], None
+        # the decay projection leaves its product in float32: the gate
+        # multiplies it by up to exp(A_log) = 16 inside a sigmoid
+        wide = nn.DenseGeneral(
+            nh * d, use_bias=False, name="f", dtype=cfg.compute_dtype,
+            param_dtype=jnp.float32, dot_general=functools.partial(
+                jax.lax.dot_general,
+                preferred_element_type=jnp.float32))
+        ys = _add_lora(x, {**{n: dense(nh * d, n)(x) for n in "qkv"},
+                           "f": wide(x)}, adapter, lora_scale)
+        q, k, v = (heads(nn.silu(short_conv(ys[n], self.param(
+            f"conv_{n}", nn.initializers.lecun_normal(),
+            (taps, nh * d))))) for n in "qkv")
+        q, k = (_l2_normalised(a) for a in (q, k))
+        a_log = self.param("A_log", nn.initializers.zeros, (nh,))
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (nh * d,))
+        g = cfg.kda_lower_bound * jax.nn.sigmoid(
+            jnp.exp(a_log.astype(jnp.float32))[:, None]
+            * heads(ys["f"].astype(jnp.float32)
+                    + dt_bias.astype(jnp.float32)))
+        beta = jax.nn.sigmoid(dense(nh, "b")(x).astype(jnp.float32))
+        if attn_mask is not None:
+            keep = attn_mask.astype(jnp.float32)[:, :, None]
+            g, beta = g * keep[..., None], beta * keep
+        out = kda_attention(_scaled(q, d ** -0.5), k, v, g, beta,
+                            impl=cfg.attention_impl)
+        out = RMSNorm(cfg.rms_eps, name="o_norm")(out)
+        out = _head_gate(out, dense(nh, "g")(x)).reshape(b, s, nh * d)
+        y = dense(cfg.hidden_size, "o")(out)
+        self.sow("kda_stats", "layer_steps", jnp.float32(1),
+                 init_fn=lambda: jnp.float32(0), reduce_fn=jnp.add)
+        return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], None
 
 
 def _l2_normalised(a):
@@ -481,43 +480,42 @@ class LatentAttention(nn.Module):
             feats, axis=-1, use_bias=False, name=name,
             dtype=cfg.compute_dtype, param_dtype=jnp.float32)
 
-        with jax.named_scope("attn.latent"):
-            first = ({"q_a": dense(cfg.q_lora_rank, "q_a")(x)}
-                     if cfg.q_lora_rank
-                     else {"q": dense((nh, nope + rope), "q")(x)})
-            down = _add_lora(x, {
-                **first, "kv_a": dense(cfg.kv_lora_rank + rope, "kv_a")(x),
-            }, adapter, lora_scale)
-            c_q = (RMSNorm(cfg.rms_eps, name="q_norm")(down["q_a"])
-                   if cfg.q_lora_rank else None)
-            c_kv = RMSNorm(cfg.rms_eps, name="kv_norm")(
-                down["kv_a"][..., :cfg.kv_lora_rank])
-            k_r = down["kv_a"][..., cfg.kv_lora_rank:]
-            q = (_add_lora(c_q, {"q_b": dense((nh, nope + rope), "q_b")(c_q)},
-                           adapter, lora_scale)["q_b"]
-                 if cfg.q_lora_rank else down["q"])
-            kv = _add_lora(c_kv, {"kv_b": dense((nh, nope + dv), "kv_b")(c_kv)},
-                           adapter, lora_scale)["kv_b"]
-            freq = rope_frequencies(rope, cfg.rope_theta, cfg.rope_scaling)
-            q = jnp.concatenate(
-                [q[..., :nope], _rope(q[..., nope:], positions, freq)], -1)
-            k_r = _rope(k_r[:, :, None, :], positions, freq)
-            k = jnp.concatenate(
-                [kv[..., :nope], jnp.broadcast_to(k_r, (b, s, nh, rope))], -1)
-            m = yarn_mscale(cfg.rope_scaling, "mscale_all_dim")
+        first = ({"q_a": dense(cfg.q_lora_rank, "q_a")(x)}
+                 if cfg.q_lora_rank
+                 else {"q": dense((nh, nope + rope), "q")(x)})
+        down = _add_lora(x, {
+            **first, "kv_a": dense(cfg.kv_lora_rank + rope, "kv_a")(x),
+        }, adapter, lora_scale)
+        c_q = (RMSNorm(cfg.rms_eps, name="q_norm")(down["q_a"])
+               if cfg.q_lora_rank else None)
+        c_kv = RMSNorm(cfg.rms_eps, name="kv_norm")(
+            down["kv_a"][..., :cfg.kv_lora_rank])
+        k_r = down["kv_a"][..., cfg.kv_lora_rank:]
+        q = (_add_lora(c_q, {"q_b": dense((nh, nope + rope), "q_b")(c_q)},
+                       adapter, lora_scale)["q_b"]
+             if cfg.q_lora_rank else down["q"])
+        kv = _add_lora(c_kv, {"kv_b": dense((nh, nope + dv), "kv_b")(c_kv)},
+                       adapter, lora_scale)["kv_b"]
+        freq = rope_frequencies(rope, cfg.rope_theta, cfg.rope_scaling)
+        q = jnp.concatenate(
+            [q[..., :nope], _rope(q[..., nope:], positions, freq)], -1)
+        k_r = _rope(k_r[:, :, None, :], positions, freq)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_r, (b, s, nh, rope))], -1)
+        m = yarn_mscale(cfg.rope_scaling, "mscale_all_dim")
 
-            from .attention import causal_attention
-            out = causal_attention(q, k, kv[..., nope:],
-                                   impl=cfg.attention_impl,
-                                   attn_mask=attn_mask,
-                                   scale=(nope + rope) ** -0.5 * m * m)
-            if cfg.attn_output_gate:
-                out = _head_gate(out, dense(nh, "g")(x))
-            out = out.reshape(b, s, nh * dv)
-            y = nn.DenseGeneral(cfg.hidden_size, use_bias=False, name="o",
-                                dtype=cfg.compute_dtype,
-                                param_dtype=jnp.float32)(out)
-            return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], None
+        from .attention import causal_attention
+        out = causal_attention(q, k, kv[..., nope:],
+                               impl=cfg.attention_impl,
+                               attn_mask=attn_mask,
+                               scale=(nope + rope) ** -0.5 * m * m)
+        if cfg.attn_output_gate:
+            out = _head_gate(out, dense(nh, "g")(x))
+        out = out.reshape(b, s, nh * dv)
+        y = nn.DenseGeneral(cfg.hidden_size, use_bias=False, name="o",
+                            dtype=cfg.compute_dtype,
+                            param_dtype=jnp.float32)(out)
+        return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], None
 
 
 class MLP(nn.Module):
@@ -532,13 +530,15 @@ class MLP(nn.Module):
             feats, use_bias=False, name=name, dtype=cfg.compute_dtype,
             param_dtype=jnp.float32)
 
-        ys = _add_lora(x, {
-            "gate": dense(width, "gate")(x),
-            "up": dense(width, "up")(x),
-        }, adapter, lora_scale)
-        act = nn.silu(ys["gate"]) * ys["up"]
-        return _add_lora(act, {"down": dense(cfg.hidden_size, "down")(act)},
-                         adapter, lora_scale)["down"]
+        with scope("mlp"):
+            ys = _add_lora(x, {
+                "gate": dense(width, "gate")(x),
+                "up": dense(width, "up")(x),
+            }, adapter, lora_scale)
+            act = nn.silu(ys["gate"]) * ys["up"]
+            return _add_lora(
+                act, {"down": dense(cfg.hidden_size, "down")(act)},
+                adapter, lora_scale)["down"]
 
 
 class MoE(nn.Module):
@@ -572,7 +572,7 @@ class MoE(nn.Module):
                 x, adapter=None if adapter is None else adapter.get("shared"),
                 lora_scale=lora_scale)
         flat = x.reshape(b * s, h)
-        with jax.named_scope("moe.route"):
+        with scope("moe.route"):
             logits = nn.DenseGeneral(
                 cfg.n_routed_experts, use_bias=False, name="router",
                 dtype=jnp.float32, param_dtype=jnp.float32)(
@@ -584,7 +584,7 @@ class MoE(nn.Module):
                                       cfg.routed_scaling_factor,
                                       cfg.norm_topk_prob, bias, cfg.n_group,
                                       cfg.topk_group)
-        with jax.named_scope("moe.experts"):
+        with scope("moe.experts"):
             routed, stats = moe.routed_experts(
                 flat, gates, chosen, w_gate, w_up, w_down, cfg.first_expert,
                 cfg.n_routed_experts)
@@ -617,19 +617,25 @@ class DecoderLayer(nn.Module):
     def __call__(self, x, positions, attn_mask=None, kv_view=None,
                  adapter=None, lora_scale: float = 1.0):
         adapter = adapter or {}
-        attention = (LinearAttention if self.linear else
-                     LatentAttention if self.cfg.kv_lora_rank else
-                     functools.partial(Attention, window=self.window))
-        a_out, new_kv = attention(self.cfg, name="attn")(
-            RMSNorm(self.cfg.rms_eps, name="ln_attn")(x), positions,
-            attn_mask, kv_view=kv_view, adapter=adapter.get("attn"),
-            lora_scale=lora_scale)
-        h = x + a_out
+        attention, kind = (
+            (LinearAttention, "attn.linear") if self.linear else
+            (LatentAttention, "attn.latent") if self.cfg.kv_lora_rank else
+            (functools.partial(Attention, window=self.window),
+             "attn.window" if self.window else "attn.full"))
+        with scope("norm"):
+            normed = RMSNorm(self.cfg.rms_eps, name="ln_attn")(x)
+        with scope(kind):
+            a_out, new_kv = attention(self.cfg, name="attn")(
+                normed, positions, attn_mask, kv_view=kv_view,
+                adapter=adapter.get("attn"), lora_scale=lora_scale)
+        with scope("norm"):
+            h = x + a_out
+            normed = RMSNorm(self.cfg.rms_eps, name="ln_mlp")(h)
         ff, name = (MoE, "moe") if self.sparse else (MLP, "mlp")
-        h = h + ff(self.cfg, name=name)(
-            RMSNorm(self.cfg.rms_eps, name="ln_mlp")(h),
-            adapter=adapter.get(name), lora_scale=lora_scale)
-        return h, new_kv
+        out = ff(self.cfg, name=name)(
+            normed, adapter=adapter.get(name), lora_scale=lora_scale)
+        with scope("norm"):
+            return h + out, new_kv
 
 
 class CausalLM(nn.Module):
@@ -654,7 +660,8 @@ class CausalLM(nn.Module):
         cfg = self.cfg
         emb = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed",
                        dtype=cfg.compute_dtype, param_dtype=jnp.float32)
-        x = emb(tokens)
+        with scope("embed"):
+            x = emb(tokens)
         if positions is None:
             pos = jnp.arange(tokens.shape[1], dtype=jnp.int32)
             if cfg.attention_impl == "ring":
@@ -678,14 +685,15 @@ class CausalLM(nn.Module):
                 else adapters.get(f"layer_{i}"),
                 lora_scale=lora_scale)
             new_kvs.append(new_kv)
-        x = RMSNorm(cfg.rms_eps, name="ln_f")(x)
-        if cfg.tie_embeddings:
-            logits = emb.attend(x)
-        else:
-            logits = nn.DenseGeneral(cfg.vocab_size, use_bias=False,
-                                     name="lm_head", dtype=cfg.compute_dtype,
-                                     param_dtype=jnp.float32)(x)
-        logits = logits.astype(jnp.float32)
+        with scope("head"):
+            x = RMSNorm(cfg.rms_eps, name="ln_f")(x)
+            if cfg.tie_embeddings:
+                logits = emb.attend(x)
+            else:
+                logits = nn.DenseGeneral(
+                    cfg.vocab_size, use_bias=False, name="lm_head",
+                    dtype=cfg.compute_dtype, param_dtype=jnp.float32)(x)
+            logits = logits.astype(jnp.float32)
         if kv_view is not None:
             return logits, new_kvs
         return logits

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import optax
 
 from ..core.algframe.client_trainer import TrainerSpec
+from ..core.obs.scopes import scope
 
 PyTree = Any
 
@@ -68,10 +69,12 @@ class CausalLMTrainer(TrainerSpec):
         tok_w = ((labels >= 0).astype(jnp.float32)
                  * batch["mask"].astype(jnp.float32)[:, None])
         safe = jnp.maximum(labels, 0)
-        per_tok = optax.softmax_cross_entropy_with_integer_labels(logits, safe)
-        loss_sum = jnp.sum(per_tok * tok_w)
-        correct = jnp.sum((jnp.argmax(logits, -1) == safe) * tok_w)
-        count = jnp.sum(tok_w)
+        with scope("head"):   # the loss over float32 logits is the head's
+            per_tok = optax.softmax_cross_entropy_with_integer_labels(
+                logits, safe)
+            loss_sum = jnp.sum(per_tok * tok_w)
+            correct = jnp.sum((jnp.argmax(logits, -1) == safe) * tok_w)
+            count = jnp.sum(tok_w)
         return loss_sum, correct, count, extra
 
     def loss(self, params, batch, rng):

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from ...core.kernels.conv_block import (MAX_FUSED_CHANNELS, fused_block,
                                         reference_block)
+from ...core.obs.scopes import scope
 
 
 class _ConvKernel(nn.Module):
@@ -66,19 +67,27 @@ class BasicBlock(nn.Module):
         if self.fused and self.filters <= MAX_FUSED_CHANNELS:
             return self._fused_call(x)
         residual = x
-        y = nn.Conv(self.filters, (3, 3), strides=(self.strides, self.strides),
-                    use_bias=False)(x)
-        y = nn.GroupNorm(num_groups=min(self.groups, self.filters))(y)
-        y = nn.relu(y)
-        y = nn.Conv(self.filters, (3, 3), use_bias=False)(y)
-        y = nn.GroupNorm(num_groups=min(self.groups, self.filters))(y)
+        with scope("cv.conv"):
+            y = nn.Conv(self.filters, (3, 3),
+                        strides=(self.strides, self.strides),
+                        use_bias=False)(x)
+        with scope("cv.norm"):
+            y = nn.GroupNorm(num_groups=min(self.groups, self.filters))(y)
+            y = nn.relu(y)
+        with scope("cv.conv"):
+            y = nn.Conv(self.filters, (3, 3), use_bias=False)(y)
+        with scope("cv.norm"):
+            y = nn.GroupNorm(num_groups=min(self.groups, self.filters))(y)
         if residual.shape != y.shape:
-            residual = nn.Conv(self.filters, (1, 1),
-                               strides=(self.strides, self.strides),
-                               use_bias=False)(x)
-            residual = nn.GroupNorm(
-                num_groups=min(self.groups, self.filters))(residual)
-        return nn.relu(residual + y)
+            with scope("cv.conv"):
+                residual = nn.Conv(self.filters, (1, 1),
+                                   strides=(self.strides, self.strides),
+                                   use_bias=False)(x)
+            with scope("cv.norm"):
+                residual = nn.GroupNorm(
+                    num_groups=min(self.groups, self.filters))(residual)
+        with scope("cv.norm"):
+            return nn.relu(residual + y)
 
     def _fused_call(self, x):
         """One fused kernel per block. The explicit ``name=`` arguments pin
@@ -98,8 +107,9 @@ class BasicBlock(nn.Module):
             p["gp_scale"], p["gp_bias"] = _GroupNormParams(
                 f, name="GroupNorm_2")()
         impl = fused_block if self.fused == "pallas" else reference_block
-        return impl(x, p, strides=self.strides,
-                    groups=min(self.groups, f))
+        with scope("cv.conv"):   # one kernel: the convolutions lead it
+            return impl(x, p, strides=self.strides,
+                        groups=min(self.groups, f))
 
 
 class CifarResNet(nn.Module):
@@ -110,15 +120,18 @@ class CifarResNet(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        x = nn.Conv(16, (3, 3), use_bias=False)(x)
-        x = nn.GroupNorm(num_groups=8)(x)
-        x = nn.relu(x)
+        with scope("cv.conv"):
+            x = nn.Conv(16, (3, 3), use_bias=False)(x)
+        with scope("cv.norm"):
+            x = nn.GroupNorm(num_groups=8)(x)
+            x = nn.relu(x)
         for stage, filters in enumerate((16, 32, 64)):
             for block in range(self.blocks_per_stage):
                 strides = 2 if (stage > 0 and block == 0) else 1
                 x = BasicBlock(filters, strides, fused=self.fused)(x)
-        x = jnp.mean(x, axis=(1, 2))
-        return nn.Dense(self.num_classes)(x)
+        with scope("cv.head"):
+            x = jnp.mean(x, axis=(1, 2))
+            return nn.Dense(self.num_classes)(x)
 
 
 class ResNet18(nn.Module):
@@ -129,20 +142,23 @@ class ResNet18(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = False):
         small = x.shape[1] <= 64  # CIFAR-style stem for small images
-        if small:
-            x = nn.Conv(64, (3, 3), use_bias=False)(x)
-        else:
-            x = nn.Conv(64, (7, 7), strides=(2, 2), use_bias=False)(x)
-        x = nn.GroupNorm(num_groups=8)(x)
-        x = nn.relu(x)
-        if not small:
-            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+        with scope("cv.conv"):
+            if small:
+                x = nn.Conv(64, (3, 3), use_bias=False)(x)
+            else:
+                x = nn.Conv(64, (7, 7), strides=(2, 2), use_bias=False)(x)
+        with scope("cv.norm"):
+            x = nn.GroupNorm(num_groups=8)(x)
+            x = nn.relu(x)
+            if not small:
+                x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         for stage, filters in enumerate((64, 128, 256, 512)):
             for block in range(2):
                 strides = 2 if (stage > 0 and block == 0) else 1
                 x = BasicBlock(filters, strides, fused=self.fused)(x)
-        x = jnp.mean(x, axis=(1, 2))
-        return nn.Dense(self.num_classes)(x)
+        with scope("cv.head"):
+            x = jnp.mean(x, axis=(1, 2))
+            return nn.Dense(self.num_classes)(x)
 
 
 def create_resnet(name: str, num_classes: int, fused: str = "") -> nn.Module:

@@ -33,6 +33,7 @@ from ...core import mlops
 from ...core.obs import metrics as obs_metrics
 from ...core.obs import profiler as obs_profiler
 from ...core.obs import recompile as obs_recompile
+from ...core.obs import scopes as obs_scopes
 from ...core.obs import trace as obs_trace
 from ...core.chaos import ChaosCrash, FaultLedger, FaultPlan
 from ...core.checkpoint import RoundCheckpointer
@@ -475,32 +476,40 @@ class TPUSimulator:
 
             def slot(carry, s):
                 states, acc_u, acc_ex, acc_w, acc_m = carry
-                li = sched_idx[s]
-                active = sched_active[s]
-                (upd, extras, w, w_den, report, mets,
-                 new_cstate) = run_slot(states, li, active, sched_work[s])
-                acc_u = jax.tree_util.tree_map(
-                    lambda acc, u: acc + u * w.astype(u.dtype), acc_u, upd)
-                acc_ex = jax.tree_util.tree_map(
-                    lambda acc, e: acc + e * w.astype(e.dtype), acc_ex,
-                    extras)
-                acc_w = acc_w + w_den
-                acc_m = jax.tree_util.tree_map(
-                    lambda acc, m: acc + m * report, acc_m, mets)
-                states = jax.tree_util.tree_map(
-                    lambda a, n: a.at[li].set(
-                        jnp.where(report > 0, n, a[li])), states,
-                    new_cstate)
-                # per-slot metrics ride out as scan ys: the selection
-                # subsystem's per-CLIENT loss signal (the psum'd acc_m
-                # sums them away). Masked like acc_m; devices keep their
-                # own [S] slices, so the output stays client-sharded.
-                slot_m = jax.tree_util.tree_map(lambda m: m * report, mets)
+                with obs_scopes.scope("engine.slot"):
+                    li = sched_idx[s]
+                    active = sched_active[s]
+                    (upd, extras, w, w_den, report, mets,
+                     new_cstate) = run_slot(states, li, active,
+                                            sched_work[s])
+                with obs_scopes.scope("engine.accumulate"):
+                    acc_u = jax.tree_util.tree_map(
+                        lambda acc, u: acc + u * w.astype(u.dtype), acc_u,
+                        upd)
+                    acc_ex = jax.tree_util.tree_map(
+                        lambda acc, e: acc + e * w.astype(e.dtype), acc_ex,
+                        extras)
+                    acc_w = acc_w + w_den
+                    acc_m = jax.tree_util.tree_map(
+                        lambda acc, m: acc + m * report, acc_m, mets)
+                    states = jax.tree_util.tree_map(
+                        lambda a, n: a.at[li].set(
+                            jnp.where(report > 0, n, a[li])), states,
+                        new_cstate)
+                    # per-slot metrics ride out as scan ys: the selection
+                    # subsystem's per-CLIENT loss signal (the psum'd acc_m
+                    # sums them away). Masked like acc_m; devices keep
+                    # their own [S] slices, so the output stays
+                    # client-sharded.
+                    slot_m = jax.tree_util.tree_map(lambda m: m * report,
+                                                    mets)
                 return (states, acc_u, acc_ex, acc_w, acc_m), slot_m
 
             (states, acc_u, acc_ex, acc_w, acc_m), slot_mets = jax.lax.scan(
                 slot, init, jnp.arange(sched_idx.shape[0]))
-            return finish(states, acc_u, acc_ex, acc_w, acc_m) + (slot_mets,)
+            with obs_scopes.scope("engine.server"):
+                return finish(states, acc_u, acc_ex, acc_w, acc_m) + (
+                    slot_mets,)
 
         return core
 
@@ -653,6 +662,7 @@ class TPUSimulator:
         phases = mlops.compile_phases_since(before)
         compiles = phases.get("compiles", 0)
         self._recompiles.observe(name, args, compiles)
+        obs_scopes.note_program(name, fn, args, compiled=compiles > 0)
         self.dispatch_stats["dispatches"] += 1
         self.dispatch_stats["compiles"] += compiles
         mlops.log_dispatch(name, wall, rounds=n_rounds, compiles=compiles,

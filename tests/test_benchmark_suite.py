@@ -30,6 +30,7 @@ _TAKEN = {
     "test_manifest": None,
     "test_moe_compact_share": None,
     "test_program_metrics": None,
+    "test_scopes": None,
     "test_trace_reduce": None,
     "test_axk1": ("test_flops_per_round_against_a_hand_count",
                   "test_flash_reader_finds_kernels_by_name_only",
