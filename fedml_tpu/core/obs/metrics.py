@@ -584,14 +584,21 @@ def record_flash_window(window: int, block_share: float, sink: bool) -> None:
                    "else 0").set(1.0 if sink else 0.0)
 
 
-def record_kda_plan(chunk: int) -> None:
+def record_kda_plan(chunk: int, fused: bool) -> None:
     """The chunk size of the linear-attention call just traced (host side,
-    once a trace; ``llm/linear_attention.py::chunk_size``)."""
+    once a trace; ``llm/linear_attention.py::chunk_size``), and whether the
+    layer's element-wise work around the kernels ran through the fused
+    passes (``kda_layer``) or the caller made the kernels' operands itself
+    (``kda_attention``)."""
     if not _cfg["enabled"]:
         return
     REGISTRY.gauge("fed_kda_chunk",
                    "positions a chunk of the last traced KDA call"
                    ).set(float(chunk))
+    REGISTRY.gauge("fed_kda_fused",
+                   "1 if the last traced KDA call ran the element-wise "
+                   "work around its kernels in the fused passes, else 0"
+                   ).set(1.0 if fused else 0.0)
 
 
 def record_recompile(program: str) -> None:

@@ -30,11 +30,20 @@ over the whole sequence holds the pair together: the backward of a chunked
 scan, with the entering states as its only residual beyond the inputs.
 The kernels read ``[b, s, h * d]`` as the projections leave it (a head is
 a 128-lane column block): nothing is transposed around them.
+
+:func:`kda_layer` is the whole layer between its frozen products: what the
+module does element by element before the kernels (the short convolutions,
+SiLU, the L2 norms, the decay gate and its running sum within a chunk,
+beta) and after them (the head norm and gate) runs as one pass a direction
+each, ``_pre_rows`` / ``_post_rows`` and their hand-written pull-backs,
+under ``custom_vjp``s whose residuals are the products' outputs and the
+kernels' output: see "the passes around the kernels" below.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +53,10 @@ from ..core.obs import metrics as obs_metrics
 
 # the two kernels' names in a device trace (forward; backward)
 KDA_KERNEL_NAMES = ("kda_fwd", "kda_bwd")
+# the element-wise passes around them (before the kernels: forward,
+# backward; after them: forward, backward); none contains a kernel's name
+KDA_PASS_NAMES = ("kda_pre_fwd", "kda_pre_bwd", "kda_post_fwd",
+                  "kda_post_bwd")
 CHUNK = 64
 SUB = 16
 # exp() of a sub-chunk's decays stays finite up to here; columns past a
@@ -54,6 +67,21 @@ _MAX_EXPONENT = 80.0
 MIN_LOG_DECAY = -_MAX_EXPONENT / SUB
 # positions the short convolution before q, k and v reads
 SHORT_CONV_TAPS = 4
+# float32 rows (one register) a chunk of the fused pass takes from the chunk
+# before it (the convolution's earlier rows) or hands the one before it (the
+# convolution's transpose), and the rows of the block they are read from
+# (one bfloat16 tile)
+_HALO = 8
+_HALO_BLOCK = 16
+# elements of one operand's block, and chunks a block, at most, in the
+# pass before the kernels (128 rows of the benchmark's 4,096 lanes: 33 MB of
+# VMEM for its pull-back's sixteen blocks, twice buffered; 256 rows do not
+# fit); the pass after them has four blocks and takes ``_TILE_WIDER`` times
+# the rows (a step's loop over the heads costs what it costs however few
+# rows a head has: 64-row blocks took 1.6 times as long as 128-row ones)
+_TILE_ELEMENTS = 128 * 4096
+_TILE_CHUNKS = 4
+_TILE_WIDER = 4
 
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
@@ -293,7 +321,12 @@ def _pallas_specs(pl, b, s, h, dk, dv, chunk, reverse):
     return hb, (b, h // hb, n), spec_k, spec_v, spec_st
 
 
-def _pallas_fwd(q, k, kb, vb, gc, chunk):
+# the Pallas forms are ``jit``s of their own: a model's layers share one
+# trace and one lowering of each kernel (``interpret`` is an argument so
+# that a process which both interprets and compiles keeps two entries)
+
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _pallas_fwd(q, k, kb, vb, gc, chunk, interpret):
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
@@ -311,7 +344,7 @@ def _pallas_fwd(q, k, kb, vb, gc, chunk):
                    jax.ShapeDtypeStruct((b, h, s // chunk, dv, dk),
                                         jnp.float32)],
         scratch_shapes=[pltpu.VMEM((hb, dv, dk), jnp.float32)],
-        interpret=kernels.interpret(),
+        interpret=interpret,
         compiler_params=kernels.tpu_compiler_params(
             ("parallel", "parallel", "arbitrary")),
         name=KDA_KERNEL_NAMES[0],
@@ -319,7 +352,8 @@ def _pallas_fwd(q, k, kb, vb, gc, chunk):
     return o.reshape(b, s, h, dv), states
 
 
-def _pallas_bwd(q, k, kb, vb, gc, states, do, chunk):
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def _pallas_bwd(q, k, kb, vb, gc, states, do, chunk, interpret):
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
@@ -337,7 +371,7 @@ def _pallas_bwd(q, k, kb, vb, gc, states, do, chunk):
         out_specs=[spec_k, spec_k, spec_k, spec_v, spec_k],
         out_shape=[like(q), like(k), like(kb), like(vb), like(gc)],
         scratch_shapes=[pltpu.VMEM((hb, dv, dk), jnp.float32)],
-        interpret=kernels.interpret(),
+        interpret=interpret,
         compiler_params=kernels.tpu_compiler_params(
             ("parallel", "parallel", "arbitrary")),
         name=KDA_KERNEL_NAMES[1],
@@ -355,15 +389,16 @@ def _kda_chunks(q, k, kb, vb, gc, chunk: int, impl: str):
 
 def _kda_chunks_fwd(q, k, kb, vb, gc, chunk, impl):
     if impl == "flash":
-        o, states = _pallas_fwd(q, k, kb, vb, gc, chunk)
+        o, states = _pallas_fwd(q, k, kb, vb, gc, chunk, kernels.interpret())
     else:
         o, states = _scan_fwd(q, k, kb, vb, gc, chunk)
     return o, (q, k, kb, vb, gc, states)
 
 
 def _kda_chunks_bwd(chunk, impl, res, do):
-    bwd = _pallas_bwd if impl == "flash" else _scan_bwd
-    return bwd(*res, do, chunk)
+    if impl == "flash":
+        return _pallas_bwd(*res, do, chunk, kernels.interpret())
+    return _scan_bwd(*res, do, chunk)
 
 
 _kda_chunks.defvjp(_kda_chunks_fwd, _kda_chunks_bwd)
@@ -389,7 +424,7 @@ def kda_attention(q, k, v, g, beta, impl: str = "dense"):
     if impl == "flash" and (dk % 128 or v.shape[-1] % 128):
         raise ValueError(f"the KDA kernels take head sizes on the 128 grid; "
                          f"got d_k {dk}, d_v {v.shape[-1]}")
-    obs_metrics.record_kda_plan(chunk)
+    obs_metrics.record_kda_plan(chunk, fused=False)
     beta = beta[..., None].astype(jnp.float32)
     kb = (k.astype(jnp.float32) * beta).astype(k.dtype)
     vb = (v.astype(jnp.float32) * beta).astype(v.dtype)
@@ -402,6 +437,540 @@ def kda_attention(q, k, v, g, beta, impl: str = "dense"):
                     axis=2).reshape(b, s + pad, h, dk)
     o = _kda_chunks(q, k, kb, vb, gc, chunk, impl)
     return o[:, :s] if pad else o
+
+
+# ------------------------------------- the passes around the kernels ---
+#
+# Everything a KDA layer does element by element between its frozen
+# products and the kernels, one chunk of one head at a time and in float32
+# throughout: ``_pre_rows`` / ``_post_rows`` and their hand-written
+# pull-backs, plain ``jax.numpy`` on ``[chunk, d]`` blocks as ``_chunk`` is.
+# ``dense`` maps them over the chunks (the pull-back's carry under a
+# reverse scan); ``flash`` runs them inside four ``pallas_call``s
+# (``KDA_PASS_NAMES``) whose blocks are whole rows of ``[b, s, h * d]`` as
+# the products leave them, so every array is read once and written once a
+# direction.
+
+class _Pass(NamedTuple):
+    """What a pass knows before it sees an array."""
+    heads: int
+    chunk: int
+    tile: int           # rows a block of the Pallas form
+    lower: float        # the gate's lower bound (log-decay)
+    eps: float          # the head norm's
+    impl: str
+    interpret: bool     # the Pallas form interpreted (no chip)
+
+
+def _taps(ext, w, first: int, rows: int, flip: bool = False):
+    """``sum_i w[i] * ext[first + i : first + i + rows]`` for ``w`` [K, d];
+    ``flip`` reads the taps last to first (the convolution's transpose)."""
+    taps = w.shape[0]
+    return sum(w[(taps - 1 - i if flip else i):(taps - i if flip else i + 1)]
+               * ext[first + i:first + i + rows] for i in range(taps))
+
+
+def _conv_silu(lead, y, w):
+    """``y`` [C, d] after the ``_HALO`` rows before it, through the causal
+    convolution and SiLU -> (c, sigmoid(c), c * sigmoid(c))."""
+    c = _taps(jnp.concatenate([lead, y], 0), w, _HALO - (w.shape[0] - 1),
+              y.shape[0])
+    sig = jax.nn.sigmoid(c)
+    return c, sig, c * sig
+
+
+def _unit(a):
+    """a / sqrt(sum a^2 + 1e-6) over a head's lanes, and that factor."""
+    r = jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+    return a * r, r
+
+
+def _ones_below(c: int, transpose: bool = False):
+    """[c, c] ones on and below the diagonal (on and above: transposed):
+    a running sum within the chunk as one product."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    return jnp.where((row <= col) if transpose else (row >= col), 1.0, 0.0)
+
+
+def _gates(yf, a, dt, logit, keep):
+    """sigmoid of the decay gate's argument [C, d] and of beta's logit
+    [C, 1], and ``keep`` (1 where no mask came)."""
+    keep = 1.0 if keep is None else keep
+    return jax.nn.sigmoid(a * (yf + dt)), jax.nn.sigmoid(logit), keep
+
+
+def _pre_rows(leads, ys, yf, ws, a, dt, logit, keep, lower):
+    """One chunk of one head before the kernels, float32. ``ys`` the q, k
+    and v products' rows [C, d] and ``leads`` the ``_HALO`` rows before
+    each (zeros at a row's start), ``ws`` their taps [K, d]; ``yf`` the
+    decay product's rows, ``a = exp(A_log)`` and ``dt`` [1, d]; ``logit``
+    beta's [C, 1]; ``keep`` [C, 1] or None -> q (at ``d ** -0.5``), k,
+    beta k, beta v and the chunk's running log-decay."""
+    sq, sk, sv = (_conv_silu(lead, y, w)[2]
+                  for lead, y, w in zip(leads, ys, ws))
+    sig, beta, keep = _gates(yf, a, dt, logit, keep)
+    k = _unit(sk)[0]
+    beta = beta * keep
+    gc = _exact(_ones_below(yf.shape[0]), (lower * keep) * sig)
+    return _unit(sq)[0] * sq.shape[-1] ** -0.5, k, k * beta, sv * beta, gc
+
+
+def _pre_rows_grads(leads, ys, yf, ws, a, dt, logit, keep, lower,
+                    dq, dk, dkb, dvb, dgc, tails):
+    """``_pre_rows`` rebuilt and pulled back, float32. ``tails``: the
+    first ``_HALO`` rows of the NEXT chunk's cotangent toward each
+    convolution's output (zeros at a row's end), which this chunk's last
+    rows fed -> the cotangents of ``ys``, ``yf`` and ``logit``, and this
+    chunk's own first rows for the chunk before it."""
+    (cq, gq, sq), (ck, gk, sk), (cv, gv, sv) = (
+        _conv_silu(lead, y, w) for lead, y, w in zip(leads, ys, ws))
+    sig, sb, keep = _gates(yf, a, dt, logit, keep)
+    (qh, rq), (kh, rk) = _unit(sq), _unit(sk)
+    beta = sb * keep
+    along = lambda x, y: jnp.sum(x * y, -1, keepdims=True)  # noqa: E731
+    dqh, dkh = dq * sq.shape[-1] ** -0.5, dk + beta * dkb
+    d_s = (rq * (dqh - qh * along(dqh, qh)),
+           rk * (dkh - kh * along(dkh, kh)), beta * dvb)
+    d_logit = (along(dkb, kh) + along(dvb, sv)) * keep * sb * (1.0 - sb)
+    d_ys, heads_ = [], []
+    for ds, c, g, w, tail in zip(d_s, (cq, ck, cv), (gq, gk, gv), ws, tails):
+        dc = ds * g * (1.0 + c * (1.0 - g))
+        d_ys.append(_taps(jnp.concatenate([dc, tail], 0), w, 0, c.shape[0],
+                          flip=True))
+        heads_.append(dc[:_HALO])
+    dg = _exact(_ones_below(yf.shape[0], transpose=True), dgc)
+    d_yf = dg * ((lower * keep) * a) * sig * (1.0 - sig)
+    return (*d_ys, d_yf, d_logit, tuple(heads_))
+
+
+def _post_rows(o, scale, logit, eps):
+    """One head's rows after the kernels, float32: the head's RMSNorm
+    (``scale`` [1, d]) times its gate ``sigmoid(logit)`` [C, 1]."""
+    r = jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps)
+    return o * r * scale * jax.nn.sigmoid(logit)
+
+
+def _post_rows_grads(o, scale, logit, eps, dy):
+    """``_post_rows`` rebuilt and pulled back -> the cotangents of ``o``
+    and ``logit``."""
+    r = jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps)
+    n, gate = o * r, jax.nn.sigmoid(logit)
+    d_logit = jnp.sum(dy * n * scale, -1, keepdims=True) * gate * (1.0 - gate)
+    dn = dy * gate * scale
+    return r * (dn - n * jnp.mean(dn * n, -1, keepdims=True)), d_logit
+
+
+# -------------------------------------------------- their jax.numpy form ---
+
+def _tiled(a, h: int, chunk: int):
+    """[b, s, h * d] -> [b, h, n, chunk, d] in float32."""
+    b, s, hd = a.shape
+    return a.astype(jnp.float32).reshape(
+        b, s // chunk, chunk, h, hd // h).transpose(0, 3, 1, 2, 4)
+
+
+def _untiled(a, dtype):
+    """[b, h, n, chunk, d] -> [b, s, h * d]."""
+    b, h, n, c, d = a.shape
+    return a.transpose(0, 2, 3, 1, 4).reshape(b, n * c, h * d).astype(dtype)
+
+
+def _dense_operands(cfg, yq, yk, yv, yf, logit, keep, ws, a, dt):
+    """The arrays by (row, head, chunk) and the parameters by head."""
+    h, c = cfg.heads, cfg.chunk
+    ys = tuple(_tiled(y, h, c) for y in (yq, yk, yv))
+    leads = tuple(jnp.concatenate(
+        [jnp.zeros_like(y[:, :, :1, -_HALO:]), y[:, :, :-1, -_HALO:]], 2)
+        for y in ys)
+    if keep is not None:
+        keep = jnp.broadcast_to(_tiled(keep, 1, c),
+                                ys[0].shape[:-1] + (1,))
+    per_head = lambda p: jnp.moveaxis(  # noqa: E731
+        p.reshape(p.shape[:-1] + (h, -1)), -2, 0)
+    return (leads, ys, _tiled(yf, h, c), tuple(per_head(w) for w in ws),
+            per_head(a), per_head(dt), _tiled(logit, h, c), keep)
+
+
+def _mapped(fn, n_arrays: int, n_params: int, levels):
+    """``fn(*arrays, *params)`` over the leading axes of the arrays, one
+    axis a level; a level of 1 also maps the parameters (the head axis)."""
+    for with_params in levels:
+        fn = jax.vmap(fn, in_axes=(0,) * n_arrays
+                      + ((0 if with_params else None),) * n_params)
+    return fn
+
+
+def _pre_dense_fwd(cfg, yq, yk, yv, yf, logit, keep, ws, a, dt):
+    leads, ys, yf_, ws, a, dt, logit, keep = _dense_operands(
+        cfg, yq, yk, yv, yf, logit, keep, ws, a, dt)
+
+    def one(leads, ys, yf, logit, keep, ws, a, dt):
+        return _pre_rows(leads, ys, yf, ws, a, dt, logit, keep, cfg.lower)
+
+    outs = _mapped(one, 5, 3, (False, True, False))(
+        leads, ys, yf_, logit, keep, ws, a, dt)
+    return tuple(_untiled(o, like.dtype)
+                 for o, like in zip(outs, (yq, yk, yk, yv, yf)))
+
+
+def _pre_dense_bwd(cfg, yq, yk, yv, yf, logit, keep, ws, a, dt, cots):
+    h, c = cfg.heads, cfg.chunk
+    leads, ys, yf_, ws, a, dt, logit_, keep = _dense_operands(
+        cfg, yq, yk, yv, yf, logit, keep, ws, a, dt)
+    cots = tuple(_tiled(g, h, c) for g in cots)
+
+    def one(leads, ys, yf, logit, keep, cots, tails, ws, a, dt):
+        return _pre_rows_grads(leads, ys, yf, ws, a, dt, logit, keep,
+                               cfg.lower, *cots, tails)
+
+    step = _mapped(one, 7, 3, (True, False))        # heads, then rows
+
+    def body(tails, xs):
+        *grads, heads_ = step(*xs, tails, ws, a, dt)
+        return heads_, tuple(grads)
+
+    by_chunk = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: jnp.moveaxis(x, 2, 0), t)
+    zero = jnp.zeros_like(ys[0][:, :, 0, :_HALO])
+    _, grads = jax.lax.scan(
+        body, (zero, zero, zero),
+        by_chunk((leads, ys, yf_, logit_, keep, cots)), reverse=True)
+    grads = [jnp.moveaxis(g, 0, 2) for g in grads]
+    return tuple(_untiled(g, like.dtype)
+                 for g, like in zip(grads, (yq, yk, yv, yf, logit)))
+
+
+def _post_dense(cfg, o, logit, scale, dy=None):
+    """Forward, or with ``dy`` the pull-back, a head's whole row a block."""
+    h, s = cfg.heads, o.shape[1]
+    arrays = (_tiled(o, h, s), _tiled(logit, h, s))
+    if dy is None:
+        fn = lambda o, logit: _post_rows(o, scale, logit, cfg.eps)  # noqa
+        return _untiled(_mapped(fn, 2, 0, (False,) * 3)(*arrays), o.dtype)
+    fn = lambda o, logit, dy: _post_rows_grads(  # noqa: E731
+        o, scale, logit, cfg.eps, dy)
+    do, d_logit = _mapped(fn, 3, 0, (False,) * 3)(*arrays, _tiled(dy, h, s))
+    return _untiled(do, o.dtype), _untiled(d_logit, logit.dtype)
+
+
+# ----------------------------------------------------- their Pallas form ---
+
+def _row_tile(s: int, chunk: int, width: int, wider: int = 1) -> int:
+    """Rows a block: whole chunks that divide the row, at most
+    ``_TILE_CHUNKS`` of them and ``_TILE_ELEMENTS`` elements an operand,
+    times ``wider``."""
+    n = s // chunk
+    most = max(1, wider * min(_TILE_CHUNKS,
+                              _TILE_ELEMENTS // (chunk * width)))
+    return chunk * max(m for m in range(1, most + 1) if n % m == 0)
+
+
+def _column(ref, rows, j):
+    """Head ``j``'s column of a ``[tile, heads]`` block as [C, 1] float32."""
+    block = ref[rows, :].astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
+    return jnp.sum(jnp.where(lane == j, block, 0.0), -1, keepdims=True)
+
+
+def _set_column(ref, rows, j, col):
+    block = ref[rows, :]
+    lane = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
+    ref[rows, :] = jnp.where(lane == j, col.astype(block.dtype), block)
+
+
+def _pre_kernel(*refs, cfg: _Pass, masked: bool, backward: bool):
+    """One (row, tile of whole chunks) program over every head. Forward:
+    tiles in any order. Backward: tiles last to first, and ``tails_ref``
+    [3, _HALO, h * d] hands each head's first rows of the convolutions'
+    cotangent to the tile before."""
+    import jax.experimental.pallas as pl
+
+    f32 = jnp.float32
+    refs = list(refs)
+    ys, halos = refs[:3], refs[3:6]
+    yf_ref, w_ref, a_ref, dt_ref, logit_ref = refs[6:11]
+    keep_ref = refs[11] if masked else None
+    rest = refs[11 + masked:]
+    if backward:
+        cots, outs, tails_ref = rest[:5], rest[5:10], rest[10]
+    else:
+        outs = rest
+    tile, c = ys[0].shape[0], cfg.chunk
+    d = ys[0].shape[1] // cfg.heads
+    # the rows before the row's first tile are zeros, not the halo block
+    # (the index map clamps it to the first block there)
+    first = (pl.program_id(1) == (pl.num_programs(1) - 1 if backward else 0))
+    inner = 1.0 - first.astype(f32)
+
+    if backward:
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            tails_ref[...] = jnp.zeros_like(tails_ref)
+
+    def head(j, carry):
+        cols = pl.ds(pl.multiple_of(j * d, d), d)
+        ws = [w_ref[x, :, cols] for x in range(3)]
+        a, dt = a_ref[:, cols], dt_ref[:, cols]
+        tails = [tails_ref[x, :, cols] for x in range(3)] if backward else ()
+        chunks = range(tile // c)
+        for i in (reversed(chunks) if backward else chunks):
+            rows = pl.ds(i * c, c)
+            before = pl.ds(i * c - _HALO_BLOCK, _HALO_BLOCK)
+            leads = [(y[before, cols].astype(f32) if i
+                      else halo[:, cols].astype(f32) * inner)[-_HALO:]
+                     for y, halo in zip(ys, halos)]
+            args = (leads, [y[rows, cols].astype(f32) for y in ys],
+                    yf_ref[rows, cols].astype(f32), ws, a, dt,
+                    _column(logit_ref, rows, j),
+                    keep_ref[rows, :].astype(f32) if masked else None,
+                    cfg.lower)
+            if backward:
+                *grads, d_logit, tails = _pre_rows_grads(
+                    *args, *(g[rows, cols].astype(f32) for g in cots), tails)
+                _set_column(outs[4], rows, j, d_logit)
+            else:
+                grads = _pre_rows(*args)
+            for out, g in zip(outs, grads):
+                out[rows, cols] = g.astype(out.dtype)
+        for x, t in enumerate(tails):
+            tails_ref[x, :, cols] = t
+        return carry
+
+    jax.lax.fori_loop(0, cfg.heads, head, 0)
+
+
+def _post_kernel(*refs, cfg: _Pass, backward: bool):
+    """One (row, tile) program over every head: no row needs another."""
+    import jax.experimental.pallas as pl
+
+    f32 = jnp.float32
+    o_ref, logit_ref, scale_ref = refs[:3]
+    tile, c = o_ref.shape[0], cfg.chunk
+    d = o_ref.shape[1] // cfg.heads
+    scale = scale_ref[...].astype(f32)
+
+    def head(j, carry):
+        cols = pl.ds(pl.multiple_of(j * d, d), d)
+        for i in range(tile // c):
+            rows = pl.ds(i * c, c)
+            o = o_ref[rows, cols].astype(f32)
+            logit = _column(logit_ref, rows, j)
+            if backward:
+                dy_ref, do_ref, d_logit_ref = refs[3:]
+                do, d_logit = _post_rows_grads(
+                    o, scale, logit, cfg.eps, dy_ref[rows, cols].astype(f32))
+                do_ref[rows, cols] = do.astype(do_ref.dtype)
+                _set_column(d_logit_ref, rows, j, d_logit)
+            else:
+                refs[3][rows, cols] = _post_rows(
+                    o, scale, logit, cfg.eps).astype(refs[3].dtype)
+        return carry
+
+    jax.lax.fori_loop(0, cfg.heads, head, 0)
+
+
+def _pass_specs(pl, cfg: _Pass, s: int, backward: bool):
+    """Block specs over the grid (row, tile): a tile's rows at a given
+    width, the ``_HALO_BLOCK`` rows before them, a whole small array."""
+    n = s // cfg.tile
+    at = (lambda t: n - 1 - t) if backward else (lambda t: t)
+    per = cfg.tile // _HALO_BLOCK
+    rows = lambda width: pl.BlockSpec(  # noqa: E731
+        (None, cfg.tile, width), lambda i, t: (i, at(t), 0))
+    halo = lambda width: pl.BlockSpec(  # noqa: E731
+        (None, _HALO_BLOCK, width),
+        lambda i, t: (i, jnp.maximum(at(t) * per - 1, 0), 0))
+    whole = lambda shape: pl.BlockSpec(  # noqa: E731
+        shape, lambda i, t: (0,) * len(shape))
+    return n, rows, halo, whole
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _pre_pallas(cfg, yq, yk, yv, yf, logit, keep, ws, a, dt, cots=None):
+    """Forward, or with ``cots`` the pull-back (a ``jit`` of its own, as
+    the kernels' forms are)."""
+    import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+
+    backward = cots is not None
+    b, s, hd = yq.shape
+    n, rows, halo, whole = _pass_specs(pl, cfg, s, backward)
+    w = jnp.stack(ws)
+    masked = keep is not None
+    operands = [yq, yk, yv, yq, yk, yv, yf, w, a, dt, logit]
+    in_specs = [rows(hd)] * 3 + [halo(hd)] * 3 + [
+        rows(hd), whole(w.shape), whole(a.shape), whole(dt.shape),
+        rows(cfg.heads)]
+    if masked:
+        operands.append(keep)
+        in_specs.append(rows(1))
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    if backward:
+        operands += list(cots)
+        in_specs += [rows(hd)] * 5
+        out_shape = [like(a) for a in (yq, yk, yv, yf, logit)]
+        out_specs = [rows(hd)] * 4 + [rows(cfg.heads)]
+        scratch = [pltpu.VMEM((3, _HALO, hd), jnp.float32)]
+    else:
+        out_shape = [like(a) for a in (yq, yk, yk, yv, yf)]
+        out_specs = [rows(hd)] * 5
+        scratch = []
+    return tuple(pl.pallas_call(
+        functools.partial(_pre_kernel, cfg=cfg, masked=masked,
+                          backward=backward),
+        grid=(b, n), in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, scratch_shapes=scratch,
+        interpret=cfg.interpret,
+        compiler_params=kernels.tpu_compiler_params(
+            ("parallel", "arbitrary" if backward else "parallel")),
+        name=KDA_PASS_NAMES[1 if backward else 0],
+    )(*operands))
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _post_pallas(cfg, o, logit, scale, dy=None):
+    """Forward, or with ``dy`` the pull-back."""
+    import jax.experimental.pallas as pl
+
+    backward = dy is not None
+    b, s, hd = o.shape
+    n, rows, _, whole = _pass_specs(pl, cfg, s, False)
+    scale = scale.reshape(1, -1)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(_post_kernel, cfg=cfg, backward=backward),
+        grid=(b, n),
+        in_specs=[rows(hd), rows(cfg.heads), whole(scale.shape)]
+        + [rows(hd)] * backward,
+        out_specs=[rows(hd), rows(cfg.heads)] if backward else rows(hd),
+        out_shape=[like(o), like(logit)] if backward else like(o),
+        interpret=cfg.interpret,
+        compiler_params=kernels.tpu_compiler_params(("parallel", "parallel")),
+        name=KDA_PASS_NAMES[3 if backward else 2],
+    )(o, logit, scale, *([dy] if backward else []))
+    return tuple(out) if backward else out
+
+
+# ------------------------------------------------- the layer around them ---
+
+def _by_lane(cfg: _Pass, conv, a_log, dt_bias):
+    """The frozen parameters as the passes read them, float32: the three
+    convolutions' taps [K, h * d], ``exp(A_log)`` a lane and ``dt_bias``,
+    both [1, h * d]."""
+    f32 = jnp.float32
+    hd = dt_bias.shape[0]
+    a = jnp.repeat(jnp.exp(a_log.astype(f32)), hd // cfg.heads)
+    return (tuple(w.astype(f32) for w in conv), a.reshape(1, hd),
+            dt_bias.astype(f32).reshape(1, hd))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _kda_pre(cfg: _Pass, yq, yk, yv, yf, logit, keep, conv, a_log, dt_bias):
+    """The q, k, v and decay products ``[b, s, h * d]`` (s whole chunks),
+    beta's logits [b, s, h], ``keep`` [b, s, 1] or None and the frozen
+    parameters -> q, k, beta k, beta v and the running log-decay, as the
+    kernels read them."""
+    return _kda_pre_fwd(cfg, yq, yk, yv, yf, logit, keep, conv, a_log,
+                        dt_bias)[0]
+
+
+def _kda_pre_fwd(cfg, yq, yk, yv, yf, logit, keep, conv, a_log, dt_bias):
+    run = _pre_pallas if cfg.impl == "flash" else _pre_dense_fwd
+    out = run(cfg, yq, yk, yv, yf, logit, keep,
+              *_by_lane(cfg, conv, a_log, dt_bias))
+    return out, (yq, yk, yv, yf, logit, keep, conv, a_log, dt_bias)
+
+
+def _kda_pre_bwd(cfg, res, cots):
+    yq, yk, yv, yf, logit, keep, conv, a_log, dt_bias = res
+    run = _pre_pallas if cfg.impl == "flash" else _pre_dense_bwd
+    grads = run(cfg, yq, yk, yv, yf, logit, keep,
+                *_by_lane(cfg, conv, a_log, dt_bias), cots)
+    return (*grads, *_pre_frozen_grads(cfg, *res, tuple(cots)))
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _pre_frozen_grads(cfg, yq, yk, yv, yf, logit, keep, conv, a_log, dt_bias,
+                      cots):
+    """The mask's and the frozen parameters' cotangents: autodiff of the
+    mapped form, which is dropped where nobody asks (a LoRA step)."""
+    _, pull = jax.vjp(
+        lambda keep, *frozen: _pre_dense_fwd(
+            cfg, yq, yk, yv, yf, logit, keep, *_by_lane(cfg, *frozen)),
+        keep, conv, a_log, dt_bias)
+    return pull(cots)
+
+
+_kda_pre.defvjp(_kda_pre_fwd, _kda_pre_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _kda_post(cfg: _Pass, o, logit, scale):
+    """The kernels' output [b, s, h * d], the head gates' logits [b, s, h]
+    and the head norm's scale [d] -> the gated, normalised heads."""
+    return _kda_post_fwd(cfg, o, logit, scale)[0]
+
+
+def _kda_post_fwd(cfg, o, logit, scale):
+    run = _post_pallas if cfg.impl == "flash" else _post_dense
+    return run(cfg, o, logit, scale.astype(jnp.float32)), (o, logit, scale)
+
+
+def _kda_post_bwd(cfg, res, dy):
+    o, logit, scale = res
+    run = _post_pallas if cfg.impl == "flash" else _post_dense
+    do, d_logit = run(cfg, o, logit, scale.astype(jnp.float32), dy)
+    return do, d_logit, _post_scale_grad(cfg, o, logit, scale, dy)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _post_scale_grad(cfg, o, logit, scale, dy):
+    """The head norm's scale's cotangent, as ``_pre_frozen_grads``."""
+    return jax.vjp(lambda sc: _post_dense(
+        cfg, o, logit, sc.astype(jnp.float32)), scale)[1](dy)[0]
+
+
+_kda_post.defvjp(_kda_post_fwd, _kda_post_bwd)
+
+
+def kda_layer(ys, beta_logits, gate_logits, conv, a_log, dt_bias, o_scale,
+              attn_mask=None, *, heads: int, lower: float, eps: float,
+              impl: str = "dense"):
+    """A KDA layer between its frozen products: ``ys`` the ``q k v f``
+    products [b, s, h * d] as they leave the MXU (``f`` in float32),
+    ``beta_logits`` and ``gate_logits`` [b, s, h], ``conv`` the three
+    convolutions' taps [K, h * d], ``A_log`` [h], ``dt_bias`` [h * d],
+    ``o_scale`` [d] the head norm's, ``attn_mask`` [b, s] or None -> what
+    the output product reads, [b, s, h * d]. Three ``custom_vjp``s in a
+    row (:func:`_kda_pre`, the kernels' :func:`_kda_chunks`,
+    :func:`_kda_post`) keep the products' outputs, the kernels' operands
+    and entering states, and the kernels' output: no intermediate."""
+    b, s, hd = ys["q"].shape
+    d = hd // heads
+    chunk = chunk_size(s)
+    impl = "flash" if impl == "flash" else "dense"
+    if impl == "flash" and d % 128:
+        raise ValueError(f"the KDA kernels take head sizes on the 128 grid; "
+                         f"got {d}")
+    obs_metrics.record_kda_plan(chunk, fused=True)
+    keep = None if attn_mask is None else attn_mask.astype(
+        jnp.float32)[:, :, None]
+    arrays = [ys[n] for n in "qkvf"] + [beta_logits, gate_logits]
+    pad = -s % chunk
+    if pad:     # zeros after the row: no decay, no write, read by nobody
+        keep = jnp.ones((b, s, 1), jnp.float32) if keep is None else keep
+        *arrays, keep = (jnp.pad(a, [(0, 0), (0, pad), (0, 0)])
+                         for a in (*arrays, keep))
+    cfg = _Pass(heads, chunk, _row_tile(s + pad, chunk, hd), float(lower),
+                float(eps), impl, impl == "flash" and kernels.interpret())
+    *operands, gates = arrays
+    flat = _kda_pre(cfg, *operands, keep, tuple(conv), a_log, dt_bias)
+    o = _kda_chunks(*(a.reshape(b, s + pad, heads, d) for a in flat), chunk,
+                    impl)
+    after = cfg._replace(tile=_row_tile(s + pad, chunk, hd, _TILE_WIDER))
+    y = _kda_post(after, o.reshape(b, s + pad, hd), gates, o_scale)
+    return y[:, :s] if pad else y
 
 
 def kda_recurrence(q, k, v, g, beta):
@@ -423,12 +992,3 @@ def kda_recurrence(q, k, v, g, beta):
     _, o = jax.lax.scan(step, st0, tuple(
         jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta)))
     return jnp.moveaxis(o, 0, 1)
-
-
-def short_conv(x, w):
-    """Causal depthwise convolution over the last ``K`` positions, no bias:
-    ``y_t = sum_i w[i] * x_{t - (K - 1) + i}``. x [b, s, c], w [K, c]."""
-    kk = w.shape[0]
-    s = x.shape[1]
-    xp = jnp.pad(x, [(0, 0), (kk - 1, 0), (0, 0)])
-    return sum(xp[:, i:i + s] * w[i].astype(x.dtype) for i in range(kk))

@@ -380,8 +380,9 @@ class LinearAttention(nn.Module):
     state; ``y = (RMSNorm_head(o) * sigmoid(x W_g)_h) W_o``. Adapters on
     ``q k v f o``; the convolutions, ``W_b``, ``W_g``, ``A_log`` and
     ``dt_bias`` are frozen. A masked key neither writes nor decays the
-    state. Training path only: a recurrent state is no list of cached
-    blocks."""
+    state. The module makes the seven products; everything between them
+    is ``kda_layer``'s (fused passes around the kernels, float32 inside).
+    Training path only: a recurrent state is no list of cached blocks."""
 
     cfg: LLMConfig
 
@@ -395,21 +396,18 @@ class LinearAttention(nn.Module):
                 "a list of key/value blocks a position, not the one "
                 "recurrent state and convolution tail a row of a KDA layer "
                 "carries")
-        from .attention import _scaled
         from .linear_attention import (MIN_LOG_DECAY, SHORT_CONV_TAPS,
-                                       kda_attention, short_conv)
+                                       kda_layer)
 
         cfg = self.cfg
         if not MIN_LOG_DECAY <= cfg.kda_lower_bound < 0:
             raise ValueError(
                 f"kda_lower_bound {cfg.kda_lower_bound}: the chunked delta "
                 f"rule is exact for log-decays in [{MIN_LOG_DECAY}, 0)")
-        b, s, _ = x.shape
         nh, d, taps = cfg.num_heads, cfg.linear_head_dim, SHORT_CONV_TAPS
         dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
             feats, axis=-1, use_bias=False, name=name,
             dtype=cfg.compute_dtype, param_dtype=jnp.float32)
-        heads = lambda a: a.reshape(b, s, nh, d)  # noqa: E731
 
         # the decay projection leaves its product in float32: the gate
         # multiplies it by up to exp(A_log) = 16 inside a sigmoid
@@ -420,35 +418,29 @@ class LinearAttention(nn.Module):
                 preferred_element_type=jnp.float32))
         ys = _add_lora(x, {**{n: dense(nh * d, n)(x) for n in "qkv"},
                            "f": wide(x)}, adapter, lora_scale)
-        q, k, v = (heads(nn.silu(short_conv(ys[n], self.param(
-            f"conv_{n}", nn.initializers.lecun_normal(),
-            (taps, nh * d))))) for n in "qkv")
-        q, k = (_l2_normalised(a) for a in (q, k))
+        conv = [self.param(f"conv_{n}", nn.initializers.lecun_normal(),
+                           (taps, nh * d)) for n in "qkv"]
         a_log = self.param("A_log", nn.initializers.zeros, (nh,))
         dt_bias = self.param("dt_bias", nn.initializers.zeros, (nh * d,))
-        g = cfg.kda_lower_bound * jax.nn.sigmoid(
-            jnp.exp(a_log.astype(jnp.float32))[:, None]
-            * heads(ys["f"].astype(jnp.float32)
-                    + dt_bias.astype(jnp.float32)))
-        beta = jax.nn.sigmoid(dense(nh, "b")(x).astype(jnp.float32))
-        if attn_mask is not None:
-            keep = attn_mask.astype(jnp.float32)[:, :, None]
-            g, beta = g * keep[..., None], beta * keep
-        out = kda_attention(_scaled(q, d ** -0.5), k, v, g, beta,
-                            impl=cfg.attention_impl)
-        out = RMSNorm(cfg.rms_eps, name="o_norm")(out)
-        out = _head_gate(out, dense(nh, "g")(x)).reshape(b, s, nh * d)
+        # everything between the products and the kernels, and between the
+        # kernels and the output product, is the layer's own fused pass
+        out = kda_layer(ys, dense(nh, "b")(x), dense(nh, "g")(x), conv,
+                        a_log, dt_bias, _NormScale(name="o_norm")(d),
+                        attn_mask, heads=nh, lower=cfg.kda_lower_bound,
+                        eps=cfg.rms_eps, impl=cfg.attention_impl)
         y = dense(cfg.hidden_size, "o")(out)
         self.sow("kda_stats", "layer_steps", jnp.float32(1),
                  init_fn=lambda: jnp.float32(0), reduce_fn=jnp.add)
         return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], None
 
 
-def _l2_normalised(a):
-    """a / sqrt(sum a^2 + 1e-6) over the last axis, in float32."""
-    f = a.astype(jnp.float32)
-    return (f * jax.lax.rsqrt(jnp.sum(f * f, -1, keepdims=True) + 1e-6)
-            ).astype(a.dtype)
+class _NormScale(nn.Module):
+    """An :class:`RMSNorm`'s parameter under its name, for a caller that
+    applies the norm itself (the KDA layer's fused pass)."""
+
+    @nn.compact
+    def __call__(self, width: int):
+        return self.param("scale", nn.initializers.ones, (width,))
 
 
 class LatentAttention(nn.Module):

@@ -22,7 +22,8 @@ from fedml_tpu.core.kernels.conv_block import fused_block
 from fedml_tpu.llm import moe
 from fedml_tpu.llm.attention import (FLASH_KERNEL_NAMES, WINDOW_KERNEL_NAMES,
                                      flash_causal_attention)
-from fedml_tpu.llm.linear_attention import KDA_KERNEL_NAMES, kda_attention
+from fedml_tpu.llm.linear_attention import (KDA_KERNEL_NAMES, KDA_PASS_NAMES,
+                                            kda_attention, kda_layer)
 
 pytestmark = pytest.mark.pallas
 
@@ -235,6 +236,71 @@ def test_kda_kernels_compile_for_v5e(v5e, dtype, heads):
     assert text.count("tpu_custom_call") == 2
     for name in KDA_KERNEL_NAMES:
         assert name in text, name
+
+
+def _kda_layer_step(heads, masked):
+    def step(ys, beta_logits, gate_logits, conv, a_log, dt_bias, o_scale,
+             mask):
+        return jax.value_and_grad(
+            lambda ys, b, g: kda_layer(
+                ys, b, g, conv, a_log, dt_bias, o_scale,
+                mask if masked else None, heads=heads, lower=-5.0, eps=1e-6,
+                impl="flash").astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(ys, beta_logits, gate_logits)
+    return step
+
+
+def _kda_layer_avals(dtype, heads, s=4096, d=128):
+    wide = lambda dt: jax.ShapeDtypeStruct((1, s, heads * d), dt)  # noqa
+    f32 = jnp.float32
+    per_head = jax.ShapeDtypeStruct((1, s, heads), dtype)
+    return ({**{n: wide(dtype) for n in "qkv"}, "f": wide(f32)}, per_head,
+            per_head, [jax.ShapeDtypeStruct((4, heads * d), f32)] * 3,
+            jax.ShapeDtypeStruct((heads,), f32),
+            jax.ShapeDtypeStruct((heads * d,), f32),
+            jax.ShapeDtypeStruct((d,), f32),
+            jax.ShapeDtypeStruct((1, s), f32))
+
+
+@pytest.mark.parametrize("dtype,heads,masked", [
+    (jnp.bfloat16, 32, False), (jnp.bfloat16, 32, True),
+    (jnp.float32, 2, False)])
+def test_kda_passes_compile_for_v5e(v5e, dtype, heads, masked):
+    """The fused passes around the KDA kernels at the benchmark's shape
+    (``[1, 4096, 32 x 128]``, bfloat16 products and a float32 decay
+    product), with a key mask, and in float32: four more Mosaic kernels
+    beside the two, under their names."""
+    text = _compile(_kda_layer_step(heads, masked), v5e,
+                    *_kda_layer_avals(dtype, heads)).as_text()
+    assert text.count("tpu_custom_call") == 6
+    for name in KDA_KERNEL_NAMES + KDA_PASS_NAMES:
+        assert name in text, name
+
+
+def test_only_the_kda_kernels_read_as_kda_kernels(v5e):
+    """``kda_kernels_roofline`` finds its two kernels by a substring of an
+    instruction's name: of the layer's six custom calls it must take
+    ``kda_fwd`` and ``kda_bwd`` and none of the passes."""
+    import importlib.util
+    import os
+    import re
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "metrics",
+        "kda_kernels_roofline.py")
+    spec = importlib.util.spec_from_file_location("kda_roofline", path)
+    metric = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(metric)
+    text = _compile(_kda_layer_step(32, False), v5e,
+                    *_kda_layer_avals(jnp.bfloat16, 32)).as_text()
+    names = re.findall(r"^\s*%?([\w.\-]+) = .*custom_call_target="
+                       r"\"tpu_custom_call\"", text, re.M)
+    assert len(names) == 6, names
+    kinds = {name: metric.kind_of(name) for name in names}
+    assert sorted(k for k in kinds.values() if k) == ["bwd", "fwd"], kinds
+    for name, kind in kinds.items():
+        mine = [n for n in KDA_KERNEL_NAMES + KDA_PASS_NAMES if n in name]
+        assert mine == (["kda_" + kind] if kind else mine[:1]) and mine, kinds
 
 
 def test_flash_bwd_never_materializes_scores(v5e):

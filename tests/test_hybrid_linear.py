@@ -301,6 +301,173 @@ def test_a_masked_key_neither_writes_nor_decays():
     assert rel(whole[:, :20], short) < 1e-5
 
 
+# --------------------------------- the fused passes around the kernels ---
+
+def short_conv(x, w):
+    """Causal depthwise convolution over the last ``K`` positions, no bias:
+    ``y_t = sum_i w[i] * x_{t - (K - 1) + i}``. x [b, s, c], w [K, c]."""
+    kk = w.shape[0]
+    s = x.shape[1]
+    xp = jnp.pad(x, [(0, 0), (kk - 1, 0), (0, 0)])
+    return sum(xp[:, i:i + s] * w[i].astype(x.dtype) for i in range(kk))
+
+
+def _l2_normalised(a):
+    f = a.astype(jnp.float32)
+    return (f * jax.lax.rsqrt(jnp.sum(f * f, -1, keepdims=True) + 1e-6)
+            ).astype(a.dtype)
+
+
+def module_as_it_was(ys, beta_logits, gate_logits, conv, a_log, dt_bias,
+                     o_scale, attn_mask=None, *, heads, lower, eps, impl):
+    """``LinearAttention`` between its products as XLA had it before the
+    fused passes (``llm/model.py`` at PR 36, line for line): the definition
+    :func:`la.kda_layer` is held to, with the same kernels under it."""
+    b, s, hd = ys["q"].shape
+    d = hd // heads
+    by_head = lambda a: a.reshape(b, s, heads, d)  # noqa: E731
+    q, k, v = (by_head(jax.nn.silu(short_conv(ys[n], w)))
+               for n, w in zip("qkv", conv))
+    q, k = (_l2_normalised(a) for a in (q, k))
+    g = lower * jax.nn.sigmoid(
+        jnp.exp(a_log.astype(jnp.float32))[:, None]
+        * by_head(ys["f"].astype(jnp.float32) + dt_bias.astype(jnp.float32)))
+    beta = jax.nn.sigmoid(beta_logits.astype(jnp.float32))
+    if attn_mask is not None:
+        keep = attn_mask.astype(jnp.float32)[:, :, None]
+        g, beta = g * keep[..., None], beta * keep
+    q = (q.astype(jnp.float32) * d ** -0.5).astype(q.dtype)
+    out = la.kda_attention(q, k, v, g, beta, impl=impl)
+    var = jnp.mean(jnp.square(out.astype(jnp.float32)), -1, keepdims=True)
+    out = (out.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
+           * o_scale).astype(out.dtype)
+    gate = jax.nn.sigmoid(gate_logits.astype(jnp.float32))[..., None]
+    return (out.astype(jnp.float32) * gate).astype(out.dtype).reshape(
+        b, s, hd)
+
+
+LAYER = dict(heads=2, lower=-5.0, eps=1e-6)
+LEAVES = ("q", "k", "v", "f", "beta_logits", "gate_logits", "conv_q",
+          "conv_k", "conv_v", "A_log", "dt_bias", "o_scale")
+
+
+def layer_inputs(s, masked, dtype=jnp.float32, h=2, d=128):
+    ks = jax.random.split(jax.random.PRNGKey(s), 13)
+    hd = h * d
+    ys = {n: jax.random.normal(ks[i], (1, s, hd)).astype(
+        jnp.float32 if n == "f" else dtype) for i, n in enumerate("qkvf")}
+    logits = [jax.random.normal(k, (1, s, h)).astype(dtype) for k in ks[4:6]]
+    conv = [0.5 * jax.random.normal(k, (4, hd)) for k in ks[6:9]]
+    frozen = (0.3 * jax.random.normal(ks[9], (h,)),
+              0.3 * jax.random.normal(ks[10], (hd,)),
+              1 + 0.1 * jax.random.normal(ks[11], (d,)))
+    mask = jnp.ones((1, s)).at[:, 2 * s // 3:].set(0) if masked else None
+    weight = jax.random.normal(ks[12], (1, s, hd))
+    return (ys, *logits, conv, *frozen), mask, weight
+
+
+def layer_value_and_grads(fn, impl, args, mask, weight):
+    """-> (output, the gradients of ``sum(output * weight)`` toward every
+    array and parameter of the layer, in ``LEAVES``' order)."""
+    def loss(*a):
+        y = fn(*a, mask, impl=impl, **LAYER)
+        return jnp.sum(y.astype(jnp.float32) * weight), y
+
+    (_, y), (d_ys, d_beta, d_gate, d_conv, *d_frozen) = jax.value_and_grad(
+        loss, argnums=tuple(range(7)), has_aux=True)(*args)
+    return y, [*(d_ys[n] for n in "qkvf"), d_beta, d_gate, *d_conv,
+               *d_frozen]
+
+
+def held_to(a, b, others):
+    """``|a - b|`` over the larger of ``|b|`` and a hundredth of the
+    largest norm among ``others`` (a gradient that all but cancels)."""
+    norm = lambda x: float(jnp.linalg.norm(x.astype(jnp.float32)))  # noqa
+    scale = max(norm(b), 1e-2 * max(norm(x) for x in others))
+    return norm(a.astype(jnp.float32) - b.astype(jnp.float32)) / scale
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("s", [64, 200, 1024])
+@pytest.mark.parametrize("impl", ["dense", "flash"])
+def test_the_fused_passes_match_the_modules_jax_numpy(impl, s, masked):
+    """One chunk, a padded row and a row of four blocks (the convolution's
+    rows and its transpose's cross block edges there), in float32: the
+    output and the gradient toward every product, both logits and every
+    frozen parameter."""
+    args, mask, weight = layer_inputs(s, masked)
+    want, want_g = layer_value_and_grads(module_as_it_was, impl, args, mask,
+                                         weight)
+    got, got_g = layer_value_and_grads(la.kda_layer, impl, args, mask,
+                                       weight)
+    if impl == "flash" and s == 1024:
+        assert la._row_tile(s, 64, 256) == 256
+    assert rel(got, want) < 1e-5
+    for name, a, b in zip(LEAVES, got_g, want_g):
+        assert held_to(a, b, want_g[:4]) < 2e-5, name
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("impl", ["dense", "flash"])
+def test_the_fused_passes_in_bfloat16_are_nearer_float32(impl, masked):
+    """bfloat16 products, as the timed path has them: the passes round
+    nothing the module did not, so their output and gradients lie within
+    the module's own distance to the float32 result (about half of it).
+    The decay's two parameters take their gradient from the kernels' own
+    float32 ``dgc`` in float32 arithmetic in both forms, a few numbers
+    each summed over the whole row: the same precision, another draw."""
+    args, mask, weight = layer_inputs(256, masked, jnp.bfloat16)
+    exact = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), args)
+    want, want_g = layer_value_and_grads(module_as_it_was, "dense", exact,
+                                         mask, weight)
+    was, was_g = layer_value_and_grads(module_as_it_was, impl, args, mask,
+                                       weight)
+    got, got_g = layer_value_and_grads(la.kda_layer, impl, args, mask,
+                                       weight)
+    assert got.dtype == jnp.bfloat16
+    assert [g.dtype for g in got_g] == [g.dtype for g in was_g]
+    assert rel(got.astype(jnp.float32), want) \
+        <= rel(was.astype(jnp.float32), want)
+    for name, a, b, c in zip(LEAVES, got_g, was_g, want_g):
+        room = 2.5 if name in ("A_log", "dt_bias") else 1.0
+        assert held_to(a, c, want_g[:4]) \
+            <= room * held_to(b, c, want_g[:4]), name
+
+
+def test_a_kda_layers_train_step_keeps_no_intermediate_of_the_module(capsys):
+    """What the backward pass of a one-layer KDA model's LoRA step holds at
+    the layer's width ``[rows, s, heads * d]``: the four products' outputs
+    (``_kda_pre``'s residuals), the kernels' five operands, the kernels'
+    output (``_kda_post``'s) and the float32 copy of the layer's result
+    that the output product's adapter reads. The module kept 23 at PR 36:
+    the convolutions' and SiLUs' outputs, float32 copies, both norms'."""
+    cfg = small_cfg(layers=1)
+    base, lora = weights(cfg)
+    tok = tokens(cfg)
+    bundle = bundle_for(cfg, base, 32)
+    spec = CausalLMTrainer(bundle.apply, bundle.extra_metrics)
+    batch = {"x": tok[:, :-1], "y": tok[:, 1:], "mask": jnp.ones((2,))}
+    from jax.ad_checkpoint import print_saved_residuals
+    print_saved_residuals(lambda p: spec.loss(p, batch, None)[0], lora)
+    wide = [line for line in capsys.readouterr().out.splitlines()
+            if line.split(" ", 1)[0] in ("f32[2,32,64]", "f32[2,32,4,16]")]
+    by_site = lambda name: sum(name in line for line in wide)  # noqa: E731
+    assert len(wide) == 11, "\n".join(wide)
+    assert by_site("(_add_lora)") == 4 and by_site("kda_layer") == 6
+    # and the passes' own residuals are their arguments, nothing made
+    args, mask, _ = layer_inputs(64, False)
+    ys, beta_logits, gate_logits, conv, a_log, dt_bias, o_scale = args
+    plan = la._Pass(2, 64, 64, -5.0, 1e-6, "dense", False)
+    operands = (*(ys[n] for n in "qkvf"), beta_logits, None, tuple(conv),
+                a_log, dt_bias)
+    out, kept = la._kda_pre_fwd(plan, *operands)
+    assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(kept),
+                                      jax.tree_util.tree_leaves(operands)))
+    o = out[3]
+    _, kept = la._kda_post_fwd(plan, o, gate_logits, o_scale)
+    assert kept[0] is o and kept[1] is gate_logits and kept[2] is o_scale
+
+
 # ------------------------------------------------------------- routing ---
 
 def test_plain_routing_is_bit_equal_to_what_it_was():
@@ -448,6 +615,11 @@ def test_round_counters_reach_the_registry_from_the_round_program():
     assert REGISTRY.counter("fed_moe_tokens_here_total").value() == \
         here_before + float(m0["moe_tokens_here"])
     assert REGISTRY.gauge("fed_kda_chunk").value() == 32.0
+    # the layer's element-wise work ran through the fused passes; a caller
+    # that hands the kernels their operands itself reads 0
+    assert REGISTRY.gauge("fed_kda_fused").value() == 1.0
+    la.kda_attention(*kda_inputs(64, -1.0, 0.0))
+    assert REGISTRY.gauge("fed_kda_fused").value() == 0.0
     assert la.chunk_size(4096) == 64 and la.chunk_size(40) == 48
 
 

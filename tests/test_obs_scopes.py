@@ -134,7 +134,8 @@ KINDS = {
     "linear": (dict(**LATENT, layer_group_size=2, linear_head_dim=128,
                     attn_output_gate=True),
                EVERY | {"attn.linear", "attn.latent"},
-               {"kda_bwd": "attn.linear", "flash_dq": "attn.latent"}),
+               {"kda_bwd": "attn.linear", "kda_pre_bwd": "attn.linear",
+                "kda_post_bwd": "attn.linear", "flash_dq": "attn.latent"}),
 }
 
 

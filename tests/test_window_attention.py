@@ -654,8 +654,11 @@ def test_the_window_counter_reaches_the_registry_from_the_round_program():
 # (commit bbeebdd) lowers them with this container's jax 0.9.0; the two
 # with an expert layer as PR 35 left them (its backward pass works from
 # the forward's gate and up products: ``llm/moe.py``), the Mistral pair
-# unmoved by it. A change that means to alter one of these programs brings
-# its new hash.
+# unmoved by it; the MiMo pair as the parent of PR 37 (commit d4c1675)
+# lowers it, and the Ling pair as PR 37 left it (its KDA layers run their
+# element-wise work in the fused passes of ``llm/linear_attention.py``,
+# which no other model has). A change that means to alter one of these
+# programs brings its new hash.
 _ACCEPTED = {
     ("mistral7b_lora_silo2", "float32"):
         "61f778a13edfff89801bb55b63d5146bd9377814d1580a6d2204001bc7971871",
@@ -666,9 +669,13 @@ _ACCEPTED = {
     ("axk1_lora_silo2_seq4096", "bfloat16"):
         "1a7fdcdbcaf7a273b37515bbb23e732915e1e125d84456e6dd45ea225b155c63",
     ("ling3flash_lora_silo2_seq4096", "float32"):
-        "4797aded1fc5e564c9926ef0788ce2af659b80d64760e0a5dc3b7f58a7368b2b",
+        "b05b4c0eed48799c1ed4f64e8ea6cf546ed24bd2a8348b3b5ec268d421fe7c68",
     ("ling3flash_lora_silo2_seq4096", "bfloat16"):
-        "62375e2f386b5b260e2fdab682c5b5b606db35cb96c94f3a0b2db83690531c65",
+        "b83aa77a710580ac38d5ac57c4d2c42427fc094cd300056c008300d8441f413f",
+    ("mimo_v2_flash_lora_silo2_seq4096", "float32"):
+        "1fb34b7547f28910dd41fde348fd94d9c3ff187d7eb14c3596279504ac9107a9",
+    ("mimo_v2_flash_lora_silo2_seq4096", "bfloat16"):
+        "a795e6669c019f88c0d5cccbc00a80a0aed09a8f20aa787b46b0eb1f0c1d297d",
 }
 
 
@@ -714,3 +721,27 @@ def test_the_accepted_small_train_steps_lower_to_the_parents_text(
         lora).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == \
         _ACCEPTED[cell_name, dtype]
+
+
+@pytest.mark.parametrize("precision,want", [
+    ("float32",
+     "07c58a0749289bfb16ceb196180f22b6596a6f259e820549677be5e4578722e1"),
+    ("bfloat16",
+     "21967ba8f2e5b8fb6be19f1550d0f1d2eb7f414aa93dc8a6180aa9aac867504c")])
+def test_the_small_resnet_train_step_lowers_to_the_parents_text(
+        precision, want):
+    """The other half of the accepted cells: ResNet-20's classification
+    step at batch 8, as the parent of PR 37 (commit d4c1675) lowers it."""
+    from fedml_tpu.arguments import Arguments
+    from fedml_tpu.core.algframe.client_trainer import ClassificationTrainer
+    from fedml_tpu.model import create
+
+    bundle = create(Arguments(model="resnet20", precision=precision), 10)
+    x = jnp.zeros((8, 32, 32, 3), jnp.float32)
+    params = bundle.init(jax.random.PRNGKey(0), x)
+    spec = ClassificationTrainer(bundle.apply)
+    batch = {"x": x, "y": jnp.zeros((8,), jnp.int32), "mask": jnp.ones((8,))}
+    text = jax.jit(jax.value_and_grad(
+        lambda p: spec.loss(p, batch, None), has_aux=True)).lower(
+        params).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == want
