@@ -57,6 +57,8 @@ FULL = {
                   prompt_chars=(3, 40, 150, 400, 700, 12)),
     "flash_shapes": ((8, 1024, 8, 128), (1, 8192, 2, 128)),
     "kda_shape": (1, 2048, 4, 128),
+    # b, s, heads, channels a head, groups, state size
+    "ssd_shape": (1, 4096, 128, 64, 8, 128),
     # b, s, query heads, key-value heads, d_qk, d_v, window
     "window_shape": (1, 4096, 64, 8, 192, 128, 128),
     "conv_batch": 32,
@@ -70,6 +72,7 @@ TOY = {
     "serve": dict(slots=4, max_new=4, prompt_chars=(3, 20, 60, 9)),
     "flash_shapes": ((2, 128, 2, 32),),
     "kda_shape": (1, 128, 2, 128),
+    "ssd_shape": (1, 160, 4, 64, 2, 128),
     "window_shape": (1, 256, 4, 2, 24, 16, 40),
     "conv_batch": 8,
     "ring_seq": 256,
@@ -513,6 +516,60 @@ def phase_kda(sz):
     return out
 
 
+def phase_ssd(sz):
+    """The state-space kernels (Pallas on a chip, interpreted elsewhere) at
+    the state-space cell's shape, forward and the gradients toward x, the
+    step sizes, B and C against the dense form (the same chunk step under
+    a scan), on bfloat16 operands whose decays ``delta A`` cover -1.6 to
+    -0.001 a step; by the norms of the output and of every gradient."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.llm.state_space import ssd_scan
+
+    b, s, h, p, g, n = sz["ssd_shape"]
+    ks = jax.random.split(jax.random.PRNGKey(17), 6)
+    x = jax.random.normal(ks[0], (b, s, h, p), jnp.bfloat16)
+    bm = (0.5 * jax.random.normal(ks[1], (b, s, g, n))).astype(jnp.bfloat16)
+    cm = (0.5 * jax.random.normal(ks[2], (b, s, g, n))).astype(jnp.bfloat16)
+    a = -jnp.exp(jnp.linspace(0.0, math.log(16.0), h))
+    dt = jnp.exp(jax.random.uniform(ks[3], (b, s, h), minval=math.log(1e-3),
+                                    maxval=math.log(0.1)))
+    ct = jax.random.normal(ks[4], (b, s, h, p), jnp.float32)
+    xs = (x, dt, a, bm, cm, jnp.ones((h,)))
+
+    def both(impl):
+        def fn(*xs):
+            def loss(*xs):
+                out = ssd_scan(*xs, impl=impl)
+                return jnp.sum(out.astype(jnp.float32) * ct), out
+            (_, out), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 3, 4), has_aux=True)(*xs)
+            return (out,) + grads
+        return jax.jit(fn)
+
+    def gap(a, w):
+        a, w = (np.asarray(t, np.float32) for t in (a, w))
+        check(np.isfinite(a).all(), "non-finite state-space result")
+        return float(np.linalg.norm(a - w) / (np.linalg.norm(w) + 1e-6))
+
+    got, want = both("flash")(*xs), both("dense")(*xs)
+    errs = [gap(a, w) for a, w in zip(got, want)]
+    check(max(errs) < 0.02, f"SSD kernels vs the dense form: {errs}")
+    out = {"ssd_rel_err_" + "x".join(map(str, sz["ssd_shape"])):
+           round(max(errs), 5),
+           "ssd_norms_out_dx_ddt_db_dc": [
+               round(float(jnp.linalg.norm(t.astype(jnp.float32))), 3)
+               for t in got]}
+    if on_chip():
+        fwd = jax.jit(functools.partial(ssd_scan, impl="flash"))
+        ms = [1e3 * statistics.median(round_trips(f, *xs, n=5))
+              for f in (fwd, both("flash"))]
+        out["ssd_ms_fwd_and_fwd_bwd"] = [round(t, 3) for t in ms]
+    return out
+
+
 def phase_window(sz):
     """The window kernels with a sink (Pallas on a chip, interpreted
     elsewhere) at the window cell's shape, grouped heads repeated before
@@ -660,6 +717,9 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rehearse", action="store_true",
                     help="toy sizes on any backend; never prints a pass")
+    ap.add_argument("--only", default="",
+                    help="comma-separated phases to run alone (never "
+                         "prints a pass): e.g. ssd,kda")
     opts = ap.parse_args()
 
     import jax
@@ -693,23 +753,36 @@ def main():
         compile_cache_dir=cache_dir, cache_entries_before=entries_before)
 
     keep = {}
-    run_phase("empty_dispatch", phase_dispatch)
-    run_phase("round_engine", phase_round_engine, sz)
-    run_phase("llm_lora_rounds", phase_llm_lora_rounds, sz, keep)
-    run_phase("llm_long_context", phase_llm_long_context, sz)
-    run_phase("serving", phase_serving, sz, keep)
-    keep.clear()
-    run_phase("kernels", phase_kernels, sz)
-    run_phase("kda", phase_kda, sz)
-    run_phase("window", phase_window, sz)
+    phases = [("empty_dispatch", phase_dispatch, ()),
+              ("round_engine", phase_round_engine, (sz,)),
+              ("llm_lora_rounds", phase_llm_lora_rounds, (sz, keep)),
+              ("llm_long_context", phase_llm_long_context, (sz,)),
+              ("serving", phase_serving, (sz, keep)),
+              ("kernels", phase_kernels, (sz,)),
+              ("kda", phase_kda, (sz,)),
+              ("ssd", phase_ssd, (sz,)),
+              ("window", phase_window, (sz,))]
     if len(devices) == 4:
-        run_phase("four_chip_llm", phase_four_chip_llm, sz)
+        phases.append(("four_chip_llm", phase_four_chip_llm, (sz,)))
+    only = set(filter(None, opts.only.split(",")))
+    unknown = only - {name for name, _, _ in phases}
+    if unknown:
+        ap.error(f"unknown phases {sorted(unknown)}")
+    for name, fn, fn_args in phases:
+        if name == "kernels":
+            keep.clear()
+        if not only or name in only:
+            run_phase(name, fn, *fn_args)
     say(phase="compile_cache", ok=True, compile_cache_dir=cache_dir,
         cache_entries_before=entries_before,
         cache_entries_after=cache_entries()[1],
         compiles=_compile["n"], compile_s=round(_compile["s"], 2),
         cache_hits=_compile["hits"])
 
+    if only:
+        print(f"chip_smoke: ran {sorted(only)} alone on {device}; this is "
+              "not a pass", file=sys.stderr)
+        return 3
     if opts.rehearse:
         print("chip_smoke: rehearsal finished — every phase ran at toy "
               f"size on {device}; this is not a pass", file=sys.stderr)

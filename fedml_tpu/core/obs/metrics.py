@@ -536,6 +536,17 @@ def record_kda_round(layer_steps: float) -> None:
                      "recorded round").inc(float(layer_steps))
 
 
+def record_ssm_round(layer_steps: float) -> None:
+    """Passes through a state-space (Mamba-2) layer (a layer and train
+    step) in one finished round, as the round program itself counted
+    them."""
+    if not _cfg["enabled"]:
+        return
+    REGISTRY.counter("fed_ssm_layer_steps_total",
+                     "passes through a state-space (Mamba-2) layer, every "
+                     "recorded round").inc(float(layer_steps))
+
+
 def record_window_round(layer_steps: float) -> None:
     """Passes through a sliding-window attention layer (a layer and train
     step) in one finished round, as the round program itself counted
@@ -599,6 +610,21 @@ def record_kda_plan(chunk: int, fused: bool) -> None:
                    "1 if the last traced KDA call ran the element-wise "
                    "work around its kernels in the fused passes, else 0"
                    ).set(1.0 if fused else 0.0)
+
+
+def record_ssd_plan(chunk: int, heads_per_step: int) -> None:
+    """The plan of the state-space call just traced (host side, once a
+    trace; ``llm/state_space.py::ssd_scan``): positions a chunk, and the
+    heads one grid step of the kernels works through (they share a group's
+    B and C)."""
+    if not _cfg["enabled"]:
+        return
+    REGISTRY.gauge("fed_ssd_chunk",
+                   "positions a chunk of the last traced state-space call"
+                   ).set(float(chunk))
+    REGISTRY.gauge("fed_ssd_heads_per_step",
+                   "heads a grid step of the last traced state-space "
+                   "call's kernels").set(float(heads_per_step))
 
 
 def record_recompile(program: str) -> None:

@@ -49,9 +49,12 @@ SCOPES = (
     "attn.window",        # projections, rotary, jnp.repeat of grouped
     "attn.latent",        # heads, layout copies, the kernels, the output
     "attn.linear",        # projection
+    "attn.ssm",           # a whole Mamba-2 mixer: projections, convolution,
+                          # gates, the SSD kernels, the grouped norm
     "mlp",                # the dense MLP and the shared expert
     "moe.route",          # the router, top-k, the gates
     "moe.experts",        # the plan, the grouped products, the slot sums
+    "moe.latent",         # the projections into and out of the experts' latent
     "norm",               # a decoder layer's two RMSNorms and residual adds
     "head",               # ln_f, the head product, float32 logits, the loss
     "lora",               # the rank-r side path, inside attn.* / mlp

@@ -3,12 +3,15 @@
 
 - ``model``: flax decoder (RMSNorm/rotary/SwiGLU) with grouped-query,
   latent or linear (Kimi delta) attention, one kind a layer, and dense or
-  sparse-expert layers, bf16 compute, MXU-shaped matmuls.
+  sparse-expert layers, or a stack of single-mixer layers (Mamba-2
+  state-space mixers, experts in a latent, attention without positions),
+  bf16 compute, MXU-shaped matmuls.
 - ``moe``: routing over all experts (plain or group-limited with a
   score-correction bias), the dropless plan for the experts a rank holds,
-  the Pallas grouped product over them.
+  the Pallas grouped product over them (SwiGLU or non-gated experts).
 - ``linear_attention``: the chunked gated delta rule, forward and the
   backward of its scan, as Pallas kernels and as ``jax.numpy``.
+- ``state_space``: Mamba-2's recurrence (SSD) in chunks, likewise.
 - ``attention``: dense golden + Pallas flash kernels (``d_qk != d_v``
   too) + ring attention over the ``sp`` mesh axis for long context.
 - ``lora``: adapters as a pure pytree transform; federated rounds ship

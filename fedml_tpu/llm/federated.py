@@ -60,6 +60,52 @@ def llm_config_from_args(args) -> LLMConfig:
     )
 
 
+def _nemotron_h_fields(config: dict, layers: int) -> dict:
+    """The :class:`LLMConfig` fields only a ``nemotron_h`` configuration
+    sets (a ``hybrid_override_pattern`` of Mamba-2, expert and attention
+    layers, one mixer a layer; ``relu2`` experts in a latent; attention
+    without rotary), or its refusal."""
+    get = config.get
+    pattern = get("hybrid_override_pattern") or ""
+    if len(pattern) != layers or set(pattern) - set("ME*"):
+        raise NotImplementedError(
+            f"hybrid_override_pattern {pattern!r}: one of M (Mamba-2), E "
+            f"(experts), * (attention) for each of the {layers} layers; the "
+            "dense '-' layer of smaller siblings is not built")
+    for key in ("use_bias", "mamba_proj_bias", "mlp_bias", "attention_bias"):
+        if get(key):
+            raise NotImplementedError(f"{key}: the projections are built "
+                                      "without biases")
+    if get("mamba_hidden_act", "silu") != "silu":
+        raise NotImplementedError(
+            f"mamba_hidden_act {get('mamba_hidden_act')!r}")
+    act = get("mlp_hidden_act", "relu2")
+    if act not in ("relu2", "silu"):
+        raise NotImplementedError(f"mlp_hidden_act {act!r}")
+    heads = int(config["mamba_num_heads"])
+    head_dim = int(config["mamba_head_dim"])
+    groups = int(get("n_groups") or 1)
+    if heads % groups:
+        raise NotImplementedError(
+            f"n_groups {groups} does not divide mamba_num_heads {heads}")
+    expand = int(get("expand") or 2)
+    if expand * int(config["hidden_size"]) != heads * head_dim:
+        raise NotImplementedError(
+            f"expand {expand} x hidden_size {config['hidden_size']} is not "
+            f"mamba_num_heads {heads} x mamba_head_dim {head_dim}")
+    return dict(
+        block_pattern=pattern, ssm_heads=heads, ssm_head_dim=head_dim,
+        ssm_state_size=int(config["ssm_state_size"]), ssm_groups=groups,
+        ssm_conv_kernel=int(get("conv_kernel") or 4),
+        ssm_conv_bias=bool(get("use_conv_bias", True)),
+        ssm_chunk=int(get("chunk_size") or 128),
+        mlp_activation="relu2" if act == "relu2" else "swiglu",
+        moe_latent_size=int(get("moe_latent_size") or 0),
+        shared_expert_size=int(
+            get("moe_shared_expert_intermediate_size") or 0),
+        use_rope=False)
+
+
 def llm_config_from_hf(config: dict, *, max_seq_len: int,
                        dtype: str = "float32", attention_impl: str = "dense",
                        first_expert: int = 0,
@@ -76,7 +122,14 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
     ``swa_rope_theta`` and the two ``add_*_attention_sink_bias`` flags,
     ``head_dim`` / ``v_head_dim`` beside grouped-query heads,
     ``partial_rotary_factor``, ``attention_value_scale``,
-    ``layernorm_epsilon``, ``moe_layer_freq`` as a list of zeros then ones).
+    ``layernorm_epsilon``, ``moe_layer_freq`` as a list of zeros then ones;
+    and of ``nemotron_h``: ``hybrid_override_pattern`` (``M`` a Mamba-2
+    mixer with ``mamba_num_heads``, ``mamba_head_dim``, ``ssm_state_size``,
+    ``n_groups``, ``conv_kernel``, ``chunk_size``, ``use_conv_bias``; ``E``
+    experts of ``mlp_hidden_act`` ``relu2`` in a ``moe_latent_size`` latent
+    beside a shared one of ``moe_shared_expert_intermediate_size``, routed
+    by sigmoid scores with a score-correction bias; ``*`` grouped-query
+    attention without rotary), one mixer a layer, ``layer_norm_epsilon``).
     ``sliding_window`` is read with a ``hybrid_layer_pattern`` only: alone
     it is left unread, as it always was (full causal attention is the same
     model up to that many positions). ``first_expert`` / ``experts_held``
@@ -130,7 +183,10 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
         raise NotImplementedError(
             f"short_conv_kernel_size {get('short_conv_kernel_size')}: the "
             f"short convolution is built over {SHORT_CONV_TAPS} positions")
-    noaux = method == "noaux_tc"
+    hybrid = (_nemotron_h_fields(config, layers)
+              if get("model_type") == "nemotron_h" else {})
+    # the nemotron_h router is the sigmoid one with a score-correction bias
+    noaux = method == "noaux_tc" or bool(hybrid)
     if get("attention_bias"):
         raise NotImplementedError("attention_bias: the projections are "
                                   "built without biases")
@@ -147,7 +203,8 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
     if head_size == quotient:
         head_size = 0
     rotary_dim = 0
-    if get("partial_rotary_factor") is not None and not (latent or group):
+    if get("partial_rotary_factor") is not None and not (
+            latent or group or hybrid):
         per_head = head_size or quotient
         rotary_dim = int(per_head * float(get("partial_rotary_factor")))
         if rotary_dim % 2 or rotary_dim <= 0:
@@ -166,7 +223,8 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
         num_kv_heads=get("num_key_value_heads"),
         max_seq_len=int(max_seq_len),
         rope_theta=float(get("rope_theta", 10000.0)),
-        rms_eps=float(get("rms_norm_eps", get("layernorm_epsilon", 1e-6))),
+        rms_eps=float(get("rms_norm_eps", get(
+            "layernorm_epsilon", get("layer_norm_epsilon", 1e-6)))),
         dtype=dtype, attention_impl=attention_impl,
         tie_embeddings=bool(get("tie_word_embeddings", False)),
         rope_scaling=dict(scaling) if scaling else None,
@@ -198,7 +256,8 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
         linear_head_dim=int(get("head_dim") or 0) if group else 0,
         kda_lower_bound=lower,
         attn_output_gate=bool(group) and get(
-            "gated_attention_proj_granularity_type") == "head_wise")
+            "gated_attention_proj_granularity_type") == "head_wise",
+        **hybrid)
 
 
 # sums a model with experts reports a step through the ``moe_stats``
@@ -247,7 +306,8 @@ class LLMBundle:
                    and cfg.n_group > 1 else ())
                 + (("kda_layer_steps",) if cfg.layer_group_size else ())
                 + (("attn_window_layer_steps",) if cfg.window_layers
-                   else ()))
+                   else ())
+                + (("ssm_layer_steps",) if cfg.ssm_layers else ()))
 
     def apply(self, params, x, rng=None, train=False, with_stats=False):
         """-> logits, or ``(logits, {name: sum})`` over
@@ -262,14 +322,16 @@ class LLMBundle:
             return self.module.apply(variables, x, train=train, **kwargs)
         logits, state = self.module.apply(
             variables, x, train=train,
-            mutable=["moe_stats", "kda_stats", "attn_stats"], **kwargs)
+            mutable=["moe_stats", "kda_stats", "attn_stats", "ssm_stats"],
+            **kwargs)
         sums = {}
-        for prefix, module in (("moe", "moe"), ("kda", "attn"),
-                               ("attn", "attn")):
+        for prefix in ("moe", "kda", "attn", "ssm"):
             for layer in state.get(prefix + "_stats", {}).values():
-                for k, v in layer[module].items():
-                    name = f"{prefix}_{k}"
-                    sums[name] = sums.get(name, 0.0) + v
+                # a layer's one module that sows under this collection
+                for module in layer.values():
+                    for k, v in module.items():
+                        name = f"{prefix}_{k}"
+                        sums[name] = sums.get(name, 0.0) + v
         return logits, sums
 
 

@@ -30,12 +30,18 @@ PyTree = Any
 # kernel parents targeted by default: attention projections (grouped-query
 # q k v o; latent attention's q_a q_b kv_a kv_b o, or q where it has no
 # query latent; linear attention's q k v o and its decay projection f) + the
-# dense MLP and the shared expert (gate up down). Routed experts, the
-# router and its bias, a window layer's sink, the attention gates (g), linear
-# attention's beta projection (b) and its convolutions carry no "kernel" leaf
-# under these names and stay frozen without adapters.
+# dense MLP and the shared expert (gate up down; up down where the
+# activation is not gated), a state-space mixer's in_proj and out_proj, and
+# the projections into and out of the experts' latent (latent_down
+# latent_up). Routed experts, the router and its bias, a window layer's
+# sink, the attention gates (g), linear attention's beta projection (b) and
+# its convolutions, a state-space mixer's convolution, A_log, D, dt_bias and
+# gated norm carry no "kernel" leaf under these names and stay frozen
+# without adapters.
 DEFAULT_TARGETS: Tuple[str, ...] = ("q", "k", "v", "o", "gate", "up", "down",
-                                    "q_a", "q_b", "kv_a", "kv_b", "f")
+                                    "q_a", "q_b", "kv_a", "kv_b", "f",
+                                    "in_proj", "out_proj", "latent_down",
+                                    "latent_up")
 
 
 def _target_paths(params: PyTree, targets: Sequence[str]):

@@ -117,6 +117,36 @@ class LLMConfig:
     window_rope_theta: float = 0.0
     window_sink: bool = False
     full_sink: bool = False
+    # a stack of single-mixer blocks (``block_pattern``: one character a
+    # layer, ``M`` a Mamba-2 state-space mixer, ``E`` the expert block,
+    # ``*`` grouped-query attention): layer i is ``x + mixer(norm(x))``, one
+    # norm and one mixer. A state-space mixer has ``ssm_heads`` heads of
+    # ``ssm_head_dim`` channels with a state of ``ssm_state_size`` a
+    # channel, B and C shared by the heads of each of ``ssm_groups``
+    # groups, a causal depthwise convolution over ``ssm_conv_kernel``
+    # positions (with a bias where ``ssm_conv_bias``) and runs in chunks
+    # of ``ssm_chunk`` positions
+    block_pattern: Optional[str] = None
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_groups: int = 1
+    ssm_conv_kernel: int = 4
+    ssm_conv_bias: bool = True
+    ssm_chunk: int = 128
+    # the feed-forward's activation, dense, shared and routed alike:
+    # ``swiglu`` (``silu(x W_gate) * (x W_up)``, three kernels) or ``relu2``
+    # (``relu(x W_up)^2``, two kernels and no gate product)
+    mlp_activation: str = "swiglu"
+    # routed experts that work in a latent of ``moe_latent_size`` (0: on the
+    # hidden state itself) between a projection down and one up; the router
+    # and the shared expert read the hidden state. ``shared_expert_size``:
+    # the shared expert's width (0: ``moe_intermediate_size *
+    # n_shared_experts``)
+    moe_latent_size: int = 0
+    shared_expert_size: int = 0
+    # False: attention without rotary or any other position term
+    use_rope: bool = True
 
     @property
     def head_dim(self) -> int:
@@ -144,19 +174,27 @@ class LLMConfig:
         return sum(map(bool, self.layer_pattern or ()))
 
     @property
+    def ssm_layers(self) -> int:
+        return (self.block_pattern or "").count("M")
+
+    @property
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
     def param_count(self) -> int:
         if (self.kv_lora_rank or self.n_routed_experts
                 or self.layer_group_size or self.layer_pattern
-                or self.head_size or self.v_head_dim):
+                or self.head_size or self.v_head_dim or self.block_pattern
+                or self.mlp_activation != "swiglu"):
             raise NotImplementedError(
                 "LLMConfig.param_count counts the dense grouped-query "
-                "decoder at head size hidden_size / num_heads alone; a "
-                "configuration with latent or linear attention, experts, "
-                "window layers or stated head sizes is counted from its "
-                "shapes under benchmarks/flops/")
+                "SwiGLU decoder at head size hidden_size / num_heads alone; "
+                "a configuration with latent or linear attention, experts, "
+                "window layers, stated head sizes, single-mixer blocks "
+                "(block_pattern: state-space layers, ssm_*; latent experts, "
+                "moe_latent_size, shared_expert_size) or a non-gated "
+                "feed-forward (mlp_activation) is counted from its shapes "
+                "under benchmarks/flops/")
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         per_layer = (h * h * 2 +                       # q, o
                      2 * h * self.kv_heads * self.head_dim +  # k, v
@@ -262,7 +300,8 @@ class Attention(nn.Module):
     configuration's window kind (its key-value heads, rotary base, sliding
     window and sink flag). ``q = x W_q`` as heads of ``head_dim``, ``k``
     likewise, ``v`` as heads of ``v_head_dim`` (default: the same size);
-    rotary on the first ``rotary_dim`` dims of every query and key head;
+    rotary on the first ``rotary_dim`` dims of every query and key head (on
+    none where ``use_rope`` is off: the scores then carry no position);
     ``v`` times ``attn_value_scale``; scores at ``head_dim ** -0.5``; with a
     sink, a frozen logit a query head beside the row's scores.
 
@@ -313,12 +352,13 @@ class Attention(nn.Module):
             "v": dense((kv_heads, d_v), "v")(x),
         }, adapter, lora_scale)
         q, k, v = qkv["q"], qkv["k"], qkv["v"]
-        rotary = cfg.rotary_dim or d_qk
-        freq = rope_frequencies(rotary, theta, cfg.rope_scaling)
+        rotary = (cfg.rotary_dim or d_qk) if cfg.use_rope else 0
+        if rotary:      # 0: no position term at all
+            freq = rope_frequencies(rotary, theta, cfg.rope_scaling)
         if rotary == d_qk:
             q = _rope(q, positions, freq)
             k = _rope(k, positions, freq)
-        else:       # the head's first dims turn, the others pass
+        elif rotary:    # the head's first dims turn, the others pass
             q, k = (jnp.concatenate(
                 [_rope(a[..., :rotary], positions, freq), a[..., rotary:]],
                 -1) for a in (q, k))
@@ -511,6 +551,11 @@ class LatentAttention(nn.Module):
 
 
 class MLP(nn.Module):
+    """The dense feed-forward (and the shared expert): ``(silu(x W_gate) *
+    (x W_up)) W_down`` where ``cfg.mlp_activation`` is ``swiglu``,
+    ``relu(x W_up)^2 W_down`` (no ``gate`` kernel) where it is ``relu2``;
+    no other activation exists."""
+
     cfg: LLMConfig
     width: Optional[int] = None   # None: cfg.intermediate_size
 
@@ -523,6 +568,13 @@ class MLP(nn.Module):
             param_dtype=jnp.float32)
 
         with scope("mlp"):
+            if cfg.mlp_activation == "relu2":
+                up = _add_lora(x, {"up": dense(width, "up")(x)}, adapter,
+                               lora_scale)["up"]
+                act = jnp.square(nn.relu(up))
+                return _add_lora(
+                    act, {"down": dense(cfg.hidden_size, "down")(act)},
+                    adapter, lora_scale)["down"]
             ys = _add_lora(x, {
                 "gate": dense(width, "gate")(x),
                 "up": dense(width, "up")(x),
@@ -543,7 +595,15 @@ class MoE(nn.Module):
     out what absent experts would add. Dropless. The routed experts and the
     router are frozen (no adapters, no weight gradient); the shared expert
     is an :class:`MLP` and takes ``adapter["shared"]``. Router load leaves
-    through the ``moe_stats`` collection as sums."""
+    through the ``moe_stats`` collection as sums.
+
+    The experts are SwiGLUs (``experts_gate`` / ``_up`` / ``_down``) or,
+    with ``mlp_activation`` ``relu2``, non-gated ``relu(x U)^2 V``
+    (``experts_up`` / ``_down`` alone); no other activation exists. With
+    ``moe_latent_size`` they work in a latent: ``l = x W_latent_down``, the
+    routed sum over ``E_e(l)``, then ``W_latent_up`` back to the hidden
+    size (both projections take adapters); the router and the shared
+    expert read ``x`` itself."""
 
     cfg: LLMConfig
 
@@ -554,13 +614,16 @@ class MoE(nn.Module):
         cfg = self.cfg
         b, s, h = x.shape
         held, width = cfg.held, cfg.moe_intermediate_size
+        inner = cfg.moe_latent_size or h      # the width the experts read
         init = nn.initializers.lecun_normal(batch_axis=(0,))
-        w_gate = self.param("experts_gate", init, (held, h, width))
-        w_up = self.param("experts_up", init, (held, h, width))
-        w_down = self.param("experts_down", init, (held, width, h))
+        w_gate = (None if cfg.mlp_activation == "relu2" else
+                  self.param("experts_gate", init, (held, inner, width)))
+        w_up = self.param("experts_up", init, (held, inner, width))
+        w_down = self.param("experts_down", init, (held, width, inner))
         shared = None
         if cfg.n_shared_experts:
-            shared = MLP(cfg, width * cfg.n_shared_experts, name="shared")(
+            shared = MLP(cfg, cfg.shared_expert_size
+                         or width * cfg.n_shared_experts, name="shared")(
                 x, adapter=None if adapter is None else adapter.get("shared"),
                 lora_scale=lora_scale)
         flat = x.reshape(b * s, h)
@@ -576,6 +639,18 @@ class MoE(nn.Module):
                                       cfg.routed_scaling_factor,
                                       cfg.norm_topk_prob, bias, cfg.n_group,
                                       cfg.topk_group)
+
+        def latent(t, feats, name):
+            """One of the two projections around the routed experts, its
+            adapter's side path added."""
+            with scope("moe.latent"):
+                y = nn.DenseGeneral(
+                    feats, use_bias=False, name=name, dtype=cfg.compute_dtype,
+                    param_dtype=jnp.float32)(t)
+                return _add_lora(t, {name: y}, adapter, lora_scale)[name]
+
+        if cfg.moe_latent_size:
+            flat = latent(x, inner, "latent_down").reshape(b * s, inner)
         with scope("moe.experts"):
             routed, stats = moe.routed_experts(
                 flat, gates, chosen, w_gate, w_up, w_down, cfg.first_expert,
@@ -588,9 +663,132 @@ class MoE(nn.Module):
         for k, v in stats.items():
             self.sow("moe_stats", k, v, init_fn=lambda: jnp.float32(0),
                      reduce_fn=jnp.add)
+        if cfg.moe_latent_size:
+            routed = latent(routed.reshape(b, s, inner).astype(x.dtype), h,
+                            "latent_up")
         if shared is None:
             return routed.reshape(b, s, h).astype(x.dtype)
         return shared + routed.reshape(b, s, h).astype(shared.dtype)
+
+
+def _causal_conv(x, w, bias):
+    """Causal depthwise convolution ``y_t = bias + sum_j w[j] x_{t-(K-1)+j}``
+    with zeros before the row, in float32. x [b, s, c], w [K, c]."""
+    taps, s = w.shape[0], x.shape[1]
+    xp = jnp.pad(x.astype(jnp.float32), [(0, 0), (taps - 1, 0), (0, 0)])
+    y = sum(xp[:, j:j + s] * w[j].astype(jnp.float32) for j in range(taps))
+    return y if bias is None else y + bias.astype(jnp.float32)
+
+
+class Mamba2(nn.Module):
+    """The Mamba-2 mixer (``llm/state_space.py``): ``[z | xBC | dt] = x
+    W_in``; ``xBC = SiLU(conv(xBC))`` through a causal depthwise convolution
+    with a bias, split into ``x`` (``ssm_heads`` heads of ``ssm_head_dim``),
+    ``B`` and ``C`` (``ssm_groups`` groups of ``ssm_state_size``); step size
+    ``delta = softplus(dt + dt_bias)`` a head, unclamped; decay ``A =
+    -exp(A_log)`` a head; the recurrence ``S_t = exp(delta A) S_{t-1} +
+    delta x_t B_t^T``, ``y_t = S_t C_t + D x_t`` over the row from a zero
+    state; ``u = y * SiLU(z)`` normalised over each group's channels
+    (``u / sqrt(mean u^2 + eps) * w``); ``u W_out``. Adapters on ``in_proj``
+    and ``out_proj``; the convolution, ``A_log``, ``D``, ``dt_bias`` and the
+    norm are frozen. A masked position neither writes nor decays the state.
+    The element-wise work around the kernels is XLA's. Training path only:
+    a recurrent state is no list of cached blocks."""
+
+    cfg: LLMConfig
+
+    @nn.compact
+    def __call__(self, x, positions, attn_mask=None, kv_view=None,
+                 adapter=None, lora_scale: float = 1.0):
+        del positions   # the recurrence carries order
+        if kv_view is not None:
+            raise NotImplementedError(
+                "a state-space mixer has no cache path: llm/kv_cache.py "
+                "holds a list of key/value blocks a position, not the one "
+                "recurrent state and convolution tail a row of a Mamba-2 "
+                "layer carries")
+        from .state_space import ssd_scan
+
+        cfg = self.cfg
+        b, s, _ = x.shape
+        f32 = jnp.float32
+        nh, p, n, g = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size,
+                       cfg.ssm_groups)
+        inner, wide = nh * p, nh * p + 2 * g * n
+        dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
+            feats, axis=-1, use_bias=False, name=name,
+            dtype=cfg.compute_dtype, param_dtype=jnp.float32)
+
+        zxbcdt = _add_lora(x, {"in_proj": dense(inner + wide + nh,
+                                                "in_proj")(x)},
+                           adapter, lora_scale)["in_proj"]
+        conv_w = self.param("conv_w", nn.initializers.lecun_normal(),
+                            (cfg.ssm_conv_kernel, wide))
+        conv_b = (self.param("conv_b", nn.initializers.zeros, (wide,))
+                  if cfg.ssm_conv_bias else None)
+        a_log = self.param("A_log", nn.initializers.zeros, (nh,))
+        skip = self.param("D", nn.initializers.ones, (nh,))
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (nh,))
+        scale = _NormScale(name="norm")(inner)
+
+        z, xbc = zxbcdt[..., :inner], zxbcdt[..., inner:inner + wide]
+        dt = jax.nn.softplus(zxbcdt[..., inner + wide:].astype(f32)
+                             + dt_bias.astype(f32))
+        if attn_mask is not None:
+            keep = attn_mask.astype(f32)[..., None]
+            xbc, dt = xbc * keep.astype(xbc.dtype), dt * keep
+        # rebuilt in the backward pass from its bfloat16 input: the float32
+        # rows before the SiLU are not kept
+        xbc = jax.checkpoint(lambda t, w, c: jax.nn.silu(
+            _causal_conv(t, w, c)).astype(x.dtype))(xbc, conv_w, conv_b)
+        y = ssd_scan(xbc[..., :inner].reshape(b, s, nh, p), dt,
+                     -jnp.exp(a_log.astype(f32)),
+                     xbc[..., inner:inner + g * n].reshape(b, s, g, n),
+                     xbc[..., inner + g * n:].reshape(b, s, g, n),
+                     skip, impl=cfg.attention_impl, chunk=cfg.ssm_chunk)
+        @jax.checkpoint     # float32 inside, rebuilt from y and z
+        def gated_norm(y, z, scale):
+            """The gate multiplies before the norm, which runs a group."""
+            u = (y.reshape(b, s, inner).astype(f32)
+                 * jax.nn.silu(z.astype(f32))).reshape(b, s, g, inner // g)
+            u = u * jax.lax.rsqrt(jnp.mean(jnp.square(u), -1, keepdims=True)
+                                  + cfg.rms_eps)
+            return (u.reshape(b, s, inner) * scale.astype(f32)).astype(x.dtype)
+
+        u = gated_norm(y, z, scale)
+        out = dense(cfg.hidden_size, "out_proj")(u)
+        self.sow("ssm_stats", "layer_steps", jnp.float32(1),
+                 init_fn=lambda: jnp.float32(0), reduce_fn=jnp.add)
+        return _add_lora(u, {"out_proj": out}, adapter,
+                         lora_scale)["out_proj"], None
+
+
+class MixerBlock(nn.Module):
+    """One layer of a ``block_pattern`` stack: ``x + mixer(norm(x))``, ONE
+    norm and ONE mixer, of the ``kind`` the pattern gives the layer: ``M``
+    :class:`Mamba2`, ``E`` :class:`MoE`, ``*`` :class:`Attention`."""
+
+    cfg: LLMConfig
+    kind: str = "M"
+
+    @nn.compact
+    def __call__(self, x, positions, attn_mask=None, kv_view=None,
+                 adapter=None, lora_scale: float = 1.0):
+        adapter = (adapter or {}).get("mixer")
+        with scope("norm"):
+            normed = RMSNorm(self.cfg.rms_eps, name="norm")(x)
+        if self.kind == "E":
+            out, new_kv = MoE(self.cfg, name="mixer")(
+                normed, adapter=adapter, lora_scale=lora_scale), None
+        else:
+            mixer, name = ((Mamba2, "attn.ssm") if self.kind == "M"
+                           else (Attention, "attn.full"))
+            with scope(name):
+                out, new_kv = mixer(self.cfg, name="mixer")(
+                    normed, positions, attn_mask, kv_view=kv_view,
+                    adapter=adapter, lora_scale=lora_scale)
+        with scope("norm"):
+            return x + out, new_kv
 
 
 class DecoderLayer(nn.Module):
@@ -669,8 +867,11 @@ class CausalLM(nn.Module):
         for i in range(cfg.num_layers):
             sparse = bool(cfg.n_routed_experts) and \
                 i >= cfg.first_k_dense_replace
-            x, new_kv = DecoderLayer(cfg, sparse, cfg.is_linear(i),
-                                     cfg.is_window(i), name=f"layer_{i}")(
+            layer = (MixerBlock(cfg, cfg.block_pattern[i], name=f"layer_{i}")
+                     if cfg.block_pattern else
+                     DecoderLayer(cfg, sparse, cfg.is_linear(i),
+                                  cfg.is_window(i), name=f"layer_{i}"))
+            x, new_kv = layer(
                 x, positions, attn_mask,
                 kv_view=None if kv_view is None else kv_view[i],
                 adapter=None if adapters is None

@@ -32,6 +32,11 @@ works from the gate and up products the forward pass kept (two ``[compact_rows,
 width]`` arrays a layer and step stay alive between the passes). At the
 worst-case size nothing of that size is kept: the backward pass rebuilds
 the two products there and runs the same pull-back (2 + 3).
+
+An expert is a SwiGLU (``silu(x W_gate) * (x W_up)) W_down``, three
+kernels) or, where the caller hands no ``w_gate`` (a static branch), the
+non-gated ``relu(x W_up)^2 W_down`` of two: two grouped products forward
+and two ``dx`` backward, from the one up product the forward pass kept.
 """
 
 from __future__ import annotations
@@ -279,11 +284,12 @@ def _mm(r: Rows, p: Plan, tile_m: int, transpose_rhs: bool = False):
 
 def _gate_up(rows: int, tile_m: int, x, w_gate, w_up, p: Plan):
     """The plan laid into ``rows`` rows, and the gate and up products of
-    the rows' tokens, ``a = xs W_gate`` and ``b = xs W_up`` [rows, width]."""
+    the rows' tokens, ``a = xs W_gate`` (None for a non-gated expert) and
+    ``b = xs W_up`` [rows, width]."""
     r = place(p, rows, tile_m)
     xs = x[r.row_token]
     mm = _mm(r, p, tile_m)
-    return r, mm(xs, w_gate), mm(xs, w_up)
+    return r, None if w_gate is None else mm(xs, w_gate), mm(xs, w_up)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
@@ -294,7 +300,8 @@ def _pass_at(rows: int, tile_m: int, x, gates, w_gate, w_up, w_down, p: Plan):
     expert layers of one shape are traced and lowered once a size and
     direction, not once a layer."""
     r, a, b = _gate_up(rows, tile_m, x, w_gate, w_up, p)
-    y = _mm(r, p, tile_m)(jax.nn.silu(a) * b, w_down)
+    act = jnp.square(jax.nn.relu(b)) if a is None else jax.nn.silu(a) * b
+    y = _mm(r, p, tile_m)(act, w_down)
     return _combine(y, gates, p), a, b
 
 
@@ -310,24 +317,46 @@ def _pull_back(rows: int, tile_m: int, a, b, g, gates, w_gate, w_up, w_down,
     gets ``u`` times the row's gate. Elementwise work and sums in float32,
     the products' operands in the compute dtype; a row's numbers stay in
     its row until ``_slot_sum`` and the gates' gather select the held
-    slots' rows, so an unwritten row reaches no sum."""
-    f32, dtype = jnp.float32, a.dtype
+    slots' rows, so an unwritten row reaches no sum. A non-gated expert
+    (``a`` None) has ``h = relu(b)^2``: ``d_gates = <h, u>``, ``d_b = 2
+    relu(b) d_h``, two ``dx`` products."""
+    f32, dtype = jnp.float32, b.dtype
     r = place(p, rows, tile_m)
     mm_t = _mm(r, p, tile_m, transpose_rhs=True)
     g_rows = jnp.where(r.row_used[:, None], g.astype(dtype)[r.row_token], 0)
     u = mm_t(g_rows, w_down).astype(f32)
+    if a is None:
+        return _pull_back_relu2(rows, b, u, gates, w_up, p, mm_t)
     a, b = a.astype(f32), b.astype(f32)
     sig = jax.nn.sigmoid(a)
     d_gates = jnp.where(p.slot_held,
                         jnp.sum(a * sig * b * u, -1)[p.slot_row], 0.0)
-    row_w = jnp.zeros((rows,), f32).at[
-        jnp.where(p.slot_held, p.slot_row, rows).reshape(-1)].set(
-        gates.astype(f32).reshape(-1), mode="drop")
-    d_h = u * row_w[:, None]
+    d_h = u * _row_weights(rows, gates, p)[:, None]
     d_a = d_h * b * sig * (1 + a * (1 - sig))
     d_b = d_h * a * sig
     d_xs = mm_t(d_a.astype(dtype), w_gate) + mm_t(d_b.astype(dtype), w_up)
     dx = _slot_sum(d_xs, p.slot_held.astype(f32), p.slot_row)
+    return dx.astype(dtype), d_gates.astype(gates.dtype)
+
+
+def _row_weights(rows: int, gates, p: Plan):
+    """[rows] float32: each held slot's gate at its row, 0 elsewhere."""
+    return jnp.zeros((rows,), jnp.float32).at[
+        jnp.where(p.slot_held, p.slot_row, rows).reshape(-1)].set(
+        gates.astype(jnp.float32).reshape(-1), mode="drop")
+
+
+def _pull_back_relu2(rows: int, b, u, gates, w_up, p: Plan, mm_t):
+    """``_pull_back``'s second half for the non-gated expert ``relu(b)^2``:
+    ``b`` the kept up product and ``u`` [rows, width] float32 the
+    unweighted cotangent rows through ``W_down^T``."""
+    f32, dtype = jnp.float32, b.dtype
+    rb = jax.nn.relu(b.astype(f32))
+    d_gates = jnp.where(p.slot_held, jnp.sum(rb * rb * u, -1)[p.slot_row],
+                        0.0)
+    d_b = u * _row_weights(rows, gates, p)[:, None] * (2.0 * rb)
+    dx = _slot_sum(mm_t(d_b.astype(dtype), w_up), p.slot_held.astype(f32),
+                   p.slot_row)
     return dx.astype(dtype), d_gates.astype(gates.dtype)
 
 
@@ -380,8 +409,9 @@ def _expert_pass_fwd(compact, full, tile_m, x, gates, w_gate, w_up, w_down, p):
     rows = min(compact, full)
 
     def worst():
-        blank = jnp.zeros((rows, w_gate.shape[2]), x.dtype)
-        return _pass_at(full, tile_m, *args)[0], blank, blank
+        blank = jnp.zeros((rows, w_up.shape[2]), x.dtype)
+        return (_pass_at(full, tile_m, *args)[0],
+                None if w_gate is None else blank, blank)
 
     y, a, b = _sized(p, compact, full,
                      lambda: _pass_at(rows, tile_m, *args), worst)
@@ -412,12 +442,13 @@ def routed_experts(x, gates, experts, w_gate, w_up, w_down,
     """The held experts' part of the layer: x [T, H], the routing of every
     token (``gates``, ``experts`` [T, k]) over ``n_experts`` and this
     rank's frozen SwiGLU kernels ``w_gate`` / ``w_up`` [G, H, I], ``w_down``
-    [G, I, H] -> ([T, H] float32, stats). The row buffers have
+    [G, I, H] (``w_gate`` None: non-gated ``relu^2`` experts of ``w_up`` and
+    ``w_down`` alone) -> ([T, H] float32, stats). The row buffers have
     ``compact_rows`` rows where this routing fits them and ``buffer_rows``
     where it does not; ``kept_steps`` is 1 where a backward pass will work
     from the forward's products (at the compact size, and where the worst
     case is no larger and ``compact_steps`` so reads 0)."""
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     t, k = experts.shape
     tile_m = tile_rows(experts.size)
     full = buffer_rows(t, k, held, tile_m)

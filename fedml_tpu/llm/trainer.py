@@ -31,11 +31,11 @@ class CausalLMTrainer(TrainerSpec):
 
     ``extra_metrics``: names of further sums the model reports a step
     (``LLMBundle.extra_metrics``: router load of a model with experts,
-    passes through linear-attention layers and through window layers);
+    passes through linear-attention, window and state-space layers);
     ``apply_fn(..., with_stats=True)`` then returns them beside the logits,
     the training metrics carry them out of the round program, and
     :meth:`record_round_counters` turns a round's sums into ``fed_moe_*``
-    ``fed_kda_*`` and ``fed_attn_window_*``."""
+    ``fed_kda_*``, ``fed_attn_window_*`` and ``fed_ssm_*``."""
 
     def __init__(self, apply_fn, extra_metrics=()):
         super().__init__(apply_fn)
@@ -54,6 +54,8 @@ class CausalLMTrainer(TrainerSpec):
             obs_metrics.record_kda_round(sums["kda_layer_steps"])
         if "attn_window_layer_steps" in sums:
             obs_metrics.record_window_round(sums["attn_window_layer_steps"])
+        if "ssm_layer_steps" in sums:
+            obs_metrics.record_ssm_round(sums["ssm_layer_steps"])
 
     def _stats(self, params, batch, rng, train):
         kwargs = {"train": train}

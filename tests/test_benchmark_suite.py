@@ -7,8 +7,9 @@ files unedited and re-exports their tests (parametrisation and fixtures as
 the files have them) as ``<file>__<test>``, so each counts and names
 itself when it fails. The tests that run whole rounds or subprocesses
 (``test_correct.py``, ``test_rehearsal.py``, the first four of
-``test_axk1.py``, the first two of ``test_ling3.py`` and of
-``test_mimo.py``: over a minute each on a CPU) stay outside tier-1.
+``test_axk1.py``, the first two of ``test_ling3.py``, of ``test_mimo.py``
+and of ``test_nemotron.py``: over a minute each on a CPU) stay outside
+tier-1.
 """
 
 import importlib
@@ -45,6 +46,13 @@ _TAKEN = {
                   "test_window_reader_finds_its_kernels_by_name_and_the_"
                   "flash_reader_not",
                   "test_block_share_reader_reads_the_gauge_or_nothing"),
+    "test_nemotron": ("test_manifest_entries_are_the_issues",
+                      "test_every_number_of_the_catalogs_config_is_in_the_"
+                      "file",
+                      "test_work_functions_against_hand_counts",
+                      "test_ssd_reader_finds_kernels_by_name_only",
+                      "test_the_two_scope_readers_read_their_scope_or_"
+                      "nothing"),
 }
 _LEFT_OUT = {
     # pins PR 26's five entries as the LAST five of BENCHMARK.json's
@@ -58,6 +66,13 @@ _LEFT_OUT = {
     # stands in and pins every other key of the entry
     ("test_moe_compact_share",
      "test_manifest_names_the_reader_for_the_axk1_cell_alone"),
+    # pins PR 36's thirteen ``scope_*`` entries as the LAST thirteen of
+    # per_layer with their ``workloads`` lists to the letter; PR 38 appended
+    # a seventh cell to those lists and three entries after them, as the
+    # contract lets a model_config PR do, and may not edit the file (PERF.md
+    # section 7 (h)): ``test_the_scope_entries_are_as_accepted_but_for_
+    # their_cells`` stands in and pins every other key
+    ("test_scopes", "test_manifest_has_the_thirteen_entries_with_their_cells"),
 }
 
 
@@ -117,10 +132,16 @@ def test_expert_layer_metrics_list_the_cells_with_experts():
         if any(k in manifest.load_json("configs", w["config"] + ".json")
                for k in ("n_routed_experts", "num_experts"))]
     assert with_experts[0] == "axk1_lora_silo2_seq4096"
+    latent = [w for w in with_experts if manifest.Cell(w).config.get(
+        "moe_latent_size")]
+    assert latent == ["nemotron3_super_lora_silo2_seq4096"]
     for m in bench["per_layer"]:
         if m["layer"] == "expert layer" and m["name"] != \
                 "moe_tokens_here_share":
-            assert m["workloads"] == with_experts, m["name"]
+            # the latent projections' scope: the cells with a latent alone
+            assert m["workloads"] == (
+                latent if m["name"] == "scope_moe_latent_ms"
+                else with_experts), m["name"]
             assert m["moves"] == "round_s"
     for cell in with_experts:
         flops = manifest.load_module(
@@ -145,6 +166,65 @@ def test_moe_compact_share_entry_is_as_accepted_but_for_its_cells():
         "moves": "round_s"}]
     assert cells[0] == "axk1_lora_silo2_seq4096"
     assert len(cells) == len(set(cells))
+
+
+def test_the_scope_entries_are_as_accepted_but_for_their_cells():
+    """What ``test_manifest_has_the_thirteen_entries_with_their_cells``
+    pinned, key by key, but for ``workloads`` and for being the list's last
+    thirteen: PR 36's thirteen entries stay together in their order, and
+    each scope metric lists, in the benchmark's order, the cells whose
+    configuration has that kind of layer; the scope metrics later PRs
+    brought follow the same rule."""
+    sys.path.insert(0, os.path.dirname(_BENCH_TESTS))
+    from harness import manifest
+    bench = manifest.benchmark()
+    cells = [w["name"] for w in bench["workloads"]]
+    cfgs = {c: manifest.Cell(c).config for c in cells}
+    lm = [c for c in cells if cfgs[c]["input"]["kind"] == "tokens"]
+    resnet = [c for c in cells if c not in lm]
+    experts = [c for c in lm if any(k in cfgs[c] for k in (
+        "n_routed_experts", "num_experts"))]
+    mixers = {c: cfgs[c].get("hybrid_override_pattern", "") for c in lm}
+    latent = [c for c in lm if cfgs[c].get("kv_lora_rank")]
+    linear = [c for c in lm if cfgs[c].get("layer_group_size")]
+    window = [c for c in lm if cfgs[c].get("hybrid_layer_pattern")]
+    # grouped-query softmax layers: what is neither latent, linear nor a
+    # single-mixer stack has them in every layer; a stack where it says *
+    full = [c for c in lm if (c not in latent and c not in linear
+                              and not mixers[c]) or "*" in mixers[c]]
+    train = "local training / LLM train step"
+    want = {"scope_engine_ms": ("round engine", cells),
+            "scope_conv_ms": (train, resnet),
+            "scope_norm_ms": (train, resnet),
+            "scope_attn_full_ms": (train, full),
+            "scope_attn_window_ms": (train, window),
+            "scope_attn_latent_ms": (train, latent),
+            "scope_attn_linear_ms": (train, linear),
+            "scope_mlp_ms": (train, lm), "scope_head_ms": (train, lm),
+            "scope_lora_ms": (train, lm),
+            "scope_moe_route_ms": ("expert layer", experts),
+            "scope_moe_experts_ms": ("expert layer", experts),
+            "scope_unscoped_share": ("device", cells),
+            "scope_attn_ssm_ms": (train, [c for c in lm if "M" in mixers[c]]),
+            "scope_moe_latent_ms": ("expert layer", [
+                c for c in experts if cfgs[c].get("moe_latent_size")])}
+    entries = [m for m in bench["per_layer"]
+               if m["name"].startswith("scope_")]
+    assert [m["name"] for m in entries] == list(want)
+    at = [m["name"] for m in bench["per_layer"]].index("scope_engine_ms")
+    assert bench["per_layer"][at:at + 13] == entries[:13]
+    for m in entries:
+        layer, listed = want[m["name"]]
+        assert m == {"name": m["name"],
+                     "unit": "%" if m["name"] == "scope_unscoped_share"
+                     else "ms",
+                     "better": "lower", "source": "device_trace",
+                     "layer": layer, "moves": "round_s",
+                     "workloads": listed}, m["name"]
+        assert os.path.exists(os.path.join(manifest.ROOT, "metrics",
+                                           m["name"] + ".py"))
+        for cell in listed:
+            assert m in manifest.Cell(cell).per_layer
 
 
 def test_the_suite_takes_what_it_says():

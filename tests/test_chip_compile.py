@@ -24,6 +24,7 @@ from fedml_tpu.llm.attention import (FLASH_KERNEL_NAMES, WINDOW_KERNEL_NAMES,
                                      flash_causal_attention)
 from fedml_tpu.llm.linear_attention import (KDA_KERNEL_NAMES, KDA_PASS_NAMES,
                                             kda_attention, kda_layer)
+from fedml_tpu.llm.state_space import SSD_KERNEL_NAMES, ssd_scan
 
 pytestmark = pytest.mark.pallas
 
@@ -236,6 +237,57 @@ def test_kda_kernels_compile_for_v5e(v5e, dtype, heads):
     assert text.count("tpu_custom_call") == 2
     for name in KDA_KERNEL_NAMES:
         assert name in text, name
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_ssd_kernels_compile_for_v5e(v5e, dtype):
+    """The state-space layer's shape in the benchmark (128 heads of 64
+    channels, state 128, 8 groups, 4,096 positions in chunks of 128),
+    bfloat16 and float32 operands: the forward kernel and the backward
+    one, whose body is ``jax.vjp`` of the chunk step, compiled by Mosaic
+    and found in the HLO under their names."""
+    f32 = jnp.float32
+    x = jax.ShapeDtypeStruct((1, 4096, 128, 64), dtype)
+    dt = jax.ShapeDtypeStruct((1, 4096, 128), f32)
+    head = jax.ShapeDtypeStruct((128,), f32)
+    bc = jax.ShapeDtypeStruct((1, 4096, 8, 128), dtype)
+    text = _compile(
+        lambda *a: jax.value_and_grad(
+            lambda *a: ssd_scan(*a, impl="flash").astype(f32).sum(),
+            argnums=(0, 1, 3, 4))(*a),
+        v5e, x, dt, head, bc, bc, head).as_text()
+    assert text.count("tpu_custom_call") == 2
+    for name in SSD_KERNEL_NAMES:
+        assert name in text, name
+
+
+def test_the_non_gated_expert_pass_compiles_for_v5e(v5e):
+    """The latent expert layer at the benchmark's shapes (4,096 tokens
+    top-22 of 512, 64 held at 1,024 -> 2,688 -> 1,024: worst case 106,496
+    rows, compact 38,912), forward and backward: two grouped products a
+    direction at the compact size (``up`` and ``down``; their two ``dx``
+    from the kept up product), 2 + (1 + 2) at the worst-case one."""
+    t, lat, width, held, k, experts = 4096, 1024, 2688, 64, 22, 512
+    assert moe.buffer_rows(t, k, held, 256) == 106496
+    assert moe.compact_rows(t, k, held, experts, 256) == 38912
+    x = jax.ShapeDtypeStruct((t, lat), jnp.bfloat16)
+    router = jax.ShapeDtypeStruct((lat, experts), jnp.float32)
+    w_up = jax.ShapeDtypeStruct((held, lat, width), jnp.bfloat16)
+    w_down = jax.ShapeDtypeStruct((held, width, lat), jnp.bfloat16)
+
+    def step(x, router, w_up, w_down):
+        def loss(x):
+            gates, chosen = moe.route(x.astype(jnp.float32) @ router, k, 5.0)
+            y, stats = moe.routed_experts(x, gates, chosen, None, w_up,
+                                          w_down, 192, experts)
+            return jnp.sum(jnp.sin(y)), stats
+        return jax.grad(loss, has_aux=True)(x)
+
+    text = _compile(step, v5e, x, router, w_up, w_down).as_text()
+    assert text.count(" conditional(") == 2
+    assert text.count("tpu_custom_call") == 2 * 2 + 2 + (1 + 2)
+    entry = text[text.index("\nENTRY "):]
+    assert "[106496" not in entry and "[106496" in text
 
 
 def _kda_layer_step(heads, masked):

@@ -33,7 +33,7 @@ def test_scope_refuses_a_name_outside_the_vocabulary():
     for name in ("attn", "attn.window.kernel", "Attn.Full", ""):
         with pytest.raises(ValueError, match="unknown scope"):
             scopes.scope(name)
-    assert len(set(scopes.SCOPES)) == len(scopes.SCOPES) == 20
+    assert len(set(scopes.SCOPES)) == len(scopes.SCOPES) == 22
 
 
 @pytest.mark.parametrize("op_name, want", [
@@ -273,9 +273,11 @@ def test_a_stale_cache_entry_does_not_decide_the_scopes(tmp_path):
 
     from fedml_tpu.core import mlops
     mlops.install_compile_counter()
+    # the arguments first: their own tiny programs may be answered by the
+    # session's shared cache directory, which another worker fills
+    args = (jnp.ones((4, 8)), jnp.ones((8, 8)))
     start = mlops.compile_phases()["cache_hits"]
     hits = lambda: mlops.compile_phases()["cache_hits"] - start  # noqa: E731
-    args = (jnp.ones((4, 8)), jnp.ones((8, 8)))
     was = jax.config.jax_compilation_cache_dir
     jax.config.update("jax_compilation_cache_dir", str(tmp_path))
     cc.reset_cache()
