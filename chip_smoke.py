@@ -567,6 +567,57 @@ def phase_ssd(sz):
         ms = [1e3 * statistics.median(round_trips(f, *xs, n=5))
               for f in (fwd, both("flash"))]
         out["ssd_ms_fwd_and_fwd_bwd"] = [round(t, 3) for t in ms]
+    out.update(_ssm_passes(sz, gap))
+    return out
+
+
+def _ssm_passes(sz, gap):
+    """The four passes around the kernels (``ssm_layer``) against XLA's
+    form of the same work around the same kernels, at the same shape with
+    a mask: the output and the ``in_proj`` product's cotangent, bfloat16
+    as the cell holds it; the convolution and step sizes drawn as the
+    benchmark's reference draws them."""
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu.llm.state_space import ssm_layer
+
+    b, s, h, p, g, n = sz["ssd_shape"]
+    inner, wide = h * p, h * p + 2 * g * n
+    ks = jax.random.split(jax.random.PRNGKey(23), 8)
+    bf = jnp.bfloat16
+    zx = jax.random.normal(ks[0], (b, s, inner + wide + h), bf)
+    dt = jnp.exp(jax.random.uniform(ks[1], (h,), minval=math.log(1e-3),
+                                    maxval=math.log(0.1)))
+    params = ((0.5 * jax.random.normal(ks[2], (4, wide))).astype(bf),
+              jax.random.uniform(ks[3], (wide,), minval=-0.1,
+                                 maxval=0.1).astype(bf),
+              jnp.log(jax.random.uniform(ks[4], (h,), minval=1.0,
+                                         maxval=16.0)).astype(bf),
+              jnp.ones((h,), bf),
+              (dt + jnp.log(-jnp.expm1(-dt))).astype(bf),
+              (1 + 0.1 * jax.random.normal(ks[5], (inner,))).astype(bf))
+    mask = jnp.ones((b, s), jnp.int32).at[:, s // 3:s // 3 + 5].set(0)
+    ct = jax.random.normal(ks[6], (b, s, inner), jnp.float32)
+
+    def layer(fused):
+        def fn(zx):
+            def loss(zx):
+                out = ssm_layer(zx, mask, *params, heads=h, head_dim=p,
+                                groups=g, state=n, eps=1e-5, fused=fused)
+                return jnp.sum(out.astype(jnp.float32) * ct), out
+            (_, out), dzx = jax.value_and_grad(loss, has_aux=True)(zx)
+            return out, dzx
+        return jax.jit(fn)
+
+    got, want = layer(True)(zx), layer(False)(zx)
+    errs = [gap(a, w) for a, w in zip(got, want)]
+    check(max(errs) < 0.01, f"SSM passes vs XLA's form: {errs}")
+    out = {"ssm_passes_rel_err_out_dzx": [round(e, 6) for e in errs]}
+    if on_chip():
+        out["ssm_layer_fwd_bwd_ms_passes_vs_xla"] = [
+            round(1e3 * statistics.median(round_trips(layer(f), zx, n=5)), 3)
+            for f in (True, False)]
     return out
 
 

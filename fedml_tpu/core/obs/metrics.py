@@ -612,13 +612,19 @@ def record_kda_plan(chunk: int, fused: bool) -> None:
                    ).set(1.0 if fused else 0.0)
 
 
-def record_ssd_plan(chunk: int, heads_per_step: int) -> None:
+def record_ssd_plan(chunk: int, heads_per_step: int, fused: bool) -> None:
     """The plan of the state-space call just traced (host side, once a
-    trace; ``llm/state_space.py::ssd_scan``): positions a chunk, and the
-    heads one grid step of the kernels works through (they share a group's
-    B and C)."""
+    trace; ``llm/state_space.py``): positions a chunk, the heads one grid
+    step of the kernels works through (they share a group's B and C), and
+    whether the layer's element-wise work around the kernels ran through
+    the fused passes (``ssm_layer``) or the caller made the kernels'
+    operands itself (``ssd_scan``)."""
     if not _cfg["enabled"]:
         return
+    REGISTRY.gauge("fed_ssm_fused",
+                   "1 if the last traced Mamba-2 call ran the element-wise "
+                   "work around its kernels in the fused passes, else 0"
+                   ).set(1.0 if fused else 0.0)
     REGISTRY.gauge("fed_ssd_chunk",
                    "positions a chunk of the last traced state-space call"
                    ).set(float(chunk))

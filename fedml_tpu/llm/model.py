@@ -692,8 +692,10 @@ class Mamba2(nn.Module):
     (``u / sqrt(mean u^2 + eps) * w``); ``u W_out``. Adapters on ``in_proj``
     and ``out_proj``; the convolution, ``A_log``, ``D``, ``dt_bias`` and the
     norm are frozen. A masked position neither writes nor decays the state.
-    The element-wise work around the kernels is XLA's. Training path only:
-    a recurrent state is no list of cached blocks."""
+    The module makes the two products; on the ``flash`` path everything
+    between them is ``ssm_layer``'s (fused passes around the kernels,
+    float32 inside), on any other it is :meth:`_dense`'s ``jax.numpy``.
+    Training path only: a recurrent state is no list of cached blocks."""
 
     cfg: LLMConfig
 
@@ -707,11 +709,9 @@ class Mamba2(nn.Module):
                 "holds a list of key/value blocks a position, not the one "
                 "recurrent state and convolution tail a row of a Mamba-2 "
                 "layer carries")
-        from .state_space import ssd_scan
+        from .state_space import ssm_layer
 
         cfg = self.cfg
-        b, s, _ = x.shape
-        f32 = jnp.float32
         nh, p, n, g = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size,
                        cfg.ssm_groups)
         inner, wide = nh * p, nh * p + 2 * g * n
@@ -730,7 +730,34 @@ class Mamba2(nn.Module):
         skip = self.param("D", nn.initializers.ones, (nh,))
         dt_bias = self.param("dt_bias", nn.initializers.zeros, (nh,))
         scale = _NormScale(name="norm")(inner)
+        if cfg.attention_impl == "flash":
+            # everything between the products and the kernels, and between
+            # the kernels and the output product, is the layer's own pass
+            u = ssm_layer(zxbcdt, attn_mask, conv_w, conv_b, a_log, skip,
+                          dt_bias, scale, heads=nh, head_dim=p, groups=g,
+                          state=n, chunk=cfg.ssm_chunk, eps=cfg.rms_eps)
+        else:
+            u = self._dense(zxbcdt, attn_mask, conv_w, conv_b, a_log, skip,
+                            dt_bias, scale, x.dtype)
+        out = dense(cfg.hidden_size, "out_proj")(u)
+        self.sow("ssm_stats", "layer_steps", jnp.float32(1),
+                 init_fn=lambda: jnp.float32(0), reduce_fn=jnp.add)
+        return _add_lora(u, {"out_proj": out}, adapter,
+                         lora_scale)["out_proj"], None
 
+    def _dense(self, zxbcdt, attn_mask, conv_w, conv_b, a_log, skip, dt_bias,
+               scale, dtype):
+        """The same layer between the products as ``jax.numpy`` around
+        :func:`ssd_scan` (the ``dense`` path, and the fused passes'
+        reference)."""
+        from .state_space import ssd_scan
+
+        cfg = self.cfg
+        b, s, _ = zxbcdt.shape
+        f32 = jnp.float32
+        nh, p, n, g = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size,
+                       cfg.ssm_groups)
+        inner, wide = nh * p, nh * p + 2 * g * n
         z, xbc = zxbcdt[..., :inner], zxbcdt[..., inner:inner + wide]
         dt = jax.nn.softplus(zxbcdt[..., inner + wide:].astype(f32)
                              + dt_bias.astype(f32))
@@ -740,12 +767,12 @@ class Mamba2(nn.Module):
         # rebuilt in the backward pass from its bfloat16 input: the float32
         # rows before the SiLU are not kept
         xbc = jax.checkpoint(lambda t, w, c: jax.nn.silu(
-            _causal_conv(t, w, c)).astype(x.dtype))(xbc, conv_w, conv_b)
+            _causal_conv(t, w, c)).astype(dtype))(xbc, conv_w, conv_b)
         y = ssd_scan(xbc[..., :inner].reshape(b, s, nh, p), dt,
                      -jnp.exp(a_log.astype(f32)),
                      xbc[..., inner:inner + g * n].reshape(b, s, g, n),
                      xbc[..., inner + g * n:].reshape(b, s, g, n),
-                     skip, impl=cfg.attention_impl, chunk=cfg.ssm_chunk)
+                     skip, chunk=cfg.ssm_chunk)
         @jax.checkpoint     # float32 inside, rebuilt from y and z
         def gated_norm(y, z, scale):
             """The gate multiplies before the norm, which runs a group."""
@@ -753,14 +780,9 @@ class Mamba2(nn.Module):
                  * jax.nn.silu(z.astype(f32))).reshape(b, s, g, inner // g)
             u = u * jax.lax.rsqrt(jnp.mean(jnp.square(u), -1, keepdims=True)
                                   + cfg.rms_eps)
-            return (u.reshape(b, s, inner) * scale.astype(f32)).astype(x.dtype)
+            return (u.reshape(b, s, inner) * scale.astype(f32)).astype(dtype)
 
-        u = gated_norm(y, z, scale)
-        out = dense(cfg.hidden_size, "out_proj")(u)
-        self.sow("ssm_stats", "layer_steps", jnp.float32(1),
-                 init_fn=lambda: jnp.float32(0), reduce_fn=jnp.add)
-        return _add_lora(u, {"out_proj": out}, adapter,
-                         lora_scale)["out_proj"], None
+        return gated_norm(y, z, scale)
 
 
 class MixerBlock(nn.Module):

@@ -24,7 +24,8 @@ from fedml_tpu.llm.attention import (FLASH_KERNEL_NAMES, WINDOW_KERNEL_NAMES,
                                      flash_causal_attention)
 from fedml_tpu.llm.linear_attention import (KDA_KERNEL_NAMES, KDA_PASS_NAMES,
                                             kda_attention, kda_layer)
-from fedml_tpu.llm.state_space import SSD_KERNEL_NAMES, ssd_scan
+from fedml_tpu.llm.state_space import (SSD_KERNEL_NAMES, SSM_PASS_NAMES,
+                                       ssd_scan, ssm_layer)
 
 pytestmark = pytest.mark.pallas
 
@@ -259,6 +260,67 @@ def test_ssd_kernels_compile_for_v5e(v5e, dtype):
     assert text.count("tpu_custom_call") == 2
     for name in SSD_KERNEL_NAMES:
         assert name in text, name
+
+
+def _ssm_layer_step(masked):
+    def step(zx, conv_w, conv_b, a_log, skip, dt_bias, scale, mask):
+        return jax.value_and_grad(
+            lambda zx: ssm_layer(
+                zx, mask if masked else None, conv_w, conv_b, a_log, skip,
+                dt_bias, scale, heads=128, head_dim=64, groups=8, state=128,
+                eps=1e-5).astype(jnp.float32).sum())(zx)
+    return step
+
+
+def _ssm_layer_avals(dtype, s=4096):
+    wide, head = 8192 + 2 * 1024, jax.ShapeDtypeStruct((128,), dtype)
+    return (jax.ShapeDtypeStruct((1, s, 8192 + wide + 128), dtype),
+            jax.ShapeDtypeStruct((4, wide), dtype),
+            jax.ShapeDtypeStruct((wide,), dtype), head, head, head,
+            jax.ShapeDtypeStruct((8192,), dtype),
+            jax.ShapeDtypeStruct((1, s), jnp.int32))
+
+
+@pytest.mark.parametrize("dtype,masked", [
+    (jnp.bfloat16, False), (jnp.bfloat16, True), (jnp.float32, False)])
+def test_ssm_passes_compile_for_v5e(v5e, dtype, masked):
+    """The fused passes around the SSD kernels at the benchmark's shape
+    (the ``in_proj`` product ``[1, 4096, 18560]``: 128 heads of 64, 8
+    groups of state 128), with a mask, and in float32: four more Mosaic
+    kernels beside the two, under their names, and the product's cotangent
+    written in place (no concatenation of its three parts)."""
+    text = _compile(_ssm_layer_step(masked), v5e,
+                    *_ssm_layer_avals(dtype)).as_text()
+    assert text.count("tpu_custom_call") == 6
+    for name in SSD_KERNEL_NAMES + SSM_PASS_NAMES:
+        assert name in text, name
+    assert "concatenate" not in text
+
+
+def test_only_the_ssd_kernels_read_as_ssd_kernels(v5e):
+    """``ssd_kernels_roofline`` finds its two kernels by a substring of an
+    instruction's name: of the layer's six custom calls it must take
+    ``ssd_fwd`` and ``ssd_bwd`` and none of the passes."""
+    import importlib.util
+    import os
+    import re
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "metrics",
+        "ssd_kernels_roofline.py")
+    spec = importlib.util.spec_from_file_location("ssd_roofline", path)
+    metric = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(metric)
+    text = _compile(_ssm_layer_step(False), v5e,
+                    *_ssm_layer_avals(jnp.bfloat16)).as_text()
+    names = re.findall(r"^\s*%?([\w.\-]+) = .*custom_call_target="
+                       r"\"tpu_custom_call\"", text, re.M)
+    assert len(names) == 6, names
+    kinds = {name: metric.kind_of(name) for name in names}
+    assert sorted(k for k in kinds.values() if k) == ["bwd", "fwd"], kinds
+    for name, kind in kinds.items():
+        mine = [n for n in SSD_KERNEL_NAMES + SSM_PASS_NAMES if n in name]
+        assert mine == (["ssd_" + kind] if kind else mine[:1]) and mine, kinds
 
 
 def test_the_non_gated_expert_pass_compiles_for_v5e(v5e):

@@ -272,6 +272,125 @@ def test_the_flash_path_of_the_stack_matches_the_dense_one():
     assert rel(flash, dense) < 1e-5
 
 
+# ---------------------------------------- the passes around the kernels ---
+
+def mamba_grads(seq, chunk, dtype, impl, masked, conv_bias, b=2, heads=4,
+                p=64, g=2, n=128, d=64):
+    """One ``Mamba2`` layer with rank-4 adapters on both products, its
+    frozen parameters drawn as the benchmark's reference draws them ->
+    (loss, gradients toward the parameters, the adapters and ``x``)."""
+    lc = LLMConfig(hidden_size=d, ssm_heads=heads, ssm_head_dim=p,
+                   ssm_state_size=n, ssm_groups=g, ssm_chunk=chunk,
+                   ssm_conv_bias=conv_bias, dtype=dtype, rms_eps=1e-5,
+                   attention_impl=impl)
+    m = Mamba2(lc)
+    ks = jax.random.split(jax.random.PRNGKey(5), 12)
+    x = jax.random.normal(ks[0], (b, seq, d))
+    params = dict(m.init(ks[1], x[:, :16], None)["params"])
+    wide, inner = heads * p + 2 * g * n, heads * p
+    params["conv_w"] = jax.random.normal(ks[2], (4, wide)) * 0.5
+    if conv_bias:
+        params["conv_b"] = jax.random.uniform(ks[3], (wide,), minval=-0.1,
+                                              maxval=0.1)
+    params["A_log"] = jnp.log(jax.random.uniform(ks[4], (heads,), minval=1,
+                                                 maxval=16))
+    dt = jnp.exp(jax.random.uniform(ks[5], (heads,), minval=np.log(1e-3),
+                                    maxval=np.log(0.1)))
+    params["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
+    params["D"] = jax.random.normal(ks[6], (heads,))
+    params["norm"] = {"scale": 1 + 0.1 * jax.random.normal(ks[7], (inner,))}
+    side = lambda k, i, o: jax.random.normal(k, (i, o)) * 0.2  # noqa: E731
+    lora = {"in_proj": {"lora_a": side(ks[8], d, 4),
+                        "lora_b": side(ks[9], 4, inner + wide + heads)},
+            "out_proj": {"lora_a": side(ks[10], inner, 4),
+                         "lora_b": side(ks[11], 4, d)}}
+    mask = None
+    if masked:
+        mask = jnp.ones((b, seq), jnp.int32).at[0, 5].set(0).at[
+            1, seq // 2:seq // 2 + 3].set(0)
+    w = jax.random.normal(jax.random.PRNGKey(99), (b, seq, d))
+
+    def loss(params, lora, x):
+        y, _ = m.apply({"params": params}, x.astype(lc.compute_dtype), None,
+                       mask, adapter=lora, lora_scale=2.0)
+        return jnp.sum(y.astype(jnp.float32) * w)
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        params, lora, x)
+
+
+# (seq, chunk, masked, conv bias, blocks of the passes: rows before the
+# kernels, rows and columns after them)
+MAMBA_CASES = {
+    # three row blocks a direction: the convolution's halo crosses two
+    # block edges forward (the rows before) and backward (the rows after);
+    # two column blocks after the kernels
+    "halo": (96, 32, True, True, (32, 32, 128)),
+    # a row shorter than one block: one chunk of 48 rows, no bias
+    "short": (40, 128, False, False, None),
+    # a row of 100 in chunks of 32: padded to four blocks
+    "ragged": (100, 32, True, True, (32, 32, 128)),
+}
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(MAMBA_CASES))
+def test_the_fused_passes_match_the_modules_form(case, dtype, monkeypatch):
+    """``flash`` runs the layer's element-wise work in the four passes
+    (interpreted here) around the kernels: the loss and every gradient,
+    toward both adapters, ``x`` and the frozen parameters, against the
+    module's ``jax.numpy`` form around the same kernels' dense form. In
+    float32 to round-off; in bfloat16 the passes round where the module
+    does (x, B and C after the SiLU, the gated norm's output)."""
+    seq, chunk, masked, conv_bias, blocks = MAMBA_CASES[case]
+    if blocks:
+        for name, v in zip(("_PRE_ROWS", "_POST_ROWS", "_POST_COLS"),
+                           blocks):
+            monkeypatch.setattr(ss, name, v)
+    got, got_g = mamba_grads(seq, chunk, dtype, "flash", masked, conv_bias)
+    want, want_g = mamba_grads(seq, chunk, dtype, "dense", masked,
+                               conv_bias)
+    tol = 1e-5 if dtype == "float32" else 5e-3
+    assert abs(float(got) - float(want)) < tol * abs(float(want))
+    flat = dict(jax.tree_util.tree_leaves_with_path(want_g))
+    assert len(flat) == 13 - (not conv_bias)
+    for path, g in jax.tree_util.tree_leaves_with_path(got_g):
+        assert float(jnp.abs(flat[path]).max()) > 0, path
+        assert rel(g, flat[path]) < tol, jax.tree_util.keystr(path)
+
+
+def test_the_fused_gauge_says_which_path_was_traced():
+    """``fed_ssm_fused`` reads 1 after the fused layer is traced and 0
+    after a caller made the kernels' operands itself; a product whose B
+    columns do not fall on its own blocks is refused."""
+    REGISTRY.reset()
+    f32 = jnp.float32
+    heads, head = 8, jax.ShapeDtypeStruct((8,), f32)
+    zx = jax.ShapeDtypeStruct((1, 256, 512 + 1536 + 8), f32)
+
+    def layer(zx, *params, **kw):
+        return ss.ssm_layer(zx, None, *params, heads=heads, head_dim=64,
+                            groups=4, state=128, **kw)
+
+    params = (jax.ShapeDtypeStruct((4, 1536), f32),
+              jax.ShapeDtypeStruct((1536,), f32), head, head, head,
+              jax.ShapeDtypeStruct((512,), f32))
+    jax.eval_shape(layer, zx, *params)
+    assert REGISTRY.gauge("fed_ssm_fused").value() == 1.0
+    assert REGISTRY.gauge("fed_ssd_chunk").value() == 128.0
+    jax.eval_shape(lambda *a: ss.ssd_scan(*a, impl="flash"),
+                   *draw(1, 256, 8, 64, 4, 128))
+    assert REGISTRY.gauge("fed_ssm_fused").value() == 0.0
+    with pytest.raises(ValueError, match="column blocks"):
+        jax.eval_shape(lambda zx: ss.ssm_layer(
+            zx, None, jnp.zeros((4, 1024)), None, jnp.zeros(2),
+            jnp.zeros(2), jnp.zeros(2), jnp.zeros(256), heads=2,
+            head_dim=128, groups=1, state=384),
+            jax.ShapeDtypeStruct((1, 128, 256 + 1024 + 2), f32))
+    REGISTRY.reset()
+
+
 @pytest.mark.parametrize("fault", ["fault_no_decay", "fault_plain_relu"])
 def test_the_planted_faults_are_seen_by_the_loss(fault):
     """What the benchmark's faults plant in the reference moves the small

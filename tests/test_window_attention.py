@@ -649,7 +649,7 @@ def test_the_window_counter_reaches_the_registry_from_the_round_program():
 # --------------------------------- the accepted models' programs are kept ---
 
 # sha256 of the StableHLO of ``value_and_grad`` of the LoRA train step of
-# the three accepted language-model configurations at their rehearsal sizes
+# the accepted language-model configurations at their rehearsal sizes
 # (the benchmark's reference draws the weights), as the parent of PR 34
 # (commit bbeebdd) lowers them with this container's jax 0.9.0; the two
 # with an expert layer as PR 35 left them (its backward pass works from
@@ -657,7 +657,10 @@ def test_the_window_counter_reaches_the_registry_from_the_round_program():
 # unmoved by it; the MiMo pair as the parent of PR 37 (commit d4c1675)
 # lowers it, and the Ling pair as PR 37 left it (its KDA layers run their
 # element-wise work in the fused passes of ``llm/linear_attention.py``,
-# which no other model has). A change that means to alter one of these
+# which no other model has); the Nemotron pair as PR 39 left it, which is
+# the text its parent (commit 882ce0d) lowers: this step takes the
+# ``dense`` path, and only ``flash`` runs the fused passes of
+# ``llm/state_space.py``. A change that means to alter one of these
 # programs brings its new hash.
 _ACCEPTED = {
     ("mistral7b_lora_silo2", "float32"):
@@ -676,6 +679,10 @@ _ACCEPTED = {
         "1fb34b7547f28910dd41fde348fd94d9c3ff187d7eb14c3596279504ac9107a9",
     ("mimo_v2_flash_lora_silo2_seq4096", "bfloat16"):
         "a795e6669c019f88c0d5cccbc00a80a0aed09a8f20aa787b46b0eb1f0c1d297d",
+    ("nemotron3_super_lora_silo2_seq4096", "float32"):
+        "a5aee3d8e402b271c0a0fac11bf916cc86f67bd36787a5c13c3301f3f40bc2f4",
+    ("nemotron3_super_lora_silo2_seq4096", "bfloat16"):
+        "2f680c73b939f1825df338dbacd949b789fba76e285f15b4a862a5fc12198294",
 }
 
 
