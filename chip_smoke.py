@@ -57,6 +57,8 @@ FULL = {
                   prompt_chars=(3, 40, 150, 400, 700, 12)),
     "flash_shapes": ((8, 1024, 8, 128), (1, 8192, 2, 128)),
     "kda_shape": (1, 2048, 4, 128),
+    # the unbounded-gate cell's KDA layer: b, s, heads, channels a head
+    "kda_softplus_shape": (1, 4096, 32, 128),
     # b, s, heads, channels a head, groups, state size
     "ssd_shape": (1, 4096, 128, 64, 8, 128),
     # b, s, query heads, key-value heads, d_qk, d_v, window
@@ -72,6 +74,7 @@ TOY = {
     "serve": dict(slots=4, max_new=4, prompt_chars=(3, 20, 60, 9)),
     "flash_shapes": ((2, 128, 2, 32),),
     "kda_shape": (1, 128, 2, 128),
+    "kda_softplus_shape": (1, 128, 2, 128),
     "ssd_shape": (1, 160, 4, 64, 2, 128),
     "window_shape": (1, 256, 4, 2, 24, 16, 40),
     "conv_batch": 8,
@@ -516,6 +519,67 @@ def phase_kda(sz):
     return out
 
 
+def phase_kda_softplus(sz):
+    """The chunk step of the unbounded gate (each sub-chunk's block against
+    itself element by element) in the Pallas kernels (on a chip; interpreted
+    elsewhere) against the same step under the ``dense`` scan, forward and
+    the gradients toward q, k, v, the log-decay and beta, on bfloat16
+    operands with log-decays all over (-20, 0), at the unbounded-gate
+    cell's shape. On a chip also the host-timed ms a call of the kernels
+    with their XLA glue, forward and forward + backward, exact form against
+    the bounded form's factorised one at the same shapes, whose result is
+    wrong at these decays: its distance to the dense step is reported."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.llm.linear_attention import kda_attention
+
+    b, s, h, d = sz["kda_softplus_shape"]
+    ks = jax.random.split(jax.random.PRNGKey(13), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa
+    q = (unit(jax.random.normal(ks[0], (b, s, h, d))) * d ** -0.5
+         ).astype(jnp.bfloat16)
+    k = unit(jax.random.normal(ks[1], (b, s, h, d))).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, s, h, d), jnp.bfloat16)
+    g = jax.random.uniform(ks[3], (b, s, h, d), minval=-20.0, maxval=0.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
+    ct = jax.random.normal(ks[5], (b, s, h, d), jnp.float32)
+    xs = (q, k, v, g, beta)
+
+    def form(impl, unbounded=True):
+        return functools.partial(kda_attention, impl=impl,
+                                 unbounded=unbounded)
+
+    def grads(fn):
+        return jax.grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * ct),
+            argnums=(0, 1, 2, 3, 4))
+
+    def gap(a, w):
+        a, w = (np.asarray(x, np.float32) for x in (a, w))
+        check(np.isfinite(a).all(), "non-finite KDA result")
+        return float(np.linalg.norm(a - w) / (np.linalg.norm(w) + 1e-6))
+
+    kernels, dense = form("flash"), form("dense")
+    errs = [gap(jax.jit(kernels)(*xs), jax.jit(dense)(*xs))]
+    errs += [gap(a, w) for a, w in zip(jax.jit(grads(kernels))(*xs),
+                                       jax.jit(grads(dense))(*xs))]
+    check(max(errs) < 0.02, f"unbounded KDA kernels vs the dense step: {errs}")
+    bounded = form("flash", False)
+    out = {"kda_softplus_rel_err_" + "x".join(map(str, (b, s, h, d))):
+           round(max(errs), 6),
+           # what the bounded form's factorised blocks give at these decays
+           "kda_bounded_form_rel_err": round(
+               gap(jax.jit(bounded)(*xs), jax.jit(dense)(*xs)), 6)}
+    if on_chip():
+        for name, fn in (("exact", kernels), ("bounded", bounded)):
+            out[f"kda_{name}_ms_fwd_and_fwd_bwd"] = [
+                round(1e3 * statistics.median(round_trips(f, *xs, n=7)), 3)
+                for f in (fn, grads(fn))]
+    return out
+
+
 def phase_ssd(sz):
     """The state-space kernels (Pallas on a chip, interpreted elsewhere) at
     the state-space cell's shape, forward and the gradients toward x, the
@@ -811,6 +875,7 @@ def main():
               ("serving", phase_serving, (sz, keep)),
               ("kernels", phase_kernels, (sz,)),
               ("kda", phase_kda, (sz,)),
+              ("kda_softplus", phase_kda_softplus, (sz,)),
               ("ssd", phase_ssd, (sz,)),
               ("window", phase_window, (sz,))]
     if len(devices) == 4:

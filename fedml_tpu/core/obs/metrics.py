@@ -536,6 +536,21 @@ def record_kda_round(layer_steps: float) -> None:
                      "recorded round").inc(float(layer_steps))
 
 
+def record_kda_decays(decays: float, steep: float) -> None:
+    """The live (position, key channel) log-decays of the unbounded KDA gate
+    in one finished round, and those of them under -5, the floor of the
+    bounded gate, as the round program itself counted them."""
+    if not _cfg["enabled"]:
+        return
+    REGISTRY.counter("fed_kda_decays_total",
+                     "live (position, key channel) log-decays of the "
+                     "unbounded KDA gate, every recorded round"
+                     ).inc(float(decays))
+    REGISTRY.counter("fed_kda_steep_decays_total",
+                     "of those, log-decays under -5 (the bounded gate's "
+                     "floor), every recorded round").inc(float(steep))
+
+
 def record_ssm_round(layer_steps: float) -> None:
     """Passes through a state-space (Mamba-2) layer (a layer and train
     step) in one finished round, as the round program itself counted
@@ -595,14 +610,20 @@ def record_flash_window(window: int, block_share: float, sink: bool) -> None:
                    "else 0").set(1.0 if sink else 0.0)
 
 
-def record_kda_plan(chunk: int, fused: bool) -> None:
+def record_kda_plan(chunk: int, fused: bool, unbounded: bool = False) -> None:
     """The chunk size of the linear-attention call just traced (host side,
-    once a trace; ``llm/linear_attention.py::chunk_size``), and whether the
+    once a trace; ``llm/linear_attention.py::chunk_size``), whether the
     layer's element-wise work around the kernels ran through the fused
     passes (``kda_layer``) or the caller made the kernels' operands itself
-    (``kda_attention``)."""
+    (``kda_attention``), and the gate's form: 0 the bounded gate, 1 the
+    unbounded softplus one (whose chunk step makes each sub-chunk's block
+    against itself element by element)."""
     if not _cfg["enabled"]:
         return
+    REGISTRY.gauge("fed_kda_gate",
+                   "the gate of the last traced KDA call: 0 bounded "
+                   "(log-decays >= -5), 1 unbounded softplus"
+                   ).set(1.0 if unbounded else 0.0)
     REGISTRY.gauge("fed_kda_chunk",
                    "positions a chunk of the last traced KDA call"
                    ).set(float(chunk))

@@ -106,6 +106,50 @@ def _nemotron_h_fields(config: dict, layers: int) -> dict:
         use_rope=False)
 
 
+def _kimi_linear_fields(config: dict, layers: int) -> dict:
+    """The :class:`LLMConfig` fields a ``kimi_linear`` configuration reads
+    its own way (Kimi delta attention by ``linear_attn_config``'s 1-based
+    ``kda_layers`` / ``full_attn_layers`` with its heads, head size and
+    short convolution, the unbounded softplus gate; latent attention
+    without positions where ``mla_use_nope``; sigmoid experts top-k by
+    score + bias, ``num_expert_group`` groups of which ``topk_group`` stay
+    under ``use_grouped_topk``), or its refusal. The top-level
+    ``head_dim`` (hidden / heads) is not the KDA head size and is not
+    read."""
+    get = config.get
+    linear = dict(get("linear_attn_config") or {})
+    kda = list(linear.get("kda_layers") or [])
+    full = list(linear.get("full_attn_layers") or [])
+    if sorted(kda + full) != list(range(1, layers + 1)):
+        raise NotImplementedError(
+            f"linear_attn_config lists kda_layers {kda} and full_attn_layers "
+            f"{full}: together they must name each of the {layers} layers "
+            "once, counting from 1")
+    heads = int(linear.get("num_heads") or 0)
+    if heads != int(config["num_attention_heads"]):
+        raise NotImplementedError(
+            f"linear_attn_config num_heads {heads}: the KDA layers are built "
+            f"with the model's {config['num_attention_heads']} heads")
+    taps = int(linear.get("short_conv_kernel_size") or SHORT_CONV_TAPS)
+    if taps != SHORT_CONV_TAPS:
+        raise NotImplementedError(
+            f"short_conv_kernel_size {taps}: the short convolution is built "
+            f"over {SHORT_CONV_TAPS} positions")
+    act = get("moe_router_activation_func", "sigmoid")
+    if act != "sigmoid":
+        raise NotImplementedError(f"moe_router_activation_func {act!r}")
+    grouped = bool(get("use_grouped_topk"))
+    return dict(
+        linear_layers=tuple(i - 1 for i in kda), kda_gate="softplus",
+        linear_head_dim=int(linear["head_dim"]),
+        use_rope=not get("mla_use_nope", False), attn_output_gate=False,
+        num_experts_per_tok=int(get("num_experts_per_token") or 0),
+        norm_topk_prob=bool(get("moe_renormalize", True)),
+        n_group=int(get("num_expert_group") or 0) if grouped else 0,
+        topk_group=int(get("topk_group") or 0) if grouped else 0,
+        router_bias=True)
+
+
 def llm_config_from_hf(config: dict, *, max_seq_len: int,
                        dtype: str = "float32", attention_impl: str = "dense",
                        first_expert: int = 0,
@@ -129,7 +173,8 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
     experts of ``mlp_hidden_act`` ``relu2`` in a ``moe_latent_size`` latent
     beside a shared one of ``moe_shared_expert_intermediate_size``, routed
     by sigmoid scores with a score-correction bias; ``*`` grouped-query
-    attention without rotary), one mixer a layer, ``layer_norm_epsilon``).
+    attention without rotary), one mixer a layer, ``layer_norm_epsilon``;
+    and of ``kimi_linear``: :func:`_kimi_linear_fields`).
     ``sliding_window`` is read with a ``hybrid_layer_pattern`` only: alone
     it is left unread, as it always was (full causal attention is the same
     model up to that many positions). ``first_expert`` / ``experts_held``
@@ -185,6 +230,8 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
             f"short convolution is built over {SHORT_CONV_TAPS} positions")
     hybrid = (_nemotron_h_fields(config, layers)
               if get("model_type") == "nemotron_h" else {})
+    kimi = (_kimi_linear_fields(config, layers)
+            if get("model_type") == "kimi_linear" else {})
     # the nemotron_h router is the sigmoid one with a score-correction bias
     noaux = method == "noaux_tc" or bool(hybrid)
     if get("attention_bias"):
@@ -214,7 +261,7 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
                 "turns pairs")
     first_dense = (leading_dense if leading_dense is not None
                    else int(get("first_k_dense_replace") or 0))
-    return LLMConfig(
+    fields = dict(
         vocab_size=int(config["vocab_size"]),
         hidden_size=int(config["hidden_size"]),
         intermediate_size=int(config["intermediate_size"]),
@@ -252,12 +299,15 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
         topk_group=int(get("topk_group") or 0) if noaux else 0,
         router_bias=noaux and bool(
             get("moe_router_enable_expert_bias", True)),
-        layer_group_size=group,
+        linear_layers=tuple(i for i in range(layers)
+                            if group and (i + 1) % group),
         linear_head_dim=int(get("head_dim") or 0) if group else 0,
         kda_lower_bound=lower,
         attn_output_gate=bool(group) and get(
             "gated_attention_proj_granularity_type") == "head_wise",
         **hybrid)
+    # kimi_linear reads some of the keys the others share its own way
+    return LLMConfig(**dict(fields, **kimi))
 
 
 # sums a model with experts reports a step through the ``moe_stats``
@@ -304,7 +354,9 @@ class LLMBundle:
         return ((MOE_METRICS if cfg.n_routed_experts else ())
                 + (("moe_tokens_here",) if cfg.n_routed_experts
                    and cfg.n_group > 1 else ())
-                + (("kda_layer_steps",) if cfg.layer_group_size else ())
+                + (("kda_layer_steps",) if cfg.linear_layers else ())
+                + (("kda_decays", "kda_steep_decays")
+                   if cfg.kda_gate == "softplus" else ())
                 + (("attn_window_layer_steps",) if cfg.window_layers
                    else ())
                 + (("ssm_layer_steps",) if cfg.ssm_layers else ()))

@@ -18,8 +18,13 @@ Training never runs that token by token. In a chunk of C positions with
 ``exp(G_r - G_j)`` is at most 1, but its factors ``e^{G_r} e^{-G_j}`` leave
 float32 over a chunk, so each sub-chunk of ``SUB`` rows measures its decays
 from its own first row: with ``g >= -5`` (the model's bounded gate) no
-exponent passes ``SUB * 5 = 80``. A is nilpotent, so ``(I + A)^-1`` is the
-sum of the powers of ``-A`` below C, doubled ``log2 C`` times on the MXU.
+exponent passes ``SUB * 5 = 80``. Where the gate has no lower bound
+(``unbounded``: Kimi Linear's ``-exp(A_log) softplus(.)``) the blocks of a
+sub-chunk against its own columns are made element by element over the key
+channels instead, every exponent a difference <= 0 taken before its
+``exp``; the blocks against earlier sub-chunks are exact at any decay
+either way. A is nilpotent, so ``(I + A)^-1`` is the sum of the powers of
+``-A`` below C, doubled ``log2 C`` times on the MXU.
 
 :func:`_chunk` is that chunk step as plain ``jax.numpy``; both forms run it
 and its ``jax.vjp``: ``dense`` under ``lax.scan`` over the chunks, ``flash``
@@ -158,12 +163,37 @@ def _unit_lower_solve_bwd(res, du):
 _unit_lower_solve.defvjp(_unit_lower_solve_fwd, _unit_lower_solve_bwd)
 
 
-def _chunk(q, k, kb, vb, gc, st, dtype):
+def _within(both, x, k, g, k_all, first: int, dtype):
+    """``both`` [2 sub, C]: one sub-chunk's rows of ``x = [beta k | q]``
+    against every column of the chunk, with the columns of the sub-chunk
+    itself (``first`` on) made exact at any decay: ``sum_c x_rc k_jc
+    exp(G_rc - G_jc)`` for ``j <= r``, each exponent a difference <= 0 (1
+    above the diagonal, which the caller masks). ``k``, ``g`` [sub, d] the
+    sub-chunk's own rows, ``k_all`` [C, d] the chunk's: ONE product of the
+    ``sub`` scaled copies of ``x`` against every column, then column ``first
+    + j`` from the j-th copy (a lane select, nothing sliced by lanes)."""
+    sub = k.shape[0]
+    r = jax.lax.broadcasted_iota(jnp.int32, (sub, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, both.shape, 1)
+    copies = []
+    for j in range(sub):
+        e = jnp.exp(jnp.where(r >= j, g - g[j:j + 1], 0.0))
+        copies.append(x * jnp.concatenate([e, e], 0))
+    cols = _mm(jnp.concatenate(copies, 0), k_all, _NT, dtype)
+    for j in range(sub):
+        both = jnp.where(lane == first + j,
+                         cols[j * 2 * sub:(j + 1) * 2 * sub], both)
+    return both
+
+
+def _chunk(q, k, kb, vb, gc, st, dtype, unbounded: bool = False):
     """One chunk of one head. ``q``, ``k`` [C, d_k]; ``kb = beta * k``;
     ``vb = beta * v`` [C, d_v]; ``gc`` [C, d_k] the chunk's running sum of
     log-decays (float32, its own row included); ``st`` [d_v, d_k] the
     entering state, transposed so that a decay scales its lanes. ->
-    (o [C, d_v] float32, the state the chunk leaves)."""
+    (o [C, d_v] float32, the state the chunk leaves). ``unbounded``: the
+    log-decays may lie under ``MIN_LOG_DECAY``, and each sub-chunk's block
+    against itself is made by :func:`_within`."""
     f32 = jnp.float32
     q, k, kb, vb, gc = (a.astype(f32) for a in (q, k, kb, vb, gc))
     c = q.shape[0]
@@ -183,6 +213,9 @@ def _chunk(q, k, kb, vb, gc, st, dtype):
         k_col = k * jnp.exp(jnp.minimum(first - gc, _MAX_EXPONENT))
         both = _mm(jnp.concatenate([kb_row[rows], q_row[rows]], 0), k_col,
                    _NT, dtype)                               # [2 sub, C]
+        if unbounded:
+            both = _within(both, jnp.concatenate([kb[rows], q[rows]], 0),
+                           k[rows], gc[rows], k, i * sub, dtype)
         a_rows.append(both[:sub])
         p_rows.append(both[sub:])
     a_mat = jnp.where(row > col, jnp.concatenate(a_rows, 0), 0.0)
@@ -199,12 +232,14 @@ def _chunk(q, k, kb, vb, gc, st, dtype):
     return o, st_new
 
 
-def _chunk_grads(q, k, kb, vb, gc, st, do, dst, dtype):
+def _chunk_grads(q, k, kb, vb, gc, st, do, dst, dtype,
+                 unbounded: bool = False):
     """``_chunk`` rebuilt and ``(do, dst)`` pulled back to its six inputs,
     each in float32."""
     f32 = jnp.float32
     args = tuple(a.astype(f32) for a in (q, k, kb, vb, gc, st))
-    _, pull = jax.vjp(functools.partial(_chunk, dtype=dtype), *args)
+    _, pull = jax.vjp(functools.partial(_chunk, dtype=dtype,
+                                        unbounded=unbounded), *args)
     return pull((do.astype(f32), dst))
 
 
@@ -222,9 +257,10 @@ def _from_chunks(a):
     return a.transpose(1, 0, 3, 2, 4).reshape(b, n * c, h, d)
 
 
-def _scan_fwd(q, k, kb, vb, gc, chunk):
+def _scan_fwd(q, k, kb, vb, gc, chunk, unbounded):
     dtype = q.dtype
-    step = jax.vmap(jax.vmap(functools.partial(_chunk, dtype=dtype)))
+    step = jax.vmap(jax.vmap(functools.partial(_chunk, dtype=dtype,
+                                               unbounded=unbounded)))
     b, _, h, dk = q.shape
 
     def body(st, xs):
@@ -237,9 +273,10 @@ def _scan_fwd(q, k, kb, vb, gc, chunk):
     return _from_chunks(o).astype(dtype), states       # states [n, b, h, ..]
 
 
-def _scan_bwd(q, k, kb, vb, gc, states, do, chunk):
+def _scan_bwd(q, k, kb, vb, gc, states, do, chunk, unbounded):
     dtype = q.dtype
-    step = jax.vmap(jax.vmap(functools.partial(_chunk_grads, dtype=dtype)))
+    step = jax.vmap(jax.vmap(functools.partial(_chunk_grads, dtype=dtype,
+                                               unbounded=unbounded)))
 
     def body(dst, xs):
         *ins, st, do_c = xs
@@ -262,7 +299,8 @@ def _heads_per_step(h: int) -> int:
 
 
 def _kda_fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, gc_ref, o_ref, states_ref,
-                    st_ref, *, heads: int, dk: int, dv: int):
+                    st_ref, *, heads: int, dk: int, dv: int,
+                    unbounded: bool):
     """One (row, head group, chunk) program; chunks run in order and the
     state stays in ``st_ref`` [heads, d_v, d_k] between them. Blocks are
     ``[chunk, heads * d]`` columns of the ``[b, s, h * d]`` arrays."""
@@ -278,14 +316,14 @@ def _kda_fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, gc_ref, o_ref, states_ref,
         st = st_ref[j]
         states_ref[j] = st
         o, st_new = _chunk(q_ref[:, ck], k_ref[:, ck], kb_ref[:, ck],
-                           vb_ref[:, cv], gc_ref[:, ck], st, dtype)
+                           vb_ref[:, cv], gc_ref[:, ck], st, dtype, unbounded)
         o_ref[:, cv] = o.astype(o_ref.dtype)
         st_ref[j] = st_new
 
 
 def _kda_bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, gc_ref, states_ref, do_ref,
                     dq_ref, dk_ref, dkb_ref, dvb_ref, dgc_ref, dst_ref,
-                    *, heads: int, dk: int, dv: int):
+                    *, heads: int, dk: int, dv: int, unbounded: bool):
     """The same grid with the chunks in reverse order; ``dst_ref`` holds
     the cotangent of the state a chunk leaves."""
     import jax.experimental.pallas as pl
@@ -299,7 +337,8 @@ def _kda_bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, gc_ref, states_ref, do_ref,
         ck, cv = pl.ds(j * dk, dk), pl.ds(j * dv, dv)
         dq, dk_, dkb, dvb, dgc, dst0 = _chunk_grads(
             q_ref[:, ck], k_ref[:, ck], kb_ref[:, ck], vb_ref[:, cv],
-            gc_ref[:, ck], states_ref[j], do_ref[:, cv], dst_ref[j], dtype)
+            gc_ref[:, ck], states_ref[j], do_ref[:, cv], dst_ref[j], dtype,
+            unbounded)
         dq_ref[:, ck] = dq.astype(dq_ref.dtype)
         dk_ref[:, ck] = dk_.astype(dk_ref.dtype)
         dkb_ref[:, ck] = dkb.astype(dkb_ref.dtype)
@@ -325,8 +364,8 @@ def _pallas_specs(pl, b, s, h, dk, dv, chunk, reverse):
 # trace and one lowering of each kernel (``interpret`` is an argument so
 # that a process which both interprets and compiles keeps two entries)
 
-@functools.partial(jax.jit, static_argnums=(5, 6))
-def _pallas_fwd(q, k, kb, vb, gc, chunk, interpret):
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _pallas_fwd(q, k, kb, vb, gc, chunk, interpret, unbounded):
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
@@ -336,7 +375,8 @@ def _pallas_fwd(q, k, kb, vb, gc, chunk, interpret):
         pl, b, s, h, dk, dv, chunk, False)
     flat = lambda a: a.reshape(b, s, -1)  # noqa: E731
     o, states = pl.pallas_call(
-        functools.partial(_kda_fwd_kernel, heads=hb, dk=dk, dv=dv),
+        functools.partial(_kda_fwd_kernel, heads=hb, dk=dk, dv=dv,
+                          unbounded=unbounded),
         grid=grid,
         in_specs=[spec_k, spec_k, spec_k, spec_v, spec_k],
         out_specs=[spec_v, spec_st],
@@ -352,8 +392,9 @@ def _pallas_fwd(q, k, kb, vb, gc, chunk, interpret):
     return o.reshape(b, s, h, dv), states
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8))
-def _pallas_bwd(q, k, kb, vb, gc, states, do, chunk, interpret):
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def _pallas_bwd(q, k, kb, vb, gc, states, do, chunk, interpret,
+                unbounded):
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
@@ -365,7 +406,8 @@ def _pallas_bwd(q, k, kb, vb, gc, states, do, chunk, interpret):
     like = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
         (b, s, a.shape[2] * a.shape[3]), a.dtype)
     grads = pl.pallas_call(
-        functools.partial(_kda_bwd_kernel, heads=hb, dk=dk, dv=dv),
+        functools.partial(_kda_bwd_kernel, heads=hb, dk=dk, dv=dv,
+                          unbounded=unbounded),
         grid=grid,
         in_specs=[spec_k, spec_k, spec_k, spec_v, spec_k, spec_st, spec_v],
         out_specs=[spec_k, spec_k, spec_k, spec_v, spec_k],
@@ -382,23 +424,25 @@ def _pallas_bwd(q, k, kb, vb, gc, states, do, chunk, interpret):
 
 # --------------------------------------------------------- the whole row ---
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _kda_chunks(q, k, kb, vb, gc, chunk: int, impl: str):
-    return _kda_chunks_fwd(q, k, kb, vb, gc, chunk, impl)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _kda_chunks(q, k, kb, vb, gc, chunk: int, impl: str,
+                unbounded: bool = False):
+    return _kda_chunks_fwd(q, k, kb, vb, gc, chunk, impl, unbounded)[0]
 
 
-def _kda_chunks_fwd(q, k, kb, vb, gc, chunk, impl):
+def _kda_chunks_fwd(q, k, kb, vb, gc, chunk, impl, unbounded):
     if impl == "flash":
-        o, states = _pallas_fwd(q, k, kb, vb, gc, chunk, kernels.interpret())
+        o, states = _pallas_fwd(q, k, kb, vb, gc, chunk, kernels.interpret(),
+                                unbounded)
     else:
-        o, states = _scan_fwd(q, k, kb, vb, gc, chunk)
+        o, states = _scan_fwd(q, k, kb, vb, gc, chunk, unbounded)
     return o, (q, k, kb, vb, gc, states)
 
 
-def _kda_chunks_bwd(chunk, impl, res, do):
+def _kda_chunks_bwd(chunk, impl, unbounded, res, do):
     if impl == "flash":
-        return _pallas_bwd(*res, do, chunk, kernels.interpret())
-    return _scan_bwd(*res, do, chunk)
+        return _pallas_bwd(*res, do, chunk, kernels.interpret(), unbounded)
+    return _scan_bwd(*res, do, chunk, unbounded)
 
 
 _kda_chunks.defvjp(_kda_chunks_fwd, _kda_chunks_bwd)
@@ -410,21 +454,22 @@ def chunk_size(s: int) -> int:
     return CHUNK if s >= CHUNK else -(-s // SUB) * SUB
 
 
-def kda_attention(q, k, v, g, beta, impl: str = "dense"):
+def kda_attention(q, k, v, g, beta, impl: str = "dense",
+                  unbounded: bool = False):
     """``q``, ``k`` [b, s, h, d_k] (normalised and scaled by the caller),
     ``v`` [b, s, h, d_v], ``g`` [b, s, h, d_k] float32 log-decays in
-    ``[MIN_LOG_DECAY, 0]``, ``beta`` [b, s, h] -> o [b, s, h, d_v]:
-    the recurrence of the module's docstring from a zero state, in chunks.
-    ``impl`` ``flash`` runs the chunks in the Pallas kernels (``d_k`` and
-    ``d_v`` multiples of 128), anything else as ``jax.numpy`` under a
-    scan."""
+    ``[MIN_LOG_DECAY, 0]`` (any finite value <= 0 where ``unbounded``),
+    ``beta`` [b, s, h] -> o [b, s, h, d_v]: the recurrence of the module's
+    docstring from a zero state, in chunks. ``impl`` ``flash`` runs the
+    chunks in the Pallas kernels (``d_k`` and ``d_v`` multiples of 128),
+    anything else as ``jax.numpy`` under a scan."""
     b, s, h, dk = q.shape
     chunk = chunk_size(s)
     impl = "flash" if impl == "flash" else "dense"
     if impl == "flash" and (dk % 128 or v.shape[-1] % 128):
         raise ValueError(f"the KDA kernels take head sizes on the 128 grid; "
                          f"got d_k {dk}, d_v {v.shape[-1]}")
-    obs_metrics.record_kda_plan(chunk, fused=False)
+    obs_metrics.record_kda_plan(chunk, fused=False, unbounded=unbounded)
     beta = beta[..., None].astype(jnp.float32)
     kb = (k.astype(jnp.float32) * beta).astype(k.dtype)
     vb = (v.astype(jnp.float32) * beta).astype(v.dtype)
@@ -435,7 +480,7 @@ def kda_attention(q, k, v, g, beta, impl: str = "dense"):
     n = (s + pad) // chunk
     gc = jnp.cumsum(g.astype(jnp.float32).reshape(b, n, chunk, h, dk),
                     axis=2).reshape(b, s + pad, h, dk)
-    o = _kda_chunks(q, k, kb, vb, gc, chunk, impl)
+    o = _kda_chunks(q, k, kb, vb, gc, chunk, impl, unbounded)
     return o[:, :s] if pad else o
 
 
@@ -456,7 +501,8 @@ class _Pass(NamedTuple):
     heads: int
     chunk: int
     tile: int           # rows a block of the Pallas form
-    lower: float        # the gate's lower bound (log-decay)
+    lower: float        # the bounded gate's floor (log-decay); None: the
+    #                     unbounded softplus gate, whose decays are counted
     eps: float          # the head norm's
     impl: str
     interpret: bool     # the Pallas form interpreted (no chip)
@@ -493,10 +539,18 @@ def _ones_below(c: int, transpose: bool = False):
     return jnp.where((row <= col) if transpose else (row >= col), 1.0, 0.0)
 
 
-def _gates(yf, a, dt, logit, keep):
-    """sigmoid of the decay gate's argument [C, d] and of beta's logit
+def _softplus(v):
+    return jnp.maximum(v, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(v)))
+
+
+def _gates(yf, a, dt, logit, keep, lower):
+    """sigmoid of the decay gate's argument [C, d] (with ``lower`` None:
+    the pair ``softplus``, ``sigmoid`` of ``yf + dt``) and of beta's logit
     [C, 1], and ``keep`` (1 where no mask came)."""
     keep = 1.0 if keep is None else keep
+    if lower is None:
+        x = yf + dt
+        return (_softplus(x), jax.nn.sigmoid(x)), jax.nn.sigmoid(logit), keep
     return jax.nn.sigmoid(a * (yf + dt)), jax.nn.sigmoid(logit), keep
 
 
@@ -506,12 +560,22 @@ def _pre_rows(leads, ys, yf, ws, a, dt, logit, keep, lower):
     each (zeros at a row's start), ``ws`` their taps [K, d]; ``yf`` the
     decay product's rows, ``a = exp(A_log)`` and ``dt`` [1, d]; ``logit``
     beta's [C, 1]; ``keep`` [C, 1] or None -> q (at ``d ** -0.5``), k,
-    beta k, beta v and the chunk's running log-decay."""
+    beta k, beta v and the chunk's running log-decay. The log-decay is
+    ``lower * sigmoid(a (yf + dt))``, or with ``lower`` None the unbounded
+    ``-a softplus(yf + dt)``, whose live and steep (under
+    ``MIN_LOG_DECAY``) decays follow as two [1, d] counts a lane."""
     sq, sk, sv = (_conv_silu(lead, y, w)[2]
                   for lead, y, w in zip(leads, ys, ws))
-    sig, beta, keep = _gates(yf, a, dt, logit, keep)
+    sig, beta, keep = _gates(yf, a, dt, logit, keep, lower)
     k = _unit(sk)[0]
     beta = beta * keep
+    if lower is None:
+        g = -(a * sig[0]) * keep
+        counts = (jnp.sum(jnp.broadcast_to(keep, g.shape), 0, keepdims=True),
+                  jnp.sum(jnp.where(g < MIN_LOG_DECAY, 1.0, 0.0), 0,
+                          keepdims=True))
+        return (_unit(sq)[0] * sq.shape[-1] ** -0.5, k, k * beta, sv * beta,
+                _exact(_ones_below(yf.shape[0]), g), *counts)
     gc = _exact(_ones_below(yf.shape[0]), (lower * keep) * sig)
     return _unit(sq)[0] * sq.shape[-1] ** -0.5, k, k * beta, sv * beta, gc
 
@@ -525,7 +589,7 @@ def _pre_rows_grads(leads, ys, yf, ws, a, dt, logit, keep, lower,
     chunk's own first rows for the chunk before it."""
     (cq, gq, sq), (ck, gk, sk), (cv, gv, sv) = (
         _conv_silu(lead, y, w) for lead, y, w in zip(leads, ys, ws))
-    sig, sb, keep = _gates(yf, a, dt, logit, keep)
+    sig, sb, keep = _gates(yf, a, dt, logit, keep, lower)
     (qh, rq), (kh, rk) = _unit(sq), _unit(sk)
     beta = sb * keep
     along = lambda x, y: jnp.sum(x * y, -1, keepdims=True)  # noqa: E731
@@ -540,13 +604,17 @@ def _pre_rows_grads(leads, ys, yf, ws, a, dt, logit, keep, lower,
                           flip=True))
         heads_.append(dc[:_HALO])
     dg = _exact(_ones_below(yf.shape[0], transpose=True), dgc)
-    d_yf = dg * ((lower * keep) * a) * sig * (1.0 - sig)
+    if lower is None:       # softplus' derivative is the sigmoid
+        d_yf = -(dg * a) * sig[1] * keep
+    else:
+        d_yf = dg * ((lower * keep) * a) * sig * (1.0 - sig)
     return (*d_ys, d_yf, d_logit, tuple(heads_))
 
 
 def _post_rows(o, scale, logit, eps):
     """One head's rows after the kernels, float32: the head's RMSNorm
-    (``scale`` [1, d]) times its gate ``sigmoid(logit)`` [C, 1]."""
+    (``scale`` [1, d]) times its gate ``sigmoid(logit)``, one a head [C, 1]
+    or one a channel [C, d]."""
     r = jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps)
     return o * r * scale * jax.nn.sigmoid(logit)
 
@@ -556,7 +624,11 @@ def _post_rows_grads(o, scale, logit, eps, dy):
     and ``logit``."""
     r = jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps)
     n, gate = o * r, jax.nn.sigmoid(logit)
-    d_logit = jnp.sum(dy * n * scale, -1, keepdims=True) * gate * (1.0 - gate)
+    if logit.shape[-1] == 1:
+        d_logit = (jnp.sum(dy * n * scale, -1, keepdims=True) * gate
+                   * (1.0 - gate))
+    else:                   # a gate a channel
+        d_logit = dy * n * scale * gate * (1.0 - gate)
     dn = dy * gate * scale
     return r * (dn - n * jnp.mean(dn * n, -1, keepdims=True)), d_logit
 
@@ -610,8 +682,11 @@ def _pre_dense_fwd(cfg, yq, yk, yv, yf, logit, keep, ws, a, dt):
 
     outs = _mapped(one, 5, 3, (False, True, False))(
         leads, ys, yf_, logit, keep, ws, a, dt)
-    return tuple(_untiled(o, like.dtype)
-                 for o, like in zip(outs, (yq, yk, yk, yv, yf)))
+    arrays = tuple(_untiled(o, like.dtype)
+                   for o, like in zip(outs, (yq, yk, yk, yv, yf)))
+    if cfg.lower is None:   # the live and the steep decays, summed
+        return arrays + (jnp.stack([jnp.sum(c) for c in outs[5:]]),)
+    return arrays
 
 
 def _pre_dense_bwd(cfg, yq, yk, yv, yf, logit, keep, ws, a, dt, cots):
@@ -681,7 +756,9 @@ def _set_column(ref, rows, j, col):
 
 def _pre_kernel(*refs, cfg: _Pass, masked: bool, backward: bool):
     """One (row, tile of whole chunks) program over every head. Forward:
-    tiles in any order. Backward: tiles last to first, and ``tails_ref``
+    tiles in any order; under the unbounded gate the last output is the
+    tile's [8, d] block of counts (row 0 the live decays a lane, row 1 the
+    steep ones). Backward: tiles last to first, and ``tails_ref``
     [3, _HALO, h * d] hands each head's first rows of the convolutions'
     cotangent to the tile before."""
     import jax.experimental.pallas as pl
@@ -696,6 +773,9 @@ def _pre_kernel(*refs, cfg: _Pass, masked: bool, backward: bool):
         cots, outs, tails_ref = rest[:5], rest[5:10], rest[10]
     else:
         outs = rest
+    counting = cfg.lower is None and not backward
+    if counting:
+        outs, counts_ref = rest[:5], rest[5]
     tile, c = ys[0].shape[0], cfg.chunk
     d = ys[0].shape[1] // cfg.heads
     # the rows before the row's first tile are zeros, not the halo block
@@ -731,17 +811,29 @@ def _pre_kernel(*refs, cfg: _Pass, masked: bool, backward: bool):
                 _set_column(outs[4], rows, j, d_logit)
             else:
                 grads = _pre_rows(*args)
+            if counting:
+                *grads, live, steep = grads
+                carry = (carry[0] + live, carry[1] + steep)
             for out, g in zip(outs, grads):
                 out[rows, cols] = g.astype(out.dtype)
         for x, t in enumerate(tails):
             tails_ref[x, :, cols] = t
         return carry
 
-    jax.lax.fori_loop(0, cfg.heads, head, 0)
+    if not counting:
+        jax.lax.fori_loop(0, cfg.heads, head, 0)
+        return
+    zero = jnp.zeros((1, d), f32)
+    live, steep = jax.lax.fori_loop(0, cfg.heads, head, (zero, zero))
+    row = jax.lax.broadcasted_iota(jnp.int32, counts_ref.shape, 0)
+    counts_ref[...] = jnp.where(row == 0, live,
+                                jnp.where(row == 1, steep, 0.0))
 
 
-def _post_kernel(*refs, cfg: _Pass, backward: bool):
-    """One (row, tile) program over every head: no row needs another."""
+def _post_kernel(*refs, cfg: _Pass, backward: bool, channel: bool):
+    """One (row, tile) program over every head: no row needs another.
+    ``channel``: the gate's logits are a ``[tile, h * d]`` block read as
+    the head's column block, else a ``[tile, heads]`` one."""
     import jax.experimental.pallas as pl
 
     f32 = jnp.float32
@@ -755,13 +847,18 @@ def _post_kernel(*refs, cfg: _Pass, backward: bool):
         for i in range(tile // c):
             rows = pl.ds(i * c, c)
             o = o_ref[rows, cols].astype(f32)
-            logit = _column(logit_ref, rows, j)
+            logit = (logit_ref[rows, cols].astype(f32) if channel
+                     else _column(logit_ref, rows, j))
             if backward:
                 dy_ref, do_ref, d_logit_ref = refs[3:]
                 do, d_logit = _post_rows_grads(
                     o, scale, logit, cfg.eps, dy_ref[rows, cols].astype(f32))
                 do_ref[rows, cols] = do.astype(do_ref.dtype)
-                _set_column(d_logit_ref, rows, j, d_logit)
+                if channel:
+                    d_logit_ref[rows, cols] = d_logit.astype(
+                        d_logit_ref.dtype)
+                else:
+                    _set_column(d_logit_ref, rows, j, d_logit)
             else:
                 refs[3][rows, cols] = _post_rows(
                     o, scale, logit, cfg.eps).astype(refs[3].dtype)
@@ -816,7 +913,11 @@ def _pre_pallas(cfg, yq, yk, yv, yf, logit, keep, ws, a, dt, cots=None):
         out_shape = [like(a) for a in (yq, yk, yk, yv, yf)]
         out_specs = [rows(hd)] * 5
         scratch = []
-    return tuple(pl.pallas_call(
+    d = hd // cfg.heads
+    if cfg.lower is None and not backward:     # a tile's [8, d] counts
+        out_shape.append(jax.ShapeDtypeStruct((b, n * 8, d), jnp.float32))
+        out_specs.append(pl.BlockSpec((None, 8, d), lambda i, t: (i, t, 0)))
+    outs = tuple(pl.pallas_call(
         functools.partial(_pre_kernel, cfg=cfg, masked=masked,
                           backward=backward),
         grid=(b, n), in_specs=in_specs, out_specs=out_specs,
@@ -826,6 +927,10 @@ def _pre_pallas(cfg, yq, yk, yv, yf, logit, keep, ws, a, dt, cots=None):
             ("parallel", "arbitrary" if backward else "parallel")),
         name=KDA_PASS_NAMES[1 if backward else 0],
     )(*operands))
+    if len(outs) > 5:       # the live and the steep decays, summed
+        outs = outs[:5] + (jnp.sum(outs[5].reshape(b, n, 8, d)[:, :, :2],
+                                   (0, 1, 3)),)
+    return outs
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -838,12 +943,15 @@ def _post_pallas(cfg, o, logit, scale, dy=None):
     n, rows, _, whole = _pass_specs(pl, cfg, s, False)
     scale = scale.reshape(1, -1)
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    channel = logit.shape[-1] == hd
     out = pl.pallas_call(
-        functools.partial(_post_kernel, cfg=cfg, backward=backward),
+        functools.partial(_post_kernel, cfg=cfg, backward=backward,
+                          channel=channel),
         grid=(b, n),
-        in_specs=[rows(hd), rows(cfg.heads), whole(scale.shape)]
+        in_specs=[rows(hd), rows(logit.shape[-1]), whole(scale.shape)]
         + [rows(hd)] * backward,
-        out_specs=[rows(hd), rows(cfg.heads)] if backward else rows(hd),
+        out_specs=([rows(hd), rows(logit.shape[-1])] if backward
+                   else rows(hd)),
         out_shape=[like(o), like(logit)] if backward else like(o),
         interpret=cfg.interpret,
         compiler_params=kernels.tpu_compiler_params(("parallel", "parallel")),
@@ -885,8 +993,9 @@ def _kda_pre_fwd(cfg, yq, yk, yv, yf, logit, keep, conv, a_log, dt_bias):
 def _kda_pre_bwd(cfg, res, cots):
     yq, yk, yv, yf, logit, keep, conv, a_log, dt_bias = res
     run = _pre_pallas if cfg.impl == "flash" else _pre_dense_bwd
+    # (the counts of the unbounded gate, last, have no pull-back)
     grads = run(cfg, yq, yk, yv, yf, logit, keep,
-                *_by_lane(cfg, conv, a_log, dt_bias), cots)
+                *_by_lane(cfg, conv, a_log, dt_bias), tuple(cots[:5]))
     return (*grads, *_pre_frozen_grads(cfg, *res, tuple(cots)))
 
 
@@ -935,17 +1044,23 @@ _kda_post.defvjp(_kda_post_fwd, _kda_post_bwd)
 
 
 def kda_layer(ys, beta_logits, gate_logits, conv, a_log, dt_bias, o_scale,
-              attn_mask=None, *, heads: int, lower: float, eps: float,
+              attn_mask=None, *, heads: int, lower, eps: float,
               impl: str = "dense"):
     """A KDA layer between its frozen products: ``ys`` the ``q k v f``
     products [b, s, h * d] as they leave the MXU (``f`` in float32),
-    ``beta_logits`` and ``gate_logits`` [b, s, h], ``conv`` the three
-    convolutions' taps [K, h * d], ``A_log`` [h], ``dt_bias`` [h * d],
-    ``o_scale`` [d] the head norm's, ``attn_mask`` [b, s] or None -> what
-    the output product reads, [b, s, h * d]. Three ``custom_vjp``s in a
-    row (:func:`_kda_pre`, the kernels' :func:`_kda_chunks`,
-    :func:`_kda_post`) keep the products' outputs, the kernels' operands
-    and entering states, and the kernels' output: no intermediate."""
+    ``beta_logits`` [b, s, h], ``gate_logits`` [b, s, h] (a gate a head)
+    or [b, s, h * d] (a gate a channel), ``conv`` the three convolutions'
+    taps [K, h * d], ``A_log`` [h], ``dt_bias`` [h * d], ``o_scale`` [d]
+    the head norm's, ``attn_mask`` [b, s] or None -> what the output
+    product reads, [b, s, h * d]. ``lower``: the bounded gate's floor
+    (``lower * sigmoid(exp(A_log) (f + dt_bias))``), or None for the
+    unbounded ``-exp(A_log) softplus(f + dt_bias)``; then the result is
+    ``(y, counts)``, ``counts`` [2] float32 the live and the steep (under
+    ``MIN_LOG_DECAY``) log-decays of every (position, key channel). Three
+    ``custom_vjp``s in a row (:func:`_kda_pre`, the kernels'
+    :func:`_kda_chunks`, :func:`_kda_post`) keep the products' outputs, the
+    kernels' operands and entering states, and the kernels' output: no
+    intermediate."""
     b, s, hd = ys["q"].shape
     d = hd // heads
     chunk = chunk_size(s)
@@ -953,7 +1068,8 @@ def kda_layer(ys, beta_logits, gate_logits, conv, a_log, dt_bias, o_scale,
     if impl == "flash" and d % 128:
         raise ValueError(f"the KDA kernels take head sizes on the 128 grid; "
                          f"got {d}")
-    obs_metrics.record_kda_plan(chunk, fused=True)
+    unbounded = lower is None
+    obs_metrics.record_kda_plan(chunk, fused=True, unbounded=unbounded)
     keep = None if attn_mask is None else attn_mask.astype(
         jnp.float32)[:, :, None]
     arrays = [ys[n] for n in "qkvf"] + [beta_logits, gate_logits]
@@ -962,15 +1078,17 @@ def kda_layer(ys, beta_logits, gate_logits, conv, a_log, dt_bias, o_scale,
         keep = jnp.ones((b, s, 1), jnp.float32) if keep is None else keep
         *arrays, keep = (jnp.pad(a, [(0, 0), (0, pad), (0, 0)])
                          for a in (*arrays, keep))
-    cfg = _Pass(heads, chunk, _row_tile(s + pad, chunk, hd), float(lower),
-                float(eps), impl, impl == "flash" and kernels.interpret())
+    cfg = _Pass(heads, chunk, _row_tile(s + pad, chunk, hd),
+                None if unbounded else float(lower), float(eps), impl,
+                impl == "flash" and kernels.interpret())
     *operands, gates = arrays
     flat = _kda_pre(cfg, *operands, keep, tuple(conv), a_log, dt_bias)
-    o = _kda_chunks(*(a.reshape(b, s + pad, heads, d) for a in flat), chunk,
-                    impl)
+    o = _kda_chunks(*(a.reshape(b, s + pad, heads, d) for a in flat[:5]),
+                    chunk, impl, unbounded)
     after = cfg._replace(tile=_row_tile(s + pad, chunk, hd, _TILE_WIDER))
     y = _kda_post(after, o.reshape(b, s + pad, hd), gates, o_scale)
-    return y[:, :s] if pad else y
+    y = y[:, :s] if pad else y
+    return (y, flat[5]) if unbounded else y
 
 
 def kda_recurrence(q, k, v, g, beta):

@@ -86,15 +86,19 @@ class LLMConfig:
     n_group: int = 0
     topk_group: int = 0
     router_bias: bool = False
-    # layers of two attention kinds (``layer_group_size > 0``): layer i has
-    # the configuration's softmax attention where ``(i + 1) %
-    # layer_group_size == 0`` and Kimi delta (linear) attention elsewhere:
-    # heads of ``linear_head_dim``, q k v through a causal depthwise
-    # convolution over 4 positions, a log-decay for every key channel in
-    # ``(kda_lower_bound, 0)``
-    layer_group_size: int = 0
+    # layers of two attention kinds: the layers ``linear_layers`` names
+    # (0-based) have Kimi delta (linear) attention, the others the
+    # configuration's softmax attention: heads of ``linear_head_dim``, q k
+    # v through a causal depthwise convolution over 4 positions, a
+    # log-decay for every key channel; the gate's form ``bounded`` (in
+    # ``(kda_lower_bound, 0)``) or ``softplus``, Kimi Linear's ``g =
+    # -exp(A_log) softplus(x W_fa W_fb + dt_bias)`` with no lower bound, the
+    # decay's and the output gate's projections low-rank pairs through
+    # ``linear_head_dim`` and the output gate a sigmoid a channel
+    linear_layers: Tuple[int, ...] = ()
     linear_head_dim: int = 0
     kda_lower_bound: float = -5.0
+    kda_gate: str = "bounded"
     # a sigmoid gate a head on the attention's output, before ``o``
     # (latent attention; linear attention always has one)
     attn_output_gate: bool = False
@@ -162,8 +166,7 @@ class LLMConfig:
 
     def is_linear(self, layer: int) -> bool:
         """Whether layer ``layer`` has linear (Kimi delta) attention."""
-        return bool(self.layer_group_size) and \
-            (layer + 1) % self.layer_group_size != 0
+        return layer in self.linear_layers
 
     def is_window(self, layer: int) -> bool:
         """Whether layer ``layer`` attends through the sliding window."""
@@ -183,7 +186,7 @@ class LLMConfig:
 
     def param_count(self) -> int:
         if (self.kv_lora_rank or self.n_routed_experts
-                or self.layer_group_size or self.layer_pattern
+                or self.linear_layers or self.layer_pattern
                 or self.head_size or self.v_head_dim or self.block_pattern
                 or self.mlp_activation != "swiglu"):
             raise NotImplementedError(
@@ -419,10 +422,15 @@ class LinearAttention(nn.Module):
     ``(e^lower, 1)``; the gated delta rule over the row from a zero
     state; ``y = (RMSNorm_head(o) * sigmoid(x W_g)_h) W_o``. Adapters on
     ``q k v f o``; the convolutions, ``W_b``, ``W_g``, ``A_log`` and
-    ``dt_bias`` are frozen. A masked key neither writes nor decays the
-    state. The module makes the seven products; everything between them
-    is ``kda_layer``'s (fused passes around the kernels, float32 inside).
-    Training path only: a recurrent state is no list of cached blocks."""
+    ``dt_bias`` are frozen. With ``kda_gate`` ``softplus`` (Kimi Linear):
+    ``g = -exp(A_log) softplus(x W_fa W_fb + dt_bias)``, unbounded below,
+    and ``y = (RMSNorm_head(o) * sigmoid(x W_ga W_gb)) W_o``, a gate a
+    channel; ``W_fa``, ``W_ga`` [hidden, d] and ``W_fb``, ``W_gb`` [d, h *
+    d] frozen, adapters on ``q k v o``. A masked key neither writes nor
+    decays the state. The module makes the products; everything between
+    them is ``kda_layer``'s (fused passes around the kernels, float32
+    inside). Training path only: a recurrent state is no list of cached
+    blocks."""
 
     cfg: LLMConfig
 
@@ -440,37 +448,46 @@ class LinearAttention(nn.Module):
                                        kda_layer)
 
         cfg = self.cfg
-        if not MIN_LOG_DECAY <= cfg.kda_lower_bound < 0:
+        unbounded = cfg.kda_gate == "softplus"
+        if not unbounded and not MIN_LOG_DECAY <= cfg.kda_lower_bound < 0:
             raise ValueError(
                 f"kda_lower_bound {cfg.kda_lower_bound}: the chunked delta "
                 f"rule is exact for log-decays in [{MIN_LOG_DECAY}, 0)")
         nh, d, taps = cfg.num_heads, cfg.linear_head_dim, SHORT_CONV_TAPS
-        dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
+        dense = lambda feats, name, **kw: nn.DenseGeneral(  # noqa: E731
             feats, axis=-1, use_bias=False, name=name,
-            dtype=cfg.compute_dtype, param_dtype=jnp.float32)
-
+            dtype=cfg.compute_dtype, param_dtype=jnp.float32, **kw)
         # the decay projection leaves its product in float32: the gate
-        # multiplies it by up to exp(A_log) = 16 inside a sigmoid
-        wide = nn.DenseGeneral(
-            nh * d, use_bias=False, name="f", dtype=cfg.compute_dtype,
-            param_dtype=jnp.float32, dot_general=functools.partial(
-                jax.lax.dot_general,
-                preferred_element_type=jnp.float32))
-        ys = _add_lora(x, {**{n: dense(nh * d, n)(x) for n in "qkv"},
-                           "f": wide(x)}, adapter, lora_scale)
+        # multiplies it by up to exp(A_log) = 16
+        wide = functools.partial(dense, nh * d, dot_general=functools.partial(
+            jax.lax.dot_general, preferred_element_type=jnp.float32))
+        if unbounded:
+            ys = _add_lora(x, {n: dense(nh * d, n)(x) for n in "qkv"},
+                           adapter, lora_scale)
+            ys["f"] = wide("f_b")(dense(d, "f_a")(x))
+        else:
+            ys = _add_lora(x, {**{n: dense(nh * d, n)(x) for n in "qkv"},
+                               "f": wide("f")(x)}, adapter, lora_scale)
         conv = [self.param(f"conv_{n}", nn.initializers.lecun_normal(),
                            (taps, nh * d)) for n in "qkv"]
         a_log = self.param("A_log", nn.initializers.zeros, (nh,))
         dt_bias = self.param("dt_bias", nn.initializers.zeros, (nh * d,))
+        beta = dense(nh, "b")(x)
+        gates = (dense(nh * d, "g_b")(dense(d, "g_a")(x)) if unbounded
+                 else dense(nh, "g")(x))
         # everything between the products and the kernels, and between the
         # kernels and the output product, is the layer's own fused pass
-        out = kda_layer(ys, dense(nh, "b")(x), dense(nh, "g")(x), conv,
-                        a_log, dt_bias, _NormScale(name="o_norm")(d),
-                        attn_mask, heads=nh, lower=cfg.kda_lower_bound,
+        out = kda_layer(ys, beta, gates, conv, a_log, dt_bias,
+                        _NormScale(name="o_norm")(d), attn_mask, heads=nh,
+                        lower=None if unbounded else cfg.kda_lower_bound,
                         eps=cfg.rms_eps, impl=cfg.attention_impl)
+        stats = {"layer_steps": jnp.float32(1)}
+        if unbounded:       # the live and the steep log-decays, counted
+            out, (stats["decays"], stats["steep_decays"]) = out
         y = dense(cfg.hidden_size, "o")(out)
-        self.sow("kda_stats", "layer_steps", jnp.float32(1),
-                 init_fn=lambda: jnp.float32(0), reduce_fn=jnp.add)
+        for name, value in stats.items():
+            self.sow("kda_stats", name, value,
+                     init_fn=lambda: jnp.float32(0), reduce_fn=jnp.add)
         return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], None
 
 
@@ -488,7 +505,8 @@ class LatentAttention(nn.Module):
     ``q = c_q W_qb`` -> heads of ``nope + rope`` dims (``q = x W_q``
     where ``q_lora_rank`` is 0: no query latent); ``[c_kv | k_r] =
     x W_kva``, ``[k_nope | v] = norm(c_kv) W_kvb``; rotary on ``q_rope`` and
-    on the one ``k_r`` all heads share; scores scaled by ``(nope +
+    on the one ``k_r`` all heads share (neither turns where ``use_rope`` is
+    off: the scores then carry no position); scores scaled by ``(nope +
     rope) ** -0.5`` times YaRN's ``mscale_all_dim`` term squared; with
     ``attn_output_gate`` a head's output is scaled by ``sigmoid(x W_g)_h``
     before ``W_o``. Training path only: a cache of latents is serving
@@ -528,10 +546,13 @@ class LatentAttention(nn.Module):
              if cfg.q_lora_rank else down["q"])
         kv = _add_lora(c_kv, {"kv_b": dense((nh, nope + dv), "kv_b")(c_kv)},
                        adapter, lora_scale)["kv_b"]
-        freq = rope_frequencies(rope, cfg.rope_theta, cfg.rope_scaling)
-        q = jnp.concatenate(
-            [q[..., :nope], _rope(q[..., nope:], positions, freq)], -1)
-        k_r = _rope(k_r[:, :, None, :], positions, freq)
+        if cfg.use_rope:
+            freq = rope_frequencies(rope, cfg.rope_theta, cfg.rope_scaling)
+            q = jnp.concatenate(
+                [q[..., :nope], _rope(q[..., nope:], positions, freq)], -1)
+            k_r = _rope(k_r[:, :, None, :], positions, freq)
+        else:
+            k_r = k_r[:, :, None, :]
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_r, (b, s, nh, rope))], -1)
         m = yarn_mscale(cfg.rope_scaling, "mscale_all_dim")

@@ -52,6 +52,9 @@ class CausalLMTrainer(TrainerSpec):
                 kept_steps=sums["moe_kept_steps"])
         if "kda_layer_steps" in sums:
             obs_metrics.record_kda_round(sums["kda_layer_steps"])
+        if "kda_decays" in sums:
+            obs_metrics.record_kda_decays(sums["kda_decays"],
+                                          sums["kda_steep_decays"])
         if "attn_window_layer_steps" in sums:
             obs_metrics.record_window_round(sums["attn_window_layer_steps"])
         if "ssm_layer_steps" in sums:

@@ -7,9 +7,10 @@ files unedited and re-exports their tests (parametrisation and fixtures as
 the files have them) as ``<file>__<test>``, so each counts and names
 itself when it fails. The tests that run whole rounds or subprocesses
 (``test_correct.py``, ``test_rehearsal.py``, the first four of
-``test_axk1.py``, the first two of ``test_ling3.py``, of ``test_mimo.py``
-and of ``test_nemotron.py``: over a minute each on a CPU) stay outside
-tier-1.
+``test_axk1.py``, the first two of ``test_ling3.py``, of ``test_mimo.py``,
+of ``test_nemotron.py`` and of ``test_kimi.py``: over a minute each
+on a CPU) stay outside tier-1, as does ``test_kimi.py``'s check against the
+model catalog, which reads it from ``MODEL_CATALOG``.
 """
 
 import importlib
@@ -53,6 +54,9 @@ _TAKEN = {
                       "test_ssd_reader_finds_kernels_by_name_only",
                       "test_the_two_scope_readers_read_their_scope_or_"
                       "nothing"),
+    "test_kimi": ("test_manifest_entries_are_as_stated",
+                  "test_work_functions_against_hand_counts",
+                  "test_steep_share_reader_reads_the_counters_or_nothing"),
 }
 _LEFT_OUT = {
     # pins PR 26's five entries as the LAST five of BENCHMARK.json's
@@ -73,6 +77,12 @@ _LEFT_OUT = {
     # section 7 (h)): ``test_the_scope_entries_are_as_accepted_but_for_
     # their_cells`` stands in and pins every other key
     ("test_scopes", "test_manifest_has_the_thirteen_entries_with_their_cells"),
+    # pins the Nemotron entries as the LAST three of per_layer and its cell
+    # as the last of each list it joined; a later cell appended itself to
+    # those lists and one entry after them, as the contract lets a
+    # model_config PR do: the test of the same name below runs the file's
+    # test unedited on the benchmark as it stood up to the Nemotron cell
+    ("test_nemotron", "test_manifest_entries_are_the_issues"),
 }
 
 
@@ -117,6 +127,41 @@ def _leave_no_state():
     obs.configure(None)
     obs_trace.clear_finished()
     REGISTRY.reset()
+
+
+def _up_to(bench, cell, config, metric):
+    """``bench`` as it stood when ``cell``, ``config`` and the per-layer
+    ``metric`` were its last entries: what later PRs appended (cells,
+    configurations, per-layer entries, and cells in the per-layer lists)
+    left out."""
+    def cut(items, last):
+        names = [x["name"] for x in items]
+        return items[:names.index(last) + 1]
+
+    cells = cut(bench["workloads"], cell)
+    kept = {w["name"] for w in cells}
+    return dict(bench, workloads=cells,
+                configs=cut(bench["configs"], config),
+                per_layer=[dict(m, workloads=[w for w in m["workloads"]
+                                              if w in kept])
+                           if "workloads" in m else m
+                           for m in cut(bench["per_layer"], metric)])
+
+
+def test_nemotron__test_manifest_entries_are_the_issues(monkeypatch):
+    """``benchmarks/tests/test_nemotron.py``'s test of its manifest entries,
+    unedited, on the benchmark as it stood up to the Nemotron cell (its
+    configuration and its last per-layer entry): the file pins that cell
+    as the last of every list it joined, which each cell appended later
+    moves. What the later cells appended is pinned by their own tests."""
+    sys.path.insert(0, _BENCH_TESTS)
+    import test_nemotron
+    manifest = test_nemotron.manifest
+    whole = manifest.benchmark()
+    monkeypatch.setattr(manifest, "benchmark", lambda: _up_to(
+        whole, test_nemotron.CELL, test_nemotron.CONFIG,
+        "scope_moe_latent_ms"))
+    test_nemotron.test_manifest_entries_are_the_issues()
 
 
 def test_expert_layer_metrics_list_the_cells_with_experts():
@@ -186,7 +231,8 @@ def test_the_scope_entries_are_as_accepted_but_for_their_cells():
         "n_routed_experts", "num_experts"))]
     mixers = {c: cfgs[c].get("hybrid_override_pattern", "") for c in lm}
     latent = [c for c in lm if cfgs[c].get("kv_lora_rank")]
-    linear = [c for c in lm if cfgs[c].get("layer_group_size")]
+    linear = [c for c in lm if cfgs[c].get("layer_group_size")
+              or cfgs[c].get("linear_attn_config")]
     window = [c for c in lm if cfgs[c].get("hybrid_layer_pattern")]
     # grouped-query softmax layers: what is neither latent, linear nor a
     # single-mixer stack has them in every layer; a stack where it says *

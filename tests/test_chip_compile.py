@@ -417,6 +417,35 @@ def test_only_the_kda_kernels_read_as_kda_kernels(v5e):
         assert mine == (["kda_" + kind] if kind else mine[:1]) and mine, kinds
 
 
+def _unbounded_layer_step(masked):
+    def step(ys, beta_logits, gate_logits, conv, a_log, dt_bias, o_scale,
+             mask):
+        def loss(ys, b, g):
+            y, counts = kda_layer(
+                ys, b, g, conv, a_log, dt_bias, o_scale,
+                mask if masked else None, heads=32, lower=None, eps=1e-5,
+                impl="flash")
+            return y.astype(jnp.float32).sum(), counts
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            ys, beta_logits, gate_logits)
+    return step
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_the_unbounded_kda_layer_compiles_for_v5e(v5e, masked):
+    """The unbounded-gate cell's KDA layer (``[1, 4096, 32 x 128]``,
+    bfloat16 products, the softplus gate, a gate a channel as a ``[1, 4096,
+    4096]`` product): the kernels with each sub-chunk's block against itself
+    made element by element, the passes with the counts of steep decays
+    and the channel gate, six Mosaic kernels under their names."""
+    avals = list(_kda_layer_avals(jnp.bfloat16, 32))
+    avals[2] = jax.ShapeDtypeStruct((1, 4096, 32 * 128), jnp.bfloat16)
+    text = _compile(_unbounded_layer_step(masked), v5e, *avals).as_text()
+    assert text.count("tpu_custom_call") == 6
+    for name in KDA_KERNEL_NAMES + KDA_PASS_NAMES:
+        assert name in text, name
+
+
 def test_flash_bwd_never_materializes_scores(v5e):
     """Training-memory contract: at s=4096 the compiled fwd+bwd must not
     allocate an [s, s] f32 buffer (64 MiB); flash peak temp stays under a

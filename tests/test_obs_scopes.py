@@ -131,7 +131,7 @@ KINDS = {
                 "flash_win_dq": "attn.window",
                 "flash_win_dkv": "attn.window"}),
     # a Kimi-delta layer (its kernels take heads of 128) and a latent one
-    "linear": (dict(**LATENT, layer_group_size=2, linear_head_dim=128,
+    "linear": (dict(**LATENT, linear_layers=(0,), linear_head_dim=128,
                     attn_output_gate=True),
                EVERY | {"attn.linear", "attn.latent"},
                {"kda_bwd": "attn.linear", "kda_pre_bwd": "attn.linear",
