@@ -57,7 +57,8 @@ FULL = {
                   prompt_chars=(3, 40, 150, 400, 700, 12)),
     "flash_shapes": ((8, 1024, 8, 128), (1, 8192, 2, 128)),
     "kda_shape": (1, 2048, 4, 128),
-    # the unbounded-gate cell's KDA layer: b, s, heads, channels a head
+    # the KDA cells' layer (both gate forms): b, s, heads, channels a head
+    "kda_cell_shape": (1, 4096, 32, 128),
     "kda_softplus_shape": (1, 4096, 32, 128),
     # b, s, heads, channels a head, groups, state size
     "ssd_shape": (1, 4096, 128, 64, 8, 128),
@@ -74,6 +75,7 @@ TOY = {
     "serve": dict(slots=4, max_new=4, prompt_chars=(3, 20, 60, 9)),
     "flash_shapes": ((2, 128, 2, 32),),
     "kda_shape": (1, 128, 2, 128),
+    "kda_cell_shape": (1, 128, 2, 128),
     "kda_softplus_shape": (1, 128, 2, 128),
     "ssd_shape": (1, 160, 4, 64, 2, 128),
     "window_shape": (1, 256, 4, 2, 24, 16, 40),
@@ -473,24 +475,23 @@ def phase_kernels(sz):
     return out
 
 
-def phase_kda(sz):
-    """The chunked delta rule (the Pallas kernels on a chip, interpreted
-    elsewhere) forward and backward against the token recurrence, on
-    bfloat16 operands with log-decays all over (-5, 0)."""
+def _kda_operands(shape, lo, seed):
+    """bfloat16 q, k (unit rows, q at ``d ** -0.5``) and v, float32
+    log-decays all over ``(lo, 0)``, beta, and a float32 cotangent of the
+    output; with the pulled-back gradients' function and a relative
+    distance that refuses a non-finite result."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from fedml_tpu.llm.linear_attention import kda_attention, kda_recurrence
-
-    b, s, h, d = sz["kda_shape"]
-    ks = jax.random.split(jax.random.PRNGKey(11), 6)
+    b, s, h, d = shape
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa
     q = (unit(jax.random.normal(ks[0], (b, s, h, d))) * d ** -0.5
          ).astype(jnp.bfloat16)
     k = unit(jax.random.normal(ks[1], (b, s, h, d))).astype(jnp.bfloat16)
     v = jax.random.normal(ks[2], (b, s, h, d), jnp.bfloat16)
-    g = jax.random.uniform(ks[3], (b, s, h, d), minval=-5.0, maxval=0.0)
+    g = jax.random.uniform(ks[3], (b, s, h, d), minval=lo, maxval=0.0)
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
     ct = jax.random.normal(ks[5], (b, s, h, d), jnp.float32)
 
@@ -504,7 +505,22 @@ def phase_kda(sz):
         check(np.isfinite(a).all(), "non-finite KDA result")
         return float(np.linalg.norm(a - w) / (np.linalg.norm(w) + 1e-6))
 
-    xs = (q, k, v, g, beta)
+    return (q, k, v, g, beta), grads, gap
+
+
+def phase_kda(sz):
+    """The chunked delta rule (the Pallas kernels on a chip, interpreted
+    elsewhere) forward and backward against the token recurrence, on
+    bfloat16 operands with log-decays all over (-5, 0); then at the KDA
+    cells' shape against the same step under the ``dense`` scan (the
+    recurrence's pull-back would hold every token's state), and on a chip
+    the host-timed ms a call of the kernels with their XLA glue, forward
+    and forward + backward."""
+    import jax
+
+    from fedml_tpu.llm.linear_attention import kda_attention, kda_recurrence
+
+    xs, grads, gap = _kda_operands(sz["kda_shape"], -5.0, 11)
     kernels = functools.partial(kda_attention, impl="flash")
     errs = [gap(jax.jit(kernels)(*xs), jax.jit(kda_recurrence)(*xs))]
     errs += [gap(a, w) for a, w in zip(jax.jit(grads(kernels))(*xs),
@@ -516,6 +532,18 @@ def phase_kda(sz):
         out["kda_fwd_bwd_ms_kernels_vs_recurrence"] = [
             round(1e3 * statistics.median(round_trips(grads(f), *xs, n=5)), 3)
             for f in (kernels, kda_recurrence)]
+    shape = "x".join(map(str, sz["kda_cell_shape"]))
+    xs, grads, gap = _kda_operands(sz["kda_cell_shape"], -5.0, 12)
+    dense = functools.partial(kda_attention, impl="dense")
+    errs = [gap(jax.jit(kernels)(*xs), jax.jit(dense)(*xs))]
+    errs += [gap(a, w) for a, w in zip(jax.jit(grads(kernels))(*xs),
+                                       jax.jit(grads(dense))(*xs))]
+    check(max(errs) < 0.02, f"KDA kernels vs the dense step: {errs}")
+    out["kda_rel_err_" + shape] = round(max(errs), 6)
+    if on_chip():
+        out["kda_ms_fwd_and_fwd_bwd_" + shape] = [
+            round(1e3 * statistics.median(round_trips(f, *xs, n=7)), 3)
+            for f in (kernels, grads(kernels))]
     return out
 
 
@@ -530,36 +558,15 @@ def phase_kda_softplus(sz):
     the bounded form's factorised one at the same shapes, whose result is
     wrong at these decays: its distance to the dense step is reported."""
     import jax
-    import jax.numpy as jnp
-    import numpy as np
 
     from fedml_tpu.llm.linear_attention import kda_attention
 
     b, s, h, d = sz["kda_softplus_shape"]
-    ks = jax.random.split(jax.random.PRNGKey(13), 6)
-    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa
-    q = (unit(jax.random.normal(ks[0], (b, s, h, d))) * d ** -0.5
-         ).astype(jnp.bfloat16)
-    k = unit(jax.random.normal(ks[1], (b, s, h, d))).astype(jnp.bfloat16)
-    v = jax.random.normal(ks[2], (b, s, h, d), jnp.bfloat16)
-    g = jax.random.uniform(ks[3], (b, s, h, d), minval=-20.0, maxval=0.0)
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
-    ct = jax.random.normal(ks[5], (b, s, h, d), jnp.float32)
-    xs = (q, k, v, g, beta)
+    xs, grads, gap = _kda_operands((b, s, h, d), -20.0, 13)
 
     def form(impl, unbounded=True):
         return functools.partial(kda_attention, impl=impl,
                                  unbounded=unbounded)
-
-    def grads(fn):
-        return jax.grad(
-            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * ct),
-            argnums=(0, 1, 2, 3, 4))
-
-    def gap(a, w):
-        a, w = (np.asarray(x, np.float32) for x in (a, w))
-        check(np.isfinite(a).all(), "non-finite KDA result")
-        return float(np.linalg.norm(a - w) / (np.linalg.norm(w) + 1e-6))
 
     kernels, dense = form("flash"), form("dense")
     errs = [gap(jax.jit(kernels)(*xs), jax.jit(dense)(*xs))]

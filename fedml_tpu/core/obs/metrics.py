@@ -610,16 +610,27 @@ def record_flash_window(window: int, block_share: float, sink: bool) -> None:
                    "else 0").set(1.0 if sink else 0.0)
 
 
-def record_kda_plan(chunk: int, fused: bool, unbounded: bool = False) -> None:
+def record_kda_plan(chunk: int, fused: bool, unbounded: bool = False,
+                    kept_bytes: int = 0) -> None:
     """The chunk size of the linear-attention call just traced (host side,
     once a trace; ``llm/linear_attention.py::chunk_size``), whether the
     layer's element-wise work around the kernels ran through the fused
     passes (``kda_layer``) or the caller made the kernels' operands itself
-    (``kda_attention``), and the gate's form: 0 the bounded gate, 1 the
+    (``kda_attention``), the gate's form: 0 the bounded gate, 1 the
     unbounded softplus one (whose chunk step makes each sub-chunk's block
-    against itself element by element)."""
+    against itself element by element), and the bytes of every chunk's
+    ``[C, C]`` matrices the forward pass keeps for the backward one (which
+    reads them where it made them again before)."""
     if not _cfg["enabled"]:
         return
+    REGISTRY.gauge("fed_kda_bwd_kept",
+                   "1 if the backward pass of the last traced KDA call "
+                   "reads the chunks' inverse and scores the forward pass "
+                   "kept, else 0").set(1.0 if kept_bytes else 0.0)
+    REGISTRY.gauge("fed_kda_kept_bytes",
+                   "bytes of the chunks' [C, C] matrices the forward pass "
+                   "of the last traced KDA call keeps a layer-step"
+                   ).set(float(kept_bytes))
     REGISTRY.gauge("fed_kda_gate",
                    "the gate of the last traced KDA call: 0 bounded "
                    "(log-decays >= -5), 1 unbounded softplus"

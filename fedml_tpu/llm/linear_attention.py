@@ -32,7 +32,10 @@ inside two Pallas kernels (``kda_fwd``: chunks in order, the state in VMEM
 scratch, every chunk's entering state written out; ``kda_bwd``: chunks in
 reverse order, the state's cotangent in VMEM scratch). One ``custom_vjp``
 over the whole sequence holds the pair together: the backward of a chunked
-scan, with the entering states as its only residual beyond the inputs.
+scan, whose residuals beyond the inputs are the entering states and each
+chunk's ``(I + A)^-1`` and ``P`` (the scores of q against the chunk's keys),
+which depend on no state: :func:`_chunk_grads` pulls back through the
+products that made them without making them, or the inverse, again.
 The kernels read ``[b, s, h * d]`` as the projections leave it (a head is
 a 128-lane column block): nothing is transposed around them.
 
@@ -136,15 +139,16 @@ def _unit_lower_inverse(a):
 
 
 @jax.custom_vjp
-def _unit_lower_solve(a, rhs):
+def _unit_lower_solve(a, inv, rhs):
     """``u`` with ``(I + a) u = rhs`` for strictly lower triangular ``a``
-    [C, C] and ``rhs`` [C, d]. The pull-back is the solve's own (two
-    products with what the forward pass holds), not an inverse's."""
-    return _unit_lower_solve_fwd(a, rhs)[0]
+    [C, C] and ``rhs`` [C, d], given ``inv = (I + a)^-1``
+    (:func:`_unit_lower_inverse`): ``a`` is read by the pull-back alone,
+    which is the solve's own (two products with ``inv`` and ``u``), not an
+    inverse's; ``inv`` takes no cotangent."""
+    return _exact(inv, rhs)
 
 
-def _unit_lower_solve_fwd(a, rhs):
-    inv = _unit_lower_inverse(a)
+def _unit_lower_solve_fwd(a, inv, rhs):
     u = _exact(inv, rhs)
     return u, (inv, u)
 
@@ -157,7 +161,7 @@ def _unit_lower_solve_bwd(res, du):
     drhs = _exact(inv, du, _TN)
     lower = (jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
              > jax.lax.broadcasted_iota(jnp.int32, (c, c), 1))
-    return jnp.where(lower, -_exact(drhs, u, _NT), 0.0), drhs
+    return jnp.where(lower, -_exact(drhs, u, _NT), 0.0), None, drhs
 
 
 _unit_lower_solve.defvjp(_unit_lower_solve_fwd, _unit_lower_solve_bwd)
@@ -186,16 +190,24 @@ def _within(both, x, k, g, k_all, first: int, dtype):
     return both
 
 
-def _chunk(q, k, kb, vb, gc, st, dtype, unbounded: bool = False):
-    """One chunk of one head. ``q``, ``k`` [C, d_k]; ``kb = beta * k``;
-    ``vb = beta * v`` [C, d_v]; ``gc`` [C, d_k] the chunk's running sum of
-    log-decays (float32, its own row included); ``st`` [d_v, d_k] the
-    entering state, transposed so that a decay scales its lanes. ->
-    (o [C, d_v] float32, the state the chunk leaves). ``unbounded``: the
-    log-decays may lie under ``MIN_LOG_DECAY``, and each sub-chunk's block
-    against itself is made by :func:`_within`."""
-    f32 = jnp.float32
-    q, k, kb, vb, gc = (a.astype(f32) for a in (q, k, kb, vb, gc))
+@jax.custom_jvp
+def _kept(made, kept):
+    """``kept``, the value ``made`` had when the forward pass made it, with
+    ``made``'s derivative: the pull-back runs through what made it, and
+    the primal leaves ``made`` unread (dead code, which the compiler
+    drops)."""
+    return kept
+
+
+@_kept.defjvp
+def _kept_jvp(primals, tangents):
+    return primals[1], tangents[0]
+
+
+def _chunk_mats(q, k, kb, gc, dtype, unbounded: bool):
+    """The chunk's matrices that do not depend on the state, from float32
+    ``q``, ``k``, ``kb``, ``gc`` [C, d_k]: ``A`` (strictly lower) and
+    ``P`` (on and below the diagonal), float32 [C, C] each."""
     c = q.shape[0]
     sub = min(SUB, c)
     row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
@@ -220,26 +232,63 @@ def _chunk(q, k, kb, vb, gc, st, dtype, unbounded: bool = False):
         p_rows.append(both[sub:])
     a_mat = jnp.where(row > col, jnp.concatenate(a_rows, 0), 0.0)
     p_mat = jnp.where(row >= col, jnp.concatenate(p_rows, 0), 0.0)
+    return a_mat, p_mat
 
+
+def _chunk_state(q, k, kb, vb, gc, st, a_mat, inv, p_mat, dtype):
+    """The rest of the step, against the entering state ``st``, given the
+    chunk's matrices and ``inv = (I + A)^-1`` (None: made here, after the
+    state's product, where the schedule wants it) -> (o, the state the
+    chunk leaves, ``inv``)."""
+    c = q.shape[0]
     # against the entering state: decays from the chunk's start, all <= 1
     from_start = jnp.exp(gc)
     seen = _mm(jnp.concatenate([kb * from_start, q * from_start], 0), st,
                _NT, dtype)                                   # [2C, d_v]
-    u = _unit_lower_solve(a_mat, vb - seen[:c])
+    if inv is None:
+        inv = _unit_lower_inverse(a_mat)
+    u = _unit_lower_solve(a_mat, inv, vb - seen[:c])
     o = seen[c:] + _mm(p_mat, u, (((1,), (0,)), ((), ())), dtype)
     last = gc[c - 1:c]
     st_new = st * jnp.exp(last) + _mm(u, k * jnp.exp(last - gc), _TN, dtype)
-    return o, st_new
+    return o, st_new, inv
 
 
-def _chunk_grads(q, k, kb, vb, gc, st, do, dst, dtype,
+def _chunk(q, k, kb, vb, gc, st, dtype, unbounded: bool = False):
+    """One chunk of one head. ``q``, ``k`` [C, d_k]; ``kb = beta * k``;
+    ``vb = beta * v`` [C, d_v]; ``gc`` [C, d_k] the chunk's running sum of
+    log-decays (float32, its own row included); ``st`` [d_v, d_k] the
+    entering state, transposed so that a decay scales its lanes. ->
+    (o [C, d_v] float32, the state the chunk leaves, and what the backward
+    pass reads of the chunk: ``(I + A)^-1`` float32 and ``P`` in ``dtype``,
+    [C, C] each, as the products used them). ``unbounded``: the
+    log-decays may lie under ``MIN_LOG_DECAY``, and each sub-chunk's block
+    against itself is made by :func:`_within`."""
+    f32 = jnp.float32
+    q, k, kb, vb, gc = (a.astype(f32) for a in (q, k, kb, vb, gc))
+    a_mat, p_mat = _chunk_mats(q, k, kb, gc, dtype, unbounded)
+    o, st_new, inv = _chunk_state(q, k, kb, vb, gc, st, a_mat, None, p_mat,
+                                  dtype)
+    return o, st_new, inv, p_mat.astype(dtype)
+
+
+def _chunk_grads(q, k, kb, vb, gc, st, inv, p, do, dst, dtype,
                  unbounded: bool = False):
-    """``_chunk`` rebuilt and ``(do, dst)`` pulled back to its six inputs,
-    each in float32."""
+    """``(do, dst)`` pulled back through ``_chunk`` to its six inputs, each
+    in float32, given what the forward pass kept of the chunk (``inv``,
+    ``p``): ``A``, ``P`` and the inverse are not made again, only the
+    pull-backs of the products that made ``A`` and ``P`` run (on their
+    operands, rebuilt element by element)."""
     f32 = jnp.float32
     args = tuple(a.astype(f32) for a in (q, k, kb, vb, gc, st))
-    _, pull = jax.vjp(functools.partial(_chunk, dtype=dtype,
-                                        unbounded=unbounded), *args)
+    p = p.astype(f32)
+
+    def step(q, k, kb, vb, gc, st):
+        a_mat, p_mat = _chunk_mats(q, k, kb, gc, dtype, unbounded)
+        return _chunk_state(q, k, kb, vb, gc, st, a_mat, inv,
+                            _kept(p_mat, p), dtype)[:2]
+
+    _, pull = jax.vjp(step, *args)
     return pull((do.astype(f32), dst))
 
 
@@ -264,27 +313,28 @@ def _scan_fwd(q, k, kb, vb, gc, chunk, unbounded):
     b, _, h, dk = q.shape
 
     def body(st, xs):
-        o, st_new = step(*xs, st)
-        return st_new, (o, st)
+        o, st_new, inv, p = step(*xs, st)
+        return st_new, (o, st, inv, p)
 
     st0 = jnp.zeros((b, h, vb.shape[-1], dk), jnp.float32)
-    _, (o, states) = jax.lax.scan(
+    _, (o, states, inv, p) = jax.lax.scan(
         body, st0, tuple(_by_chunk(a, chunk) for a in (q, k, kb, vb, gc)))
-    return _from_chunks(o).astype(dtype), states       # states [n, b, h, ..]
+    # states [n, b, h, d_v, d_k]; inv, p [n, b, h, C, C]
+    return _from_chunks(o).astype(dtype), states, inv, p
 
 
-def _scan_bwd(q, k, kb, vb, gc, states, do, chunk, unbounded):
+def _scan_bwd(q, k, kb, vb, gc, states, inv, p, do, chunk, unbounded):
     dtype = q.dtype
     step = jax.vmap(jax.vmap(functools.partial(_chunk_grads, dtype=dtype,
                                                unbounded=unbounded)))
 
     def body(dst, xs):
-        *ins, st, do_c = xs
-        dq, dk, dkb, dvb, dgc, dst0 = step(*ins, st, do_c, dst)
+        *ins, st, inv, p, do_c = xs
+        dq, dk, dkb, dvb, dgc, dst0 = step(*ins, st, inv, p, do_c, dst)
         return dst0, (dq, dk, dkb, dvb, dgc)
 
     xs = tuple(_by_chunk(a, chunk) for a in (q, k, kb, vb, gc)) \
-        + (states, _by_chunk(do, chunk))
+        + (states, inv, p, _by_chunk(do, chunk))
     _, grads = jax.lax.scan(body, jnp.zeros_like(states[0]), xs, reverse=True)
     return tuple(_from_chunks(g).astype(a.dtype)
                  for g, a in zip(grads, (q, k, kb, vb, gc)))
@@ -299,11 +349,13 @@ def _heads_per_step(h: int) -> int:
 
 
 def _kda_fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, gc_ref, o_ref, states_ref,
-                    st_ref, *, heads: int, dk: int, dv: int,
+                    inv_ref, p_ref, st_ref, *, heads: int, dk: int, dv: int,
                     unbounded: bool):
     """One (row, head group, chunk) program; chunks run in order and the
     state stays in ``st_ref`` [heads, d_v, d_k] between them. Blocks are
-    ``[chunk, heads * d]`` columns of the ``[b, s, h * d]`` arrays."""
+    ``[chunk, heads * d]`` columns of the ``[b, s, h * d]`` arrays; the
+    chunk's kept ``[C, C]`` matrices go to ``[C, heads * C]`` blocks, the
+    group's heads side by side."""
     import jax.experimental.pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
@@ -311,19 +363,24 @@ def _kda_fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, gc_ref, o_ref, states_ref,
         st_ref[...] = jnp.zeros_like(st_ref)
 
     dtype = q_ref.dtype
+    c = q_ref.shape[0]
     for j in range(heads):
-        ck, cv = pl.ds(j * dk, dk), pl.ds(j * dv, dv)
+        ck, cv, cc = pl.ds(j * dk, dk), pl.ds(j * dv, dv), pl.ds(j * c, c)
         st = st_ref[j]
         states_ref[j] = st
-        o, st_new = _chunk(q_ref[:, ck], k_ref[:, ck], kb_ref[:, ck],
-                           vb_ref[:, cv], gc_ref[:, ck], st, dtype, unbounded)
+        o, st_new, inv, p = _chunk(q_ref[:, ck], k_ref[:, ck], kb_ref[:, ck],
+                                   vb_ref[:, cv], gc_ref[:, ck], st, dtype,
+                                   unbounded)
         o_ref[:, cv] = o.astype(o_ref.dtype)
+        inv_ref[:, cc] = inv
+        p_ref[:, cc] = p
         st_ref[j] = st_new
 
 
-def _kda_bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, gc_ref, states_ref, do_ref,
-                    dq_ref, dk_ref, dkb_ref, dvb_ref, dgc_ref, dst_ref,
-                    *, heads: int, dk: int, dv: int, unbounded: bool):
+def _kda_bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, gc_ref, states_ref,
+                    inv_ref, p_ref, do_ref, dq_ref, dk_ref, dkb_ref, dvb_ref,
+                    dgc_ref, dst_ref, *, heads: int, dk: int, dv: int,
+                    unbounded: bool):
     """The same grid with the chunks in reverse order; ``dst_ref`` holds
     the cotangent of the state a chunk leaves."""
     import jax.experimental.pallas as pl
@@ -333,12 +390,13 @@ def _kda_bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, gc_ref, states_ref, do_ref,
         dst_ref[...] = jnp.zeros_like(dst_ref)
 
     dtype = q_ref.dtype
+    c = q_ref.shape[0]
     for j in range(heads):
-        ck, cv = pl.ds(j * dk, dk), pl.ds(j * dv, dv)
+        ck, cv, cc = pl.ds(j * dk, dk), pl.ds(j * dv, dv), pl.ds(j * c, c)
         dq, dk_, dkb, dvb, dgc, dst0 = _chunk_grads(
             q_ref[:, ck], k_ref[:, ck], kb_ref[:, ck], vb_ref[:, cv],
-            gc_ref[:, ck], states_ref[j], do_ref[:, cv], dst_ref[j], dtype,
-            unbounded)
+            gc_ref[:, ck], states_ref[j], inv_ref[:, cc], p_ref[:, cc],
+            do_ref[:, cv], dst_ref[j], dtype, unbounded)
         dq_ref[:, ck] = dq.astype(dq_ref.dtype)
         dk_ref[:, ck] = dk_.astype(dk_ref.dtype)
         dkb_ref[:, ck] = dkb.astype(dkb_ref.dtype)
@@ -357,7 +415,9 @@ def _pallas_specs(pl, b, s, h, dk, dv, chunk, reverse):
                           lambda i, j, c: (i, at(c), j))
     spec_st = pl.BlockSpec((None, hb, None, dv, dk),
                            lambda i, j, c: (i, j, at(c), 0, 0))
-    return hb, (b, h // hb, n), spec_k, spec_v, spec_st
+    spec_c = pl.BlockSpec((None, None, None, chunk, hb * chunk),
+                          lambda i, j, c: (i, j, at(c), 0, 0))
+    return hb, (b, h // hb, n), spec_k, spec_v, spec_st, spec_c
 
 
 # the Pallas forms are ``jit``s of their own: a model's layers share one
@@ -371,36 +431,39 @@ def _pallas_fwd(q, k, kb, vb, gc, chunk, interpret, unbounded):
 
     b, s, h, dk = q.shape
     dv = vb.shape[-1]
-    hb, grid, spec_k, spec_v, spec_st = _pallas_specs(
+    hb, grid, spec_k, spec_v, spec_st, spec_c = _pallas_specs(
         pl, b, s, h, dk, dv, chunk, False)
     flat = lambda a: a.reshape(b, s, -1)  # noqa: E731
-    o, states = pl.pallas_call(
+    kept = lambda dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        (b, h // hb, s // chunk, chunk, hb * chunk), dtype)
+    o, states, inv, p = pl.pallas_call(
         functools.partial(_kda_fwd_kernel, heads=hb, dk=dk, dv=dv,
                           unbounded=unbounded),
         grid=grid,
         in_specs=[spec_k, spec_k, spec_k, spec_v, spec_k],
-        out_specs=[spec_v, spec_st],
+        out_specs=[spec_v, spec_st, spec_c, spec_c],
         out_shape=[jax.ShapeDtypeStruct((b, s, h * dv), q.dtype),
                    jax.ShapeDtypeStruct((b, h, s // chunk, dv, dk),
-                                        jnp.float32)],
+                                        jnp.float32),
+                   kept(jnp.float32), kept(q.dtype)],
         scratch_shapes=[pltpu.VMEM((hb, dv, dk), jnp.float32)],
         interpret=interpret,
         compiler_params=kernels.tpu_compiler_params(
             ("parallel", "parallel", "arbitrary")),
         name=KDA_KERNEL_NAMES[0],
     )(flat(q), flat(k), flat(kb), flat(vb), flat(gc))
-    return o.reshape(b, s, h, dv), states
+    return o.reshape(b, s, h, dv), states, inv, p
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9))
-def _pallas_bwd(q, k, kb, vb, gc, states, do, chunk, interpret,
+@functools.partial(jax.jit, static_argnums=(9, 10, 11))
+def _pallas_bwd(q, k, kb, vb, gc, states, inv, p, do, chunk, interpret,
                 unbounded):
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
     b, s, h, dk = q.shape
     dv = vb.shape[-1]
-    hb, grid, spec_k, spec_v, spec_st = _pallas_specs(
+    hb, grid, spec_k, spec_v, spec_st, spec_c = _pallas_specs(
         pl, b, s, h, dk, dv, chunk, True)
     flat = lambda a: a.reshape(b, s, -1)  # noqa: E731
     like = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
@@ -409,7 +472,8 @@ def _pallas_bwd(q, k, kb, vb, gc, states, do, chunk, interpret,
         functools.partial(_kda_bwd_kernel, heads=hb, dk=dk, dv=dv,
                           unbounded=unbounded),
         grid=grid,
-        in_specs=[spec_k, spec_k, spec_k, spec_v, spec_k, spec_st, spec_v],
+        in_specs=[spec_k, spec_k, spec_k, spec_v, spec_k, spec_st, spec_c,
+                  spec_c, spec_v],
         out_specs=[spec_k, spec_k, spec_k, spec_v, spec_k],
         out_shape=[like(q), like(k), like(kb), like(vb), like(gc)],
         scratch_shapes=[pltpu.VMEM((hb, dv, dk), jnp.float32)],
@@ -417,7 +481,8 @@ def _pallas_bwd(q, k, kb, vb, gc, states, do, chunk, interpret,
         compiler_params=kernels.tpu_compiler_params(
             ("parallel", "parallel", "arbitrary")),
         name=KDA_KERNEL_NAMES[1],
-    )(flat(q), flat(k), flat(kb), flat(vb), flat(gc), states, flat(do))
+    )(flat(q), flat(k), flat(kb), flat(vb), flat(gc), states, inv, p,
+      flat(do))
     return tuple(g.reshape(a.shape)
                  for g, a in zip(grads, (q, k, kb, vb, gc)))
 
@@ -432,11 +497,11 @@ def _kda_chunks(q, k, kb, vb, gc, chunk: int, impl: str,
 
 def _kda_chunks_fwd(q, k, kb, vb, gc, chunk, impl, unbounded):
     if impl == "flash":
-        o, states = _pallas_fwd(q, k, kb, vb, gc, chunk, kernels.interpret(),
-                                unbounded)
+        o, *kept = _pallas_fwd(q, k, kb, vb, gc, chunk, kernels.interpret(),
+                               unbounded)
     else:
-        o, states = _scan_fwd(q, k, kb, vb, gc, chunk, unbounded)
-    return o, (q, k, kb, vb, gc, states)
+        o, *kept = _scan_fwd(q, k, kb, vb, gc, chunk, unbounded)
+    return o, (q, k, kb, vb, gc, *kept)
 
 
 def _kda_chunks_bwd(chunk, impl, unbounded, res, do):
@@ -446,6 +511,14 @@ def _kda_chunks_bwd(chunk, impl, unbounded, res, do):
 
 
 _kda_chunks.defvjp(_kda_chunks_fwd, _kda_chunks_bwd)
+
+
+def _kept_bytes(b: int, s: int, h: int, chunk: int, dtype) -> int:
+    """Bytes the forward pass keeps of every chunk's matrices for the
+    backward pass (``(I + A)^-1`` in float32, ``P`` in ``dtype``) in a row
+    of ``s`` positions padded to whole chunks."""
+    return (b * h * -(-s // chunk) * chunk * chunk
+            * (4 + jnp.dtype(dtype).itemsize))
 
 
 def chunk_size(s: int) -> int:
@@ -469,7 +542,9 @@ def kda_attention(q, k, v, g, beta, impl: str = "dense",
     if impl == "flash" and (dk % 128 or v.shape[-1] % 128):
         raise ValueError(f"the KDA kernels take head sizes on the 128 grid; "
                          f"got d_k {dk}, d_v {v.shape[-1]}")
-    obs_metrics.record_kda_plan(chunk, fused=False, unbounded=unbounded)
+    obs_metrics.record_kda_plan(chunk, fused=False, unbounded=unbounded,
+                                kept_bytes=_kept_bytes(b, s, h, chunk,
+                                                       q.dtype))
     beta = beta[..., None].astype(jnp.float32)
     kb = (k.astype(jnp.float32) * beta).astype(k.dtype)
     vb = (v.astype(jnp.float32) * beta).astype(v.dtype)
@@ -1059,8 +1134,8 @@ def kda_layer(ys, beta_logits, gate_logits, conv, a_log, dt_bias, o_scale,
     ``MIN_LOG_DECAY``) log-decays of every (position, key channel). Three
     ``custom_vjp``s in a row (:func:`_kda_pre`, the kernels'
     :func:`_kda_chunks`, :func:`_kda_post`) keep the products' outputs, the
-    kernels' operands and entering states, and the kernels' output: no
-    intermediate."""
+    kernels' operands, entering states and chunks' ``[C, C]`` matrices, and
+    the kernels' output: no intermediate of the layer's width."""
     b, s, hd = ys["q"].shape
     d = hd // heads
     chunk = chunk_size(s)
@@ -1069,7 +1144,9 @@ def kda_layer(ys, beta_logits, gate_logits, conv, a_log, dt_bias, o_scale,
         raise ValueError(f"the KDA kernels take head sizes on the 128 grid; "
                          f"got {d}")
     unbounded = lower is None
-    obs_metrics.record_kda_plan(chunk, fused=True, unbounded=unbounded)
+    obs_metrics.record_kda_plan(chunk, fused=True, unbounded=unbounded,
+                                kept_bytes=_kept_bytes(b, s, heads, chunk,
+                                                       ys["q"].dtype))
     keep = None if attn_mask is None else attn_mask.astype(
         jnp.float32)[:, :, None]
     arrays = [ys[n] for n in "qkvf"] + [beta_logits, gate_logits]

@@ -7,11 +7,13 @@ each kernel's final bundles, every loop's length and what fills it.
     python3 scripts/flash_bundles.py 1 4096 4 192 128     # b s h d_qk d_v
     python3 scripts/flash_bundles.py 2 1024 4 128 128 --mask --blocks 256,256
     python3 scripts/flash_bundles.py --kda 1 4096 32 128  # b s h d
+    python3 scripts/flash_bundles.py --kda 1 4096 32 128 --unbounded
 
 ``--kda`` does the same for the linear-attention kernels (``kda_fwd``,
-``kda_bwd``: ``llm/linear_attention.py``). Their body is straight-line code,
-one chunk of two heads, so the only loop is the grid and its length is a
-grid step.
+``kda_bwd``: ``llm/linear_attention.py``), in the bounded gate's form or,
+with ``--unbounded``, the form exact at any decay. Their body is
+straight-line code, one chunk of two heads, so the only loop is the grid
+and its length is a grid step.
 
 A bundle issues in a cycle unless it waits, so a loop's length is the least
 its iteration can take; PR 31's probes read 0.70-0.78 ns a bundle on the
@@ -78,7 +80,8 @@ def kda_train(args):
 
     def train(*a):
         return jax.value_and_grad(
-            lambda *a: kda_attention(*a, impl="flash").astype(
+            lambda *a: kda_attention(
+                *a, impl="flash", unbounded=args.unbounded).astype(
                 jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))(*a)
 
     return train, (qk, qk, qk, g, beta)
@@ -129,6 +132,8 @@ def main():
                     help="b s h d_qk d_v (the flash kernels)")
     ap.add_argument("--kda", type=int, nargs=4, metavar="N",
                     help="b s h d: the linear-attention kernels instead")
+    ap.add_argument("--unbounded", action="store_true",
+                    help="--kda: the form exact at any decay")
     ap.add_argument("--mask", action="store_true",
                     help="the variant with a key mask")
     ap.add_argument("--blocks", default="512,512")

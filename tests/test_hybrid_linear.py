@@ -217,6 +217,12 @@ def test_kda_in_bfloat16_stays_near_the_recurrence():
     assert rel(got.astype(jnp.float32), want) < 2e-2
 
 
+def solve(a, rhs):
+    """The chunk's solve as the forward kernel runs it: the inverse by
+    doubling, then ``u`` from it."""
+    return la._unit_lower_solve(a, la._unit_lower_inverse(a), rhs)
+
+
 def solve_inputs(c, d=128):
     ks = jax.random.split(jax.random.PRNGKey(c), 3)
     a = 0.3 * jax.random.normal(ks[0], (c, c))
@@ -240,7 +246,7 @@ def test_the_chunks_solve_matches_solve_triangular(c):
     with jax.default_matmul_precision("highest"):
         want, pull = jax.vjp(plain, a, rhs)
         want_a, want_rhs = pull(ct)
-    got, pull = jax.vjp(la._unit_lower_solve, a, rhs)
+    got, pull = jax.vjp(solve, a, rhs)
     got_a, got_rhs = pull(ct)
     assert rel(got, want) < 2e-6
     assert rel(got_rhs, want_rhs) < 2e-6
@@ -252,7 +258,7 @@ def test_the_solves_gradient_toward_a_is_strictly_lower(c):
     """Only ``a``'s strict lower triangle is free: the pull-back leaves the
     diagonal and everything above it at exactly zero, whatever comes in."""
     a, rhs, ct = solve_inputs(c)
-    got_a = jax.vjp(la._unit_lower_solve, a, rhs)[1](ct)[0]
+    got_a = jax.vjp(solve, a, rhs)[1](ct)[0]
     assert float(jnp.abs(jnp.triu(got_a)).max()) == 0.0
     assert float(jnp.abs(got_a)[jnp.tril_indices(c, -1)].min()) > 0.0
 
@@ -274,20 +280,132 @@ def full_precision_products(jaxpr):
 def test_the_chunk_step_makes_seven_full_precision_products():
     """At C = 64 in bfloat16 the solve is the step's only full-precision
     work: six doublings and ``u`` forward; in the backward kernel's body
-    (the step rebuilt and pulled back) those seven and the pull-back's
-    two. A full-precision product is six MXU passes and a link of the
-    step's dependent chain: one more has to show here."""
+    (the step pulled back from the inverse the forward kept) ``u`` and the
+    pull-back's two, no doubling. A full-precision product is six MXU
+    passes and a link of the step's dependent chain: one more has to show
+    here."""
     c, d = 64, 128
     x = jnp.zeros((c, d), jnp.bfloat16)
     gc = jnp.zeros((c, d), jnp.float32)
     st = jnp.zeros((d, d), jnp.float32)
+    cc = jnp.zeros((c, c), jnp.float32)
     fwd = jax.make_jaxpr(functools.partial(la._chunk, dtype=jnp.bfloat16))(
         x, x, x, x, gc, st)
     assert full_precision_products(fwd.jaxpr) == 7
     bwd = jax.make_jaxpr(
         functools.partial(la._chunk_grads, dtype=jnp.bfloat16))(
-            x, x, x, x, gc, st, x, st)
-    assert full_precision_products(bwd.jaxpr) == 9
+            x, x, x, x, gc, st, cc, cc.astype(jnp.bfloat16), x, st)
+    assert full_precision_products(bwd.jaxpr) == 3
+
+
+# ------------------- the backward pass reads what the forward pass kept ---
+
+def chunk_operands(s, lo, dtype, masked, h=2, d=128, seed=0):
+    """``_kda_chunks``' operands as ``kda_attention`` makes them (q, k,
+    beta k, beta v in ``dtype``; the running log-decay within each chunk,
+    float32) from log-decays over ``(lo, 0)``; ``masked``: the last third
+    of the row neither writes nor decays, as a mask makes it. -> (operands,
+    the chunk, a cotangent of the output)."""
+    q, k, v, g, beta = kda_inputs(s, lo, 0.0, h=h, dk=d, dv=d, seed=seed)
+    if masked:
+        keep = (jnp.arange(s) < 2 * s // 3).astype(jnp.float32)[None, :,
+                                                                  None]
+        beta, g = beta * keep, g * keep[..., None]
+    chunk = la.chunk_size(s)
+    gc = jnp.cumsum(g.reshape(1, s // chunk, chunk, h, d), 2).reshape(
+        g.shape)
+    beta = beta[..., None]
+    operands = tuple(a.astype(dtype) for a in (q, k, k * beta, v * beta))
+    do = jax.random.normal(jax.random.PRNGKey(seed + 1), v.shape)
+    return operands + (gc,), chunk, do.astype(dtype)
+
+
+def rebuilt_backward(q, k, kb, vb, gc, do, chunk, unbounded):
+    """``_kda_chunks``' backward pass as it was before the forward pass
+    kept anything: every chunk's whole step made again (``A``, ``P``, the
+    inverse's doublings) and pulled back, chunks last to first from the
+    entering states."""
+    dtype = q.dtype
+    states = la._scan_fwd(q, k, kb, vb, gc, chunk, unbounded)[1]
+
+    def one(q, k, kb, vb, gc, st, do, dst):
+        args = tuple(a.astype(jnp.float32) for a in (q, k, kb, vb, gc, st))
+        _, pull = jax.vjp(lambda *a: la._chunk(*a, dtype, unbounded)[:2],
+                          *args)
+        return pull((do.astype(jnp.float32), dst))
+
+    step = jax.vmap(jax.vmap(one))
+
+    def body(dst, xs):
+        *grads, dst = step(*xs, dst)
+        return dst, tuple(grads)
+
+    xs = tuple(la._by_chunk(a, chunk) for a in (q, k, kb, vb, gc, do))
+    grads = jax.lax.scan(body, jnp.zeros_like(states[0]),
+                         xs[:5] + (states, xs[5]), reverse=True)[1]
+    return tuple(la._from_chunks(g).astype(a.dtype)
+                 for g, a in zip(grads, (q, k, kb, vb, gc)))
+
+
+def check_the_kept_backward(s, lo, dtype, masked, unbounded):
+    """``_kda_chunks``' five gradients: the ``dense`` form's equal to the
+    rebuilt backward pass's bit for bit (the same operations on the same
+    values, made once where they were made twice), the Pallas kernels'
+    (interpreted) within float32 rounding of the ``dense`` form's, as they
+    were before."""
+    operands, chunk, do = chunk_operands(s, lo, dtype, masked)
+    want = jax.jit(rebuilt_backward, static_argnums=(6, 7))(
+        *operands, do, chunk, unbounded)
+    got = {}
+    for impl in ("dense", "flash"):
+        pull = jax.jit(lambda *a: jax.vjp(  # noqa: B023
+            lambda *x: la._kda_chunks(*x, chunk, impl, unbounded),
+            *a[:5])[1](a[5]))
+        got[impl] = pull(*operands, do)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))  # noqa: E731
+    for name, a, b, c in zip("q k kb vb gc".split(), got["dense"], want,
+                             got["flash"]):
+        assert a.dtype == b.dtype and np.array_equal(f32(a), f32(b)), name
+        assert held_to(c, a, want) < 1e-6, name
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("s,masked", [
+    (128, False),       # two chunks
+    (192, True),        # three chunks, the last third masked
+    (48, False),        # a short row: one chunk of three sub-chunks
+])
+def test_the_backward_reads_what_the_forward_kept_bit_for_bit(s, masked,
+                                                              dtype):
+    """The bounded gate's form (log-decays over (-5, 0)): the backward
+    pass that reads the chunks' inverse and ``P`` from the forward pass
+    against the one that made them again."""
+    check_the_kept_backward(s, -5.0, dtype, masked, unbounded=False)
+
+
+def test_the_forward_keeps_each_chunks_inverse_and_scores():
+    """What the forward pass keeps is the chunk's own ``(I + A)^-1`` in
+    float32 and ``P`` in the compute dtype, in both forms (the kernels'
+    blocks put a step's two heads side by side)."""
+    operands, chunk, _ = chunk_operands(128, -5.0, jnp.bfloat16, False)
+    _, kept = la._kda_chunks_fwd(*operands, chunk, "dense", False)
+    inv, p = kept[6:]
+    assert inv.shape == p.shape == (2, 1, 2, chunk, chunk)
+    assert inv.dtype == jnp.float32 and p.dtype == jnp.bfloat16
+    q, k, kb, vb, gc = (la._by_chunk(a, chunk)[1, 0, 1] for a in operands)
+    a_mat, p_mat = la._chunk_mats(*(a.astype(jnp.float32)
+                                    for a in (q, k, kb, gc)),
+                                  jnp.bfloat16, False)
+    assert np.array_equal(np.asarray(inv[1, 0, 1]),
+                          np.asarray(la._unit_lower_inverse(a_mat)))
+    assert np.array_equal(np.asarray(p[1, 0, 1]),
+                          np.asarray(p_mat.astype(jnp.bfloat16)))
+    _, flat = la._kda_chunks_fwd(*operands, chunk, "flash", False)
+    for mine, theirs in zip(flat[6:], kept[6:]):
+        assert mine.shape == (1, 1, 2, chunk, 2 * chunk)
+        by_head = jnp.stack([mine[0, 0, :, :, :chunk],
+                             mine[0, 0, :, :, chunk:]], 1)
+        assert held_to(by_head, theirs[:, 0], [theirs]) < 1e-6
 
 
 def test_a_masked_key_neither_writes_nor_decays():
@@ -440,7 +558,9 @@ def test_a_kda_layers_train_step_keeps_no_intermediate_of_the_module(capsys):
     (``_kda_pre``'s residuals), the kernels' five operands, the kernels'
     output (``_kda_post``'s) and the float32 copy of the layer's result
     that the output product's adapter reads. The module kept 23 at PR 36:
-    the convolutions' and SiLUs' outputs, float32 copies, both norms'."""
+    the convolutions' and SiLUs' outputs, float32 copies, both norms'.
+    Beside them the kernels keep two ``[n, b, h, C, C]`` matrices a chunk
+    (its solve's inverse and ``P``), which the backward pass reads."""
     cfg = small_cfg(layers=1)
     base, lora = weights(cfg)
     tok = tokens(cfg)
@@ -449,11 +569,15 @@ def test_a_kda_layers_train_step_keeps_no_intermediate_of_the_module(capsys):
     batch = {"x": tok[:, :-1], "y": tok[:, 1:], "mask": jnp.ones((2,))}
     from jax.ad_checkpoint import print_saved_residuals
     print_saved_residuals(lambda p: spec.loss(p, batch, None)[0], lora)
-    wide = [line for line in capsys.readouterr().out.splitlines()
-            if line.split(" ", 1)[0] in ("f32[2,32,64]", "f32[2,32,4,16]")]
+    lines = capsys.readouterr().out.splitlines()
+    shaped = lambda *shapes: [  # noqa: E731
+        line for line in lines if line.split(" ", 1)[0] in shapes]
+    wide = shaped("f32[2,32,64]", "f32[2,32,4,16]")
     by_site = lambda name: sum(name in line for line in wide)  # noqa: E731
     assert len(wide) == 11, "\n".join(wide)
     assert by_site("(_add_lora)") == 4 and by_site("kda_layer") == 6
+    # one chunk of 32 positions, 2 rows, 4 heads
+    assert len(shaped("f32[1,2,4,32,32]")) == 2, "\n".join(lines)
     # and the passes' own residuals are their arguments, nothing made
     args, mask, _ = layer_inputs(64, False)
     ys, beta_logits, gate_logits, conv, a_log, dt_bias, o_scale = args
@@ -618,8 +742,13 @@ def test_round_counters_reach_the_registry_from_the_round_program():
     # the layer's element-wise work ran through the fused passes; a caller
     # that hands the kernels their operands itself reads 0
     assert REGISTRY.gauge("fed_kda_fused").value() == 1.0
+    # the backward pass reads each chunk's inverse and P from the forward:
+    # a row of 32 at 4 heads, [32, 32] twice in float32
+    assert REGISTRY.gauge("fed_kda_bwd_kept").value() == 1.0
+    assert REGISTRY.gauge("fed_kda_kept_bytes").value() == 4 * 32 * 32 * 8
     la.kda_attention(*kda_inputs(64, -1.0, 0.0))
     assert REGISTRY.gauge("fed_kda_fused").value() == 0.0
+    assert REGISTRY.gauge("fed_kda_kept_bytes").value() == 2 * 64 * 64 * 8
     assert la.chunk_size(4096) == 64 and la.chunk_size(40) == 48
 
 

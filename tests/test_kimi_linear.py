@@ -26,6 +26,7 @@ from fedml_tpu.llm.federated import LLMBundle, llm_config_from_hf
 from fedml_tpu.llm.lora import lora_init
 from fedml_tpu.llm.model import CausalLM, LatentAttention
 from fedml_tpu.llm.trainer import CausalLMTrainer
+from tests.test_hybrid_linear import check_the_kept_backward
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONFIG = os.path.join(REPO, "benchmarks", "configs", "kimi_linear_ep8_l9.json")
@@ -133,6 +134,21 @@ def test_the_exact_chunk_step_matches_the_recurrence_at_steep_decays(
     assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
     for name, a, b in zip("q k v g beta".split(), got_g, want_g):
         assert held_to(a, b, want_g) < 5e-5, name
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("s,masked", [
+    (128, False),       # two chunks
+    (192, True),        # three chunks, the last third masked
+    (48, False),        # a short row: one chunk of three sub-chunks
+])
+def test_the_exact_backward_reads_what_the_forward_kept_bit_for_bit(
+        s, masked, dtype):
+    """The form exact at any decay (log-decays over (-30, 0)): the backward
+    pass that reads the chunks' inverse and ``P`` from the forward pass,
+    where it made them again before (``_within``'s products of scaled
+    copies among them), against the one that made them again."""
+    check_the_kept_backward(s, -30.0, dtype, masked, unbounded=True)
 
 
 @pytest.mark.parametrize("impl", ["dense", "flash"])

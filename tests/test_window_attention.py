@@ -655,8 +655,9 @@ def test_the_window_counter_reaches_the_registry_from_the_round_program():
 # with an expert layer as PR 35 left them (its backward pass works from
 # the forward's gate and up products: ``llm/moe.py``), the Mistral pair
 # unmoved by it; the MiMo pair as the parent of PR 37 (commit d4c1675)
-# lowers it, and the Ling pair as PR 37 left it (its KDA layers run their
-# element-wise work in the fused passes of ``llm/linear_attention.py``,
+# lowers it, and the Ling pair with its KDA layers' element-wise work in
+# the fused passes of ``llm/linear_attention.py`` (and the backward pass
+# reading each chunk's inverse and scores that the forward pass kept,
 # which no other model has); the Nemotron pair as PR 39 left it, which is
 # the text its parent (commit 882ce0d) lowers: this step takes the
 # ``dense`` path, and only ``flash`` runs the fused passes of
@@ -672,9 +673,9 @@ _ACCEPTED = {
     ("axk1_lora_silo2_seq4096", "bfloat16"):
         "1a7fdcdbcaf7a273b37515bbb23e732915e1e125d84456e6dd45ea225b155c63",
     ("ling3flash_lora_silo2_seq4096", "float32"):
-        "b05b4c0eed48799c1ed4f64e8ea6cf546ed24bd2a8348b3b5ec268d421fe7c68",
+        "6e40313ca5180ff3b167445e682fc3c46fc58479c43fd3c3945f7016fcc8dfb6",
     ("ling3flash_lora_silo2_seq4096", "bfloat16"):
-        "b83aa77a710580ac38d5ac57c4d2c42427fc094cd300056c008300d8441f413f",
+        "57055d8d5ec04bc27c0e30d469392487a16d7a3d9b32c50b8352490d9fb68be2",
     ("mimo_v2_flash_lora_silo2_seq4096", "float32"):
         "1fb34b7547f28910dd41fde348fd94d9c3ff187d7eb14c3596279504ac9107a9",
     ("mimo_v2_flash_lora_silo2_seq4096", "bfloat16"):
