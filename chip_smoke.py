@@ -694,11 +694,12 @@ def _ssm_passes(sz, gap):
 
 def phase_window(sz):
     """The window kernels with a sink (Pallas on a chip, interpreted
-    elsewhere) at the window cell's shape, grouped heads repeated before
-    them as the model does, forward and the four gradients against the
-    dense path (one key-value head's query heads at a time: the dense
+    elsewhere) at the window cell's shape, taking the 8 key-value heads as
+    the model hands them (a grid step is one of them and its 8 query
+    heads), forward and the four gradients against the dense path on
+    repeated heads (one key-value head's query heads at a time: the dense
     scores of all 64 would not fit), on bfloat16 operands; beside them the
-    time of the causal kernels at the same shape."""
+    time of the causal kernels at the same shape, on repeated heads."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -716,12 +717,15 @@ def phase_window(sz):
     ct = jax.random.normal(ks[4], (b, s, h, d_v), jnp.float32)
 
     def attend(impl, **kw):
+        grouped = impl == "flash" and kw
+
         def fn(q, k, v, sink, ct):
             def loss(q, k, v, sink):
-                out = causal_attention(
-                    q, jnp.repeat(k, q.shape[2] // k.shape[2], axis=2),
-                    jnp.repeat(v, q.shape[2] // v.shape[2], axis=2),
-                    impl=impl, sink=sink if kw else None, **kw)
+                if not grouped:
+                    k, v = (jnp.repeat(a, q.shape[2] // a.shape[2], axis=2)
+                            for a in (k, v))
+                out = causal_attention(q, k, v, impl=impl,
+                                       sink=sink if kw else None, **kw)
                 return jnp.sum(out.astype(jnp.float32) * ct), out
             (_, out), g = jax.value_and_grad(loss, argnums=(0, 1, 2, 3),
                                              has_aux=True)(q, k, v, sink)

@@ -589,13 +589,15 @@ def record_flash_plan(interior_share: float, key_mask: bool) -> None:
                    "else 0").set(1.0 if key_mask else 0.0)
 
 
-def record_flash_window(window: int, block_share: float, sink: bool) -> None:
+def record_flash_window(window: int, block_share: float, sink: bool,
+                        heads_per_step: int = 1) -> None:
     """The block plan of the flash call just traced that has a sliding
     window or a sink (host side, once a trace;
     ``llm/attention.py::flash_causal_attention``): the window (0: none),
     the score blocks a head its plan computes over those the causal plan
-    computes at the same length and blocks, and whether the softmax has a
-    sink column."""
+    computes at the same length and blocks, whether the softmax has a
+    sink column, and the query heads a grid step of its kernels covers
+    (a window call's: a key-value head's group)."""
     if not _cfg["enabled"]:
         return
     REGISTRY.gauge("fed_flash_window",
@@ -608,6 +610,9 @@ def record_flash_window(window: int, block_share: float, sink: bool) -> None:
     REGISTRY.gauge("fed_flash_sink",
                    "1 if that call's softmax has a learned sink column, "
                    "else 0").set(1.0 if sink else 0.0)
+    REGISTRY.gauge("fed_flash_window_heads_per_step",
+                   "query heads a grid step of that call's kernels covers"
+                   ).set(float(heads_per_step))
 
 
 def record_kda_plan(chunk: int, fused: bool, unbounded: bool = False,

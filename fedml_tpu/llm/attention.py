@@ -25,7 +25,8 @@ counterparts here are first-class:
   A sliding ``window`` (a query sees its last ``window`` keys, itself
   among them) gives the plan a first live block as well as a last one, so
   the kernels compute the band's blocks alone, under names of their own
-  (``flash_win_*``); a learned ``sink`` (one logit a query head that takes
+  (``flash_win_*``), a grid step one key-value head and its group of query
+  heads; a learned ``sink`` (one logit a query head that takes
   softmax mass and carries no value) is where the forward's running
   maximum and sum start, so the stored logsumexp carries it and the
   backward kernels need nothing new.
@@ -234,6 +235,21 @@ class FlashBlockPlan(NamedTuple):
         j_edge = _least(j_last, _most((w + j * bk) // bq, j_full))
         return j0, j_full, j_edge, j_last
 
+    def band_rows(self) -> int:
+        """The query rows the widest kv block's band spans, in whole q
+        blocks: what dK/dV of a window call holds of a query head."""
+        widest = max(w[3] - w[0] for w in map(self.k_major_window,
+                                              range(self.n_k)))
+        return widest * self.block_q
+
+    def band_start(self, j, rows: int):
+        """The first of the ``rows`` query rows dK/dV holds for kv block
+        ``j``: its first live q block's, moved back where the band would
+        run past the last query."""
+        # a multiple of block_q that Mosaic can see: the product last
+        return _least(self.n_q - rows // self.block_q,
+                      (j * self.block_k) // self.block_q) * self.block_q
+
     def counts(self, k_major: bool = False):
         """(interior, diagonal, skipped) blocks a head, q-major or k-major
         (the same blocks, counted along the other axis). With a window the
@@ -407,32 +423,42 @@ def _flash_fwd_kernel(*refs, plan: FlashBlockPlan, scale: float,
                          mask_ref[:, keys] > 0 if key_mask else None,
                          compare, plan.window)
             s_blk = jnp.where(live, s_blk, NEG_INF)
-        m = m_ref[:]
-        m_new = jnp.maximum(m, jnp.max(s_blk, -1, keepdims=True))
-        p = jnp.exp2(s_blk - _across(m_new, block_k))
-        if key_mask:
-            # gate on `live`, not just the exp: for a row with NO live
-            # keys m_new stays NEG_INF, so exp2(s_blk - m_new) = 1 at every
-            # masked position and O would silently become an unmasked
-            # average of V; gating keeps l = 0 so the row's output is
-            # exactly zero and its stored LSE ≈ NEG_INF (flagging the row)
-            # instead. Without a key mask every row has seen key 0 by its
-            # first block, so m_new is finite and the exp2 of a masked
-            # score is already 0. (Under a window a row may meet blocks
-            # before its first live key; what they leave in l and acc is
-            # multiplied by alpha = exp2(NEG_INF - finite) = 0 at that key,
-            # which every row reaches: its own.)
-            p = jnp.where(live, p, 0.0)
-        alpha = jnp.exp2(m - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * _across(alpha, d_v) + jnp.dot(
-            p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32)
+        _softmax_step(s_blk, live if key_mask else None, v_blk, m_ref, l_ref,
+                      acc_ref, slice(None))
 
     _fold_q_major(pl, plan, fold, key_mask)
     l = jnp.maximum(l_ref[:], 1e-30)
     o_ref[:] = (acc_ref[:] * _across(1.0 / l, d_v)).astype(o_ref.dtype)
     lse_ref[:] = ((m_ref[:] + jnp.log2(l)) * _LN2)[:, :1]
+
+
+def _softmax_step(s_blk, live, v_blk, m_ref, l_ref, acc_ref, at):
+    """A block's scores (``[rows, block_k]``, masked ones NEG_INF) into the
+    running softmax that ``m_ref[at]``, ``l_ref[at]``, ``acc_ref[at]``
+    hold. ``live``: the block's live scores where the call has a key mask,
+    else None."""
+    block_k, d_v = s_blk.shape[1], v_blk.shape[1]
+    m = m_ref[at]
+    m_new = jnp.maximum(m, jnp.max(s_blk, -1, keepdims=True))
+    p = jnp.exp2(s_blk - _across(m_new, block_k))
+    if live is not None:
+        # gate on `live`, not just the exp: for a row with NO live
+        # keys m_new stays NEG_INF, so exp2(s_blk - m_new) = 1 at every
+        # masked position and O would silently become an unmasked
+        # average of V; gating keeps l = 0 so the row's output is
+        # exactly zero and its stored LSE ≈ NEG_INF (flagging the row)
+        # instead. Without a key mask every row has seen key 0 by its
+        # first block, so m_new is finite and the exp2 of a masked
+        # score is already 0. (Under a window a row may meet blocks
+        # before its first live key; what they leave in l and acc is
+        # multiplied by alpha = exp2(NEG_INF - finite) = 0 at that key,
+        # which every row reaches: its own.)
+        p = jnp.where(live, p, 0.0)
+    alpha = jnp.exp2(m - m_new)
+    m_ref[at] = m_new
+    l_ref[at] = l_ref[at] * alpha + jnp.sum(p, -1, keepdims=True)
+    acc_ref[at] = acc_ref[at] * _across(alpha, d_v) + jnp.dot(
+        p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32)
 
 
 def _flash_dq_kernel(*refs, plan: FlashBlockPlan, scale: float,
@@ -466,14 +492,20 @@ def _flash_dq_kernel(*refs, plan: FlashBlockPlan, scale: float,
                          mask_ref[:, keys] > 0 if key_mask else None,
                          compare, plan.window)
             p = jnp.where(live, p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v_blk, _NT, preferred_element_type=jnp.float32)
-        ds = p * (dp - dd)
-        acc_ref[:] += jnp.dot(ds.astype(k_blk.dtype), k_blk,
-                              preferred_element_type=jnp.float32)
+        _dq_step(p, do, dd, k_blk, v_blk, acc_ref, slice(None))
 
     _fold_q_major(pl, plan, fold, key_mask)
     dq_ref[:] = (acc_ref[:] * scale).astype(dq_ref.dtype)
+
+
+def _dq_step(p, do, dd, k_blk, v_blk, acc_ref, at):
+    """A block's probabilities ``p`` (masked ones 0) into the dQ that
+    ``acc_ref[at]`` holds: dS = P ∘ (dO·Vᵀ − D), dQ += dS·K."""
+    dp = jax.lax.dot_general(
+        do, v_blk, _NT, preferred_element_type=jnp.float32)
+    ds = p * (dp - dd)
+    acc_ref[at] += jnp.dot(ds.astype(k_blk.dtype), k_blk,
+                           preferred_element_type=jnp.float32)
 
 
 def _flash_dkv_kernel(*refs, plan: FlashBlockPlan, scale: float,
@@ -515,18 +547,173 @@ def _flash_dkv_kernel(*refs, plan: FlashBlockPlan, scale: float,
                 jnp.int32, (1, block_q), 1)
             p_t = jnp.where(_live(q_pos, k_pos, None, compare, plan.window),
                             p_t, 0.0)
-        dv_acc[:] += jnp.dot(p_t.astype(do_blk.dtype), do_blk,
-                             preferred_element_type=jnp.float32)
-        dp_t = jax.lax.dot_general(
-            v, do_blk, _NT, preferred_element_type=jnp.float32)
-        ds_t = p_t * (dp_t - dd_ref[:, rows])
-        dk_acc[:] += jnp.dot(ds_t.astype(q_blk.dtype), q_blk,
-                             preferred_element_type=jnp.float32)
+        _dkv_step(p_t, q_blk, do_blk, v, dd_ref, (slice(None), rows), dk_acc,
+                  dv_acc)
 
     _fold_k_major(pl, plan, fold, key_mask)
+    _dkv_out(dk_ref, dv_ref, dk_acc, dv_acc)
+
+
+def _dkv_step(p_t, q_blk, do_blk, v, dd_ref, dd_at, dk_acc, dv_acc):
+    """A q block's transposed probabilities ``p_t`` (masked ones 0) into
+    dK and dV: dV += Pᵀ·dO, dSᵀ = Pᵀ ∘ (V·dOᵀ − D), dK += dSᵀ·Q; D is
+    ``dd_ref[dd_at]``, a ``[1, block_q]`` row."""
+    dv_acc[:] += jnp.dot(p_t.astype(do_blk.dtype), do_blk,
+                         preferred_element_type=jnp.float32)
+    dp_t = jax.lax.dot_general(
+        v, do_blk, _NT, preferred_element_type=jnp.float32)
+    ds_t = p_t * (dp_t - dd_ref[dd_at])
+    dk_acc[:] += jnp.dot(ds_t.astype(q_blk.dtype), q_blk,
+                         preferred_element_type=jnp.float32)
+
+
+def _dkv_out(dk_ref, dv_ref, dk_acc, dv_acc):
     # q carried scale * log2(e); dK wants the scale alone
     dk_ref[:] = (dk_acc[:] * _LN2).astype(dk_ref.dtype)
     dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _window_fwd_kernel(*refs, plan: FlashBlockPlan, scale: float,
+                       key_mask: bool, sink: bool):
+    """One (batch*key-value head, q-block) program: the online softmax of
+    the group's g query heads against the key-value row they share. A kv
+    block is loaded once and every head's step on it runs in the same loop
+    body, so one head's products overlap another's softmax.
+
+    q_ref: [g, block_q, d_qk]; k_ref, v_ref, mask_ref as
+    :func:`_flash_fwd_kernel` has them; sink_ref: [g, 1, 128]; o_ref: [g,
+    block_q, d_v]; lse_ref: [g, block_q, 1]; scratch: qs_ref [g, block_q,
+    d_qk] (the scaled queries), float32 acc_ref [g, block_q, d_v], m_ref
+    and l_ref [g, block_q, 128].
+    """
+    import jax.experimental.pallas as pl
+
+    q_ref, k_ref, v_ref, mask_ref, *rest = _split_refs(refs, 3, key_mask)
+    sink_ref = rest.pop(0) if sink else None
+    o_ref, lse_ref, qs_ref, acc_ref, m_ref, l_ref = rest
+    heads, block_q, block_k = q_ref.shape[0], plan.block_q, plan.block_k
+    d_v = acc_ref.shape[-1]
+    q_pos = pl.program_id(1) * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, 1), 0)
+    for a in range(heads):
+        qs_ref[a] = _scaled(q_ref[a], scale * _LOG2E)
+        acc_ref[a] = jnp.zeros(acc_ref.shape[1:], jnp.float32)
+        if sink:    # a key every row has already seen: score sink, value 0
+            m_ref[a] = jnp.broadcast_to(sink_ref[a] * _LOG2E,
+                                        m_ref.shape[1:])
+            l_ref[a] = jnp.ones(l_ref.shape[1:], jnp.float32)
+        else:
+            m_ref[a] = jnp.full(m_ref.shape[1:], NEG_INF, jnp.float32)
+            l_ref[a] = jnp.zeros(l_ref.shape[1:], jnp.float32)
+
+    def fold(start, compare):
+        """Keys ``[start, start + block_k)`` into every head's softmax."""
+        keys = pl.ds(start, block_k)
+        k_blk, v_blk = k_ref[keys, :], v_ref[keys, :]
+        if compare:
+            k_pos = start + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_k), 1)
+            live = _live(q_pos, k_pos,
+                         mask_ref[:, keys] > 0 if key_mask else None,
+                         compare, plan.window)
+        for a in range(heads):
+            s_blk = jax.lax.dot_general(
+                qs_ref[a], k_blk, _NT, preferred_element_type=jnp.float32)
+            if compare:
+                s_blk = jnp.where(live, s_blk, NEG_INF)
+            _softmax_step(s_blk, live if key_mask else None, v_blk, m_ref,
+                          l_ref, acc_ref, a)
+
+    _fold_q_major(pl, plan, fold, key_mask)
+    for a in range(heads):
+        l = jnp.maximum(l_ref[a], 1e-30)
+        o_ref[a] = (acc_ref[a] * _across(1.0 / l, d_v)).astype(o_ref.dtype)
+        lse_ref[a] = ((m_ref[a] + jnp.log2(l)) * _LN2)[:, :1]
+
+
+def _window_dq_kernel(*refs, plan: FlashBlockPlan, scale: float,
+                      key_mask: bool):
+    """dQ for one q block of the group's query heads, every head's step on
+    a kv block in the same loop body: q_ref, do_ref, dq_ref [g, block_q,
+    .], lse_ref, dd_ref [g, block_q, 1]; scratch qs_ref [g, block_q, d_qk]
+    (the scaled queries) and float32 acc_ref [g, block_q, d_qk]."""
+    import jax.experimental.pallas as pl
+
+    (q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, dd_ref, dq_ref, qs_ref,
+     acc_ref) = _split_refs(refs, 3, key_mask)
+    heads, block_q, block_k = q_ref.shape[0], plan.block_q, plan.block_k
+    q_pos = pl.program_id(1) * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, 1), 0)
+    for a in range(heads):
+        qs_ref[a] = _scaled(q_ref[a], scale * _LOG2E)
+        acc_ref[a] = jnp.zeros(acc_ref.shape[1:], jnp.float32)
+
+    def fold(start, compare):
+        """Keys ``[start, start + block_k)`` into every head's dQ."""
+        keys = pl.ds(start, block_k)
+        k_blk, v_blk = k_ref[keys, :], v_ref[keys, :]
+        if compare:
+            k_pos = start + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_k), 1)
+            live = _live(q_pos, k_pos,
+                         mask_ref[:, keys] > 0 if key_mask else None,
+                         compare, plan.window)
+        for a in range(heads):
+            s_blk = jax.lax.dot_general(
+                qs_ref[a], k_blk, _NT, preferred_element_type=jnp.float32)
+            p = jnp.exp2(s_blk - lse_ref[a] * _LOG2E)
+            if compare:
+                p = jnp.where(live, p, 0.0)
+            _dq_step(p, do_ref[a], dd_ref[a], k_blk, v_blk, acc_ref, a)
+
+    _fold_q_major(pl, plan, fold, key_mask)
+    for a in range(heads):
+        dq_ref[a] = (acc_ref[a] * scale).astype(dq_ref.dtype)
+
+
+def _window_dkv_kernel(*refs, plan: FlashBlockPlan, scale: float,
+                       key_mask: bool):
+    """dK/dV for one kv block of one key-value head: the group's query
+    heads' steps on a q block in the same loop body, into the same float32
+    scratch, so the block's gradient is the group's sum. q_ref, do_ref:
+    [1, g, rows, .] and lse_ref, dd_ref: [1, g, 1, rows], the band's
+    queries from row ``plan.band_start`` on; the rest as
+    :func:`_flash_dkv_kernel` has them."""
+    import jax.experimental.pallas as pl
+
+    (k_ref, v_ref, q_ref, mask_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref,
+     dk_acc, dv_acc) = _split_refs(refs, 3, key_mask)
+    heads, block_q, block_k = q_ref.shape[1], plan.block_q, plan.block_k
+    k_pos = pl.program_id(1) * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_k, 1), 0)
+    if key_mask:                          # this kv block's, via BlockSpec
+        k_pos = jnp.where(mask_ref[:] > 0, k_pos, _NEVER)
+    k, v = k_ref[:], v_ref[:]
+    dk_acc[:] = jnp.zeros_like(dk_acc)
+    dv_acc[:] = jnp.zeros_like(dv_acc)
+    first = plan.band_start(pl.program_id(1), q_ref.shape[2])
+
+    def fold(start, compare):
+        """Queries ``[start, start + block_q)`` of every head into dK and
+        dV."""
+        rows = pl.ds(pl.multiple_of(start - first, block_q), block_q)
+        if compare:
+            q_pos = start + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_q), 1)
+            live = _live(q_pos, k_pos, None, compare, plan.window)
+        for a in range(heads):
+            q_blk = _scaled(q_ref[0, a, rows, :], scale * _LOG2E)
+            do_blk = do_ref[0, a, rows, :]
+            s_t = jax.lax.dot_general(
+                k, q_blk, _NT, preferred_element_type=jnp.float32)
+            p_t = jnp.exp2(s_t - lse_ref[0, a, :, rows] * _LOG2E)
+            if compare:
+                p_t = jnp.where(live, p_t, 0.0)
+            _dkv_step(p_t, q_blk, do_blk, v, dd_ref, (0, a, slice(None), rows),
+                      dk_acc, dv_acc)
+
+    _fold_k_major(pl, plan, fold, key_mask)
+    _dkv_out(dk_ref, dv_ref, dk_acc, dv_acc)
 
 
 def _heads_first(a):
@@ -547,19 +734,14 @@ def _row_mask(pl, mask, h: int):
             [pl.BlockSpec((None, 1, s), lambda i, j: (i // h, 0, 0))])
 
 
-def _kernel_names(plan: FlashBlockPlan):
-    return FLASH_KERNEL_NAMES if plan.window is None else WINDOW_KERNEL_NAMES
-
-
-def _flash_fwd(q, k, v, mask, sink, block_q: int, block_k: int, scale: float,
-               window: Optional[int]):
+def _flash_fwd(q, k, v, mask, sink, block_q: int, block_k: int, scale: float):
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
     b, s, h, d = q.shape
     dv = v.shape[-1]
     key_mask = mask is not None
-    plan = flash_block_plan(s, block_q, block_k, window)
+    plan = flash_block_plan(s, block_q, block_k)
     qf, kf, vf = _heads_first(q), _heads_first(k), _heads_first(v)
     mask_rows, mask_specs = _row_mask(pl, mask, h)
     sink_rows, sink_specs = [], []
@@ -590,13 +772,13 @@ def _flash_fwd(q, k, v, mask, sink, block_q: int, block_k: int, scale: float,
                         pltpu.VMEM((block_q, 128), jnp.float32)],
         interpret=kernels.interpret(),
         compiler_params=kernels.tpu_compiler_params(),
-        name=_kernel_names(plan)[0],
+        name=FLASH_KERNEL_NAMES[0],
     )(qf, kf, vf, *mask_rows, *sink_rows)
     return out.reshape(b, h, s, dv).transpose(0, 2, 1, 3), lse
 
 
 def _flash_bwd(q, k, v, mask, sink, o, lse, g, block_q: int, block_k: int,
-               scale: float, window: Optional[int]):
+               scale: float):
     """-> (dq, dk, dv, dsink); ``dsink`` None without a sink."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
@@ -604,7 +786,7 @@ def _flash_bwd(q, k, v, mask, sink, o, lse, g, block_q: int, block_k: int,
     b, s, h, d = q.shape
     dv = v.shape[-1]
     key_mask = mask is not None
-    plan = flash_block_plan(s, block_q, block_k, window)
+    plan = flash_block_plan(s, block_q, block_k)
     qf, kf, vf = _heads_first(q), _heads_first(k), _heads_first(v)
     gf, of = _heads_first(g), _heads_first(o)
     # D_i = Σ_d dO_i ∘ O_i — one cheap elementwise pass in XLA
@@ -630,7 +812,7 @@ def _flash_bwd(q, k, v, mask, sink, o, lse, g, block_q: int, block_k: int,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=kernels.interpret(),
         compiler_params=kernels.tpu_compiler_params(),
-        name=_kernel_names(plan)[1],
+        name=FLASH_KERNEL_NAMES[1],
     )(qf, kf, vf, *mask_rows, gf, lse, dd)
 
     dk, dv_ = pl.pallas_call(
@@ -659,38 +841,188 @@ def _flash_bwd(q, k, v, mask, sink, o, lse, g, block_q: int, block_k: int,
                         pltpu.VMEM((block_k, dv), jnp.float32)],
         interpret=kernels.interpret(),
         compiler_params=kernels.tpu_compiler_params(),
-        name=_kernel_names(plan)[2],
+        name=FLASH_KERNEL_NAMES[2],
     )(kf, vf, qf, *([mask] if key_mask else []), gf,
       lse.reshape(b * h, 1, s), dd.reshape(b * h, 1, s))
 
-    dsink = None
-    if sink is not None:
-        # the sink's column of dS = P (dP - D) with dP = 0 (it has no
-        # value): -exp(sink - lse) D, summed over rows and the batch
-        p_sink = jnp.exp(sink.astype(jnp.float32)[None, :, None, None]
-                         - lse.reshape(b, h, s, 1))
-        dsink = -jnp.sum(p_sink * dd.reshape(b, h, s, 1),
-                         axis=(0, 2, 3)).astype(sink.dtype)
+    dsink = _sink_grad(sink, lse, dd, b, h, s)
     unflat = lambda a: a.reshape(b, h, s, -1).transpose(0, 2, 1, 3)
     return unflat(dq), unflat(dk), unflat(dv_), dsink
+
+
+def _sink_grad(sink, lse, dd, b: int, h: int, s: int):
+    """The sink's column of dS = P (dP - D) with dP = 0 (it has no value):
+    -exp(sink - lse) D, summed over rows and the batch; None without a
+    sink. ``lse``, ``dd``: any layout that reshapes to ``[b, h, s]``."""
+    if sink is None:
+        return None
+    p_sink = jnp.exp(sink.astype(jnp.float32)[None, :, None, None]
+                     - lse.reshape(b, h, s, 1))
+    return -jnp.sum(p_sink * dd.reshape(b, h, s, 1),
+                    axis=(0, 2, 3)).astype(sink.dtype)
+
+
+def _grouped(a, h_kv: int):
+    """[b, s, h, d] -> [b*h_kv, h/h_kv, s, d]: query head ``i`` is member
+    ``i % g`` of key-value head ``i // g``'s group (``jnp.repeat``'s
+    order)."""
+    b, s, h, d = a.shape
+    return _heads_first(a).reshape(b * h_kv, h // h_kv, s, d)
+
+
+def _window_fwd(q, k, v, mask, sink, block_q: int, block_k: int,
+                scale: float, window: int):
+    """The window kernels' forward: a grid step is one key-value head's
+    group of query heads (k, v at ``h_kv`` heads) and one q block."""
+    import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+
+    b, s, h, d = q.shape
+    h_kv, dv = k.shape[2], v.shape[-1]
+    g = h // h_kv
+    plan = flash_block_plan(s, block_q, block_k, window)
+    mask_rows, mask_specs = _row_mask(pl, mask, h_kv)
+    sink_rows, sink_specs = [], []
+    if sink is not None:    # a head's logit, lane-replicated as m and l are
+        sink_rows = [jnp.broadcast_to(sink.astype(jnp.float32).reshape(
+            h_kv, g, 1, 1), (h_kv, g, 1, 128))]
+        sink_specs = [pl.BlockSpec((None, g, 1, 128),
+                                   lambda i, j: (i % h_kv, 0, 0, 0))]
+    group = lambda width: pl.BlockSpec(  # noqa: E731
+        (None, g, block_q, width), lambda i, j: (i, 0, j, 0))
+    out, lse = pl.pallas_call(
+        functools.partial(_window_fwd_kernel, plan=plan, scale=scale,
+                          key_mask=mask is not None, sink=sink is not None),
+        grid=(b * h_kv, plan.n_q),
+        in_specs=[
+            group(d),
+            pl.BlockSpec((None, s, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, s, dv), lambda i, j: (i, 0, 0)),
+            *mask_specs, *sink_specs,
+        ],
+        out_specs=[group(dv), group(1)],
+        out_shape=[
+            jax.ShapeDtypeStruct((b * h_kv, g, s, dv), q.dtype),
+            jax.ShapeDtypeStruct((b * h_kv, g, s, 1), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((g, block_q, d), q.dtype),
+                        pltpu.VMEM((g, block_q, dv), jnp.float32),
+                        pltpu.VMEM((g, block_q, 128), jnp.float32),
+                        pltpu.VMEM((g, block_q, 128), jnp.float32)],
+        interpret=kernels.interpret(),
+        compiler_params=kernels.tpu_compiler_params(),
+        name=WINDOW_KERNEL_NAMES[0],
+    )(_grouped(q, h_kv), _heads_first(k), _heads_first(v), *mask_rows,
+      *sink_rows)
+    return out.reshape(b, h, s, dv).transpose(0, 2, 1, 3), lse
+
+
+def _window_bwd(q, k, v, mask, sink, o, lse, g_, block_q: int, block_k: int,
+                scale: float, window: int):
+    """-> (dq, dk, dv, dsink), dk and dv at ``h_kv`` heads: the group's
+    sum is made in dK/dV's scratch."""
+    import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+
+    b, s, h, d = q.shape
+    h_kv, dv = k.shape[2], v.shape[-1]
+    g = h // h_kv
+    key_mask = mask is not None
+    plan = flash_block_plan(s, block_q, block_k, window)
+    qf, kf, vf = _grouped(q, h_kv), _heads_first(k), _heads_first(v)
+    gf = _grouped(g_, h_kv)
+    # D_i = Σ_d dO_i ∘ O_i — one cheap elementwise pass in XLA
+    dd = jnp.sum(gf.astype(jnp.float32)
+                 * _grouped(o, h_kv).astype(jnp.float32),
+                 axis=-1, keepdims=True)
+    mask_rows, mask_specs = _row_mask(pl, mask, h_kv)
+    group = lambda width: pl.BlockSpec(  # noqa: E731
+        (None, g, block_q, width), lambda i, j: (i, 0, j, 0))
+
+    dq = pl.pallas_call(
+        functools.partial(_window_dq_kernel, plan=plan, scale=scale,
+                          key_mask=key_mask),
+        grid=(b * h_kv, plan.n_q),
+        in_specs=[
+            group(d),
+            pl.BlockSpec((None, s, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, s, dv), lambda i, j: (i, 0, 0)),
+            *mask_specs, group(dv), group(1), group(1),
+        ],
+        out_specs=group(d),
+        out_shape=jax.ShapeDtypeStruct((b * h_kv, g, s, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((g, block_q, d), q.dtype),
+                        pltpu.VMEM((g, block_q, d), jnp.float32)],
+        interpret=kernels.interpret(),
+        compiler_params=kernels.tpu_compiler_params(),
+        name=WINDOW_KERNEL_NAMES[1],
+    )(qf, kf, vf, *mask_rows, gf, lse, dd)
+
+    # the queries that see a kv block: its band's rows, in element offsets
+    rows = plan.band_rows()
+    band = lambda width: pl.BlockSpec(  # noqa: E731
+        tuple(map(pl.Element, (1, g, rows, width))),
+        lambda i, j: (i, 0, plan.band_start(j, rows), 0))
+    stat = pl.BlockSpec(tuple(map(pl.Element, (1, g, 1, rows))),
+                        lambda i, j: (i, 0, 0, plan.band_start(j, rows)))
+    dk, dv_ = pl.pallas_call(
+        functools.partial(_window_dkv_kernel, plan=plan, scale=scale,
+                          key_mask=key_mask),
+        grid=(b * h_kv, plan.n_k),
+        in_specs=[
+            pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, block_k, dv), lambda i, j: (i, j, 0)),
+            band(d),
+            *([pl.BlockSpec((None, block_k, 1),
+                            lambda i, j: (i // h_kv, j, 0))]
+              if key_mask else []),
+            band(dv), stat, stat,
+        ],
+        out_specs=[
+            pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, block_k, dv), lambda i, j: (i, j, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b * h_kv, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h_kv, s, dv), q.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
+        interpret=kernels.interpret(),
+        compiler_params=kernels.tpu_compiler_params(),
+        name=WINDOW_KERNEL_NAMES[2],
+    )(kf, vf, qf, *([mask] if key_mask else []), gf,
+      lse.reshape(b * h_kv, g, 1, s), dd.reshape(b * h_kv, g, 1, s))
+
+    dsink = _sink_grad(sink, lse, dd, b, h, s)
+    unflat = lambda a, n: a.reshape(b, n, s, -1).transpose(0, 2, 1, 3)  # noqa: E731
+    return unflat(dq, h), unflat(dk, h_kv), unflat(dv_, h_kv), dsink
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def _flash(q, k, v, mask, sink, block_q: int, block_k: int, scale: float,
            window: Optional[int]):
-    return _flash_fwd(q, k, v, mask, sink, block_q, block_k, scale, window)[0]
+    return _flash_fwd_rule(q, k, v, mask, sink, block_q, block_k, scale,
+                           window)[0]
 
 
 def _flash_fwd_rule(q, k, v, mask, sink, block_q, block_k, scale, window):
-    out, lse = _flash_fwd(q, k, v, mask, sink, block_q, block_k, scale,
-                          window)
+    if window is None:
+        out, lse = _flash_fwd(q, k, v, mask, sink, block_q, block_k, scale)
+    else:
+        out, lse = _window_fwd(q, k, v, mask, sink, block_q, block_k, scale,
+                               window)
     return out, (q, k, v, mask, sink, out, lse)
 
 
 def _flash_bwd_rule(block_q, block_k, scale, window, res, g):
     q, k, v, mask, sink, out, lse = res
-    dq, dk, dv, dsink = _flash_bwd(q, k, v, mask, sink, out, lse, g, block_q,
-                                   block_k, scale, window)
+    if window is None:
+        dq, dk, dv, dsink = _flash_bwd(q, k, v, mask, sink, out, lse, g,
+                                       block_q, block_k, scale)
+    else:
+        dq, dk, dv, dsink = _window_bwd(q, k, v, mask, sink, out, lse, g,
+                                        block_q, block_k, scale, window)
     return (dq, dk, dv, None if mask is None else jnp.zeros_like(mask),
             dsink)
 
@@ -705,7 +1037,9 @@ def flash_causal_attention(q, k, v, block_q: Optional[int] = None,
                            window: Optional[int] = None,
                            sink: Optional[jnp.ndarray] = None):
     """Pallas flash attention, fused fwd+bwd (see module docstring).
-    q/k: [b, s, h, d_qk], v: [b, s, h, d_v] -> [b, s, h, d_v]; one set of
+    q: [b, s, h, d_qk], k: [b, s, h_kv, d_qk], v: [b, s, h_kv, d_v] ->
+    [b, s, h, d_v], ``h_kv`` dividing ``h`` (query head ``i`` reads
+    key-value head ``i // (h / h_kv)``, ``jnp.repeat``'s order); one set of
     kernels serves ``d_qk == d_v`` (128) and latent attention's 192/128.
     ``attn_mask``: optional [b, s] key-padding mask (1 = real); ``scale``
     multiplies the scores (default ``d_qk ** -0.5``). ``window``: query i
@@ -721,10 +1055,20 @@ def flash_causal_attention(q, k, v, block_q: Optional[int] = None,
     4.82 / 6.68), ``[8,1024,32,128]`` 0.93 / 1.10 / 1.38 (1.41 / 1.10 /
     2.04), ``[1,8192,2,128]`` 0.25 / 0.33 / 0.42 (0.38 / 0.33 / 0.53); dQ
     and dK/dV then sit at the MXU's own time for the blocks they compute.
-    A window call takes ``WINDOW_BLOCKS`` (256x256): at ``[1,4096,64,
-    192/128]``, window 128 with a sink (PR 34) 1.64 / 1.62 / 1.98 against
-    1.60 / 2.12 / 2.69 at 512x512 and 2.15 / 2.18 / 2.96 at 128x128; about
-    1 us of every grid step is its set-up, so smaller blocks stop paying.
+    The causal kernels get grouped keys and values repeated here; a window
+    call's kernels take them as they are, a grid step one key-value head
+    with its group of query heads: a kv block is loaded once and every
+    head's step on it runs in one loop body, and dK/dV sums the group in
+    its scratch and reads only its band's queries. Blocks ``WINDOW_BLOCKS``
+    (128x128):
+    at ``[1,4096,64,192/128]``, 8 key-value heads, window 128 with a sink
+    (PR 42) 1.01 / 0.95 / 0.88 against 0.98 / 1.06 / 1.11 at 256x256 and
+    1.32 / 1.80 / 2.07 at 512x512; one query head a step on keys repeated
+    to 64 heads took 1.71 / 1.61 / 1.98 at 256x256 and 2.24 / 2.17 / 2.96
+    at 128x128, and the group a step with its heads one after the other
+    1.37 / 1.35 / 1.53: what a block costs beyond its products is its
+    head's chain of product, softmax and product, which the group's heads
+    now overlap, not the grid step.
 
     Sequences are padded up to a multiple of 128 so every Pallas block is
     lane/sublane-aligned on real TPU hardware (a non-power-of-two s like
@@ -734,12 +1078,18 @@ def flash_causal_attention(q, k, v, block_q: Optional[int] = None,
     Padded keys are masked out; padded query rows are sliced away.
     """
     b, s, h, d = q.shape
+    h_kv = k.shape[2]
+    if h % h_kv or v.shape[2] != h_kv:
+        raise ValueError(f"{h} query heads on {h_kv} key and {v.shape[2]} "
+                         "value heads: one head count must divide the other")
     scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
     s_pad = -(-s // 128) * 128
     if window is not None:
         if window < 1:
             raise ValueError(f"window {window}: a query sees itself at least")
         window = int(window) if window < s else None
+    if window is None and h_kv != h:    # the causal kernels take every head
+        k, v = (jnp.repeat(a, h // h_kv, axis=2) for a in (k, v))
     want_q, want_k = WINDOW_BLOCKS if window is not None else (512, 512)
     block_q, block_k = block_q or want_q, block_k or want_k
     # the key mask is a static property of the call: without one, and with
@@ -763,14 +1113,16 @@ def flash_causal_attention(q, k, v, block_q: Optional[int] = None,
         computed = sum(flash_block_plan(s_pad, block_q, block_k,
                                         window).counts()[:2])
         obs_metrics.record_flash_window(
-            window or 0, computed / (interior + diagonal), sink is not None)
+            window or 0, computed / (interior + diagonal), sink is not None,
+            h // h_kv if window else 1)
     out = _flash(q, k, v, mask, sink, block_q, block_k, scale, window)
     return out[:, :s] if s_pad != s else out
 
 
 # a window call's blocks where the caller names none (PERF.md section 6,
-# PR 34: the probe on the chip at [1,4096,64,192/128], window 128)
-WINDOW_BLOCKS = (256, 256)
+# PR 42: the probe on the chip at [1,4096,64,192/128], 8 key-value heads,
+# window 128)
+WINDOW_BLOCKS = (128, 128)
 
 
 def _fit_block(s_pad: int, want: int) -> int:

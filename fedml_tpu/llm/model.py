@@ -387,7 +387,9 @@ class Attention(nn.Module):
             out = cached_attention(q, k_all, v_all, positions)
         else:
             new_kv = None
-            if kv_heads != cfg.num_heads:
+            # the window kernels read a key-value head once for its group
+            grouped = window and cfg.attention_impl == "flash"
+            if kv_heads != cfg.num_heads and not grouped:
                 rep = cfg.num_heads // kv_heads
                 k = jnp.repeat(k, rep, axis=2)
                 v = jnp.repeat(v, rep, axis=2)

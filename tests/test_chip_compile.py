@@ -81,9 +81,10 @@ def test_flash_unequal_head_sizes_compile_for_v5e(v5e):
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_window_kernels_with_a_sink_compile_for_v5e(v5e, dtype):
     """The window cell's shape: 64 query heads of 192 / 128 on 8 key-value
-    heads repeated before the kernels, 4,096 positions, a window of 128 and
-    a sink: three kernels under their own names, and the sink's gradient
-    beside them, from what the backward already keeps."""
+    heads that the kernels take as they are (a grid step holds one of them
+    and its group of 8), 4,096 positions, a window of 128 and a sink: three
+    kernels under their own names, and the sink's gradient beside them,
+    from what the backward already keeps."""
     q = jax.ShapeDtypeStruct((1, 4096, 64, 192), dtype)
     k = jax.ShapeDtypeStruct((1, 4096, 8, 192), dtype)
     v = jax.ShapeDtypeStruct((1, 4096, 8, 128), dtype)
@@ -92,8 +93,7 @@ def test_window_kernels_with_a_sink_compile_for_v5e(v5e, dtype):
     def train(q, k, v, sink):
         return jax.value_and_grad(
             lambda q, k, v, sink: flash_causal_attention(
-                q, jnp.repeat(k, 8, axis=2), jnp.repeat(v, 8, axis=2),
-                window=128, sink=sink).astype(jnp.float32).sum(),
+                q, k, v, window=128, sink=sink).astype(jnp.float32).sum(),
             argnums=(0, 1, 2, 3))(q, k, v, sink)
 
     text = _compile(train, v5e, q, k, v, sink).as_text()

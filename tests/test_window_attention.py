@@ -323,6 +323,14 @@ def test_the_window_plan_covers_the_band_and_no_more(s, block_q, block_k):
             by_k[j_full:j_edge, kj] = 1
             by_k[j_edge:j_last, kj] = 2
         assert np.array_equal(by_k, kind), window
+        # dK/dV holds band_rows() queries from band_start(j): a kv block's
+        # every live q block lies inside them
+        rows = plan.band_rows()
+        for kj in range(plan.n_k):
+            j0, _, _, j_last = plan.k_major_window(kj)
+            first = plan.band_start(kj, rows)
+            assert first % block_q == 0 and 0 <= first <= j0 * block_q
+            assert j_last * block_q <= first + rows <= s, (window, kj)
         want = (int((kind == 1).sum()), int((kind > 1).sum()),
                 int((kind == 0).sum()))
         assert plan.counts() == plan.counts(k_major=True) == want
@@ -338,6 +346,13 @@ def test_the_window_plan_at_the_cells_shape_by_hand():
     # a window of several blocks leaves interior blocks between its edges
     assert flash_block_plan(4096, 128, 128, 1024).counts()[0] > 0
     assert flash_block_plan(4096, 512, 512).counts() == (28, 8, 28)
+    # dK/dV's band: a kv block's own q block and the next one, and nine
+    # blocks of 128 for a window of 1,024
+    assert flash_block_plan(4096, 128, 128, 128).band_rows() == 256
+    assert flash_block_plan(4096, 256, 256, 128).band_rows() == 512
+    assert flash_block_plan(4096, 128, 128, 1024).band_rows() == 1152
+    assert flash_block_plan(4096, 128, 128, 128).band_start(31, 256) == 3840
+    assert flash_block_plan(4096, 128, 128, 128).band_start(7, 256) == 896
 
 
 # ------------------------------------------------------ the flash kernels ---
@@ -350,26 +365,52 @@ def _qkv(b, s, h, d_qk=24, d_v=16, seed=0):
             jax.random.normal(jax.random.fold_in(key, 3), (b, s, h, d_v)))
 
 
+def _grouped_qkv(b, s, heads, kv_heads, seed=0):
+    """q and the cotangent at ``heads``, k and v at ``kv_heads``."""
+    q, k, v, c = _qkv(b, s, heads, seed=seed)
+    return q, k[:, :, :kv_heads], v[:, :, :kv_heads], c
+
+
+def _repeated(fn, heads):
+    """``fn`` on k and v repeated to ``heads`` (the dense path's operands:
+    its gradients flow back to the key-value heads through the repeat)."""
+    def call(q, k, v, *args, **kw):
+        k, v = (jnp.repeat(a, heads // a.shape[2], axis=2) for a in (k, v))
+        return fn(q, k, v, *args, **kw)
+    return call
+
+
 @pytest.mark.pallas
 @pytest.mark.parametrize("with_sink", [False, True])
-@pytest.mark.parametrize("s,window,block_q,block_k", [
-    (256, 16, 128, 128), (256, 128, 128, 128), (256, 200, 128, 128),
-    (512, 16, 256, 128), (512, 128, 128, 256), (512, 200, 256, 256),
-    (1024, 16, 512, 512), (1024, 128, 256, 256), (1024, 200, 128, 512)])
-def test_window_kernels_match_dense(s, window, block_q, block_k, with_sink):
+@pytest.mark.parametrize("s,window,block_q,block_k,group", [
+    *(pytest.param(*case, 1, id="-".join(map(str, case))) for case in (
+        (256, 16, 128, 128), (256, 128, 128, 128), (256, 200, 128, 128),
+        (512, 16, 256, 128), (512, 128, 128, 256), (512, 200, 256, 256),
+        (1024, 16, 512, 512), (1024, 128, 256, 256), (1024, 200, 128, 512))),
+    pytest.param(256, 16, 128, 128, 2, id="256-16-128-128-group2"),
+    pytest.param(512, 300, 128, 128, 2, id="512-300-128-128-group2"),
+    pytest.param(512, 40, 128, 128, 8, id="512-40-128-128-group8"),
+    pytest.param(768, 300, 128, 128, 8, id="768-300-128-128-group8")])
+def test_window_kernels_match_dense(s, window, block_q, block_k, group,
+                                    with_sink):
     """Interpreted: forward, the three gradients and the sink's against the
     dense path, for windows narrower than, equal to and wider than a block,
-    blocks the window does and does not cross."""
-    q, k, v, c = _qkv(1, s, 2)
-    sink = jnp.asarray([0.7, 3.0]) if with_sink else None
+    blocks the window does and does not cross; 2 query heads on 2, 1 or 2
+    on 4 query heads and 1 on 8 key-value heads (``group``: query heads a
+    key-value head), the dense path on keys and values repeated, their
+    gradients at the key-value heads."""
+    kv_heads = 2 if group < 8 else 1
+    q, k, v, c = _grouped_qkv(1, s, kv_heads * group, kv_heads)
+    sink = (jnp.linspace(0.7, 3.0, kv_heads * group) if with_sink
+            else None)
 
     def loss(fn):
         return lambda q, k, v, sink: jnp.sum(fn(q, k, v, sink) * c)
 
     flash = lambda q, k, v, sink: flash_causal_attention(  # noqa: E731
         q, k, v, block_q=block_q, block_k=block_k, window=window, sink=sink)
-    dense = lambda q, k, v, sink: dense_causal_attention(  # noqa: E731
-        q, k, v, window=window, sink=sink)
+    dense = _repeated(lambda q, k, v, sink: dense_causal_attention(
+        q, k, v, window=window, sink=sink), kv_heads * group)
     assert rel(flash(q, k, v, sink), dense(q, k, v, sink)) < 1e-5
     args = (0, 1, 2, 3) if with_sink else (0, 1, 2)
     got = jax.grad(loss(flash), argnums=args)(q, k, v, sink)
@@ -378,22 +419,61 @@ def test_window_kernels_match_dense(s, window, block_q, block_k, with_sink):
         assert g.shape == w.shape and rel(g, w) < 1e-5
 
 
-@pytest.mark.pallas
-def test_window_kernels_with_a_key_mask_and_padding_match_dense():
-    """A length off the 128 grid and a key mask: every block then makes
-    both compares and gates on them."""
-    q, k, v, c = _qkv(2, 300, 2)
+def _key_mask_and_padding_case(heads, kv_heads):
+    q, k, v, c = _grouped_qkv(2, 300, heads, kv_heads)
     mask = (jax.random.uniform(jax.random.PRNGKey(9), (2, 300)) > 0.2
             ).astype(jnp.float32).at[:, 0].set(1.0)
-    sink = jnp.asarray([0.2, 1.5])
+    sink = jnp.linspace(0.2, 1.5, heads)
     f = lambda fn: lambda q, k, v, sink: jnp.sum(fn(  # noqa: E731
         q, k, v, attn_mask=mask, window=40, sink=sink) * c)
     got = jax.grad(f(flash_causal_attention), argnums=(0, 1, 2, 3))(
         q, k, v, sink)
-    want = jax.grad(f(dense_causal_attention), argnums=(0, 1, 2, 3))(
-        q, k, v, sink)
+    want = jax.grad(f(_repeated(dense_causal_attention, heads)),
+                    argnums=(0, 1, 2, 3))(q, k, v, sink)
     for g, w in zip(got, want):
-        assert rel(g, w) < 1e-5
+        assert g.shape == w.shape and rel(g, w) < 1e-5
+
+
+@pytest.mark.pallas
+def test_window_kernels_with_a_key_mask_and_padding_match_dense():
+    """A length off the 128 grid and a key mask: every block then makes
+    both compares and gates on them."""
+    _key_mask_and_padding_case(2, 2)
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (8, 1)])
+def test_grouped_window_kernels_with_a_key_mask_and_padding_match_dense(
+        heads, kv_heads):
+    """The same with a key-value head's group of query heads in a grid
+    step: the key mask's row is the batch row's, whatever the head."""
+    _key_mask_and_padding_case(heads, kv_heads)
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("group", [2, 8])
+def test_a_group_is_its_query_heads_on_repeated_keys(group):
+    """Keys and values at ``h / group`` heads give, bit for bit, the output,
+    dQ and the sink's gradient that the same call on them repeated to ``h``
+    heads gives (one query head a grid step, the kernels' form before the
+    group), and dK / dV that are that call's gradients summed over the
+    group: a query head's arithmetic is its own, whatever its step holds."""
+    q, k, v, c = _grouped_qkv(1, 512, 2 * group, 2)
+    sink = jnp.linspace(-0.5, 2.5, 2 * group)
+    run = lambda fn: jax.value_and_grad(  # noqa: E731
+        lambda q, k, v, sink: jnp.sum(fn(
+            q, k, v, block_q=128, block_k=128, window=100, sink=sink) * c),
+        argnums=(0, 1, 2, 3))(q, k, v, sink)
+    (lg, (dq_g, dk_g, dv_g, ds_g)) = run(flash_causal_attention)
+    (lr, (dq_r, dk_r, dv_r, ds_r)) = run(_repeated(flash_causal_attention,
+                                                   2 * group))
+    out = lambda q, k, v: flash_causal_attention(  # noqa: E731
+        q, k, v, block_q=128, block_k=128, window=100, sink=sink)
+    assert jnp.array_equal(out(q, k, v), _repeated(out, 2 * group)(q, k, v))
+    for a, b in ((lg, lr), (dq_g, dq_r), (ds_g, ds_r)):
+        assert jnp.array_equal(a, b)
+    for a, b in ((dk_g, dk_r), (dv_g, dv_r)):
+        assert a.shape == (1, 512, 2, a.shape[-1]) and rel(a, b) < 1e-6
 
 
 @pytest.mark.pallas
@@ -435,6 +515,7 @@ def test_a_window_call_sets_its_gauges_and_a_plain_call_does_not():
     q, k, v, _ = _qkv(1, 512, 2)
     jax.eval_shape(lambda q, k, v: flash_causal_attention(q, k, v), q, k, v)
     assert REGISTRY.gauge("fed_flash_window").value() is None
+    assert REGISTRY.gauge("fed_flash_window_heads_per_step").value() is None
     assert REGISTRY.gauge("fed_flash_interior_block_share").value() is not None
     jax.eval_shape(lambda q, k, v: flash_causal_attention(
         q, k, v, block_q=128, block_k=128, window=16,
@@ -445,6 +526,14 @@ def test_a_window_call_sets_its_gauges_and_a_plain_call_does_not():
     # the causal plan's 10 blocks
     assert abs(REGISTRY.gauge("fed_flash_window_block_share").value()
                - 0.7) < 1e-12
+    # a grid step takes a key-value head and its group: one query head here,
+    # the cell's 8 where 64 query heads read 8 key-value heads
+    assert REGISTRY.gauge("fed_flash_window_heads_per_step").value() == 1.0
+    q64 = jax.ShapeDtypeStruct((1, 512, 64, 24), jnp.float32)
+    k8 = jax.ShapeDtypeStruct((1, 512, 8, 24), jnp.float32)
+    jax.eval_shape(lambda q, k: flash_causal_attention(
+        q, k, k, window=128, sink=jnp.zeros((64,))), q64, k8)
+    assert REGISTRY.gauge("fed_flash_window_heads_per_step").value() == 8.0
     REGISTRY.reset()
 
 
