@@ -615,61 +615,6 @@ def record_flash_window(window: int, block_share: float, sink: bool,
                    ).set(float(heads_per_step))
 
 
-def record_kda_plan(chunk: int, fused: bool, unbounded: bool = False,
-                    kept_bytes: int = 0) -> None:
-    """The chunk size of the linear-attention call just traced (host side,
-    once a trace; ``llm/linear_attention.py::chunk_size``), whether the
-    layer's element-wise work around the kernels ran through the fused
-    passes (``kda_layer``) or the caller made the kernels' operands itself
-    (``kda_attention``), the gate's form: 0 the bounded gate, 1 the
-    unbounded softplus one (whose chunk step makes each sub-chunk's block
-    against itself element by element), and the bytes of every chunk's
-    ``[C, C]`` matrices the forward pass keeps for the backward one (which
-    reads them where it made them again before)."""
-    if not _cfg["enabled"]:
-        return
-    REGISTRY.gauge("fed_kda_bwd_kept",
-                   "1 if the backward pass of the last traced KDA call "
-                   "reads the chunks' inverse and scores the forward pass "
-                   "kept, else 0").set(1.0 if kept_bytes else 0.0)
-    REGISTRY.gauge("fed_kda_kept_bytes",
-                   "bytes of the chunks' [C, C] matrices the forward pass "
-                   "of the last traced KDA call keeps a layer-step"
-                   ).set(float(kept_bytes))
-    REGISTRY.gauge("fed_kda_gate",
-                   "the gate of the last traced KDA call: 0 bounded "
-                   "(log-decays >= -5), 1 unbounded softplus"
-                   ).set(1.0 if unbounded else 0.0)
-    REGISTRY.gauge("fed_kda_chunk",
-                   "positions a chunk of the last traced KDA call"
-                   ).set(float(chunk))
-    REGISTRY.gauge("fed_kda_fused",
-                   "1 if the last traced KDA call ran the element-wise "
-                   "work around its kernels in the fused passes, else 0"
-                   ).set(1.0 if fused else 0.0)
-
-
-def record_ssd_plan(chunk: int, heads_per_step: int, fused: bool) -> None:
-    """The plan of the state-space call just traced (host side, once a
-    trace; ``llm/state_space.py``): positions a chunk, the heads one grid
-    step of the kernels works through (they share a group's B and C), and
-    whether the layer's element-wise work around the kernels ran through
-    the fused passes (``ssm_layer``) or the caller made the kernels'
-    operands itself (``ssd_scan``)."""
-    if not _cfg["enabled"]:
-        return
-    REGISTRY.gauge("fed_ssm_fused",
-                   "1 if the last traced Mamba-2 call ran the element-wise "
-                   "work around its kernels in the fused passes, else 0"
-                   ).set(1.0 if fused else 0.0)
-    REGISTRY.gauge("fed_ssd_chunk",
-                   "positions a chunk of the last traced state-space call"
-                   ).set(float(chunk))
-    REGISTRY.gauge("fed_ssd_heads_per_step",
-                   "heads a grid step of the last traced state-space "
-                   "call's kernels").set(float(heads_per_step))
-
-
 def record_recompile(program: str) -> None:
     """Recompile forensics: a program compiled PAST its pinned
     expectation (the steady-state invariant is zero)."""

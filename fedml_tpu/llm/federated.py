@@ -25,8 +25,7 @@ import jax.numpy as jnp
 from .data import ByteTokenizer, build_llm_federated
 from .linear_attention import MIN_LOG_DECAY, SHORT_CONV_TAPS
 from .lora import lora_init
-from .model import CausalLM, LLMConfig, init_llm
-from .moe import STATS as MOE_STATS
+from .model import CausalLM, LLMConfig, init_llm, layer_stats
 from .trainer import CausalLMTrainer
 
 logger = logging.getLogger(__name__)
@@ -60,11 +59,161 @@ def llm_config_from_args(args) -> LLMConfig:
     )
 
 
+# key maps' parts that families share, by the DeepSeek-V3 names (the
+# Bailing and Kimi families name the experts ``num_experts`` /
+# ``num_shared_experts``; a caller that holds some of them states the
+# router's count under the first name, as ``fed_rounds_bailing.py`` does)
+_EXPERTS = {"n_routed_experts": ("n_routed_experts", "num_experts"),
+            "n_shared_experts": ("n_shared_experts", "num_shared_experts"),
+            **{k: k for k in ("num_experts_per_tok", "moe_intermediate_size",
+                              "routed_scaling_factor", "norm_topk_prob")}}
+_LATENT = {k: k for k in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                          "qk_rope_head_dim", "v_head_dim")}
+_DEFAULTS = {f.name: f.default for f in dataclasses.fields(LLMConfig)}
+
+
+def _read(config: dict, keys: dict) -> dict:
+    """A key map ``{field: published key, or keys in order}`` read in each
+    field's type: a flag is its key's truth (the default where the key is
+    missing), a size or scale the first key set to other than null or zero
+    (else the default)."""
+    out = {}
+    for field, names in keys.items():
+        names = (names,) if isinstance(names, str) else names
+        default = _DEFAULTS[field]
+        if isinstance(default, bool):
+            out[field] = bool(config.get(names[0], default))
+        else:
+            got = [config[k] for k in names if config.get(k)]
+            out[field] = type(default)(got[0] if got else default)
+    return out
+
+
+def _noaux(config: dict, always: bool = False) -> dict:
+    """The group limit and the router's bias of routing by score + bias,
+    where ``topk_method`` is ``noaux_tc`` (or ``always``)."""
+    get = config.get
+    if not (always or get("topk_method") == "noaux_tc"):
+        return {}
+    return dict(n_group=int(get("n_group") or 0),
+                topk_group=int(get("topk_group") or 0),
+                router_bias=bool(get("moe_router_enable_expert_bias", True)))
+
+
+def _layers(config: dict, mixers, experts: int) -> tuple:
+    """``mixer+moe`` where there are experts after the leading dense layers
+    (``first_k_dense_replace``, or the zeros of a ``moe_layer_freq`` list
+    before its ones), ``mixer+mlp`` elsewhere."""
+    freq = config.get("moe_layer_freq", 1)
+    dense = int(config.get("first_k_dense_replace") or 0)
+    if experts and isinstance(freq, (list, tuple)):
+        dense = len(freq) - sum(freq)
+        if (len(freq) != len(mixers)
+                or list(freq) != [0] * dense + [1] * sum(freq)):
+            raise NotImplementedError(
+                "moe_layer_freq as a list is read as zeros (dense layers) "
+                "then ones (expert layers), one entry a layer")
+    elif experts and freq != 1:
+        raise NotImplementedError("moe_layer_freq != 1")
+    return tuple(m + ("+moe" if experts and i >= dense else "+mlp")
+                 for i, m in enumerate(mixers))
+
+
+def _short_conv(taps) -> None:
+    """Refuses a short convolution over other than 4 positions."""
+    if int(taps or SHORT_CONV_TAPS) != SHORT_CONV_TAPS:
+        raise NotImplementedError(
+            f"short_conv_kernel_size {taps}: the short convolution is built "
+            f"over {SHORT_CONV_TAPS} positions")
+
+
+def _head_sizes(config: dict, rotary: bool = True) -> dict:
+    """A stated ``head_dim`` (none where it is hidden / heads) and, with
+    ``rotary``, the whole pairs ``partial_rotary_factor`` turns a head."""
+    quotient = int(config["hidden_size"]) // int(config["num_attention_heads"])
+    head_size = int(config.get("head_dim") or 0)
+    head_size = 0 if head_size == quotient else head_size
+    factor, rotary_dim = config.get("partial_rotary_factor"), 0
+    if rotary and factor is not None:
+        rotary_dim = int((head_size or quotient) * float(factor))
+        if rotary_dim % 2 or rotary_dim <= 0:
+            raise NotImplementedError(
+                f"partial_rotary_factor {factor} of a head of "
+                f"{head_size or quotient} gives {rotary_dim} rotary dims: "
+                "rotary turns pairs")
+    return dict(head_size=head_size, rotary_dim=rotary_dim)
+
+
+def _deepseek_fields(config: dict, layers: int) -> dict:
+    """The Llama/Mistral keys and DeepSeek-V3's (``axk1``): latent attention
+    where ``kv_lora_rank`` is set, experts after leading dense layers.
+    ``sliding_window`` stays unread: full causal attention is the same model
+    up to that many positions."""
+    fields = _read(config, {**_EXPERTS, **_LATENT})
+    mixer = "latent" if fields["kv_lora_rank"] else "full"
+    return dict(fields, **_noaux(config),
+                **(_head_sizes(config) if mixer == "full" else {}),
+                layers=_layers(config, [mixer] * layers,
+                               fields["n_routed_experts"]))
+
+
+def _bailing_fields(config: dict, layers: int) -> dict:
+    """Bailing hybrid (Ling): Kimi delta attention with the bounded gate in
+    all but the last layer of each ``layer_group_size``, gated latent
+    attention in that one."""
+    get = config.get
+    if not (get("kda_safe_gate") and get("linear_silu", True)
+            and get("no_kda_lora", True)):
+        raise NotImplementedError(
+            "linear attention is built as Kimi delta attention with the "
+            "bounded gate (kda_safe_gate), SiLU after the short "
+            "convolution and a full-rank decay projection (no_kda_lora)")
+    fields = _read(config, {**_EXPERTS, **_LATENT,
+                            "linear_head_dim": "head_dim"})
+    lower = float(get("kda_lower_bound", _DEFAULTS["kda_lower_bound"]))
+    if not MIN_LOG_DECAY <= lower < 0:
+        raise NotImplementedError(
+            f"kda_lower_bound {lower}: the chunked delta rule is exact for "
+            f"log-decays in [{MIN_LOG_DECAY}, 0)")
+    _short_conv(get("short_conv_kernel_size"))
+    group = int(config["layer_group_size"])
+    softmax = "latent" if fields["kv_lora_rank"] else "full"
+    return dict(
+        fields, **_noaux(config), kda_lower_bound=lower,
+        attn_output_gate=get(
+            "gated_attention_proj_granularity_type") == "head_wise",
+        layers=_layers(config, ["linear" if (i + 1) % group else softmax
+                                for i in range(layers)],
+                       fields["n_routed_experts"]))
+
+
+def _mimo_v2_flash_fields(config: dict, layers: int) -> dict:
+    """MiMo-V2-Flash: window layers where ``hybrid_layer_pattern`` is 1,
+    of the ``sliding_window`` / ``swa_*`` / sink keys; stated head sizes,
+    partial rotary, a value scale; ``moe_layer_freq`` a list."""
+    pattern = config.get("hybrid_layer_pattern") or [0] * layers
+    if len(pattern) != layers:
+        raise NotImplementedError(
+            f"hybrid_layer_pattern has {len(pattern)} entries for "
+            f"{layers} layers")
+    fields = _read(config, {
+        **_EXPERTS, "v_head_dim": "v_head_dim",
+        "attn_value_scale": "attention_value_scale",
+        "sliding_window": "sliding_window",
+        "window_kv_heads": "swa_num_key_value_heads",
+        "window_rope_theta": "swa_rope_theta",
+        "window_sink": "add_swa_attention_sink_bias",
+        "full_sink": "add_full_attention_sink_bias"})
+    return dict(fields, **_noaux(config), **_head_sizes(config),
+                layers=_layers(config, ["window" if p else "full"
+                                        for p in pattern],
+                               fields["n_routed_experts"]))
+
+
 def _nemotron_h_fields(config: dict, layers: int) -> dict:
-    """The :class:`LLMConfig` fields only a ``nemotron_h`` configuration
-    sets (a ``hybrid_override_pattern`` of Mamba-2, expert and attention
-    layers, one mixer a layer; ``relu2`` experts in a latent; attention
-    without rotary), or its refusal."""
+    """``nemotron_h``: one mixer a layer by ``hybrid_override_pattern``,
+    ``M`` Mamba-2, ``E`` experts in a latent routed by score + bias, ``*``
+    attention without rotary."""
     get = config.get
     pattern = get("hybrid_override_pattern") or ""
     if len(pattern) != layers or set(pattern) - set("ME*"):
@@ -72,7 +221,7 @@ def _nemotron_h_fields(config: dict, layers: int) -> dict:
             f"hybrid_override_pattern {pattern!r}: one of M (Mamba-2), E "
             f"(experts), * (attention) for each of the {layers} layers; the "
             "dense '-' layer of smaller siblings is not built")
-    for key in ("use_bias", "mamba_proj_bias", "mlp_bias", "attention_bias"):
+    for key in ("use_bias", "mamba_proj_bias", "mlp_bias"):
         if get(key):
             raise NotImplementedError(f"{key}: the projections are built "
                                       "without biases")
@@ -82,40 +231,35 @@ def _nemotron_h_fields(config: dict, layers: int) -> dict:
     act = get("mlp_hidden_act", "relu2")
     if act not in ("relu2", "silu"):
         raise NotImplementedError(f"mlp_hidden_act {act!r}")
-    heads = int(config["mamba_num_heads"])
-    head_dim = int(config["mamba_head_dim"])
-    groups = int(get("n_groups") or 1)
+    fields = _read(config, {
+        **_EXPERTS, "ssm_heads": "mamba_num_heads",
+        "ssm_head_dim": "mamba_head_dim", "ssm_state_size": "ssm_state_size",
+        "ssm_groups": "n_groups", "ssm_conv_kernel": "conv_kernel",
+        "ssm_conv_bias": "use_conv_bias", "ssm_chunk": "chunk_size",
+        "moe_latent_size": "moe_latent_size",
+        "shared_expert_size": "moe_shared_expert_intermediate_size"})
+    heads, groups = fields["ssm_heads"], fields["ssm_groups"]
     if heads % groups:
         raise NotImplementedError(
             f"n_groups {groups} does not divide mamba_num_heads {heads}")
-    expand = int(get("expand") or 2)
-    if expand * int(config["hidden_size"]) != heads * head_dim:
+    expand, inner = int(get("expand") or 2), heads * fields["ssm_head_dim"]
+    if expand * int(config["hidden_size"]) != inner:
         raise NotImplementedError(
             f"expand {expand} x hidden_size {config['hidden_size']} is not "
-            f"mamba_num_heads {heads} x mamba_head_dim {head_dim}")
+            f"mamba_num_heads {heads} x mamba_head_dim {inner // heads}")
     return dict(
-        block_pattern=pattern, ssm_heads=heads, ssm_head_dim=head_dim,
-        ssm_state_size=int(config["ssm_state_size"]), ssm_groups=groups,
-        ssm_conv_kernel=int(get("conv_kernel") or 4),
-        ssm_conv_bias=bool(get("use_conv_bias", True)),
-        ssm_chunk=int(get("chunk_size") or 128),
+        fields, **_noaux(config, always=True),
+        **_head_sizes(config, rotary=False), use_rope=False,
         mlp_activation="relu2" if act == "relu2" else "swiglu",
-        moe_latent_size=int(get("moe_latent_size") or 0),
-        shared_expert_size=int(
-            get("moe_shared_expert_intermediate_size") or 0),
-        use_rope=False)
+        layers=tuple({"M": "ssm", "E": "moe", "*": "full"}[c]
+                     for c in pattern))
 
 
 def _kimi_linear_fields(config: dict, layers: int) -> dict:
-    """The :class:`LLMConfig` fields a ``kimi_linear`` configuration reads
-    its own way (Kimi delta attention by ``linear_attn_config``'s 1-based
-    ``kda_layers`` / ``full_attn_layers`` with its heads, head size and
-    short convolution, the unbounded softplus gate; latent attention
-    without positions where ``mla_use_nope``; sigmoid experts top-k by
-    score + bias, ``num_expert_group`` groups of which ``topk_group`` stay
-    under ``use_grouped_topk``), or its refusal. The top-level
-    ``head_dim`` (hidden / heads) is not the KDA head size and is not
-    read."""
+    """``kimi_linear``: Kimi delta attention under the unbounded softplus
+    gate in ``linear_attn_config``'s 1-based ``kda_layers`` (the top-level
+    ``head_dim`` is not its head size), latent attention without positions
+    where ``mla_use_nope`` in the others; experts by score + bias."""
     get = config.get
     linear = dict(get("linear_attn_config") or {})
     kda = list(linear.get("kda_layers") or [])
@@ -130,74 +274,66 @@ def _kimi_linear_fields(config: dict, layers: int) -> dict:
         raise NotImplementedError(
             f"linear_attn_config num_heads {heads}: the KDA layers are built "
             f"with the model's {config['num_attention_heads']} heads")
-    taps = int(linear.get("short_conv_kernel_size") or SHORT_CONV_TAPS)
-    if taps != SHORT_CONV_TAPS:
-        raise NotImplementedError(
-            f"short_conv_kernel_size {taps}: the short convolution is built "
-            f"over {SHORT_CONV_TAPS} positions")
+    _short_conv(linear.get("short_conv_kernel_size"))
     act = get("moe_router_activation_func", "sigmoid")
     if act != "sigmoid":
         raise NotImplementedError(f"moe_router_activation_func {act!r}")
-    grouped = bool(get("use_grouped_topk"))
+    fields = _read(config, {
+        **_EXPERTS, **_LATENT,
+        "num_experts_per_tok": "num_experts_per_token",
+        "norm_topk_prob": "moe_renormalize",
+        **({"n_group": "num_expert_group", "topk_group": "topk_group"}
+           if get("use_grouped_topk") else {})})
+    softmax = "latent" if fields["kv_lora_rank"] else "full"
     return dict(
-        linear_layers=tuple(i - 1 for i in kda), kda_gate="softplus",
+        fields, router_bias=True, kda_gate="softplus",
         linear_head_dim=int(linear["head_dim"]),
-        use_rope=not get("mla_use_nope", False), attn_output_gate=False,
-        num_experts_per_tok=int(get("num_experts_per_token") or 0),
-        norm_topk_prob=bool(get("moe_renormalize", True)),
-        n_group=int(get("num_expert_group") or 0) if grouped else 0,
-        topk_group=int(get("topk_group") or 0) if grouped else 0,
-        router_bias=True)
+        use_rope=not get("mla_use_nope", False),
+        layers=_layers(config, ["linear" if i + 1 in kda else softmax
+                                for i in range(layers)],
+                       fields["n_routed_experts"]))
+
+
+# a published ``model_type`` -> the key map of its family
+_FAMILIES = {"axk1": _deepseek_fields, "bailing_hybrid": _bailing_fields,
+             "mimo_v2_flash": _mimo_v2_flash_fields,
+             "nemotron_h": _nemotron_h_fields,
+             "kimi_linear": _kimi_linear_fields}
+
+
+def _family(config: dict):
+    """The key map of ``config``'s family: by its ``model_type`` where
+    that names one, else Bailing by ``layer_group_size``, MiMo by
+    ``hybrid_layer_pattern``, else the Llama/DeepSeek-V3 keys."""
+    return _FAMILIES.get(config.get("model_type")) or (
+        _bailing_fields if config.get("layer_group_size") else
+        _mimo_v2_flash_fields if config.get("hybrid_layer_pattern") is not None
+        else _deepseek_fields)
 
 
 def llm_config_from_hf(config: dict, *, max_seq_len: int,
                        dtype: str = "float32", attention_impl: str = "dense",
                        first_expert: int = 0,
                        experts_held: int = 0) -> LLMConfig:
-    """An :class:`LLMConfig` from a published ``config.json`` dict (the keys
-    of the Llama/Mistral family, of the DeepSeek-V3 family, which ``axk1``
-    shares: latent attention, sigmoid-routed experts with shared ones,
-    leading dense layers, YaRN; and of the Bailing hybrid family, which
-    names the experts ``num_experts`` / ``num_shared_experts`` /
-    ``score_function``, routes with ``noaux_tc`` and mixes Kimi delta
-    attention layers with gated latent ones by ``layer_group_size``; and of
-    ``mimo_v2_flash``: ``hybrid_layer_pattern`` (1 = a window layer) with
-    the window kind's ``sliding_window``, ``swa_num_key_value_heads``,
-    ``swa_rope_theta`` and the two ``add_*_attention_sink_bias`` flags,
-    ``head_dim`` / ``v_head_dim`` beside grouped-query heads,
-    ``partial_rotary_factor``, ``attention_value_scale``,
-    ``layernorm_epsilon``, ``moe_layer_freq`` as a list of zeros then ones;
-    and of ``nemotron_h``: ``hybrid_override_pattern`` (``M`` a Mamba-2
-    mixer with ``mamba_num_heads``, ``mamba_head_dim``, ``ssm_state_size``,
-    ``n_groups``, ``conv_kernel``, ``chunk_size``, ``use_conv_bias``; ``E``
-    experts of ``mlp_hidden_act`` ``relu2`` in a ``moe_latent_size`` latent
-    beside a shared one of ``moe_shared_expert_intermediate_size``, routed
-    by sigmoid scores with a score-correction bias; ``*`` grouped-query
-    attention without rotary), one mixer a layer, ``layer_norm_epsilon``;
-    and of ``kimi_linear``: :func:`_kimi_linear_fields`).
-    ``sliding_window`` is read with a ``hybrid_layer_pattern`` only: alone
-    it is left unread, as it always was (full causal attention is the same
-    model up to that many positions). ``first_expert`` / ``experts_held``
+    """An :class:`LLMConfig` from a published ``config.json`` dict: the
+    keys every family shares here, the rest (``layers`` among them) by its
+    family's key map (:func:`_family`). ``first_expert`` / ``experts_held``
     say which routed experts this expert-parallel rank holds (0 = all)."""
     get = config.get
-    scaling = get("rope_scaling")
-    experts = get("n_routed_experts") or get("num_experts")
-    scoring = get("scoring_func") or get("score_function") or "sigmoid"
+    if get("num_nextn_predict_layers"):
+        raise NotImplementedError(
+            "multi-token prediction layers are not built: leave them out "
+            "(num_nextn_predict_layers 0) where their loss weight is 0")
+    if get("attention_bias"):
+        raise NotImplementedError("attention_bias: the projections are "
+                                  "built without biases")
     method = get("topk_method", "none")
     if method not in ("none", "greedy", "noaux_tc"):
         raise NotImplementedError(f"topk_method {method!r}")
-    if experts and scoring != "sigmoid":
-        raise NotImplementedError(f"scoring_func {scoring!r}")
-    freq, leading_dense = get("moe_layer_freq", 1), None
-    if experts and isinstance(freq, (list, tuple)):
-        leading_dense = len(freq) - sum(freq)
-        if (len(freq) != int(config["num_hidden_layers"])
-                or list(freq) != [0] * leading_dense + [1] * sum(freq)):
-            raise NotImplementedError(
-                "moe_layer_freq as a list is read as zeros (dense layers) "
-                "then ones (expert layers), one entry a layer")
-    elif experts and freq != 1:
-        raise NotImplementedError("moe_layer_freq != 1")
+    score = get("scoring_func") or get("score_function") or "sigmoid"
+    if (get("n_routed_experts") or get("num_experts")) and score != "sigmoid":
+        raise NotImplementedError(f"scoring_func {score!r}")
+    scaling = get("rope_scaling")
     if scaling and scaling.get("mscale", 1) != scaling.get("mscale_all_dim", 1):
         raise NotImplementedError("rotary cos/sin scale mscale / "
                                   "mscale_all_dim != 1")
@@ -207,112 +343,20 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
             raise NotImplementedError(
                 f"{key} is nonzero in a layer held: the clamped SwiGLU is "
                 "not built")
-    if get("num_nextn_predict_layers"):
-        raise NotImplementedError(
-            "multi-token prediction layers are not built: leave them out "
-            "(num_nextn_predict_layers 0) where their loss weight is 0")
-    group = int(get("layer_group_size") or 0)
-    if group and not (get("kda_safe_gate") and get("linear_silu", True)
-                      and get("no_kda_lora", True)):
-        raise NotImplementedError(
-            "linear attention is built as Kimi delta attention with the "
-            "bounded gate (kda_safe_gate), SiLU after the short "
-            "convolution and a full-rank decay projection (no_kda_lora)")
-    lower = float(get("kda_lower_bound", -5.0))
-    if group and not MIN_LOG_DECAY <= lower < 0:
-        raise NotImplementedError(
-            f"kda_lower_bound {lower}: the chunked delta rule is exact for "
-            f"log-decays in [{MIN_LOG_DECAY}, 0)")
-    if group and int(get("short_conv_kernel_size") or SHORT_CONV_TAPS
-                     ) != SHORT_CONV_TAPS:
-        raise NotImplementedError(
-            f"short_conv_kernel_size {get('short_conv_kernel_size')}: the "
-            f"short convolution is built over {SHORT_CONV_TAPS} positions")
-    hybrid = (_nemotron_h_fields(config, layers)
-              if get("model_type") == "nemotron_h" else {})
-    kimi = (_kimi_linear_fields(config, layers)
-            if get("model_type") == "kimi_linear" else {})
-    # the nemotron_h router is the sigmoid one with a score-correction bias
-    noaux = method == "noaux_tc" or bool(hybrid)
-    if get("attention_bias"):
-        raise NotImplementedError("attention_bias: the projections are "
-                                  "built without biases")
-    pattern = get("hybrid_layer_pattern")
-    if pattern is not None and len(pattern) != layers:
-        raise NotImplementedError(
-            f"hybrid_layer_pattern has {len(pattern)} entries for "
-            f"{layers} layers")
-    latent = bool(get("kv_lora_rank"))
-    # a stated head size beside grouped-query heads (latent attention has
-    # its own nope / rope / v sizes, linear attention ``linear_head_dim``)
-    quotient = int(config["hidden_size"]) // int(config["num_attention_heads"])
-    head_size = 0 if latent or group else int(get("head_dim") or 0)
-    if head_size == quotient:
-        head_size = 0
-    rotary_dim = 0
-    if get("partial_rotary_factor") is not None and not (
-            latent or group or hybrid):
-        per_head = head_size or quotient
-        rotary_dim = int(per_head * float(get("partial_rotary_factor")))
-        if rotary_dim % 2 or rotary_dim <= 0:
-            raise NotImplementedError(
-                f"partial_rotary_factor {get('partial_rotary_factor')} of a "
-                f"head of {per_head} gives {rotary_dim} rotary dims: rotary "
-                "turns pairs")
-    first_dense = (leading_dense if leading_dense is not None
-                   else int(get("first_k_dense_replace") or 0))
-    fields = dict(
+    return LLMConfig(
         vocab_size=int(config["vocab_size"]),
         hidden_size=int(config["hidden_size"]),
-        intermediate_size=int(config["intermediate_size"]),
-        num_layers=layers,
+        intermediate_size=int(config["intermediate_size"]), num_layers=layers,
         num_heads=int(config["num_attention_heads"]),
-        num_kv_heads=get("num_key_value_heads"),
-        max_seq_len=int(max_seq_len),
+        num_kv_heads=get("num_key_value_heads"), max_seq_len=int(max_seq_len),
         rope_theta=float(get("rope_theta", 10000.0)),
         rms_eps=float(get("rms_norm_eps", get(
             "layernorm_epsilon", get("layer_norm_epsilon", 1e-6)))),
         dtype=dtype, attention_impl=attention_impl,
         tie_embeddings=bool(get("tie_word_embeddings", False)),
         rope_scaling=dict(scaling) if scaling else None,
-        routed_scaling_factor=float(get("routed_scaling_factor") or 1.0),
-        norm_topk_prob=bool(get("norm_topk_prob", True)),
-        **{k: int(get(k) or 0) for k in (
-            "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-            "qk_rope_head_dim", "v_head_dim", "num_experts_per_tok",
-            "moe_intermediate_size")},
-        first_k_dense_replace=first_dense,
-        head_size=head_size, rotary_dim=rotary_dim,
-        attn_value_scale=float(get("attention_value_scale") or 1.0),
-        layer_pattern=None if pattern is None else tuple(
-            int(bool(p)) for p in pattern),
-        sliding_window=int(get("sliding_window") or 0) if pattern else 0,
-        window_kv_heads=int(get("swa_num_key_value_heads") or 0),
-        window_rope_theta=float(get("swa_rope_theta") or 0.0),
-        window_sink=bool(get("add_swa_attention_sink_bias")),
-        full_sink=bool(get("add_full_attention_sink_bias")),
-        n_routed_experts=int(experts or 0),
-        n_shared_experts=int(get("n_shared_experts")
-                             or get("num_shared_experts") or 0),
         first_expert=int(first_expert), experts_held=int(experts_held),
-        n_group=int(get("n_group") or 0) if noaux else 0,
-        topk_group=int(get("topk_group") or 0) if noaux else 0,
-        router_bias=noaux and bool(
-            get("moe_router_enable_expert_bias", True)),
-        linear_layers=tuple(i for i in range(layers)
-                            if group and (i + 1) % group),
-        linear_head_dim=int(get("head_dim") or 0) if group else 0,
-        kda_lower_bound=lower,
-        attn_output_gate=bool(group) and get(
-            "gated_attention_proj_granularity_type") == "head_wise",
-        **hybrid)
-    # kimi_linear reads some of the keys the others share its own way
-    return LLMConfig(**dict(fields, **kimi))
-
-
-# sums a model with experts reports a step through the ``moe_stats``
-# collection, and the trainer's metrics carry out of the round program
-MOE_METRICS = tuple("moe_" + k for k in MOE_STATS)
+        **_family(config)(config, layers))
 
 
 @dataclasses.dataclass
@@ -349,17 +393,11 @@ class LLMBundle:
 
     @property
     def extra_metrics(self):
-        """Names of the sums ``apply(with_stats=True)`` returns."""
-        cfg = self.cfg
-        return ((MOE_METRICS if cfg.n_routed_experts else ())
-                + (("moe_tokens_here",) if cfg.n_routed_experts
-                   and cfg.n_group > 1 else ())
-                + (("kda_layer_steps",) if cfg.linear_layers else ())
-                + (("kda_decays", "kda_steep_decays")
-                   if cfg.kda_gate == "softplus" else ())
-                + (("attn_window_layer_steps",) if cfg.window_layers
-                   else ())
-                + (("ssm_layer_steps",) if cfg.ssm_layers else ()))
+        """Names of the sums ``apply(with_stats=True)`` returns: those the
+        layers' kinds sow (:func:`~.model.layer_stats`),
+        ``<prefix>_<sum>``."""
+        return tuple(f"{prefix}_{k}" for prefix, sums
+                     in layer_stats(self.cfg).items() for k in sums)
 
     def apply(self, params, x, rng=None, train=False, with_stats=False):
         """-> logits, or ``(logits, {name: sum})`` over
@@ -372,12 +410,12 @@ class LLMBundle:
                       "lora_scale": self.lora_alpha / self.lora_rank}
         if not with_stats:
             return self.module.apply(variables, x, train=train, **kwargs)
+        prefixes = tuple(layer_stats(self.cfg))
         logits, state = self.module.apply(
             variables, x, train=train,
-            mutable=["moe_stats", "kda_stats", "attn_stats", "ssm_stats"],
-            **kwargs)
+            mutable=[p + "_stats" for p in prefixes], **kwargs)
         sums = {}
-        for prefix in ("moe", "kda", "attn", "ssm"):
+        for prefix in prefixes:
             for layer in state.get(prefix + "_stats", {}).values():
                 # a layer's one module that sows under this collection
                 for module in layer.values():
@@ -429,7 +467,6 @@ def run_federated_llm(args) -> dict:
     runner = FedMLRunner(args, dataset=fed, model=bundle,
                          client_trainer=spec)
     result = runner.run()
-    export_dir = getattr(args, "llm_adapter_export_dir", None)
     if export_dir and isinstance(result, dict) and "params" in result:
         export_silo_adapters(args, export_dir, result=result,
                              prebuilt=(fed, bundle, spec))

@@ -57,7 +57,6 @@ import jax
 import jax.numpy as jnp
 
 from ..core import kernels
-from ..core.obs import metrics as obs_metrics
 
 # the two kernels' names in a device trace (forward; backward)
 KDA_KERNEL_NAMES = ("kda_fwd", "kda_bwd")
@@ -513,14 +512,6 @@ def _kda_chunks_bwd(chunk, impl, unbounded, res, do):
 _kda_chunks.defvjp(_kda_chunks_fwd, _kda_chunks_bwd)
 
 
-def _kept_bytes(b: int, s: int, h: int, chunk: int, dtype) -> int:
-    """Bytes the forward pass keeps of every chunk's matrices for the
-    backward pass (``(I + A)^-1`` in float32, ``P`` in ``dtype``) in a row
-    of ``s`` positions padded to whole chunks."""
-    return (b * h * -(-s // chunk) * chunk * chunk
-            * (4 + jnp.dtype(dtype).itemsize))
-
-
 def chunk_size(s: int) -> int:
     """Positions a chunk: ``CHUNK`` where the row has them, else the row
     rounded up to whole sub-chunks."""
@@ -542,9 +533,6 @@ def kda_attention(q, k, v, g, beta, impl: str = "dense",
     if impl == "flash" and (dk % 128 or v.shape[-1] % 128):
         raise ValueError(f"the KDA kernels take head sizes on the 128 grid; "
                          f"got d_k {dk}, d_v {v.shape[-1]}")
-    obs_metrics.record_kda_plan(chunk, fused=False, unbounded=unbounded,
-                                kept_bytes=_kept_bytes(b, s, h, chunk,
-                                                       q.dtype))
     beta = beta[..., None].astype(jnp.float32)
     kb = (k.astype(jnp.float32) * beta).astype(k.dtype)
     vb = (v.astype(jnp.float32) * beta).astype(v.dtype)
@@ -1144,9 +1132,6 @@ def kda_layer(ys, beta_logits, gate_logits, conv, a_log, dt_bias, o_scale,
         raise ValueError(f"the KDA kernels take head sizes on the 128 grid; "
                          f"got {d}")
     unbounded = lower is None
-    obs_metrics.record_kda_plan(chunk, fused=True, unbounded=unbounded,
-                                kept_bytes=_kept_bytes(b, s, heads, chunk,
-                                                       ys["q"].dtype))
     keep = None if attn_mask is None else attn_mask.astype(
         jnp.float32)[:, :, None]
     arrays = [ys[n] for n in "qkvf"] + [beta_logits, gate_logits]

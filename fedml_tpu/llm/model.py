@@ -64,17 +64,22 @@ class LLMConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    # sparse experts (``n_routed_experts > 0``): the first
-    # ``first_k_dense_replace`` layers keep the dense MLP, the rest route
-    # each token to ``num_experts_per_tok`` of ``n_routed_experts`` SwiGLU
-    # experts of ``moe_intermediate_size`` beside ``n_shared_experts`` that
-    # every token passes. This rank of an expert-parallel layer holds
-    # ``experts_held`` of them from ``first_expert`` on (0 = all)
+    # what each layer is, one entry a layer (empty: ``num_layers`` x
+    # ``full+mlp``): a mixer and a feed-forward, ``mixer+ff``, or a mixer
+    # alone (:class:`DecoderLayer`). Mixers: ``full`` / ``window``
+    # grouped-query softmax attention, ``latent`` latent attention,
+    # ``linear`` Kimi delta attention, ``ssm`` Mamba-2, ``moe`` the expert
+    # block; feed-forwards ``mlp`` (dense) and ``moe``
+    layers: Tuple[str, ...] = ()
+    # the expert block: each token routed to ``num_experts_per_tok`` of
+    # ``n_routed_experts`` SwiGLU experts of ``moe_intermediate_size``
+    # beside ``n_shared_experts`` that every token passes. This rank of an
+    # expert-parallel layer holds ``experts_held`` of them from
+    # ``first_expert`` on (0 = all)
     n_routed_experts: int = 0
     num_experts_per_tok: int = 0
     moe_intermediate_size: int = 0
     n_shared_experts: int = 0
-    first_k_dense_replace: int = 0
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
     first_expert: int = 0
@@ -86,16 +91,13 @@ class LLMConfig:
     n_group: int = 0
     topk_group: int = 0
     router_bias: bool = False
-    # layers of two attention kinds: the layers ``linear_layers`` names
-    # (0-based) have Kimi delta (linear) attention, the others the
-    # configuration's softmax attention: heads of ``linear_head_dim``, q k
-    # v through a causal depthwise convolution over 4 positions, a
-    # log-decay for every key channel; the gate's form ``bounded`` (in
+    # Kimi delta (linear) attention: heads of ``linear_head_dim``, q k v
+    # through a causal depthwise convolution over 4 positions, a log-decay
+    # for every key channel; the gate's form ``bounded`` (in
     # ``(kda_lower_bound, 0)``) or ``softplus``, Kimi Linear's ``g =
     # -exp(A_log) softplus(x W_fa W_fb + dt_bias)`` with no lower bound, the
     # decay's and the output gate's projections low-rank pairs through
     # ``linear_head_dim`` and the output gate a sigmoid a channel
-    linear_layers: Tuple[int, ...] = ()
     linear_head_dim: int = 0
     kda_lower_bound: float = -5.0
     kda_gate: str = "bounded"
@@ -109,28 +111,21 @@ class LLMConfig:
     head_size: int = 0
     rotary_dim: int = 0
     attn_value_scale: float = 1.0
-    # layers of two softmax kinds (``layer_pattern``: one entry a layer, 1 =
-    # window): a window layer's query sees its last ``sliding_window`` keys,
-    # itself among them, and has its own count of key-value heads and rotary
-    # base (0: the full layers'); ``window_sink`` / ``full_sink`` give a
-    # kind's softmax one learned logit a query head that takes mass and
-    # carries no value
-    layer_pattern: Optional[Tuple[int, ...]] = None
+    # a window layer's query sees its last ``sliding_window`` keys, itself
+    # among them, and has its own count of key-value heads and rotary base
+    # (0: the full layers'); ``window_sink`` / ``full_sink`` give a kind's
+    # softmax one learned logit a query head that takes mass and carries no
+    # value
     sliding_window: int = 0
     window_kv_heads: int = 0
     window_rope_theta: float = 0.0
     window_sink: bool = False
     full_sink: bool = False
-    # a stack of single-mixer blocks (``block_pattern``: one character a
-    # layer, ``M`` a Mamba-2 state-space mixer, ``E`` the expert block,
-    # ``*`` grouped-query attention): layer i is ``x + mixer(norm(x))``, one
-    # norm and one mixer. A state-space mixer has ``ssm_heads`` heads of
-    # ``ssm_head_dim`` channels with a state of ``ssm_state_size`` a
-    # channel, B and C shared by the heads of each of ``ssm_groups``
-    # groups, a causal depthwise convolution over ``ssm_conv_kernel``
-    # positions (with a bias where ``ssm_conv_bias``) and runs in chunks
-    # of ``ssm_chunk`` positions
-    block_pattern: Optional[str] = None
+    # a state-space mixer: ``ssm_heads`` heads of ``ssm_head_dim`` channels
+    # with a state of ``ssm_state_size`` a channel, B and C shared by the
+    # heads of each of ``ssm_groups`` groups, a causal depthwise
+    # convolution over ``ssm_conv_kernel`` positions (with a bias where
+    # ``ssm_conv_bias``), in chunks of ``ssm_chunk`` positions
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state_size: int = 0
@@ -152,6 +147,24 @@ class LLMConfig:
     # False: attention without rotary or any other position term
     use_rope: bool = True
 
+    def __post_init__(self):
+        self.layers = tuple(self.layers)
+        parts = [k.partition("+") for k in self.plan]
+        if len(parts) != self.num_layers or any(
+                m not in _MIXERS or ff not in (_FEED_FORWARDS if plus else "")
+                for m, plus, ff in parts):
+            raise ValueError(
+                f"layers {self.layers}: one entry for each of the "
+                f"{self.num_layers} layers, a mixer of {tuple(_MIXERS)} "
+                f"alone or with '+' a feed-forward of "
+                f"{tuple(_FEED_FORWARDS)}")
+
+    @property
+    def plan(self) -> Tuple[str, ...]:
+        """Each layer's kind: ``layers``, or ``num_layers`` x ``full+mlp``
+        where that is empty."""
+        return self.layers or ("full+mlp",) * self.num_layers
+
     @property
     def head_dim(self) -> int:
         return self.head_size or self.hidden_size // self.num_heads
@@ -164,40 +177,19 @@ class LLMConfig:
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
 
-    def is_linear(self, layer: int) -> bool:
-        """Whether layer ``layer`` has linear (Kimi delta) attention."""
-        return layer in self.linear_layers
-
-    def is_window(self, layer: int) -> bool:
-        """Whether layer ``layer`` attends through the sliding window."""
-        return bool(self.layer_pattern) and bool(self.layer_pattern[layer])
-
-    @property
-    def window_layers(self) -> int:
-        return sum(map(bool, self.layer_pattern or ()))
-
-    @property
-    def ssm_layers(self) -> int:
-        return (self.block_pattern or "").count("M")
-
     @property
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
     def param_count(self) -> int:
-        if (self.kv_lora_rank or self.n_routed_experts
-                or self.linear_layers or self.layer_pattern
-                or self.head_size or self.v_head_dim or self.block_pattern
-                or self.mlp_activation != "swiglu"):
+        if (set(self.plan) - {"full+mlp"} or self.head_size
+                or self.v_head_dim or self.mlp_activation != "swiglu"):
             raise NotImplementedError(
                 "LLMConfig.param_count counts the dense grouped-query "
                 "SwiGLU decoder at head size hidden_size / num_heads alone; "
-                "a configuration with latent or linear attention, experts, "
-                "window layers, stated head sizes, single-mixer blocks "
-                "(block_pattern: state-space layers, ssm_*; latent experts, "
-                "moe_latent_size, shared_expert_size) or a non-gated "
-                "feed-forward (mlp_activation) is counted from its shapes "
-                "under benchmarks/flops/")
+                "a configuration with layers other than full+mlp, stated "
+                "head sizes or a non-gated feed-forward (mlp_activation) is "
+                "counted from its shapes under benchmarks/flops/")
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         per_layer = (h * h * 2 +                       # q, o
                      2 * h * self.kv_heads * self.head_dim +  # k, v
@@ -263,6 +255,14 @@ class RMSNorm(nn.Module):
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
         normed = x.astype(jnp.float32) * jax.lax.rsqrt(var + self.eps)
         return (normed * scale).astype(x.dtype)
+
+
+def _dense(cfg: LLMConfig, feats, name: str, **kw) -> nn.DenseGeneral:
+    """A projection of the last axis without a bias, in the compute dtype
+    over float32 parameters."""
+    return nn.DenseGeneral(feats, use_bias=False, name=name,
+                           dtype=cfg.compute_dtype, param_dtype=jnp.float32,
+                           **kw)
 
 
 def _add_lora(x: jnp.ndarray, ys: dict, adapter, scale: float) -> dict:
@@ -345,9 +345,7 @@ class Attention(nn.Module):
                 "llm/kv_cache.py holds blocks of one shape for every layer "
                 "and keeps them all, where a window layer frees what slid "
                 "out")
-        dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
-            feats, axis=-1, use_bias=False, name=name,
-            dtype=cfg.compute_dtype, param_dtype=jnp.float32)
+        dense = functools.partial(_dense, cfg)
 
         qkv = _add_lora(x, {
             "q": dense((cfg.num_heads, d_qk), "q")(x),
@@ -402,9 +400,7 @@ class Attention(nn.Module):
                 self.sow("attn_stats", "window_layer_steps", jnp.float32(1),
                          init_fn=lambda: jnp.float32(0), reduce_fn=jnp.add)
         out = out.reshape(b, s, cfg.num_heads * d_v)
-        y = nn.DenseGeneral(cfg.hidden_size, use_bias=False, name="o",
-                            dtype=cfg.compute_dtype,
-                            param_dtype=jnp.float32)(out)
+        y = dense(cfg.hidden_size, "o")(out)
         return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], new_kv
 
 
@@ -456,9 +452,7 @@ class LinearAttention(nn.Module):
                 f"kda_lower_bound {cfg.kda_lower_bound}: the chunked delta "
                 f"rule is exact for log-decays in [{MIN_LOG_DECAY}, 0)")
         nh, d, taps = cfg.num_heads, cfg.linear_head_dim, SHORT_CONV_TAPS
-        dense = lambda feats, name, **kw: nn.DenseGeneral(  # noqa: E731
-            feats, axis=-1, use_bias=False, name=name,
-            dtype=cfg.compute_dtype, param_dtype=jnp.float32, **kw)
+        dense = functools.partial(_dense, cfg)
         # the decay projection leaves its product in float32: the gate
         # multiplies it by up to exp(A_log) = 16
         wide = functools.partial(dense, nh * d, dot_general=functools.partial(
@@ -528,9 +522,7 @@ class LatentAttention(nn.Module):
         b, s, _ = x.shape
         nh, nope, rope, dv = (cfg.num_heads, cfg.qk_nope_head_dim,
                               cfg.qk_rope_head_dim, cfg.v_head_dim)
-        dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
-            feats, axis=-1, use_bias=False, name=name,
-            dtype=cfg.compute_dtype, param_dtype=jnp.float32)
+        dense = functools.partial(_dense, cfg)
 
         first = ({"q_a": dense(cfg.q_lora_rank, "q_a")(x)}
                  if cfg.q_lora_rank
@@ -567,9 +559,7 @@ class LatentAttention(nn.Module):
         if cfg.attn_output_gate:
             out = _head_gate(out, dense(nh, "g")(x))
         out = out.reshape(b, s, nh * dv)
-        y = nn.DenseGeneral(cfg.hidden_size, use_bias=False, name="o",
-                            dtype=cfg.compute_dtype,
-                            param_dtype=jnp.float32)(out)
+        y = dense(cfg.hidden_size, "o")(out)
         return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], None
 
 
@@ -586,9 +576,7 @@ class MLP(nn.Module):
     def __call__(self, x, adapter=None, lora_scale: float = 1.0):
         cfg = self.cfg
         width = self.width or cfg.intermediate_size
-        dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
-            feats, use_bias=False, name=name, dtype=cfg.compute_dtype,
-            param_dtype=jnp.float32)
+        dense = functools.partial(_dense, cfg)
 
         with scope("mlp"):
             if cfg.mlp_activation == "relu2":
@@ -667,9 +655,7 @@ class MoE(nn.Module):
             """One of the two projections around the routed experts, its
             adapter's side path added."""
             with scope("moe.latent"):
-                y = nn.DenseGeneral(
-                    feats, use_bias=False, name=name, dtype=cfg.compute_dtype,
-                    param_dtype=jnp.float32)(t)
+                y = _dense(cfg, feats, name)(t)
                 return _add_lora(t, {name: y}, adapter, lora_scale)[name]
 
         if cfg.moe_latent_size:
@@ -738,9 +724,7 @@ class Mamba2(nn.Module):
         nh, p, n, g = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size,
                        cfg.ssm_groups)
         inner, wide = nh * p, nh * p + 2 * g * n
-        dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
-            feats, axis=-1, use_bias=False, name=name,
-            dtype=cfg.compute_dtype, param_dtype=jnp.float32)
+        dense = functools.partial(_dense, cfg)
 
         zxbcdt = _add_lora(x, {"in_proj": dense(inner + wide + nh,
                                                 "in_proj")(x)},
@@ -808,67 +792,76 @@ class Mamba2(nn.Module):
         return gated_norm(y, z, scale)
 
 
-class MixerBlock(nn.Module):
-    """One layer of a ``block_pattern`` stack: ``x + mixer(norm(x))``, ONE
-    norm and ONE mixer, of the ``kind`` the pattern gives the layer: ``M``
-    :class:`Mamba2`, ``E`` :class:`MoE`, ``*`` :class:`Attention`."""
+# the vocabulary of ``LLMConfig.layers``: a mixer -> its module and the
+# scope its call runs in (None: the module's own scopes alone)
+_MIXERS = {
+    "full": (Attention, "attn.full"),
+    "window": (functools.partial(Attention, window=True), "attn.window"),
+    "latent": (LatentAttention, "attn.latent"),
+    "linear": (LinearAttention, "attn.linear"),
+    "ssm": (Mamba2, "attn.ssm"),
+    "moe": (MoE, None),
+}
+_FEED_FORWARDS = {"mlp": MLP, "moe": MoE}
 
-    cfg: LLMConfig
-    kind: str = "M"
 
-    @nn.compact
-    def __call__(self, x, positions, attn_mask=None, kv_view=None,
-                 adapter=None, lora_scale: float = 1.0):
-        adapter = (adapter or {}).get("mixer")
-        with scope("norm"):
-            normed = RMSNorm(self.cfg.rms_eps, name="norm")(x)
-        if self.kind == "E":
-            out, new_kv = MoE(self.cfg, name="mixer")(
-                normed, adapter=adapter, lora_scale=lora_scale), None
-        else:
-            mixer, name = ((Mamba2, "attn.ssm") if self.kind == "M"
-                           else (Attention, "attn.full"))
-            with scope(name):
-                out, new_kv = mixer(self.cfg, name="mixer")(
-                    normed, positions, attn_mask, kv_view=kv_view,
-                    adapter=adapter, lora_scale=lora_scale)
-        with scope("norm"):
-            return x + out, new_kv
+def layer_stats(cfg: LLMConfig) -> dict:
+    """The sums the kinds in ``cfg.plan`` sow a step: ``{prefix: sums}``,
+    each sown into the collection ``<prefix>_stats``."""
+    from . import moe
+    kinds = {part for kind in cfg.plan for part in kind.split("+")}
+    moe_sums = moe.STATS
+    if cfg.n_group > 1:
+        moe_sums += ("tokens_here",)
+    kda_sums = ("layer_steps",)
+    if cfg.kda_gate == "softplus":
+        kda_sums += ("decays", "steep_decays")
+    table = (("moe", "moe", moe_sums), ("linear", "kda", kda_sums),
+             ("window", "attn", ("window_layer_steps",)),
+             ("ssm", "ssm", ("layer_steps",)))
+    return {prefix: sums for kind, prefix, sums in table if kind in kinds}
+
+
+def _mix(kind, cfg, name, x, positions, attn_mask, kv_view, adapter,
+         lora_scale):
+    """The ``kind`` mixer of a layer, made under ``name``: ``(out,
+    new_kv)``."""
+    module, where = _MIXERS[kind]
+    if where is None:       # the expert block reads no position
+        return module(cfg, name=name)(x, adapter=adapter,
+                                      lora_scale=lora_scale), None
+    with scope(where):
+        return module(cfg, name=name)(
+            x, positions, attn_mask, kv_view=kv_view, adapter=adapter,
+            lora_scale=lora_scale)
 
 
 class DecoderLayer(nn.Module):
-    """One pre-norm layer; ``linear`` says whether its attention is the
-    linear (Kimi delta) kind, else the configuration does (grouped-query,
-    of the ``window`` kind or the full one, or latent); ``sparse`` whether
-    its feed-forward is the expert block (``moe``) or the dense MLP
-    (``mlp``)."""
+    """One pre-norm layer of the ``kind`` its ``LLMConfig.layers`` entry
+    names: a mixer alone, ``x + mixer(norm(x))`` (``norm``, ``mixer``), or
+    ``mixer+ff``, ``h = x + mixer(ln_attn(x))`` then ``h + ff(ln_mlp(h))``
+    (``ln_attn``, ``attn``, ``ln_mlp``, ``mlp`` or ``moe``)."""
 
     cfg: LLMConfig
-    sparse: bool = False
-    linear: bool = False
-    window: bool = False
+    kind: str = "full+mlp"
 
     @nn.compact
     def __call__(self, x, positions, attn_mask=None, kv_view=None,
                  adapter=None, lora_scale: float = 1.0):
         adapter = adapter or {}
-        attention, kind = (
-            (LinearAttention, "attn.linear") if self.linear else
-            (LatentAttention, "attn.latent") if self.cfg.kv_lora_rank else
-            (functools.partial(Attention, window=self.window),
-             "attn.window" if self.window else "attn.full"))
+        mixer, _, ff = self.kind.partition("+")
+        norm, name = ("ln_attn", "attn") if ff else ("norm", "mixer")
         with scope("norm"):
-            normed = RMSNorm(self.cfg.rms_eps, name="ln_attn")(x)
-        with scope(kind):
-            a_out, new_kv = attention(self.cfg, name="attn")(
-                normed, positions, attn_mask, kv_view=kv_view,
-                adapter=adapter.get("attn"), lora_scale=lora_scale)
+            normed = RMSNorm(self.cfg.rms_eps, name=norm)(x)
+        out, new_kv = _mix(mixer, self.cfg, name, normed, positions,
+                           attn_mask, kv_view, adapter.get(name), lora_scale)
         with scope("norm"):
-            h = x + a_out
+            h = x + out
+            if not ff:
+                return h, new_kv
             normed = RMSNorm(self.cfg.rms_eps, name="ln_mlp")(h)
-        ff, name = (MoE, "moe") if self.sparse else (MLP, "mlp")
-        out = ff(self.cfg, name=name)(
-            normed, adapter=adapter.get(name), lora_scale=lora_scale)
+        out = _FEED_FORWARDS[ff](self.cfg, name=ff)(
+            normed, adapter=adapter.get(ff), lora_scale=lora_scale)
         with scope("norm"):
             return h + out, new_kv
 
@@ -909,14 +902,8 @@ class CausalLM(nn.Module):
                     pos = pos + jax.lax.axis_index(ax[0]) * tokens.shape[1]
             positions = jnp.broadcast_to(pos[None, :], tokens.shape)
         new_kvs = []
-        for i in range(cfg.num_layers):
-            sparse = bool(cfg.n_routed_experts) and \
-                i >= cfg.first_k_dense_replace
-            layer = (MixerBlock(cfg, cfg.block_pattern[i], name=f"layer_{i}")
-                     if cfg.block_pattern else
-                     DecoderLayer(cfg, sparse, cfg.is_linear(i),
-                                  cfg.is_window(i), name=f"layer_{i}"))
-            x, new_kv = layer(
+        for i, kind in enumerate(cfg.plan):
+            x, new_kv = DecoderLayer(cfg, kind, name=f"layer_{i}")(
                 x, positions, attn_mask,
                 kv_view=None if kv_view is None else kv_view[i],
                 adapter=None if adapters is None
@@ -928,9 +915,7 @@ class CausalLM(nn.Module):
             if cfg.tie_embeddings:
                 logits = emb.attend(x)
             else:
-                logits = nn.DenseGeneral(
-                    cfg.vocab_size, use_bias=False, name="lm_head",
-                    dtype=cfg.compute_dtype, param_dtype=jnp.float32)(x)
+                logits = _dense(cfg, cfg.vocab_size, "lm_head")(x)
             logits = logits.astype(jnp.float32)
         if kv_view is not None:
             return logits, new_kvs

@@ -52,7 +52,6 @@ import jax
 import jax.numpy as jnp
 
 from ..core import kernels
-from ..core.obs import metrics as obs_metrics
 from .linear_attention import _exact, _taps
 
 # the two kernels' names in a device trace (forward; backward)
@@ -524,7 +523,6 @@ def ssd_scan(x, dt, a, bm, cm, d, impl: str = "dense", chunk: int = CHUNK):
     groups, n = bm.shape[2], bm.shape[3]
     impl, hb = _kernel_plan(h, groups, p, n, impl)
     chunk = chunk_size(s, chunk)
-    obs_metrics.record_ssd_plan(chunk, hb, fused=False)
     f32 = jnp.float32
     dt = dt.astype(f32)
     pad = -s % chunk
@@ -997,7 +995,6 @@ def ssm_layer(zxbcdt, attn_mask, conv_w, conv_b, a_log, skip, dt_bias,
             f"B's {bc} columns must divide 2 x {inner} and the heads "
             f"{heads} the {2 * inner + 2 * bc} columns before dt")
     chunk = chunk_size(s, chunk)
-    obs_metrics.record_ssd_plan(chunk, hb, fused=fused)
     keep = None if attn_mask is None else attn_mask.astype(
         jnp.float32)[:, :, None]
     pad = -s % chunk

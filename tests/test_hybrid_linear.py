@@ -111,8 +111,8 @@ def test_logits_loss_and_adapter_gradients_match_the_reference():
     tok = tokens(cfg)
     x, y = tok[:, :-1], tok[:, 1:]
     bundle = bundle_for(cfg, base, 32)
-    assert [bundle.cfg.is_linear(i) for i in range(7)] == \
-        [True] * 5 + [False, True]
+    assert bundle.cfg.layers == ("linear+mlp",) + ("linear+moe",) * 4 + (
+        "latent+moe", "linear+moe")
     grad_fn = REF.make_model(cfg)
     batch = {"x": x, "y": y, "mask": jnp.ones((2,))}
     with jax.default_matmul_precision("highest"):
@@ -689,7 +689,8 @@ def test_round_counters_reach_the_registry_from_the_round_program():
     """A federated LoRA round of the small model through ``TPUSimulator``:
     ``fed_kda_layer_steps_total`` and ``fed_moe_tokens_here_total`` come
     from the round's own metrics, flushed by the caller who has read the
-    round's loss; the chunk gauge is set when the call is traced."""
+    round's loss; the layer's chunk, the residuals its forward keeps and
+    the fused passes it runs."""
     import fedml_tpu
     from fedml_tpu.arguments import Arguments
     from fedml_tpu.core.algframe.types import ClientData, TrainHyper
@@ -738,18 +739,37 @@ def test_round_counters_reach_the_registry_from_the_round_program():
         kda_before + 24
     assert REGISTRY.counter("fed_moe_tokens_here_total").value() == \
         here_before + float(m0["moe_tokens_here"])
-    assert REGISTRY.gauge("fed_kda_chunk").value() == 32.0
-    # the layer's element-wise work ran through the fused passes; a caller
-    # that hands the kernels their operands itself reads 0
-    assert REGISTRY.gauge("fed_kda_fused").value() == 1.0
+    assert la.chunk_size(32) == 32
     # the backward pass reads each chunk's inverse and P from the forward:
     # a row of 32 at 4 heads, [32, 32] twice in float32
-    assert REGISTRY.gauge("fed_kda_bwd_kept").value() == 1.0
-    assert REGISTRY.gauge("fed_kda_kept_bytes").value() == 4 * 32 * 32 * 8
-    la.kda_attention(*kda_inputs(64, -1.0, 0.0))
-    assert REGISTRY.gauge("fed_kda_fused").value() == 0.0
-    assert REGISTRY.gauge("fed_kda_kept_bytes").value() == 2 * 64 * 64 * 8
+    row = jax.ShapeDtypeStruct((1, 32, 4, 16), jnp.float32)
+    assert kept_bytes(row, 32) == 4 * 32 * 32 * 8
+    assert kept_bytes(jax.ShapeDtypeStruct((1, 64, 2, 128), jnp.float32),
+                      64) == 2 * 64 * 64 * 8
+    # the layer's element-wise work runs through the fused passes; a caller
+    # that hands the kernels their operands itself runs none of them
+    (ys, beta, gates, conv, *frozen), _, _ = layer_inputs(64, False)
+    fused = jax.jit(lambda ys, b, g: la.kda_layer(
+        ys, b, g, conv, *frozen, heads=2, lower=-5.0, eps=1e-6,
+        impl="flash")).lower(ys, beta, gates).as_text(debug_info=True)
+    plain = jax.jit(functools.partial(la.kda_attention, impl="flash")).lower(
+        *kda_inputs(64, -1.0, 0.0)).as_text(debug_info=True)
+    for name in ("kda_pre_fwd", "kda_post_fwd"):
+        assert name in fused and name not in plain
+    assert not any(name in plain for name in la.KDA_PASS_NAMES)
     assert la.chunk_size(4096) == 64 and la.chunk_size(40) == 48
+
+
+def kept_bytes(row, chunk):
+    """Bytes of the residuals the chunked forward keeps of every chunk's
+    ``[C, C]`` matrices (its inverse and P) for the backward pass, of a row
+    of q, k, v and log-decays shaped ``row``."""
+    _, res = jax.eval_shape(functools.partial(
+        la._kda_chunks_fwd, chunk=chunk, impl="dense", unbounded=False),
+        row, row, row, row, row)
+    mats = [a for a in res if a.shape[-2:] == (chunk, chunk)]
+    assert [a.dtype for a in mats] == [jnp.float32, row.dtype]
+    return sum(a.size * a.dtype.itemsize for a in mats)
 
 
 def test_the_cache_path_of_linear_attention_refuses_clearly():

@@ -20,11 +20,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from fedml_tpu.core.obs import REGISTRY
 from fedml_tpu.llm import linear_attention as la
 from fedml_tpu.llm.federated import LLMBundle, llm_config_from_hf
 from fedml_tpu.llm.lora import lora_init
-from fedml_tpu.llm.model import CausalLM, LatentAttention
+from fedml_tpu.llm.model import CausalLM, LatentAttention, LLMConfig
 from fedml_tpu.llm.trainer import CausalLMTrainer
 from tests.test_hybrid_linear import check_the_kept_backward
 
@@ -241,9 +240,10 @@ def test_the_unbounded_layer_matches_its_jax_numpy(impl, masked):
     assert float(counts[0]) == live
     assert float(counts[1]) == float(jnp.sum(g < la.MIN_LOG_DECAY))
     assert 0.05 < float(counts[1]) / live < 0.95
-    assert REGISTRY.gauge("fed_kda_gate").value() == 1.0
-    la.kda_attention(*kda_inputs(64, -1.0, 0.0))
-    assert REGISTRY.gauge("fed_kda_gate").value() == 0.0
+    # the configuration that builds this layer: Kimi Linear's unbounded
+    # gate, where the default (and Ling's) is the bounded one
+    assert system_cfg(small_cfg(), 32).kda_gate == "softplus"
+    assert LLMConfig().kda_gate == "bounded"
 
 
 # --------------------------------------------------------- the loader ---
@@ -255,8 +255,8 @@ def test_the_loader_reads_the_published_keys():
     latent layers, top-8 by score + bias without groups."""
     lc = llm_config_from_hf(published(), max_seq_len=64)
     assert lc.num_layers == 27
-    assert [i + 1 for i in range(27) if not lc.is_linear(i)] == \
-        [4, 8, 12, 16, 20, 24, 27]
+    assert [i + 1 for i, k in enumerate(lc.layers)
+            if not k.startswith("linear")] == [4, 8, 12, 16, 20, 24, 27]
     assert lc.linear_head_dim == 128 and lc.head_dim == 72
     assert lc.head_size == 0 and lc.num_heads == 32
     assert lc.kda_gate == "softplus" and not lc.use_rope
@@ -267,7 +267,8 @@ def test_the_loader_reads_the_published_keys():
             lc.moe_intermediate_size) == (256, 8, 1, 1024)
     assert lc.routed_scaling_factor == 2.446 and lc.norm_topk_prob
     assert lc.router_bias and lc.n_group == 1 and lc.topk_group == 1
-    assert lc.first_k_dense_replace == 1 and lc.intermediate_size == 9216
+    assert lc.layers[:2] == ("linear+mlp", "linear+moe")
+    assert lc.intermediate_size == 9216
     assert lc.rms_eps == 1e-5 and not lc.tie_embeddings
     assert lc.vocab_size == 163840
     # the cut's lists: the published layers 1-9
@@ -276,8 +277,9 @@ def test_the_loader_reads_the_published_keys():
                              if k in ("num_hidden_layers",
                                       "linear_attn_config")}),
         max_seq_len=64)
-    assert [cut.is_linear(i) for i in range(9)] == \
-        [True] * 3 + [False] + [True] * 3 + [False, True]
+    assert cut.layers == ("linear+mlp", "linear+moe", "linear+moe",
+                          "latent+moe", "linear+moe", "linear+moe",
+                          "linear+moe", "latent+moe", "linear+moe")
 
 
 def _relisted(**linear):

@@ -108,8 +108,7 @@ COMMON = dict(vocab_size=64, hidden_size=32, intermediate_size=48,
               num_heads=2, num_layers=2, max_seq_len=128,
               tie_embeddings=False, attention_impl="flash")
 EXPERTS = dict(n_routed_experts=8, num_experts_per_tok=2,
-               moe_intermediate_size=16, first_k_dense_replace=1,
-               experts_held=4, first_expert=2)
+               moe_intermediate_size=16, experts_held=4, first_expert=2)
 LATENT = dict(kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
               v_head_dim=16)
 EVERY = {"embed", "head", "norm", "lora", "mlp"}
@@ -117,22 +116,22 @@ KINDS = {
     # latent attention (the flash kernels at 24/16), experts with a shared
     # one behind a dense first layer
     "latent": (dict(**EXPERTS, **LATENT, n_shared_experts=1,
-                    q_lora_rank=16),
+                    q_lora_rank=16, layers=("latent+mlp", "latent+moe")),
                EVERY | {"attn.latent", "moe.route", "moe.experts"},
                {"flash_dq": "attn.latent", "flash_dkv": "attn.latent",
                 "moe_grouped_dx": "moe.experts"}),
     # a full layer and a window layer with a sink, grouped-query heads
     "window": (dict(**EXPERTS, num_kv_heads=1, head_size=24, v_head_dim=16,
-                    rotary_dim=8, layer_pattern=(0, 1), sliding_window=32,
-                    window_sink=True),
+                    rotary_dim=8, layers=("full+mlp", "window+moe"),
+                    sliding_window=32, window_sink=True),
                EVERY | {"attn.full", "attn.window", "moe.route",
                         "moe.experts"},
                {"flash_dq": "attn.full", "flash_dkv": "attn.full",
                 "flash_win_dq": "attn.window",
                 "flash_win_dkv": "attn.window"}),
     # a Kimi-delta layer (its kernels take heads of 128) and a latent one
-    "linear": (dict(**LATENT, linear_layers=(0,), linear_head_dim=128,
-                    attn_output_gate=True),
+    "linear": (dict(**LATENT, layers=("linear+mlp", "latent+mlp"),
+                    linear_head_dim=128, attn_output_gate=True),
                EVERY | {"attn.linear", "attn.latent"},
                {"kda_bwd": "attn.linear", "kda_pre_bwd": "attn.linear",
                 "kda_post_bwd": "attn.linear", "flash_dq": "attn.latent"}),

@@ -212,19 +212,19 @@ def test_a_masked_position_neither_writes_nor_decays():
 
 
 def test_the_plan_gauges_are_set_when_a_call_is_traced():
-    REGISTRY.reset()
+    """The plan of a call at the benchmark's shape: chunks of 128, 16 of a
+    group's heads a grid step."""
     args = draw(1, 4096, 128, 64, 8, 128)
     jax.eval_shape(lambda *a: ss.ssd_scan(*a, impl="dense"), *args)
-    assert REGISTRY.gauge("fed_ssd_chunk").value() == 128.0
-    assert REGISTRY.gauge("fed_ssd_heads_per_step").value() == 16.0
-    assert ss.chunk_size(40) == 48 and ss.chunk_size(4096) == 128
+    assert ss.chunk_size(4096) == 128
+    assert ss.heads_per_step(128, 8, 64) == 16
+    assert ss.chunk_size(40) == 48
     assert ss.heads_per_lane_block(64, 16) == 2
     assert ss.heads_per_lane_block(128, 4) == 1
     with pytest.raises(ValueError, match="128 grid"):
         ss.ssd_scan(*draw(1, 32, 4, 8, 2, 16), impl="flash")
     with pytest.raises(ValueError, match="groups"):
         ss.ssd_scan(*draw(1, 32, 4, 8, 3, 16))
-    REGISTRY.reset()
 
 
 # ---------------------------------------------- system against reference ---
@@ -361,10 +361,10 @@ def test_the_fused_passes_match_the_modules_form(case, dtype, monkeypatch):
 
 
 def test_the_fused_gauge_says_which_path_was_traced():
-    """``fed_ssm_fused`` reads 1 after the fused layer is traced and 0
-    after a caller made the kernels' operands itself; a product whose B
-    columns do not fall on its own blocks is refused."""
-    REGISTRY.reset()
+    """The fused layer's lowered text holds its passes beside the kernels,
+    that of a caller who made the kernels' operands itself the kernels
+    alone; a product whose B columns do not fall on its own blocks is
+    refused."""
     f32 = jnp.float32
     heads, head = 8, jax.ShapeDtypeStruct((8,), f32)
     zx = jax.ShapeDtypeStruct((1, 256, 512 + 1536 + 8), f32)
@@ -376,19 +376,20 @@ def test_the_fused_gauge_says_which_path_was_traced():
     params = (jax.ShapeDtypeStruct((4, 1536), f32),
               jax.ShapeDtypeStruct((1536,), f32), head, head, head,
               jax.ShapeDtypeStruct((512,), f32))
-    jax.eval_shape(layer, zx, *params)
-    assert REGISTRY.gauge("fed_ssm_fused").value() == 1.0
-    assert REGISTRY.gauge("fed_ssd_chunk").value() == 128.0
-    jax.eval_shape(lambda *a: ss.ssd_scan(*a, impl="flash"),
-                   *draw(1, 256, 8, 64, 4, 128))
-    assert REGISTRY.gauge("fed_ssm_fused").value() == 0.0
+    fused = jax.jit(layer).lower(zx, *params).as_text(debug_info=True)
+    assert ss.chunk_size(256) == 128
+    plain = jax.jit(lambda *a: ss.ssd_scan(*a, impl="flash")).lower(
+        *draw(1, 256, 8, 64, 4, 128)).as_text(debug_info=True)
+    for name in ("ssm_pre_fwd", "ssm_post_fwd", ss.SSD_KERNEL_NAMES[0]):
+        assert name in fused, name
+    assert ss.SSD_KERNEL_NAMES[0] in plain
+    assert not any(name in plain for name in ss.SSM_PASS_NAMES)
     with pytest.raises(ValueError, match="column blocks"):
         jax.eval_shape(lambda zx: ss.ssm_layer(
             zx, None, jnp.zeros((4, 1024)), None, jnp.zeros(2),
             jnp.zeros(2), jnp.zeros(2), jnp.zeros(256), heads=2,
             head_dim=128, groups=1, state=384),
             jax.ShapeDtypeStruct((1, 128, 256 + 1024 + 2), f32))
-    REGISTRY.reset()
 
 
 @pytest.mark.parametrize("fault", ["fault_no_decay", "fault_plain_relu"])
@@ -578,7 +579,7 @@ def test_attention_without_rotary_is_a_plain_softmax_and_knows_no_position():
 def test_the_loader_reads_the_published_keys():
     cfg = small_cfg()
     lc = system_cfg(cfg, 24)
-    assert lc.block_pattern == "MEM*E" and lc.ssm_layers == 2
+    assert lc.layers == ("ssm", "moe", "ssm", "full", "moe")
     assert (lc.ssm_heads, lc.ssm_head_dim, lc.ssm_state_size, lc.ssm_groups,
             lc.ssm_conv_kernel, lc.ssm_chunk, lc.ssm_conv_bias) == (
         8, 8, 16, 2, 4, 16, True)
@@ -590,7 +591,7 @@ def test_the_loader_reads_the_published_keys():
     assert lc.routed_scaling_factor == 5.0 and lc.rms_eps == 1e-5
     assert (lc.num_heads, lc.kv_heads, lc.head_dim) == (8, 2, 4)
     assert not lc.use_rope and lc.rotary_dim == 0
-    assert not lc.tie_embeddings and lc.first_k_dense_replace == 0
+    assert not lc.tie_embeddings and not any("+" in k for k in lc.layers)
     silu = system_cfg(dict(cfg, mlp_hidden_act="silu"), 24)
     assert silu.mlp_activation == "swiglu"
     # the published file itself, at its published sizes
@@ -609,7 +610,8 @@ def test_the_loader_reads_the_published_keys():
     assert (big.moe_latent_size, big.moe_intermediate_size,
             big.shared_expert_size, big.mlp_activation) == (
         1024, 2688, 5376, "relu2")
-    assert big.block_pattern == "MEMEMEMEM*E" and big.vocab_size == 16384
+    assert big.layers == ("ssm", "moe") * 4 + ("ssm", "full", "moe")
+    assert big.vocab_size == 16384
 
 
 @pytest.mark.parametrize("over,match", [
@@ -643,7 +645,7 @@ def test_the_cache_path_of_a_state_space_layer_refuses_clearly():
 
 
 def test_dense_count_refuses_the_new_fields():
-    with pytest.raises(NotImplementedError, match="block_pattern"):
+    with pytest.raises(NotImplementedError, match="layers"):
         system_cfg(small_cfg(), 24).param_count()
     with pytest.raises(NotImplementedError, match="mlp_activation"):
         LLMConfig(mlp_activation="relu2").param_count()
