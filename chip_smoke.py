@@ -64,6 +64,10 @@ FULL = {
     "ssd_shape": (1, 4096, 128, 64, 8, 128),
     # b, s, query heads, key-value heads, d_qk, d_v, window
     "window_shape": (1, 4096, 64, 8, 192, 128, 128),
+    # b, s, query heads, key-value heads, head size, index heads, index
+    # head size, keys a query; the rows of the gradient check
+    "sparse_shape": (1, 16384, 32, 4, 128, 16, 64, 2048),
+    "sparse_grad_seq": 4096,
     "conv_batch": 32,
     "ring_seq": 8192,
 }
@@ -79,6 +83,8 @@ TOY = {
     "kda_softplus_shape": (1, 128, 2, 128),
     "ssd_shape": (1, 160, 4, 64, 2, 128),
     "window_shape": (1, 256, 4, 2, 24, 16, 40),
+    "sparse_shape": (1, 384, 4, 2, 128, 4, 64, 100),
+    "sparse_grad_seq": 256,
     "conv_batch": 8,
     "ring_seq": 256,
 }
@@ -839,6 +845,98 @@ def phase_four_chip_llm(sz):
 
 # ------------------------------------------------------------------ main ----
 
+def phase_sparse(sz):
+    """The sparse attention kernels (Pallas on a chip, interpreted
+    elsewhere) at the Keye cell's shape, on bfloat16 operands: the attention
+    kernels' output against the dense path on the first and last rows of
+    one key-value head's group, on the index kernel's selection; at
+    ``sparse_grad_seq`` positions (where the dense path's scores fit) that
+    selection against the dense path's from the same inputs (the share of
+    (query, key) bits apart: the two sum the same products in their own
+    orders, so a near tie may fall either way) and the gradients against
+    the dense path; and the kernels' times at the cell's shape."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.llm import sparse_attention as sa
+
+    b, s, h, kv, d, ih, idim, topk = sz["sparse_shape"]
+    flash = "flash" if on_chip() else "dense"
+
+    def operands(s, seed):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+        bf = jnp.bfloat16
+        return (jax.random.normal(ks[0], (b, s, ih, idim), bf),
+                jax.random.normal(ks[1], (b, s, idim), bf),
+                jax.random.normal(ks[2], (b, s, ih), jnp.float32) * 0.03,
+                jax.random.normal(ks[3], (b, s, h, d), bf),
+                jax.random.normal(ks[4], (b, s, kv, d), bf),
+                jax.random.normal(ks[5], (b, s, kv, d), bf),
+                jax.random.normal(ks[6], (b, s, h, d), jnp.float32))
+
+    qi, ki, w, q, k, v, ct = operands(s, 17)
+    select = jax.jit(functools.partial(sa.select_keys, topk=topk,
+                                       impl=flash))
+    words = select(qi, ki, w)
+    attend = jax.jit(lambda q, k, v, wd: sa.sparse_attend(
+        q, k, v, wd, topk, impl=flash))
+    got = attend(q, k, v, words)
+    rep, rows = h // kv, min(512, s)
+    keep = sa.unpack(words, s)
+
+    def dense_rows(lo):
+        """The first key-value head's group on rows ``lo .. lo + rows``."""
+        qs = q[:, lo:lo + rows, :rep].astype(jnp.float32)
+        scores = jnp.einsum("bqhd,bkd->bhqk", qs,
+                            k[:, :, 0].astype(jnp.float32)) * d ** -0.5
+        scores = jnp.where(keep[:, None, lo:lo + rows], scores, -1e30)
+        probs = jax.nn.softmax(scores, -1)
+        return jnp.einsum("bhqk,bkd->bqhd", probs,
+                          v[:, :, 0].astype(jnp.float32))
+
+    def gap(a, want):
+        a, want = (np.asarray(x, np.float32) for x in (a, want))
+        check(np.isfinite(a).all(), "non-finite sparse-attention result")
+        return float(np.linalg.norm(a - want) / (np.linalg.norm(want)
+                                                + 1e-6))
+
+    errs = [gap(got[:, lo:lo + rows, :rep], jax.jit(dense_rows,
+                                                     static_argnums=0)(lo))
+            for lo in (0, s - rows)]
+    gs = sz["sparse_grad_seq"]
+    qi2, ki2, w2, q2, k2, v2, ct2 = operands(gs, 19)
+    words2 = jax.jit(functools.partial(sa.select_keys, topk=topk,
+                                       impl="dense"))(qi2, ki2, w2)
+    apart = int(jnp.sum(jax.lax.population_count(select(qi2, ki2, w2)
+                                                 ^ words2)))
+    kept = sa.selected(gs, topk)
+    check(apart <= 1e-3 * kept, f"index kernel vs the dense selection: "
+          f"{apart} bits apart of {kept}")
+
+    def grads(impl):
+        def loss(q, k, v):
+            out = sa.sparse_attend(q, k, v, words2, topk, impl=impl)
+            return jnp.sum(out.astype(jnp.float32) * ct2)
+        return jax.jit(jax.grad(loss, (0, 1, 2)))(q2, k2, v2)
+
+    errs += [gap(a, want) for a, want in zip(grads(flash), grads("dense"))]
+    check(max(errs) < 0.03, f"sparse kernels vs the dense path: {errs}")
+    out = {"sparse_select_bits_apart": apart, "sparse_selected": kept,
+           "sparse_rel_err_out_first_last_dq_dk_dv": [round(e, 5)
+                                                      for e in errs]}
+    if on_chip():
+        def fwd_bwd(q, k, v, wd):
+            return jax.grad(lambda q, k, v: jnp.sum(sa.sparse_attend(
+                q, k, v, wd, topk, impl="flash").astype(jnp.float32)
+                * ct), (0, 1, 2))(q, k, v)
+        out["sparse_ms_select_fwd_fwd_bwd"] = [
+            round(1e3 * statistics.median(round_trips(f, *xs, n=5)), 3)
+            for f, xs in ((select, (qi, ki, w)), (attend, (q, k, v, words)),
+                          (fwd_bwd, (q, k, v, words)))]
+    return out
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rehearse", action="store_true",
@@ -888,7 +986,8 @@ def main():
               ("kda", phase_kda, (sz,)),
               ("kda_softplus", phase_kda_softplus, (sz,)),
               ("ssd", phase_ssd, (sz,)),
-              ("window", phase_window, (sz,))]
+              ("window", phase_window, (sz,)),
+              ("sparse", phase_sparse, (sz,))]
     if len(devices) == 4:
         phases.append(("four_chip_llm", phase_four_chip_llm, (sz,)))
     only = set(filter(None, opts.only.split(",")))

@@ -615,6 +615,19 @@ def record_flash_window(window: int, block_share: float, sink: bool,
                    ).set(float(heads_per_step))
 
 
+def record_sparse_plan(computed_over_selected: float) -> None:
+    """The plan of the sparse attention call just traced (host side, once
+    a trace; ``llm/sparse_attention.py::sparse_attend``): the score
+    positions its kernels compute a head, every causal block whole, over
+    those the selection keeps."""
+    if not _cfg["enabled"]:
+        return
+    REGISTRY.gauge("fed_sparse_computed_over_selected",
+                   "score positions the last traced sparse attention "
+                   "call's kernels compute / those its selection keeps"
+                   ).set(float(computed_over_selected))
+
+
 def record_recompile(program: str) -> None:
     """Recompile forensics: a program compiled PAST its pinned
     expectation (the steady-state invariant is zero)."""

@@ -51,6 +51,9 @@ SCOPES = (
     "attn.linear",        # projection
     "attn.ssm",           # a whole Mamba-2 mixer: projections, convolution,
                           # gates, the SSD kernels, the grouped norm
+    "attn.sparse",        # a whole sparse attention module but its indexer
+    "attn.index",         # the indexer: its projections, norm, rotary, the
+                          # index scores and the selection
     "mlp",                # the dense MLP and the shared expert
     "moe.route",          # the router, top-k, the gates
     "moe.experts",        # the plan, the grouped products, the slot sums

@@ -294,11 +294,46 @@ def _kimi_linear_fields(config: dict, layers: int) -> dict:
                        fields["n_routed_experts"]))
 
 
+def _keye_vl2_fields(config: dict, layers: int) -> dict:
+    """``KeyeVL2``'s language model: in every layer grouped-query
+    attention with an RMSNorm a head on q and k over the keys a lightning
+    indexer selects (``sa_config``), then softmax-routed experts
+    (``decoder_sparse_step`` 1, no ``mlp_only_layers``) without a shared
+    one. ``mrope_section`` gives a text token one position in each of its
+    sections, which then turn as one plain rotary over the whole head."""
+    get = config.get
+    sa = dict(get("sa_config") or {})
+    if int(sa.get("indexer_num_kv_heads", 1)) != 1:
+        raise NotImplementedError(
+            f"indexer_num_kv_heads {sa['indexer_num_kv_heads']}: the "
+            "indexer is built with one key head")
+    if int(get("decoder_sparse_step", 1)) != 1 or get("mlp_only_layers"):
+        raise NotImplementedError(
+            "decoder_sparse_step != 1 or mlp_only_layers: every layer is "
+            "built with experts")
+    if get("use_sliding_window"):
+        raise NotImplementedError("use_sliding_window: the layers are "
+                                  "built without a window")
+    fields = _read(config, _EXPERTS)
+    head = int(get("head_dim") or 0)
+    sections = (get("rope_scaling") or {}).get("mrope_section")
+    if sections and 2 * sum(sections) != head:
+        raise NotImplementedError(
+            f"mrope_section {sections} covers {2 * sum(sections)} of a "
+            f"head's {head} dims: a text token's rotary is built over all")
+    return dict(fields, **_head_sizes(config), router_scoring="softmax",
+                qk_norm=True, index_heads=int(sa["indexer_num_heads"]),
+                index_head_dim=int(sa["indexer_head_dim"]),
+                index_topk=int(sa["topk"]),
+                layers=("sparse+moe",) * layers)
+
+
 # a published ``model_type`` -> the key map of its family
 _FAMILIES = {"axk1": _deepseek_fields, "bailing_hybrid": _bailing_fields,
              "mimo_v2_flash": _mimo_v2_flash_fields,
              "nemotron_h": _nemotron_h_fields,
-             "kimi_linear": _kimi_linear_fields}
+             "kimi_linear": _kimi_linear_fields,
+             "KeyeVL2": _keye_vl2_fields}
 
 
 def _family(config: dict):
@@ -334,6 +369,8 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
     if (get("n_routed_experts") or get("num_experts")) and score != "sigmoid":
         raise NotImplementedError(f"scoring_func {score!r}")
     scaling = get("rope_scaling")
+    if scaling and scaling.get("type", scaling.get("rope_type")) == "default":
+        scaling = None      # the plain rotary
     if scaling and scaling.get("mscale", 1) != scaling.get("mscale_all_dim", 1):
         raise NotImplementedError("rotary cos/sin scale mscale / "
                                   "mscale_all_dim != 1")
@@ -356,6 +393,7 @@ def llm_config_from_hf(config: dict, *, max_seq_len: int,
         tie_embeddings=bool(get("tie_word_embeddings", False)),
         rope_scaling=dict(scaling) if scaling else None,
         first_expert=int(first_expert), experts_held=int(experts_held),
+        remat=bool(get("gradient_checkpointing", False)),
         **_family(config)(config, layers))
 
 

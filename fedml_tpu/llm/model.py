@@ -25,6 +25,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..core.obs.scopes import scope
 
@@ -67,9 +68,10 @@ class LLMConfig:
     # what each layer is, one entry a layer (empty: ``num_layers`` x
     # ``full+mlp``): a mixer and a feed-forward, ``mixer+ff``, or a mixer
     # alone (:class:`DecoderLayer`). Mixers: ``full`` / ``window``
-    # grouped-query softmax attention, ``latent`` latent attention,
-    # ``linear`` Kimi delta attention, ``ssm`` Mamba-2, ``moe`` the expert
-    # block; feed-forwards ``mlp`` (dense) and ``moe``
+    # grouped-query softmax attention, ``sparse`` grouped-query attention
+    # over the keys a lightning indexer selects, ``latent`` latent
+    # attention, ``linear`` Kimi delta attention, ``ssm`` Mamba-2, ``moe``
+    # the expert block; feed-forwards ``mlp`` (dense) and ``moe``
     layers: Tuple[str, ...] = ()
     # the expert block: each token routed to ``num_experts_per_tok`` of
     # ``n_routed_experts`` SwiGLU experts of ``moe_intermediate_size``
@@ -146,6 +148,22 @@ class LLMConfig:
     shared_expert_size: int = 0
     # False: attention without rotary or any other position term
     use_rope: bool = True
+    # the router's scores: ``sigmoid`` of each logit, or ``softmax`` over
+    # all ``n_routed_experts`` logits (both in float32)
+    router_scoring: str = "sigmoid"
+    # learned sparse attention (``sparse`` layers): grouped-query heads with
+    # an RMSNorm a head on q and k where ``qk_norm``; a lightning indexer of
+    # ``index_heads`` heads of ``index_head_dim`` and one index key head
+    # picks the ``index_topk`` keys each query attends to
+    qk_norm: bool = False
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # rematerialize each layer in the backward pass: a layer keeps its input
+    # (and a sparse layer its selection) for the backward and computes the
+    # rest of its forward again there, so the saved activations no longer
+    # grow with the layers held
+    remat: bool = False
 
     def __post_init__(self):
         self.layers = tuple(self.layers)
@@ -411,6 +429,93 @@ def _head_gate(out, logits):
     return (out.astype(jnp.float32) * gate).astype(out.dtype)
 
 
+class _LayerNorm(nn.Module):
+    """LayerNorm over the last axis with a weight and a bias, in float32."""
+
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        width = x.shape[-1]
+        scale = self.param("scale", nn.initializers.ones, (width,))
+        bias = self.param("bias", nn.initializers.zeros, (width,))
+        xf = x.astype(jnp.float32)
+        mu = jnp.mean(xf, -1, keepdims=True)
+        var = jnp.mean(jnp.square(xf - mu), -1, keepdims=True)
+        y = (xf - mu) * jax.lax.rsqrt(var + self.eps)
+        return (y * scale.astype(jnp.float32)
+                + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+class SparseAttention(nn.Module):
+    """Grouped-query attention over the keys a lightning indexer selects
+    (DeepSeek-V3.2-Exp's sparse attention; ``llm/sparse_attention.py``).
+    ``q = x W_q``, ``k = x W_k``, ``v = x W_v`` as heads of ``head_dim``;
+    with ``qk_norm`` an RMSNorm a head over q and k; rotary on every dim.
+    The indexer reads ``x`` with no gradient: ``qi = rot(x W_iq)`` as
+    ``index_heads`` heads of ``index_head_dim``, ``ki = rot(LayerNorm(x
+    W_ik))`` one head, the rotary on the first half of an index head's
+    dims at ``rope_theta``, ``w = x W_iw * (index_heads * index_head_dim)
+    ** -0.5`` in float32; query ``t`` keeps the ``min(index_topk, t + 1)``
+    keys of the largest ``sum_j w_j relu(qi_j . ki)``, exactly, and every
+    query head attends to those alone at ``head_dim ** -0.5``. Adapters on
+    ``q k v o``; the norms and the indexer are frozen. Training path only:
+    ``llm/kv_cache.py`` holds no index key a position."""
+
+    cfg: LLMConfig
+
+    @nn.compact
+    def __call__(self, x, positions, attn_mask=None, kv_view=None,
+                 adapter=None, lora_scale: float = 1.0):
+        if kv_view is not None or attn_mask is not None:
+            raise NotImplementedError(
+                "sparse attention has no cache path and no key mask: "
+                "llm/kv_cache.py holds no index key a position, and a "
+                "decode step selects among the cached keys by them")
+        from .sparse_attention import select_keys, sparse_attend
+
+        cfg = self.cfg
+        b, s, _ = x.shape
+        d, heads, width = cfg.head_dim, cfg.index_heads, cfg.index_head_dim
+        dense = functools.partial(_dense, cfg)
+        qkv = _add_lora(x, {
+            "q": dense((cfg.num_heads, d), "q")(x),
+            "k": dense((cfg.kv_heads, d), "k")(x),
+            "v": dense((cfg.kv_heads, d), "v")(x),
+        }, adapter, lora_scale)
+        q, k = qkv["q"], qkv["k"]
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.rms_eps, name="q_norm")(q)
+            k = RMSNorm(cfg.rms_eps, name="k_norm")(k)
+        freq = rope_frequencies(d, cfg.rope_theta, cfg.rope_scaling)
+        q, k = _rope(q, positions, freq), _rope(k, positions, freq)
+        with scope("attn.index"):
+            xs = jax.lax.stop_gradient(x)
+            qi = dense((heads, width), "index_q")(xs)
+            ki = _LayerNorm(cfg.rms_eps, name="index_norm")(
+                dense(width, "index_k")(xs))[:, :, None, :]
+            w = nn.DenseGeneral(heads, use_bias=False, name="index_w",
+                                dtype=jnp.float32, param_dtype=jnp.float32)(
+                xs.astype(jnp.float32)) * (heads * width) ** -0.5
+            rot = width // 2
+            ifreq = rope_frequencies(rot, cfg.rope_theta)
+            qi, ki = (jnp.concatenate(
+                [_rope(a[..., :rot], positions, ifreq), a[..., rot:]], -1)
+                for a in (qi, ki))
+            words = select_keys(qi, ki[:, :, 0], w, cfg.index_topk,
+                                impl=cfg.attention_impl)
+            # kept by a rematerialized layer (``LLMConfig.remat``): the
+            # backward reads the selection and does not run the indexer
+            words = checkpoint_name(words, "selection")
+        # the selection's bits, for a caller that asks for intermediates
+        self.sow("intermediates", "selection", words)
+        out = sparse_attend(q, k, qkv["v"], words, cfg.index_topk,
+                            impl=cfg.attention_impl)
+        out = out.reshape(b, s, cfg.num_heads * d)
+        y = dense(cfg.hidden_size, "o")(out)
+        return _add_lora(out, {"o": y}, adapter, lora_scale)["o"], None
+
+
 class LinearAttention(nn.Module):
     """Kimi delta attention (``llm/linear_attention.py``): ``q, k, v =
     SiLU(conv(x W))`` through a causal depthwise convolution, ``q`` and
@@ -598,7 +703,9 @@ class MLP(nn.Module):
 
 class MoE(nn.Module):
     """``shared(x) + sum over the top-k experts this rank holds of g_e
-    E_e(x)`` (the routed sum alone where ``n_shared_experts`` is 0): sigmoid scores in float32 over ALL ``n_routed_experts``, plain
+    E_e(x)`` (the routed sum alone where ``n_shared_experts`` is 0): sigmoid
+    (or, by ``router_scoring``, softmax) scores in float32 over ALL
+    ``n_routed_experts``, plain
     top-k (group-limited with a score-correction bias where ``n_group`` or
     ``router_bias`` is set: ``moe.route``), the chosen scores normalised
     and scaled; the rank computes the
@@ -649,7 +756,7 @@ class MoE(nn.Module):
             gates, chosen = moe.route(logits, cfg.num_experts_per_tok,
                                       cfg.routed_scaling_factor,
                                       cfg.norm_topk_prob, bias, cfg.n_group,
-                                      cfg.topk_group)
+                                      cfg.topk_group, cfg.router_scoring)
 
         def latent(t, feats, name):
             """One of the two projections around the routed experts, its
@@ -800,6 +907,7 @@ _MIXERS = {
     "latent": (LatentAttention, "attn.latent"),
     "linear": (LinearAttention, "attn.linear"),
     "ssm": (Mamba2, "attn.ssm"),
+    "sparse": (SparseAttention, "attn.sparse"),
     "moe": (MoE, None),
 }
 _FEED_FORWARDS = {"mlp": MLP, "moe": MoE}
@@ -901,14 +1009,17 @@ class CausalLM(nn.Module):
                 if ax is not None:
                     pos = pos + jax.lax.axis_index(ax[0]) * tokens.shape[1]
             positions = jnp.broadcast_to(pos[None, :], tokens.shape)
+        layer = DecoderLayer
+        if cfg.remat:
+            layer = nn.remat(DecoderLayer, policy=jax.checkpoint_policies
+                             .save_only_these_names("selection"))
         new_kvs = []
         for i, kind in enumerate(cfg.plan):
-            x, new_kv = DecoderLayer(cfg, kind, name=f"layer_{i}")(
+            x, new_kv = layer(cfg, kind, name=f"layer_{i}")(
                 x, positions, attn_mask,
-                kv_view=None if kv_view is None else kv_view[i],
-                adapter=None if adapters is None
-                else adapters.get(f"layer_{i}"),
-                lora_scale=lora_scale)
+                None if kv_view is None else kv_view[i],
+                None if adapters is None else adapters.get(f"layer_{i}"),
+                lora_scale)
             new_kvs.append(new_kv)
         with scope("head"):
             x = RMSNorm(cfg.rms_eps, name="ln_f")(x)

@@ -75,10 +75,11 @@ class Rows(NamedTuple):
 
 def route(scores_in: jnp.ndarray, top_k: int, scaling: float,
           norm_topk: bool = True, bias=None, n_group: int = 0,
-          topk_group: int = 0):
+          topk_group: int = 0, scoring: str = "sigmoid"):
     """``scores_in`` [T, E] router logits -> (gates [T, k] float32, experts
-    [T, k] int32): sigmoid scores in float32, top-k over all experts, the
-    chosen scores normalised to sum 1 and scaled.
+    [T, k] int32): sigmoid scores in float32 (``scoring`` ``softmax``: a
+    softmax over all E logits, a static branch), top-k over all experts,
+    the chosen scores normalised to sum 1 and scaled.
 
     With ``bias`` [E] (a score-correction bias) or ``n_group > 1`` the
     choice is DeepSeek-V3's ``noaux_tc``: experts are chosen by ``s + b``,
@@ -86,7 +87,11 @@ def route(scores_in: jnp.ndarray, top_k: int, scaling: float,
     best ``s + b``, the best ``topk_group`` groups stay and the top-k is
     taken among their experts; the gates come from ``s`` without ``b``.
     Without either (a static branch) this is plain top-k on ``s``."""
-    s = jax.nn.sigmoid(scores_in.astype(jnp.float32))
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"router scoring {scoring!r}: sigmoid or softmax")
+    s = (jax.nn.softmax(scores_in.astype(jnp.float32), -1)
+         if scoring == "softmax" else
+         jax.nn.sigmoid(scores_in.astype(jnp.float32)))
     if bias is None and n_group <= 1:
         vals, idx = jax.lax.top_k(s, top_k)
     else:

@@ -83,7 +83,9 @@ def _step(cfg, lc):
 # inverse and scores that the forward pass kept, which no other model
 # has); the Nemotron pair as commit 882ce0d lowers it: this step takes the
 # ``dense`` path, and only ``flash`` runs the fused passes of
-# ``llm/state_space.py``; the Kimi pair as commit d86eeb7 lowers it.
+# ``llm/state_space.py``; the Kimi pair as commit d86eeb7 lowers it; the
+# Keye pair (sparse attention, softmax routing) as the commit that brought
+# it lowers it.
 _ACCEPTED = {
     ("mistral7b_lora_silo2", "float32"):
         "61f778a13edfff89801bb55b63d5146bd9377814d1580a6d2204001bc7971871",
@@ -109,6 +111,10 @@ _ACCEPTED = {
         "0c30295415f7a69785e4588c76af2b28aae8da4fec9fade162602f8834a41385",
     ("kimi_linear_lora_silo2_seq4096", "bfloat16"):
         "25e3479b46c15960d918e16f7b2a7c3daa1c82c9bc43e306f061b8df3abc9379",
+    ("keye_vl2_lora_silo2_seq16384", "float32"):
+        "6859fc46f905e5e00537d9f1c2e039cbd5968b4cd64f449db67911fcd0983461",
+    ("keye_vl2_lora_silo2_seq16384", "bfloat16"):
+        "be4b7162f1e6981ac66869a6a0d461900aa41826b75cb01d7eeac682e25ba01e",
 }
 
 
@@ -151,7 +157,8 @@ def _without_kernel_locations(text):
 # the step of each cell as the chip runs it: bfloat16 on the ``flash``
 # path at the cell's own sizes, sequence and rows a step, lowered for a
 # v5e with its Pallas kernels as Mosaic modules (printed without
-# locations), as commit d86eeb7 lowers it
+# locations), as commit d86eeb7 lowers it (the Keye cell's as the commit
+# that brought it lowers it)
 _ACCEPTED_FLASH = {
     "mistral7b_lora_silo2":
         "2c908feb7d21f45805a31dba70e716deeaa65086f321b831aed0f99b46f53747",
@@ -165,6 +172,8 @@ _ACCEPTED_FLASH = {
         "8c4f0426616b5c9afd010f88753fe30582e4f1d833738f119db43ba3f6421adb",
     "kimi_linear_lora_silo2_seq4096":
         "df4f2e9d782ca1e823483f722d6a148e3ca86feb6de3922d9514d2e5108a3ac4",
+    "keye_vl2_lora_silo2_seq16384":
+        "dd3aef224823149f3dd665a8e4f2027aee5ce1cc70cd1339af355c76e7caaf59",
 }
 
 

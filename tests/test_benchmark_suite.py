@@ -8,9 +8,10 @@ the files have them) as ``<file>__<test>``, so each counts and names
 itself when it fails. The tests that run whole rounds or subprocesses
 (``test_correct.py``, ``test_rehearsal.py``, the first four of
 ``test_axk1.py``, the first two of ``test_ling3.py``, of ``test_mimo.py``,
-of ``test_nemotron.py`` and of ``test_kimi.py``: over a minute each
-on a CPU) stay outside tier-1, as does ``test_kimi.py``'s check against the
-model catalog, which reads it from ``MODEL_CATALOG``.
+of ``test_nemotron.py``, of ``test_kimi.py`` and of ``test_keye.py``: over
+a minute each on a CPU) stay outside tier-1, as do ``test_kimi.py``'s and
+``test_keye.py``'s checks against the model catalog, which read it from
+``MODEL_CATALOG``.
 """
 
 import importlib
@@ -57,6 +58,10 @@ _TAKEN = {
     "test_kimi": ("test_manifest_entries_are_as_stated",
                   "test_work_functions_against_hand_counts",
                   "test_steep_share_reader_reads_the_counters_or_nothing"),
+    "test_keye": ("test_manifest_entries_are_as_stated",
+                  "test_work_functions_against_hand_counts",
+                  "test_sparse_reader_finds_its_kernels_by_name_only",
+                  "test_ratio_reader_reads_the_gauge_or_nothing"),
 }
 _LEFT_OUT = {
     # pins PR 26's five entries as the LAST five of BENCHMARK.json's
@@ -83,6 +88,10 @@ _LEFT_OUT = {
     # model_config PR do: the test of the same name below runs the file's
     # test unedited on the benchmark as it stood up to the Nemotron cell
     ("test_nemotron", "test_manifest_entries_are_the_issues"),
+    # the same for the Kimi cell, whose test pins its metric as the last of
+    # per_layer and its cell as the last of each list it joined: the
+    # Keye cell appended itself and four entries after them
+    ("test_kimi", "test_manifest_entries_are_as_stated"),
 }
 
 
@@ -164,6 +173,20 @@ def test_nemotron__test_manifest_entries_are_the_issues(monkeypatch):
     test_nemotron.test_manifest_entries_are_the_issues()
 
 
+def test_kimi__test_manifest_entries_are_as_stated(monkeypatch):
+    """``benchmarks/tests/test_kimi.py``'s test of its manifest entries,
+    unedited, on the benchmark as it stood up to the Kimi cell (its
+    configuration and its last per-layer entry), as the Nemotron one
+    above runs."""
+    sys.path.insert(0, _BENCH_TESTS)
+    import test_kimi
+    manifest = test_kimi.manifest
+    whole = manifest.benchmark()
+    monkeypatch.setattr(manifest, "benchmark", lambda: _up_to(
+        whole, test_kimi.CELL, test_kimi.CONFIG, "kda_steep_decay_share"))
+    test_kimi.test_manifest_entries_are_as_stated()
+
+
 def test_expert_layer_metrics_list_the_cells_with_experts():
     """Every metric of the expert layer lists the cells whose configuration
     has routed experts, the axk1 cell first as PR 29 left it, and no
@@ -234,10 +257,26 @@ def test_the_scope_entries_are_as_accepted_but_for_their_cells():
     linear = [c for c in lm if cfgs[c].get("layer_group_size")
               or cfgs[c].get("linear_attn_config")]
     window = [c for c in lm if cfgs[c].get("hybrid_layer_pattern")]
-    # grouped-query softmax layers: what is neither latent, linear nor a
-    # single-mixer stack has them in every layer; a stack where it says *
+    sparse = [c for c in lm if cfgs[c].get("sa_config")]
+    # grouped-query softmax layers: what is neither latent, linear, sparse
+    # nor a single-mixer stack has them in every layer; a stack where it
+    # says *
     full = [c for c in lm if (c not in latent and c not in linear
-                              and not mixers[c]) or "*" in mixers[c]]
+                              and c not in sparse and not mixers[c])
+            or "*" in mixers[c]]
+
+    def has_mlp(cfg):
+        """A dense feed-forward somewhere: no experts, a shared expert, or
+        layers before or between the expert ones."""
+        freq = cfg.get("moe_layer_freq", 1)
+        return (not any(k in cfg for k in ("n_routed_experts",
+                                           "num_experts"))
+                or any(cfg.get(k) for k in (
+                    "n_shared_experts", "num_shared_experts",
+                    "moe_shared_expert_intermediate_size"))
+                or bool(cfg.get("first_k_dense_replace"))
+                or (isinstance(freq, list) and 0 in freq)
+                or bool(cfg.get("mlp_only_layers")))
     train = "local training / LLM train step"
     want = {"scope_engine_ms": ("round engine", cells),
             "scope_conv_ms": (train, resnet),
@@ -246,14 +285,17 @@ def test_the_scope_entries_are_as_accepted_but_for_their_cells():
             "scope_attn_window_ms": (train, window),
             "scope_attn_latent_ms": (train, latent),
             "scope_attn_linear_ms": (train, linear),
-            "scope_mlp_ms": (train, lm), "scope_head_ms": (train, lm),
+            "scope_mlp_ms": (train, [c for c in lm if has_mlp(cfgs[c])]),
+            "scope_head_ms": (train, lm),
             "scope_lora_ms": (train, lm),
             "scope_moe_route_ms": ("expert layer", experts),
             "scope_moe_experts_ms": ("expert layer", experts),
             "scope_unscoped_share": ("device", cells),
             "scope_attn_ssm_ms": (train, [c for c in lm if "M" in mixers[c]]),
             "scope_moe_latent_ms": ("expert layer", [
-                c for c in experts if cfgs[c].get("moe_latent_size")])}
+                c for c in experts if cfgs[c].get("moe_latent_size")]),
+            "scope_attn_sparse_ms": (train, sparse),
+            "scope_attn_index_ms": (train, sparse)}
     entries = [m for m in bench["per_layer"]
                if m["name"].startswith("scope_")]
     assert [m["name"] for m in entries] == list(want)

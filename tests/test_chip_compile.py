@@ -24,6 +24,9 @@ from fedml_tpu.llm.attention import (FLASH_KERNEL_NAMES, WINDOW_KERNEL_NAMES,
                                      flash_causal_attention)
 from fedml_tpu.llm.linear_attention import (KDA_KERNEL_NAMES, KDA_PASS_NAMES,
                                             kda_attention, kda_layer)
+from fedml_tpu.llm.sparse_attention import (SPARSE_KERNEL_NAMES,
+                                            select_keys, sparse_attend,
+                                            word_count)
 from fedml_tpu.llm.state_space import (SSD_KERNEL_NAMES, SSM_PASS_NAMES,
                                        ssd_scan, ssm_layer)
 
@@ -444,6 +447,71 @@ def test_the_unbounded_kda_layer_compiles_for_v5e(v5e, masked):
     assert text.count("tpu_custom_call") == 6
     for name in KDA_KERNEL_NAMES + KDA_PASS_NAMES:
         assert name in text, name
+
+
+def _sparse_step(qi, ki, w, q, k, v):
+    """The Keye cell's sparse attention layer between its products: the
+    selection, then the attention forward and its gradients."""
+    words = select_keys(qi, ki, w, 2048, impl="flash")
+    return jax.value_and_grad(
+        lambda q, k, v: sparse_attend(q, k, v, words, 2048,
+                                      impl="flash").astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+def _sparse_avals(s=16384):
+    bf = jnp.bfloat16
+    return (jax.ShapeDtypeStruct((1, s, 16, 64), bf),
+            jax.ShapeDtypeStruct((1, s, 64), bf),
+            jax.ShapeDtypeStruct((1, s, 16), jnp.float32),
+            jax.ShapeDtypeStruct((1, s, 32, 128), bf),
+            jax.ShapeDtypeStruct((1, s, 4, 128), bf),
+            jax.ShapeDtypeStruct((1, s, 4, 128), bf))
+
+
+def test_sparse_kernels_compile_for_v5e(v5e):
+    """The Keye cell's shape (16,384 positions, 32 query heads on 4 of 128,
+    16 index heads of 64, 2,048 keys a query, bfloat16): the index kernel
+    with its row of 16,384 sortable keys in VMEM, and the attention
+    kernels forward and backward, compiled by Mosaic under their names;
+    the selection leaves as 32 MiB of bits and no score-sized buffer is
+    made."""
+    compiled = _compile(_sparse_step, v5e, *_sparse_avals())
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4
+    for name in SPARSE_KERNEL_NAMES:
+        assert name in text, name
+    assert word_count(16384) == 512
+    assert "s32[1,16384,512]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16384 * 16384 * 4
+
+
+def test_only_the_sparse_kernels_read_as_sparse_kernels(v5e):
+    """``sparse_kernels_roofline`` finds its four kernels by a substring of
+    an instruction's name, and ``flash_kernels_roofline`` takes none of
+    them."""
+    import importlib.util
+    import os
+    import re
+
+    def metric(name):
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "metrics",
+            name + ".py")
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    sparse, flash = (metric("sparse_kernels_roofline"),
+                     metric("flash_kernels_roofline"))
+    text = _compile(_sparse_step, v5e, *_sparse_avals(1024)).as_text()
+    names = re.findall(r"^\s*%?([\w.\-]+) = .*custom_call_target="
+                       r"\"tpu_custom_call\"", text, re.M)
+    assert len(names) == 4, names
+    assert sorted(map(sparse.kind_of, names)) == ["dkv", "dq", "fwd",
+                                                  "index"]
+    assert not any(map(flash.kind_of, names))
 
 
 def test_flash_bwd_never_materializes_scores(v5e):

@@ -33,7 +33,7 @@ def test_scope_refuses_a_name_outside_the_vocabulary():
     for name in ("attn", "attn.window.kernel", "Attn.Full", ""):
         with pytest.raises(ValueError, match="unknown scope"):
             scopes.scope(name)
-    assert len(set(scopes.SCOPES)) == len(scopes.SCOPES) == 22
+    assert len(set(scopes.SCOPES)) == len(scopes.SCOPES) == 24
 
 
 @pytest.mark.parametrize("op_name, want", [
@@ -51,6 +51,9 @@ def test_scope_refuses_a_name_outside_the_vocabulary():
     ("jit(f)/local.grad/transpose(jvp(CausalLM))/layer_2/attn.linear/attn/"
      "kda_bwd/pallas_call", "attn.linear"),
     ("jit(f)/vmap(jvp(norm))/rematted_computation/mul", "norm"),
+    # the indexer inside a sparse attention module is the indexer's
+    ("jit(f)/local.grad/jvp(CausalLM)/layer_0/attn.sparse/attn/attn.index/"
+     "sparse_index/pallas_call", "attn.index"),
     # a flax module called like a scope is the same thing; one that only
     # contains a scope's name is not
     ("jit(f)/CausalLM/embed.attend/dot_general", None),
